@@ -1,20 +1,13 @@
-"""Version shims for the narrow band of jax APIs that moved homes,
-plus the ONE backend-selection convention every Pallas-vs-XLA fork in
-this repo follows (``backend_is_tpu``).
-
-``shard_map`` graduated from ``jax.experimental.shard_map`` to the
-top-level ``jax.shard_map`` namespace; this repo targets both sides of
-that move (the CI image pins an older jaxlib than some deploy targets).
-Import it from here everywhere — the shim prefers the top-level export
-and falls back to the experimental module, defaulting ``check_rep`` off
-there to match the graduated API's behavior (the experimental checker
-rejects some replication patterns the final API accepts).
-
-``pltpu.CompilerParams`` was named ``TPUCompilerParams`` before the
-rename; ``tpu_compiler_params`` resolves whichever this jax ships.
+"""The repo-wide JAX conventions: which backend a traced program is for
+(``backend_is_tpu``), how a kernel-or-reference choice is put on record
+(``note_path`` / ``record_paths``), and how ``shard_map`` is called.
 """
 
 from __future__ import annotations
+
+import contextlib
+import contextvars
+import os
 
 import jax
 
@@ -34,51 +27,57 @@ def backend_is_tpu() -> bool:
     return jax.default_backend() == "tpu"
 
 
-def axis_size(axis_name) -> int:
-    """``jax.lax.axis_size`` where this jax ships it; the classic
-    ``psum(1, axis)`` counting identity otherwise (exact — it is what
-    the primitive lowers to)."""
-    if hasattr(jax.lax, "axis_size"):
-        return jax.lax.axis_size(axis_name)
-    return jax.lax.psum(1, axis_name)
+def enable_compile_cache() -> str:
+    """Turn on JAX's persistent compilation cache for an entry point of
+    this checkout (``bench.py``, ``chip_smoke.py``, ``tests/``) and
+    return its directory. Where ``JAX_COMPILATION_CACHE_DIR`` is set
+    JAX already uses it and nothing is set here; otherwise the cache is
+    ``<repo>/.jax_cache`` — a fixed path inside the checkout, because
+    the path is part of the cache key and a directory that moves never
+    hits."""
+    path = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if not path:
+        path = os.path.join(
+            os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
+            ".jax_cache")
+        jax.config.update("jax_compilation_cache_dir", path)
+    return path
 
 
-def cost_analysis(compiled) -> dict:
-    """``compiled.cost_analysis()`` normalized across jax versions: older
-    releases return a one-element LIST of per-computation dicts, newer
-    ones the dict itself. Always returns a dict ({} when unavailable)."""
-    ca = compiled.cost_analysis()
-    if isinstance(ca, (list, tuple)):
-        ca = ca[0] if ca else {}
-    return ca or {}
+_PATHS = contextvars.ContextVar("kernel_paths", default=None)
 
 
-def tpu_compiler_params(**kwargs):
-    """``pltpu.CompilerParams(**kwargs)`` under whichever name this jax
-    version exports (older: ``TPUCompilerParams``)."""
-    from jax.experimental.pallas import tpu as pltpu
-    cls = getattr(pltpu, "CompilerParams", None) \
-        or getattr(pltpu, "TPUCompilerParams")
-    return cls(**kwargs)
-
-try:  # jax >= 0.4.38-ish: top-level export
-    _shard_map = jax.shard_map
-except AttributeError:
-    _shard_map = None
+def note_path(site: str, path: str) -> None:
+    """Called where traced code picks a Pallas kernel or its XLA
+    reference (``site``: which op; ``path``: what it picked and, for a
+    reference, why). A no-op unless a :func:`record_paths` scope is
+    open around the trace."""
+    log = _PATHS.get()
+    if log is not None:
+        log.add(f"{site}={path}")
 
 
-def shard_map(f, *, mesh, in_specs, out_specs, **kwargs):
-    """``jax.shard_map`` where available, the experimental one otherwise.
-    The graduated API renamed ``check_rep`` to ``check_vma``; accept
-    either spelling and translate to whichever implementation is live."""
-    if _shard_map is not None:
-        if "check_rep" in kwargs:
-            kwargs["check_vma"] = kwargs.pop("check_rep")
-        return _shard_map(f, mesh=mesh, in_specs=in_specs,
-                          out_specs=out_specs, **kwargs)
-    from jax.experimental.shard_map import shard_map as legacy
-    if "check_vma" in kwargs:
-        kwargs["check_rep"] = kwargs.pop("check_vma")
-    kwargs.setdefault("check_rep", False)
-    return legacy(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                  **kwargs)
+@contextlib.contextmanager
+def record_paths():
+    """Collect the :func:`note_path` calls made while a program is
+    traced: yields the set they land in. The serving engine opens one
+    around each of its jitted programs, so "kernel or reference" is
+    read from ``health()`` instead of inferred from the backend."""
+    log: set = set()
+    token = _PATHS.set(log)
+    try:
+        yield log
+    finally:
+        _PATHS.reset(token)
+
+
+def shard_map(f, *, mesh, in_specs, out_specs, check_vma=False, **kwargs):
+    """``jax.shard_map`` with the varying-manual-axes check OFF unless a
+    caller asks for it. The check rejects two things this repo does on
+    purpose: a ``pallas_call`` whose ``out_shape`` carries no ``vma``
+    (every kernel in ``ops/``) and a custom-VJP backward that returns a
+    device-varying cotangent for a replicated parameter (BatchNorm's
+    cross-replica statistics). Import ``shard_map`` from here
+    everywhere so the decision is made once."""
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=check_vma, **kwargs)

@@ -26,7 +26,7 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 
-from distkeras_tpu.compat import axis_size, backend_is_tpu
+from distkeras_tpu.compat import backend_is_tpu, note_path
 from distkeras_tpu.models.core import (Layer, layer_from_spec, layer_spec,
                                        register_layer)
 from distkeras_tpu.models.layers import Dropout, get_activation, init_weights
@@ -99,7 +99,7 @@ class PositionalEmbedding(Layer):
         if self.seq_axis_name and self._axis_bound():
             # fail loudly if the table can't cover the GLOBAL sequence —
             # dynamic_slice would silently clamp out-of-range shard starts
-            global_len = s * axis_size(self.seq_axis_name)
+            global_len = s * jax.lax.axis_size(self.seq_axis_name)
             if global_len > self.max_len:
                 raise ValueError(
                     f"PositionalEmbedding(max_len={self.max_len}) is too "
@@ -117,7 +117,7 @@ class PositionalEmbedding(Layer):
         (e.g. unsharded eval via model.predict) the input holds the FULL
         sequence, so shard-local slicing is the correct behavior."""
         try:
-            axis_size(self.seq_axis_name)
+            jax.lax.axis_size(self.seq_axis_name)
             return True
         except NameError:
             return False
@@ -175,6 +175,7 @@ def _attention_compute(q, k, v, *, causal, impl, axis_name=None,
             q, k, v, axis_name=axis_name, causal=causal,
             impl="flash" if impl == "ulysses_flash" else "xla",
             segment_ids=segment_ids)
+    note_path("flash_attention", "xla_reference")
     return dot_product_attention(q, k, v, causal=causal, window=window,
                                  segment_ids=segment_ids)
 

@@ -61,6 +61,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
+from distkeras_tpu.compat import note_path
 from distkeras_tpu.models.core import (AUX_LOSS_KEY, Layer,
                                        register_layer)
 from distkeras_tpu.models.layers import get_activation, init_weights
@@ -327,6 +328,7 @@ class MoE(Layer):
             topi.reshape(n, k), gates.reshape(n, k), e, c)
         xt = x.reshape(n, d).astype(dt)
 
+        note_path("moe", "fused_kernel" if fused else "tokens_xla")
         if fused:
             from distkeras_tpu.ops import moe_kernels
             w1 = params["w1"].astype(dt)
@@ -467,6 +469,7 @@ class MoE(Layer):
                                            self._balance_loss(full, mask))
             return out.astype(x.dtype), new_state
 
+        note_path("moe", "dense_xla")
         probs, full, mask = self._gate_probs(x, params["gate"])  # f32
 
         xc = x.astype(dt)

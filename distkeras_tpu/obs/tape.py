@@ -32,28 +32,44 @@ from typing import Dict, Optional
 from distkeras_tpu.obs import collectors
 from distkeras_tpu.utils.profiling import now
 
-#: bf16 peak matmul throughput per chip, by device_kind substring —
-#: published TPU spec sheets (v4: 275, v5e: 197, v5p: 459,
-#: v6e/Trillium: 918 TFLOP/s bf16). Previously bench.py-private; the
-#: tape needs the same table, so bench imports it from here.
-BF16_PEAK_FLOPS = (
-    ("v6e", 918e12), ("v6", 918e12),
-    ("v5p", 459e12),
-    ("v5 lite", 197e12), ("v5e", 197e12), ("v5litepod", 197e12),
-    ("v5", 459e12),
-    ("v4", 275e12),
-)
+#: bf16 peak matmul FLOP/s per chip, keyed by the EXACT
+#: ``device_kind`` JAX reports (Google Cloud TPU documentation, per
+#: generation: v4 275, v5e 197, v5p 459, v6e/Trillium 918 TFLOP/s).
+#: A TPU that is not in the table is an error, not a guessed peak.
+BF16_PEAK_FLOPS = {
+    "TPU v4": 275e12,
+    "TPU v5 lite": 197e12,
+    "TPU v5e": 197e12,
+    "TPU v5": 459e12,
+    "TPU v5p": 459e12,
+    "TPU v6 lite": 918e12,
+    "TPU v6e": 918e12,
+}
+
+
+def peak_flops_of(platform: str, device_kind: str):
+    """bf16 peak FLOP/s for a device: the table's value on a known
+    TPU, ``None`` off-TPU (MFU is not a CPU metric), and an error for
+    a TPU the table does not know."""
+    if platform != "tpu":
+        return None
+    try:
+        return BF16_PEAK_FLOPS[device_kind]
+    except KeyError:
+        raise ValueError(
+            f"unknown TPU device_kind {device_kind!r}: add its published "
+            f"bf16 peak to obs.tape.BF16_PEAK_FLOPS (known: "
+            f"{sorted(BF16_PEAK_FLOPS)})") from None
 
 
 def detect_peak_flops():
     """``(peak_flops_or_None, device_kind)`` of device 0."""
     import jax
-    kind = jax.devices()[0].device_kind
-    low = kind.lower()
-    for sub, peak in BF16_PEAK_FLOPS:
-        if sub in low:
-            return peak, kind
-    return None, kind
+    dev = jax.devices()[0]
+    # the device's own platform, not the trace-time backend: the peak
+    # belongs to the chip that is there
+    platform = dev.platform  # lint: allow-backend-sniff
+    return peak_flops_of(platform, dev.device_kind), dev.device_kind
 
 
 class _NullTape:

@@ -51,10 +51,7 @@ import jax.numpy as jnp
 from jax import lax
 from jax.experimental import pallas as pl
 
-try:
-    from jax.experimental.pallas import tpu as pltpu
-except ImportError:  # pragma: no cover
-    pltpu = None
+from jax.experimental.pallas import tpu as pltpu
 
 from distkeras_tpu.compat import backend_is_tpu
 from distkeras_tpu.ops.attention import NEG_INF
@@ -213,12 +210,6 @@ def decode_attention(q, k, v, t, *, scale: Optional[float] = None,
             pl.BlockSpec((bh_block, block_l), lambda b, li, *_: (b, li)),
         ]
         operands += [k_scale, v_scale]
-    kwargs = {}
-    if pltpu is not None and not interpret:
-        kwargs["compiler_params"] = pltpu.CompilerParams(
-            dimension_semantics=("parallel", "arbitrary"))
-    if pltpu is None:  # pragma: no cover — no Pallas TPU support
-        raise RuntimeError("decode_attention requires Pallas TPU support")
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
         grid=grid,
@@ -234,7 +225,8 @@ def decode_attention(q, k, v, t, *, scale: Optional[float] = None,
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((bh, g, d), jnp.float32),
-        interpret=interpret,
-        **kwargs,
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary")),
+        name="decode_attention", interpret=interpret,
     )(jnp.asarray(t, jnp.int32).reshape(1), *operands)
     return out[:, :g_orig]

@@ -36,6 +36,15 @@ Structure (one ``custom_vjp`` op, ``moe_fused_experts``):
     ``_bwd_dw1`` accumulates ``dw1[e] += x_tile^T @ dz_tile`` across
     the capacity grid in a VMEM-resident f32 block.
 
+What the row gather costs on the chip (first compiled by PR 22; until
+then the kernels had only run interpreted): Mosaic slices a ref only
+at multiples of its tiling, so rows are gathered from an ``[N, 1, d]``
+32-bit view (``_gather_source``) — one XLA pass re-lays the residual
+stream out, a bf16 stream is widened to f32 for it, and the gather
+then moves 4 bytes per element instead of 2. Correct on the v5e,
+forward and backward (``chip_smoke.py``); whether it beats the
+``tokens`` path is not measured (ROADMAP S2).
+
 Numerics contract: identical routing, drop, tie-break, and NaN-masking
 semantics to ``dispatch="tokens"`` — both consume one ``_dispatch_plan``
 and mask gathered rows with ``where(keep, ..., 0)`` BEFORE the gate
@@ -45,8 +54,9 @@ CPU gate), including capacity drops and top-k ties.
 
 Backend selection follows the repo-wide convention
 (``compat.backend_is_tpu``, trace-time default backend — the documented
-contract of ``models.decoding.generate``): on TPU the kernels compile;
-elsewhere ``MoE`` falls back to the XLA-floor ``tokens`` path unless a
+contract of ``models.decoding.generate``): on TPU the kernels compile
+(``tests/test_tpu_compile.py`` holds them to that for a described
+v5e); elsewhere ``MoE`` takes the XLA-floor ``tokens`` path unless a
 test forces interpreter mode via ``force_interpret()``.
 """
 
@@ -54,6 +64,7 @@ from __future__ import annotations
 
 import contextlib
 import functools
+import math
 from typing import Optional
 
 import jax
@@ -61,12 +72,9 @@ import jax.numpy as jnp
 from jax import lax
 from jax.experimental import pallas as pl
 
-try:
-    from jax.experimental.pallas import tpu as pltpu
-except ImportError:  # pragma: no cover
-    pltpu = None
+from jax.experimental.pallas import tpu as pltpu
 
-from distkeras_tpu.compat import backend_is_tpu, tpu_compiler_params
+from distkeras_tpu.compat import backend_is_tpu
 from distkeras_tpu.models.layers import get_activation
 
 #: upper bound on the capacity-tile row count. 128 keeps the worst
@@ -75,6 +83,25 @@ from distkeras_tpu.models.layers import get_activation
 MAX_BLOCK_C = 128
 
 _FORCE_INTERPRET = False
+
+
+def _nbytes(shape, dtype) -> int:
+    return math.prod(shape) * jnp.dtype(dtype).itemsize
+
+
+def _compiler_params(pipelined: int, resident: int):
+    """Grid = (expert, capacity tile); the capacity sweep carries the
+    dw1 accumulator, so it stays sequential. Each kernel keeps a whole
+    expert's weight block in VMEM, which at d=1024 / H=4096 is past
+    Mosaic's 16 MiB default scoped limit ("Scoped allocation with size
+    36.54M and limit 16.00M exceeded"), so the limit is stated from the
+    shapes: ``pipelined`` bytes of BlockSpec blocks (double-buffered)
+    plus ``resident`` bytes of scratch and f32 temporaries. A shape
+    past the chip's VMEM is then the compiler's error, not a guess."""
+    need = 2 * pipelined + resident + (4 << 20)
+    return pltpu.CompilerParams(
+        dimension_semantics=("parallel", "arbitrary"),
+        vmem_limit_bytes=max(16 << 20, need))
 
 
 @contextlib.contextmanager
@@ -95,8 +122,6 @@ def fused_supported() -> bool:
     """Whether ``dispatch="fused"`` should take the kernel path — the
     single gate ``MoE.apply`` consults (same trace-time convention as
     every Pallas-vs-XLA fork in this repo: ``compat.backend_is_tpu``)."""
-    if pltpu is None:
-        return False
     return _FORCE_INTERPRET or backend_is_tpu()
 
 
@@ -135,15 +160,44 @@ def _slot_tokens(kn: int, k: int):
 # row gather: HBM -> contiguous VMEM tile, by prefetched plan indices
 # ---------------------------------------------------------------------------
 
+def _gather_source(a):
+    """[N, d] rows -> the [N, 1, d] 32-bit view the row gather DMAs
+    from. Mosaic slices a ref only at multiples of its tiling, and a
+    2-D [N, d] ref tiles its row axis (8 rows f32, 16 bf16): a one-row
+    ``src.at[tok]`` is refused ("Slice shape along dimension 0 must be
+    aligned to tiling (8), but is 1"). With a singleton second-minor
+    axis the tile is (1, 128) and the token index lands on an untiled
+    leading axis — but only for 32-bit elements (a bf16 [N, 1, d] ref
+    tiles (2, 128) and is refused the same way), so narrower inputs are
+    widened here (exact) and narrowed back in VMEM (``_tile``)."""
+    return a.astype(_gather_dtype(a.dtype))[:, None, :]
+
+
+def _gather_dtype(dtype):
+    return jnp.float32 if jnp.dtype(dtype).itemsize < 4 else dtype
+
+
+def _gather_scratch(block_c: int, d: int, dtype):
+    """VMEM landing tile matching :func:`_gather_source`'s view."""
+    return pltpu.VMEM((block_c, 1, d), _gather_dtype(dtype))
+
+
+def _tile(gathered, dtype):
+    """The gathered [block_c, 1, d] VMEM tile as a [block_c, d] value in
+    the compute dtype."""
+    return gathered[:, 0, :].astype(dtype)
+
+
 def _gather_tile(idx_ref, src_hbm, dst_vmem, sem, base, rows: int):
-    """DMA ``rows`` arbitrary rows of ``src_hbm`` into the contiguous
-    VMEM tile ``dst_vmem``, indices ``idx_ref[base + r]`` (SMEM scalar
-    prefetch). Start-all-then-wait-all: every row's DMA is in flight
-    before the first wait, so the gather runs at the DMA engines' row
-    rate rather than serial round-trip latency. Rows with index < 0
-    (capacity rows no slot won) are zeroed — their downstream garbage
-    is masked by ``keep`` exactly as in the tokens path, but zeroing
-    keeps the matmul operands finite."""
+    """DMA ``rows`` arbitrary rows of ``src_hbm`` [N, 1, d] into the
+    contiguous VMEM tile ``dst_vmem`` [rows, 1, d], indices
+    ``idx_ref[base + r]`` (SMEM scalar prefetch). Start-all-then-wait-
+    all: every row's DMA is in flight before the first wait, so the
+    gather runs at the DMA engines' row rate rather than serial
+    round-trip latency. Rows with index < 0 (capacity rows no slot won)
+    are zeroed — their downstream garbage is masked by ``keep`` exactly
+    as in the tokens path, but zeroing keeps the matmul operands
+    finite."""
 
     def _start(r, carry):
         tok = idx_ref[base + r]
@@ -155,7 +209,7 @@ def _gather_tile(idx_ref, src_hbm, dst_vmem, sem, base, rows: int):
 
         @pl.when(tok < 0)
         def _():
-            dst_vmem[r, :] = jnp.zeros_like(dst_vmem[r, :])
+            dst_vmem[r] = jnp.zeros_like(dst_vmem[r])
         return carry
 
     def _wait(r, carry):
@@ -180,7 +234,8 @@ def _fwd_kernel(src_ref, x_ref, w1_ref, b1_ref, h_ref, xg, sem, *,
     e, c = pl.program_id(0), pl.program_id(1)
     _gather_tile(src_ref, x_ref, xg, sem, e * capacity + c * block_c,
                  block_c)
-    z = jnp.dot(xg[:], w1_ref[0], preferred_element_type=jnp.float32) \
+    z = jnp.dot(_tile(xg, w1_ref.dtype), w1_ref[0],
+                preferred_element_type=jnp.float32) \
         + b1_ref[0].astype(jnp.float32)
     h_ref[0] = get_activation(act_name)(z).astype(h_ref.dtype)
 
@@ -191,31 +246,31 @@ def _gather_gemm1(xt, src_tok, w1, b1, *, capacity: int, block_c: int,
     no intermediate HBM buffer."""
     e, d, hid = w1.shape
     grid = (e, capacity // block_c)
-    kwargs = {}
-    if not interpret:  # pragma: no cover — compiled path needs a TPU
-        kwargs["compiler_params"] = tpu_compiler_params(
-            dimension_semantics=("parallel", "arbitrary"))
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
         grid=grid,
         in_specs=[
-            pl.BlockSpec(memory_space=pltpu.ANY),               # x [N, d]
+            pl.BlockSpec(memory_space=pl.ANY),            # x [N, 1, d]
             pl.BlockSpec((1, d, hid), lambda e_, c_, *_: (e_, 0, 0)),
             pl.BlockSpec((1, 1, hid), lambda e_, c_, *_: (e_, 0, 0)),
         ],
         out_specs=pl.BlockSpec((1, block_c, hid),
                                lambda e_, c_, *_: (e_, c_, 0)),
         scratch_shapes=[
-            pltpu.VMEM((block_c, d), xt.dtype),
+            _gather_scratch(block_c, d, xt.dtype),
             pltpu.SemaphoreType.DMA,
         ])
     kernel = functools.partial(_fwd_kernel, block_c=block_c,
                                capacity=capacity, act_name=act_name)
+    params = _compiler_params(
+        _nbytes((d + 1, hid), w1.dtype) + _nbytes((block_c, hid), xt.dtype),
+        _nbytes((block_c, d + 2 * hid), jnp.float32))
     return pl.pallas_call(
         kernel, grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((e, capacity, hid), xt.dtype),
-        interpret=interpret, **kwargs,
-    )(src_tok, xt, w1, b1.reshape(e, 1, hid))
+        compiler_params=params,
+        name="moe_gather_gemm1", interpret=interpret,
+    )(src_tok, _gather_source(xt), w1, b1.reshape(e, 1, hid))
 
 
 # ---------------------------------------------------------------------------
@@ -233,7 +288,7 @@ def _bwd_dx_kernel(src_ref, x_ref, g_ref, w1_ref, w2_ref, b1_ref, b2_ref,
     base = e * capacity + c * block_c
     _gather_tile(src_ref, g_ref, gg, sem, base, block_c)
     _gather_tile(src_ref, x_ref, xg, sem, base, block_c)
-    ggf = gg[:].astype(jnp.float32)
+    ggf = gg[:, 0, :].astype(jnp.float32)
     gy = ggf * rowg_ref[0]                                   # [BC, d] f32
     # router cotangent ingredient: per-row <y, g> (y recomputed from the
     # saved h tile — one extra MXU pass instead of an [E, C, d] residual)
@@ -243,7 +298,8 @@ def _bwd_dx_kernel(src_ref, x_ref, g_ref, w1_ref, w2_ref, b1_ref, b2_ref,
     # dh = gy @ w2^T (contract the d axes — no transpose materialized)
     dh = lax.dot_general(gy, w2_ref[0], (((1,), (1,)), ((), ())),
                          preferred_element_type=jnp.float32)
-    z = jnp.dot(xg[:], w1_ref[0], preferred_element_type=jnp.float32) \
+    z = jnp.dot(_tile(xg, w1_ref.dtype), w1_ref[0],
+                preferred_element_type=jnp.float32) \
         + b1_ref[0].astype(jnp.float32)
     _, dz = jax.jvp(get_activation(act_name), (z,), (dh,))
     dz_ref[0] = dz.astype(dz_ref.dtype)
@@ -257,16 +313,12 @@ def _bwd_dx(xt, g, src_tok, row_gate, w1, b1, w2, b2, h, *,
             capacity: int, block_c: int, act_name: str, interpret: bool):
     e, d, hid = w1.shape
     grid = (e, capacity // block_c)
-    kwargs = {}
-    if not interpret:  # pragma: no cover — compiled path needs a TPU
-        kwargs["compiler_params"] = tpu_compiler_params(
-            dimension_semantics=("parallel", "arbitrary"))
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
         grid=grid,
         in_specs=[
-            pl.BlockSpec(memory_space=pltpu.ANY),               # x [N, d]
-            pl.BlockSpec(memory_space=pltpu.ANY),               # g [N, d]
+            pl.BlockSpec(memory_space=pl.ANY),            # x [N, 1, d]
+            pl.BlockSpec(memory_space=pl.ANY),            # g [N, 1, d]
             pl.BlockSpec((1, d, hid), lambda e_, c_, *_: (e_, 0, 0)),
             pl.BlockSpec((1, hid, d), lambda e_, c_, *_: (e_, 0, 0)),
             pl.BlockSpec((1, 1, hid), lambda e_, c_, *_: (e_, 0, 0)),
@@ -287,13 +339,17 @@ def _bwd_dx(xt, g, src_tok, row_gate, w1, b1, w2, b2, h, *,
                          lambda e_, c_, *_: (e_, c_, 0)),        # <y, g>
         ),
         scratch_shapes=[
-            pltpu.VMEM((block_c, d), xt.dtype),
-            pltpu.VMEM((block_c, d), g.dtype),
+            _gather_scratch(block_c, d, xt.dtype),
+            _gather_scratch(block_c, d, g.dtype),
             pltpu.SemaphoreType.DMA,
         ])
     kernel = functools.partial(_bwd_dx_kernel, block_c=block_c,
                                capacity=capacity, act_name=act_name)
     dt = xt.dtype
+    params = _compiler_params(
+        _nbytes((2 * d + 1, hid), w1.dtype) + _nbytes((1, d), w2.dtype)
+        + _nbytes((block_c, 2 * hid + 2 * d + 2), dt),
+        _nbytes((block_c, 5 * d + 3 * hid), jnp.float32))
     return pl.pallas_call(
         kernel, grid_spec=grid_spec,
         out_shape=(
@@ -302,9 +358,11 @@ def _bwd_dx(xt, g, src_tok, row_gate, w1, b1, w2, b2, h, *,
             jax.ShapeDtypeStruct((e, capacity, d), dt),
             jax.ShapeDtypeStruct((e, capacity, 1), jnp.float32),
         ),
-        interpret=interpret, **kwargs,
-    )(src_tok, xt, g, w1, w2, b1.reshape(e, 1, hid), b2.reshape(e, 1, d),
-      h, row_gate.reshape(e, capacity, 1))
+        compiler_params=params,
+        name="moe_bwd_dx", interpret=interpret,
+    )(src_tok, _gather_source(xt), _gather_source(g), w1, w2,
+      b1.reshape(e, 1, hid), b2.reshape(e, 1, d), h,
+      row_gate.reshape(e, capacity, 1))
 
 
 def _bwd_dw1_kernel(src_ref, x_ref, dz_ref, dw1_ref, xg, sem, *,
@@ -320,7 +378,7 @@ def _bwd_dw1_kernel(src_ref, x_ref, dz_ref, dw1_ref, xg, sem, *,
     # dw1[e] += x_tile^T @ dz_tile (contract the capacity axes); the
     # [d, H] f32 accumulator stays VMEM-resident across the c grid
     dw1_ref[0] += lax.dot_general(
-        xg[:], dz_ref[0], (((0,), (0,)), ((), ())),
+        _tile(xg, dz_ref.dtype), dz_ref[0], (((0,), (0,)), ((), ())),
         preferred_element_type=jnp.float32)
 
 
@@ -330,31 +388,31 @@ def _bwd_dw1(xt, dz, src_tok, *, capacity: int, block_c: int,
     d = xt.shape[1]
     hid = dz.shape[2]
     grid = (e, capacity // block_c)
-    kwargs = {}
-    if not interpret:  # pragma: no cover — compiled path needs a TPU
-        kwargs["compiler_params"] = tpu_compiler_params(
-            dimension_semantics=("parallel", "arbitrary"))
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
         grid=grid,
         in_specs=[
-            pl.BlockSpec(memory_space=pltpu.ANY),               # x [N, d]
+            pl.BlockSpec(memory_space=pl.ANY),            # x [N, 1, d]
             pl.BlockSpec((1, block_c, hid),
                          lambda e_, c_, *_: (e_, c_, 0)),        # dz
         ],
         out_specs=pl.BlockSpec((1, d, hid),
                                lambda e_, c_, *_: (e_, 0, 0)),
         scratch_shapes=[
-            pltpu.VMEM((block_c, d), xt.dtype),
+            _gather_scratch(block_c, d, xt.dtype),
             pltpu.SemaphoreType.DMA,
         ])
     kernel = functools.partial(_bwd_dw1_kernel, block_c=block_c,
                                capacity=capacity)
+    params = _compiler_params(
+        _nbytes((block_c, hid), dz.dtype) + _nbytes((d, hid), jnp.float32),
+        _nbytes((block_c + hid, d), jnp.float32))
     return pl.pallas_call(
         kernel, grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((e, d, hid), jnp.float32),
-        interpret=interpret, **kwargs,
-    )(src_tok, xt, dz)
+        compiler_params=params,
+        name="moe_bwd_dw1", interpret=interpret,
+    )(src_tok, _gather_source(xt), dz)
 
 
 # ---------------------------------------------------------------------------
@@ -464,8 +522,6 @@ def fused_moe_apply(xt, w1, b1, w2, b2, sg, dest, keep, *,
     convention (interpreter anywhere that is not a TPU — callers that
     want the XLA fallback instead must gate on ``fused_supported()``,
     which is what ``MoE.apply`` does)."""
-    if pltpu is None:  # pragma: no cover — no Pallas TPU support
-        raise RuntimeError("fused MoE requires Pallas TPU support")
     if interpret is None:
         interpret = _FORCE_INTERPRET or not backend_is_tpu()
     if block_c is None:

@@ -76,10 +76,7 @@ import jax.numpy as jnp
 from jax import lax
 from jax.experimental import pallas as pl
 
-try:
-    from jax.experimental.pallas import tpu as pltpu
-except ImportError:  # pragma: no cover
-    pltpu = None
+from jax.experimental.pallas import tpu as pltpu
 
 from distkeras_tpu.compat import backend_is_tpu
 from distkeras_tpu.ops.attention import NEG_INF
@@ -295,9 +292,6 @@ def paged_decode_attention(q, k_pages, v_pages, t, table, *,
             "ancestor-mask lane budget (128 nodes)")
     if interpret is None:
         interpret = not backend_is_tpu()
-    if pltpu is None:  # pragma: no cover — no Pallas TPU support
-        raise RuntimeError(
-            "paged_decode_attention requires Pallas TPU support")
     # rows = W*G is the per-head matmul M dim; pad to the 8-row
     # sublane rule (zero rows are independent softmaxes, sliced off)
     rows = w_len * g
@@ -348,10 +342,6 @@ def paged_decode_attention(q, k_pages, v_pages, t, table, *,
         w_len=int(w_len), hkv=int(hkv), window=window,
         quantized=quantized, int4=int4, n_pages=int(n_pages),
         tree=anc is not None)
-    kwargs = {}
-    if not interpret:
-        kwargs["compiler_params"] = pltpu.CompilerParams(
-            dimension_semantics=("parallel", "arbitrary"))
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
         grid=(s, n_logical),
@@ -366,8 +356,9 @@ def paged_decode_attention(q, k_pages, v_pages, t, table, *,
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((s, hkv, rows_p, d), jnp.float32),
-        interpret=interpret,
-        **kwargs,
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary")),
+        name="paged_decode_attention", interpret=interpret,
     )(jnp.asarray(t, jnp.int32), jnp.asarray(table, jnp.int32),
       *operands)
     return out[:, :, :rows].reshape(s, hkv, w_len, g, d) \

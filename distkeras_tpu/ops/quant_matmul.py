@@ -62,12 +62,9 @@ import numpy as np
 from jax import lax
 from jax.experimental import pallas as pl
 
-try:
-    from jax.experimental.pallas import tpu as pltpu
-except ImportError:  # pragma: no cover
-    pltpu = None
+from jax.experimental.pallas import tpu as pltpu
 
-from distkeras_tpu.compat import backend_is_tpu, tpu_compiler_params
+from distkeras_tpu.compat import backend_is_tpu, note_path
 
 #: upper bound on the output-channel tile. 512 f32 lanes x the whole
 #: K column block stays well inside VMEM at decode batch sizes.
@@ -117,7 +114,7 @@ def kernel_enabled() -> bool:
     whether its decode programs keep attention projections quantized
     (shape misalignments still degrade per-leaf to the reference
     inside :func:`quant_matmul`)."""
-    return pltpu is not None and (_FORCE_INTERPRET or backend_is_tpu())
+    return _FORCE_INTERPRET or backend_is_tpu()
 
 
 def fused_supported(k: int, n: int) -> bool:
@@ -269,7 +266,9 @@ def quant_matmul(x, wq, *, interpret: Optional[bool] = None
     lead, k = x.shape[:-1], x.shape[-1]
     q2d, scale, int4, n = _resolve_2d(k, wq)
     if not fused_supported(k, n):
+        note_path("quant_matmul", f"xla_reference[k={k},n={n}]")
         return reference_matmul(x, wq)
+    note_path("quant_matmul", "kernel")
     if interpret is None:
         interpret = not backend_is_tpu()
     bn = choose_block_n(n)
@@ -289,9 +288,9 @@ def quant_matmul(x, wq, *, interpret: Optional[bool] = None
         ],
         out_specs=pl.BlockSpec((mp, bn), lambda i: (0, i)),
         out_shape=jax.ShapeDtypeStruct((mp, n), jnp.float32),
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)),
-        interpret=interpret,
+        name="quant_matmul", interpret=interpret,
     )(x2, q2d, scale.reshape(1, n))
     return out[:m].reshape(lead + (n,))
 

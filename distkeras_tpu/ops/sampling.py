@@ -4,12 +4,13 @@ The unfused sampler (``models.decoding._sample_vec``) walks the
 [S, V] logits several times at full vocab width: rank argsorts for
 top-k, a sort + softmax + cumsum for the nucleus cut, then
 ``jax.random.categorical`` — each an [S, V] HBM round trip at real
-vocab sizes. This module folds everything AFTER the one irreducible
-sort into a single Pallas pass: the kernel consumes the
-temperature-scaled logits, their descending sort, and an externally
+vocab sizes. This module folds the mask construction, the nucleus
+threshold and the draw into a single Pallas pass: XLA keeps the one
+irreducible sort and the two prefix sums over it (Mosaic lowers no
+``cumsum``), and the kernel consumes the temperature-scaled logits,
+the top-k-masked sorted row, those prefix sums and an externally
 drawn gumbel field, and emits the sampled token ids directly — the
-masked logits, softmax probabilities, cumulative sums, and perturbed
-scores live only in VMEM.
+masked logits and perturbed scores live only in VMEM.
 
 Exactness contract (the reason the pieces factor this way):
 
@@ -28,16 +29,15 @@ Exactness contract (the reason the pieces factor this way):
     with stable lowest-index-first ties (reconstructed from the
     sorted row: ``count_above + tie_prefix_rank <= k``), the nucleus
     cut's exclusive-cumsum threshold over the top-k-masked sorted
-    row (the masked sort is derived from the unmasked sort — the
-    rank mask keeps exactly the k largest VALUES, ties only shuffle
-    indices), and first-index argmax for both the greedy and the
-    gumbel winner.
+    row, and first-index argmax for both the greedy and the gumbel
+    winner.
 
 Alignment: vocab % 128 (lane tiling); slot rows pad to 8. Gate:
 ``fused_supported`` (same backend convention as every Pallas-vs-XLA
 fork — ``compat.backend_is_tpu`` or a test forcing interpreter mode);
-``sample_epilogue`` falls back to the reference path silently, so the
-engine enables ``fused_sampling`` unconditionally.
+``sample_epilogue`` takes the reference path where the gate is shut
+(on record through ``compat.note_path``), so the engine enables
+``fused_sampling`` unconditionally.
 """
 
 from __future__ import annotations
@@ -50,12 +50,9 @@ import jax.numpy as jnp
 from jax import lax
 from jax.experimental import pallas as pl
 
-try:
-    from jax.experimental.pallas import tpu as pltpu
-except ImportError:  # pragma: no cover
-    pltpu = None
+from jax.experimental.pallas import tpu as pltpu
 
-from distkeras_tpu.compat import backend_is_tpu, tpu_compiler_params
+from distkeras_tpu.compat import backend_is_tpu, note_path
 from distkeras_tpu.ops.attention import NEG_INF
 
 #: slot-row tile (Mosaic second-to-last-dim rule)
@@ -79,8 +76,6 @@ def force_interpret():
 
 def fused_supported(vocab: int) -> bool:
     """Whether the epilogue kernel runs for this vocab width."""
-    if pltpu is None:
-        return False
     if not (_FORCE_INTERPRET or backend_is_tpu()):
         return False
     return vocab % 128 == 0
@@ -95,42 +90,30 @@ def gumbel_noise(keys, vocab: int) -> jnp.ndarray:
         lambda k: jax.random.gumbel(k, (vocab,), jnp.float32))(keys)
 
 
-def _kernel(lf_ref, srt_ref, g_ref, t_ref, k_ref, p_ref, o_ref):
+def _kernel(lf_ref, srt_ref, g_ref, tie_ref, excl_ref, t_ref, k_ref, p_ref,
+            kth_ref, o_ref):
     lf = lf_ref[...]                     # [bs, V] temp-scaled f32
-    srt = srt_ref[...]                   # [bs, V] descending sort of lf
+    srt_m = srt_ref[...]                 # [bs, V] top-k-masked sorted row
     g = g_ref[...]                       # [bs, V] gumbel
     temp = t_ref[...]                    # [bs, 1]
     kk = k_ref[...]                      # [bs, 1] i32
     p = p_ref[...]                       # [bs, 1]
+    kth = kth_ref[...]                   # [bs, 1] k-th largest value
     v = lf.shape[-1]
     iota = lax.broadcasted_iota(jnp.int32, lf.shape, 1)
 
-    # rank top-k, stable lowest-index-first ties: the k-th largest
-    # VALUE from the sorted row, then admit everything above it plus
-    # the leading tied indices up to the remaining budget
-    kc = jnp.clip(kk, 1, v)
-    kth = jnp.sum(jnp.where(iota == kc - 1, srt, 0.0), axis=1,
-                  keepdims=True)
+    # rank top-k, stable lowest-index-first ties: admit everything
+    # above the k-th largest VALUE plus the leading tied indices up to
+    # the remaining budget (``tie_ref``: inclusive prefix count of ties)
     n_gt = jnp.sum((lf > kth).astype(jnp.int32), axis=1, keepdims=True)
-    eq = lf == kth
-    tie_rank = jnp.cumsum(eq.astype(jnp.int32), axis=1)      # inclusive
-    keep_k = (kk <= 0) | (lf > kth) | (eq & (n_gt + tie_rank <= kc))
+    keep_k = (kk <= 0) | (lf > kth) | (
+        (lf == kth) & (n_gt + tie_ref[...] <= jnp.clip(kk, 1, v)))
     lfk = jnp.where(keep_k, lf, NEG_INF)
 
-    # the top-k-masked SORTED row derives from the unmasked sort: the
-    # rank mask keeps exactly the k largest values (ties only shuffle
-    # which INDEX survives, never the value multiset)
-    kcount = jnp.where(kk <= 0, v, kc)
-    srt_m = jnp.where(iota < kcount, srt, NEG_INF)
-
-    # nucleus: softmax over the masked sorted row, exclusive cumsum,
-    # same boundary construction as the unfused path
-    mx = jnp.max(srt_m, axis=1, keepdims=True)
-    ex = jnp.exp(srt_m - mx)
-    probs = ex / jnp.sum(ex, axis=1, keepdims=True)
-    excl = jnp.cumsum(probs, axis=1) - probs
-    keep_s = excl < p
-    thresh = jnp.min(jnp.where(keep_s, srt_m, jnp.inf), axis=1,
+    # nucleus: ``excl_ref`` is the exclusive cumsum of the masked
+    # sorted row's softmax; same boundary construction as the unfused
+    # path
+    thresh = jnp.min(jnp.where(excl_ref[...] < p, srt_m, jnp.inf), axis=1,
                      keepdims=True)
     lfm = jnp.where((p >= 1.0) | (lfk >= thresh), lfk, NEG_INF)
 
@@ -155,16 +138,33 @@ def sample_epilogue(logits, temperature, top_k, top_p, gumbel, *,
 
     s, v = logits.shape
     if not fused_supported(v):
+        note_path("sampling", f"xla_reference[vocab={v}]")
         lf = _masked_logits_vec(logits, temperature, top_k, top_p)
         sampled = jnp.argmax(lf + gumbel, axis=-1)
         return jnp.where(temperature > 0.0, sampled,
                          jnp.argmax(logits, axis=-1))
+    note_path("sampling", "kernel")
     if interpret is None:
         interpret = not backend_is_tpu()
     lf = logits.astype(jnp.float32)
     safe_t = jnp.where(temperature > 0.0, temperature, 1.0)
     lf = lf / safe_t[:, None]
     srt = jnp.flip(jnp.sort(lf, axis=-1), axis=-1)   # the one XLA sort
+    # The rank mask keeps exactly the k largest values (ties only
+    # shuffle which INDEX survives, never the value multiset), so the
+    # top-k-masked sorted row derives from the unmasked sort. The two
+    # prefix sums over it stay in XLA: Mosaic has no cumsum
+    # ("Unimplemented primitive in Pallas TPU lowering for
+    # KernelType.TC: cumsum"), and XLA's is the one the unfused
+    # sampler's boundary is defined by.
+    kk = top_k.astype(jnp.int32)[:, None]
+    kc = jnp.clip(kk, 1, v)
+    kth = jnp.take_along_axis(srt, kc - 1, axis=1)
+    tie_rank = jnp.cumsum((lf == kth).astype(jnp.int32), axis=1)
+    srt_m = jnp.where(jnp.arange(v)[None, :] < jnp.where(kk <= 0, v, kc),
+                      srt, NEG_INF)
+    probs = jax.nn.softmax(srt_m, axis=-1)
+    excl = jnp.cumsum(probs, axis=1) - probs
     sp = -(-s // BLOCK_S) * BLOCK_S
     pad = sp - s
 
@@ -172,27 +172,25 @@ def sample_epilogue(logits, temperature, top_k, top_p, gumbel, *,
         return jnp.pad(a, ((0, pad),) + ((0, 0),) * (a.ndim - 1),
                        constant_values=fill) if pad else a
 
-    args = (prep(lf, NEG_INF), prep(srt, NEG_INF),
+    args = (prep(lf, NEG_INF), prep(srt_m, NEG_INF),
             prep(gumbel.astype(jnp.float32), 0.0),
+            prep(tie_rank, 0), prep(excl, 0.0),
             prep(temperature.astype(jnp.float32)[:, None], 0.0),
-            prep(top_k.astype(jnp.int32)[:, None], 0),
-            prep(top_p.astype(jnp.float32)[:, None], 1.0))
+            prep(kk, 0),
+            prep(top_p.astype(jnp.float32)[:, None], 1.0),
+            prep(kth, NEG_INF))
     out = pl.pallas_call(
         _kernel,
         grid=(sp // BLOCK_S,),
         in_specs=[
-            pl.BlockSpec((BLOCK_S, v), lambda i: (i, 0)),
-            pl.BlockSpec((BLOCK_S, v), lambda i: (i, 0)),
-            pl.BlockSpec((BLOCK_S, v), lambda i: (i, 0)),
-            pl.BlockSpec((BLOCK_S, 1), lambda i: (i, 0)),
-            pl.BlockSpec((BLOCK_S, 1), lambda i: (i, 0)),
-            pl.BlockSpec((BLOCK_S, 1), lambda i: (i, 0)),
+            *[pl.BlockSpec((BLOCK_S, v), lambda i: (i, 0))] * 5,
+            *[pl.BlockSpec((BLOCK_S, 1), lambda i: (i, 0))] * 4,
         ],
         out_specs=pl.BlockSpec((BLOCK_S, 1), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((sp, 1), jnp.int32),
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)),
-        interpret=interpret,
+        name="sample_epilogue", interpret=interpret,
     )(*args)
     return out[:s, 0]
 

@@ -101,6 +101,7 @@ class DistributedTrainer(Trainer):
         state = engine.init_state(tree["params"], tree["state"],
                                   jax.random.PRNGKey(self.seed))
         state = jax.device_put(state, engine.shardings())
+        self._record_placement("worker_state", state["worker"])
 
         from distkeras_tpu.utils.prefetch import Prefetcher
         assemble = lambda epoch: shard_epoch_data(

@@ -508,8 +508,7 @@ class DistributedEngine:
         mapped = shard_map(
             inner, mesh=self.mesh,
             in_specs=(state_specs, P(None, axis), P(None, axis)),
-            out_specs=(state_specs, P(None, axis)),
-            check_vma=False)
+            out_specs=(state_specs, P(None, axis)))
         self._epoch_fn = jax.jit(mapped, donate_argnums=(0,))
         # detector bound HERE, with the function it watches — callers
         # (and tests) invoke _build() directly, so run_epoch cannot

@@ -38,7 +38,7 @@ import numpy as np
 from jax import lax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from distkeras_tpu.compat import axis_size, shard_map
+from distkeras_tpu.compat import shard_map
 from distkeras_tpu.models.core import Layer
 from distkeras_tpu.ops.optimizers import Optimizer, apply_updates
 
@@ -123,7 +123,7 @@ def make_pipeline_fn(block: Layer, axis_name: str = "pp",
         return h
 
     def fn(local_params, x_mb):
-        nstages = axis_size(axis_name)
+        nstages = lax.axis_size(axis_name)
         idx = lax.axis_index(axis_name)
         M = x_mb.shape[0]
         ticks = M * v + nstages - 1
@@ -338,7 +338,7 @@ class PipelinedLM:
                 # loss -> ring -> stage params -> first rank's embed) is
                 # handled by the collective transposes inside jax.grad.
                 is_last = (lax.axis_index(pp_axis)
-                           == axis_size(pp_axis) - 1)
+                           == lax.axis_size(pp_axis) - 1)
                 # scaled so that psum over data+pp axes == global mean loss
                 return loss_fn(y, logits) * is_last / div, (logits, is_last)
 
@@ -364,8 +364,7 @@ class PipelinedLM:
         grads_fn = shard_map(
             local_grads, mesh=mesh,
             in_specs=(pspecs, data_spec, data_spec),
-            out_specs=(pspecs, P(), {n: P() for n in metric_fns}),
-            check_vma=False)
+            out_specs=(pspecs, P(), {n: P() for n in metric_fns}))
 
         def step(carry, batch):
             params, opt_state = carry
@@ -585,8 +584,7 @@ class PipelineTrainer:
             evalf, mesh=self.mesh,
             in_specs=(pspecs, data_spec, data_spec),
             out_specs={"val_loss": P(),
-                       **{f"val_{n}": P() for n in metric_fns}},
-            check_vma=False))
+                       **{f"val_{n}": P() for n in metric_fns}}))
         return lambda params: sharded(params, Xv, yv)
 
     def _validate(self, X, Y):

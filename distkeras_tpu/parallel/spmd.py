@@ -73,6 +73,7 @@ class SPMDTrainer(Trainer):
                 f"data_axes {unknown} not in mesh axes "
                 f"{tuple(mesh.shape)}")
         self.data_axes = tuple(data_axes)
+        self._epoch_fn = self._epoch_args = None     # see lower_epoch
         self.tp_axis = tp_axis
         self.ep_axis = ep_axis
         self.fsdp_axis = fsdp_axis
@@ -82,6 +83,21 @@ class SPMDTrainer(Trainer):
             raise ValueError(
                 f"global batch_size {self.batch_size} must divide evenly "
                 f"over data axes {self.data_axes} (size {dp})")
+
+    def _trace_scope(self):
+        # XLA cannot partition a Mosaic kernel: the flash kernel runs
+        # per shard of the batch (data axes) and heads (tp axis)
+        from distkeras_tpu.ops.flash_attention import partitioned
+        return partitioned(self.mesh, self.data_axes, self.tp_axis)
+
+    def lower_epoch(self):
+        """The epoch program of the last ``train()`` as it was called
+        (``jax.stages.Lowered``, arguments by shape and sharding):
+        ``.compile().as_text()`` shows what each partition holds — the
+        kernels and the collectives GSPMD placed."""
+        if self._epoch_fn is None:
+            raise RuntimeError("lower_epoch() needs a train() first")
+        return self._epoch_fn.lower(*self._epoch_args)
 
     # -- sharding plumbing --------------------------------------------------
     def _placements(self, model: Model):
@@ -291,6 +307,7 @@ class SPMDTrainer(Trainer):
                                         param_sh)
             rng = jax.device_put(jnp.asarray(restored["rng"]), repl)
         carry = TrainCarry(params, state, opt_state, rng)
+        self._record_placement("params", params)
 
         step = make_train_step(model.module, self.loss, self.worker_optimizer,
                                self._metric_fns(), self.grad_accum_steps,
@@ -325,7 +342,8 @@ class SPMDTrainer(Trainer):
 
         @partial(jax.jit, donate_argnums=(0,), out_shardings=(carry_sh, None))
         def run_epoch(carry, Xs, Ys):
-            return jax.lax.scan(step, carry, (Xs, Ys))
+            with self._trace_scope():
+                return jax.lax.scan(step, carry, (Xs, Ys))
 
         tape = self._make_tape()
         tape.watch("SPMDTrainer.epoch", run_epoch)
@@ -404,6 +422,12 @@ class SPMDTrainer(Trainer):
                         # loader thread (device_stager above); per-step
                         # losses/metrics stay on device until the
                         # epoch-boundary fetch (overlap PR)
+                        self._record_placement("batch", Xs)
+                        self._epoch_fn, self._epoch_args = run_epoch, \
+                            jax.tree_util.tree_map(
+                                lambda a: jax.ShapeDtypeStruct(
+                                    a.shape, a.dtype, sharding=a.sharding),
+                                (carry, Xs, Ys))
                         carry, outs = run_epoch(carry, Xs, Ys)
                         losses, mets = self._split_outs(outs)
                         host_async((losses, mets))

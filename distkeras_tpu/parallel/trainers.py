@@ -14,6 +14,7 @@ label_col, ...)`` and ``trainer.train(dataset) -> Model``.
 
 from __future__ import annotations
 
+import contextlib
 import threading
 from typing import Callable, List, Optional, Sequence, Union
 
@@ -155,6 +156,11 @@ class Trainer:
         self.seed = int(seed)
         self.shuffle_each_epoch = bool(shuffle_each_epoch)
         self.history = History()
+        #: name -> sorted ids of the devices that held that piece of
+        #: training state ("params", "batch", "worker_state") in the
+        #: last ``train()`` — mesh trainers record it so a run that
+        #: silently sat on one chip is read, not inferred
+        self.placement: dict = {}
         # checkpoint/resume (capability ADD over the reference, which has
         # none — SURVEY §5.4); snapshots the master/center model per epoch
         self.checkpoint_dir = checkpoint_dir
@@ -419,6 +425,17 @@ class Trainer:
     def _device_validation_arrays(self, Xv, yv):
         return cache_validation_on_device(self, Xv, yv)
 
+    def _record_placement(self, name: str, tree) -> None:
+        self.placement[name] = sorted(
+            {shard.device.id for leaf in jax.tree_util.tree_leaves(tree)
+             for shard in leaf.addressable_shards})
+
+    def _trace_scope(self):
+        """Context the trainer's jitted programs are TRACED under.
+        Trainers whose programs GSPMD partitions over a mesh override
+        it (``SPMDTrainer``: kernels run per shard)."""
+        return contextlib.nullcontext()
+
     def _make_validator(self, module):
         """Jitted full-set eval: ``validator(params, state) ->
         {"val_loss": ..., "val_<metric>": ...}`` (scalars). Built once; the
@@ -439,7 +456,8 @@ class Trainer:
 
         @jax.jit
         def evalf(params, state, Xv, yv):
-            out, _ = module.apply(params, state, Xv, training=False)
+            with self._trace_scope():
+                out, _ = module.apply(params, state, Xv, training=False)
             res = {"val_loss": loss_fn(yv, out)}
             for name, fn in metric_fns.items():
                 res[f"val_{name}"] = fn(yv, out)

@@ -86,6 +86,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from distkeras_tpu import obs
+from distkeras_tpu.compat import record_paths, shard_map
 from distkeras_tpu.obs.recorder import resolve_recorder
 from distkeras_tpu.obs.slo import SLOEngine
 from distkeras_tpu.obs.timeseries import TimeSeries
@@ -206,8 +207,9 @@ class ServingEngine:
       ``"paged"`` forces the kernel (interpreter mode off-TPU — the
       oracle hook tier-1 uses); ``"off"`` forces the gather path
       (the A/B baseline). Pools whose ``page_len`` breaks the
-      kernel's tiling rule (% 8 float, % 32 int8) silently keep the
-      gather path.
+      kernel's tiling rule (% 8 float, % 32 int8) keep the gather
+      path; ``health()["programs"]`` says which one each compiled
+      program took.
     * ``prefix_cache`` — hash-cons identical prompt prefixes onto
       shared pages (on by default; sharing is exact up to
       chunked-prefill fp reassociation — see ``kv_pool.PrefixCache``).
@@ -629,6 +631,10 @@ class ServingEngine:
             [np.array(jax.random.PRNGKey(0))] * s)       # [S, key]
 
         self._step_fns = {}                  # greedy_only -> jit
+        #: program name -> the kernel-or-reference choices made while
+        #: it was traced (``_jit_serving``); read it in ``health()``
+        self.program_paths: Dict[str, str] = {}
+        self._logits_fns = {}                # decode_logits variants
         self._prefill_fns = {}
         self._first_fn = None
 
@@ -786,7 +792,8 @@ class ServingEngine:
         # whole point: per-chip weight traffic shrinks with the mesh
         self._params = jax.device_put(self._params, shardings)
 
-    def _jit_serving(self, f, n_args: int, keep_attn: bool = False):
+    def _jit_serving(self, f, n_args: int, name: str,
+                     keep_attn: bool = False):
         """Compile one serving program: plain ``jax.jit``, or — under
         expert parallelism — ``jit(shard_map(f))`` with the params
         (always argument 0) split by the expert specs and every other
@@ -795,7 +802,18 @@ class ServingEngine:
         dequantizes the qdict tree in-graph; ``keep_attn`` (the
         decode/fused programs, whose only attention-weight consumers
         are ``_project_qkv`` / ``_attn_out``) leaves the attention
-        projections quantized for the fused dequant-matmul kernel."""
+        projections quantized for the fused dequant-matmul kernel.
+        While the program is traced, every kernel-or-reference choice
+        inside it (``compat.note_path``) lands in
+        ``program_paths[name]`` — ``health()["programs"]``."""
+        traced = f
+
+        def f(*args):
+            with record_paths() as paths:
+                out = traced(*args)
+            self.program_paths[name] = ", ".join(sorted(paths))
+            return out
+
         if self.weight_quant is not None:
             from distkeras_tpu.ops.quant_matmul import dequant_params_tree
             inner, dt = f, self._wq_dequant_dt
@@ -808,7 +826,6 @@ class ServingEngine:
         if self._ep_mesh is None:
             return jax.jit(f)
         from jax.sharding import PartitionSpec as P
-        from distkeras_tpu.compat import shard_map
         return jax.jit(shard_map(
             f, mesh=self._ep_mesh,
             in_specs=(self._ep_pspec,) + (P(),) * (n_args - 1),
@@ -1369,7 +1386,9 @@ class ServingEngine:
                                     topk, topp, keys, None)
                     n_args = 9
 
-            fn = self._jit_serving(fn, n_args, keep_attn=True)
+            fn = self._jit_serving(
+                fn, n_args, "decode_greedy" if greedy_only
+                else "decode_sampled", keep_attn=True)
             self._step_fns[greedy_only] = fn
             self._recompile.watch(
                 "serving.decode_greedy" if greedy_only
@@ -1435,7 +1454,9 @@ class ServingEngine:
                                     temp, topk, topp, keys, None)
                     n_args = 10
 
-            fn = self._jit_serving(fn, n_args, keep_attn=True)
+            fn = self._jit_serving(
+                fn, n_args, "decode_fused_greedy" if greedy_only
+                else "decode_fused_sampled", keep_attn=True)
             self._fused_fns[greedy_only] = fn
             self._recompile.watch(
                 "serving.decode_fused_greedy" if greedy_only
@@ -1541,7 +1562,9 @@ class ServingEngine:
                                     None)
                     n_args = 10
 
-            fn = self._jit_serving(fn, n_args)
+            fn = self._jit_serving(
+                fn, n_args, "verify_greedy" if greedy_only
+                else "verify_sampled")
             self._spec_fns[greedy_only] = fn
             self._recompile.watch(
                 "serving.verify_greedy" if greedy_only
@@ -1633,7 +1656,9 @@ class ServingEngine:
                                     parents, depth, anc, temp, topk,
                                     topp, keys, None)
                     n_args = 12
-            fn = self._jit_serving(fn, n_args)
+            fn = self._jit_serving(
+                fn, n_args, "verify_tree_greedy" if greedy_only
+                else "verify_tree_sampled")
             self._tree_fns[greedy_only] = fn
             self._recompile.watch(
                 "serving.verify_tree_greedy" if greedy_only
@@ -1796,7 +1821,7 @@ class ServingEngine:
                                               final=final)
             # EP models shard_map-wrap here too: prefill runs the MoE
             # layers' own apply, which psums over the expert axis
-            fn = self._jit_serving(f, 4)
+            fn = self._jit_serving(f, 4, "prefill")
         # re-insert at the back: dict order is the LRU order
         self._prefill_fns[key] = fn
         while len(self._prefill_fns) > self.MAX_PREFILL_PROGRAMS:
@@ -2543,6 +2568,7 @@ class ServingEngine:
                          "cancelled": m.requests_cancelled,
                          "preempted": m.requests_preempted},
             "telemetry": obs.telemetry_snapshot(),
+            "programs": dict(self.program_paths),
         }
         if self._moe:
             out["moe"] = {
@@ -2571,6 +2597,46 @@ class ServingEngine:
                     "nodes": len(self.prefix),
                     "hit_rate": m.prefix_hit_rate})
         return out
+
+    def decode_logits(self, decode_kernel: Optional[str] = None,
+                      moe_decode: Optional[str] = None) -> np.ndarray:
+        """Diagnostic read (``chip_smoke.py``, tests): the ``[S, V]``
+        logits ONE plain decode step produces for every slot from the
+        current paged cache. The stream state does not advance — the
+        in-flight step is consumed first so the host owns every slot's
+        input token, the pages that step would write are allocated as
+        the next ``step()`` would allocate them, and the step's cache
+        writes are dropped. ``decode_kernel`` (``"paged"``/``"off"``)
+        and ``moe_decode`` (``"dispatched"``/``"dense"``) override this
+        engine's own choices, which is the point: a kernel and its
+        reference read the SAME state. Rows of free slots are
+        garbage."""
+        if self.kv_layout != "paged":
+            raise ValueError("decode_logits reads the paged cache")
+        self._flush_pending()
+        self._ensure_decode_pages()
+        fn = self._logits_fns.get((decode_kernel, moe_decode))
+        if fn is None:
+            pk = (self._paged_kernel if decode_kernel is None
+                  else {"paged": True, "off": False}[decode_kernel])
+            dispatched = (self._moe_dispatched if moe_decode is None
+                          else bool(self._moe)
+                          and moe_decode == "dispatched")
+            module, page_len = self.module, self.page_len
+
+            def f(params, state, cache, tok, t, tables):
+                return decode_step_slots_paged(
+                    module, params, state, cache, tok, t, tables,
+                    page_len, paged_kernel=pk,
+                    moe_dispatched=dispatched)[0]
+
+            fn = self._logits_fns[decode_kernel, moe_decode] = \
+                self._jit_serving(
+                    f, 6, f"decode_logits[{decode_kernel},{moe_decode}]",
+                    keep_attn=True)
+        return np.asarray(fn(
+            self._params, self._state, self.pool.cache,
+            _snap(self._tok), _snap(self._t), self.pool.device_tables()))
 
     # --- internals --------------------------------------------------------
 

@@ -39,7 +39,6 @@ def main():
 
     # FLOPs per example from XLA's own cost analysis of one jitted
     # train step — the honest numerator for MFU
-    from distkeras_tpu.compat import cost_analysis
     from distkeras_tpu.ops import get_loss, get_optimizer
     from distkeras_tpu.parallel.worker import TrainCarry, make_train_step
     step = make_train_step(
@@ -54,7 +53,7 @@ def main():
         carry, (np.zeros((batch, 16), np.float32),
                 np.zeros((batch,), np.int32)))
     flops_per_example = float(
-        cost_analysis(lowered.compile()).get("flops", 0.0)) / batch
+        lowered.compile().cost_analysis().get("flops", 0.0)) / batch
 
     peak, kind = obs.detect_peak_flops()
     if peak is None:

@@ -77,7 +77,7 @@ def ring_out(q, k, v, causal, block_size):
                                        causal=causal,
                                        block_size=block_size),
         mesh=mesh, in_specs=(P(None, "sp"),) * 3,
-        out_specs=P(None, "sp"), check_vma=False)
+        out_specs=P(None, "sp"))
     return np.asarray(jax.jit(fn)(q, k, v))
 
 

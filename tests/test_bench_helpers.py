@@ -8,6 +8,9 @@ import json
 import os
 import sys
 
+import jax
+import pytest
+
 # repo root (bench.py is not in the package) — cwd-independent
 sys.path.insert(0, os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))))
@@ -278,3 +281,78 @@ def test_quant_hbm_math_rider():
     assert wb["int8"] < wb["bf16"] * 0.75
     assert wb["int4"] < wb["int8"]
     assert kv["bf16"] > kv["int8"] > kv["int4"]
+
+
+@pytest.mark.parametrize("exc,oom", [
+    (jax.errors.JaxRuntimeError(
+        "RESOURCE_EXHAUSTED: Out of memory allocating 1GiB"), True),
+    # a kernel Mosaic refuses mentions memory too: NOT an OOM
+    (jax.errors.JaxRuntimeError(
+        "INTERNAL: Mosaic failed to compile TPU kernel: exceeds VMEM "
+        "memory"), False),
+    (RuntimeError("RESOURCE_EXHAUSTED: out of memory"), False),
+    (ValueError("memory"), False),
+])
+def test_is_oom_is_typed_and_resource_exhausted_only(exc, oom):
+    assert bench._is_oom(exc) is oom
+
+
+def test_batch_ladder_steps_down_on_oom_only():
+    """OOM -> the next smaller batch; a compile refusal propagates at
+    once instead of becoming a smaller headline batch."""
+    oom = jax.errors.JaxRuntimeError("RESOURCE_EXHAUSTED: hbm")
+    tried = []
+
+    def fn(b):
+        tried.append(b)
+        if b > 8:
+            raise oom
+        return b * 10
+
+    assert bench._with_fallbacks(fn, [32, 16, 8, 4], "x") == (80, 8)
+    assert tried == [32, 16, 8]
+
+    def refuses(b):
+        tried.append(b)
+        raise jax.errors.JaxRuntimeError("Mosaic failed to compile")
+
+    tried.clear()
+    with pytest.raises(jax.errors.JaxRuntimeError, match="Mosaic"):
+        bench._with_fallbacks(refuses, [32, 16], "x")
+    assert tried == [32]                   # no retry, no smaller batch
+    with pytest.raises(RuntimeError, match="all batch sizes failed"):
+        bench._with_fallbacks(lambda b: (_ for _ in ()).throw(oom),
+                              [2, 1], "x")
+
+
+def test_bench_starts_no_child_that_needs_the_chip():
+    """One process per chip: the only ``subprocess`` use left in
+    bench.py is the CPU-forced expert-parallel child."""
+    import ast
+    src = open(bench.__file__).read()
+    users = {fn.name for fn in ast.walk(ast.parse(src))
+             if isinstance(fn, ast.FunctionDef)
+             and any(isinstance(n, ast.Name) and n.id == "subprocess"
+                     for n in ast.walk(fn))}
+    assert users == {"_serving_moe_ep_subprocess"}
+    assert 'env["JAX_PLATFORMS"] = "cpu"' in src
+
+
+@pytest.mark.parametrize("env_dir", [None, "/some/dir"])
+def test_compile_cache_dir_env_wins_else_repo_local(monkeypatch, env_dir):
+    """``JAX_COMPILATION_CACHE_DIR`` set: nothing is set in code.
+    Unset: ``<repo>/.jax_cache`` — fixed, inside the checkout."""
+    from distkeras_tpu import compat
+    updates = []
+    monkeypatch.setattr(compat.jax.config, "update",
+                        lambda k, v: updates.append((k, v)))
+    if env_dir is None:
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+        want = os.path.join(os.path.dirname(os.path.abspath(
+            bench.__file__)), ".jax_cache")
+        assert compat.enable_compile_cache() == want
+        assert updates == [("jax_compilation_cache_dir", want)]
+    else:
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", env_dir)
+        assert compat.enable_compile_cache() == env_dir
+        assert updates == []

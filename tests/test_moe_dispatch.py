@@ -29,9 +29,8 @@ def _run_ctx(dispatch):
 def _program_flops(moe, params, x):
     """XLA cost-analysis FLOPs of the jitted apply (per-device program
     when the inputs carry GSPMD shardings)."""
-    from distkeras_tpu.compat import cost_analysis
     f = jax.jit(lambda p, xx: moe.apply(p, {}, xx)[0])
-    return cost_analysis(f.lower(params, x).compile())["flops"]
+    return f.lower(params, x).compile().cost_analysis()["flops"]
 
 
 def _mk(e=8, d=16, hid=32, k=2, **kw):

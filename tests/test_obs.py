@@ -464,6 +464,24 @@ def test_histogram_reservoir_seed_is_process_stable():
     assert h.samples() == res
 
 
+@pytest.mark.parametrize("platform,kind,want", [
+    ("tpu", "TPU v5 lite", 197e12),
+    ("tpu", "TPU v4", 275e12),
+    ("cpu", "cpu", None),                 # MFU is not a CPU metric
+    ("tpu", "TPU v5 lite pod-ish", ValueError),   # no substring guesses
+    ("tpu", "TPU v9", ValueError),
+])
+def test_peak_flops_exact_device_kinds_only(platform, kind, want):
+    """The chip peak comes from an exact ``device_kind`` match: an
+    unknown TPU raises instead of inheriting a neighbour's peak."""
+    from distkeras_tpu.obs.tape import peak_flops_of
+    if want is ValueError:
+        with pytest.raises(ValueError, match="unknown TPU device_kind"):
+            peak_flops_of(platform, kind)
+    else:
+        assert peak_flops_of(platform, kind) == want
+
+
 def test_null_tape_is_inert():
     t = obs.NULL_TAPE
     t.train_begin()

@@ -169,6 +169,57 @@ def test_engine_kernel_greedy_matches_generate(memorized_lm):
         out[r1], generate(m, PATTERN[None, :6], 5, temperature=0.0)[0])
 
 
+@pytest.mark.parametrize("decode_kernel,page_len,path", [
+    ("paged", 8, "paged_attention=kernel"),
+    ("off", 8, "paged_attention=gather_reference"),
+    ("auto", 8, "paged_attention=gather_reference"),    # off-TPU
+    ("paged", 4, "paged_attention=gather_reference"),   # tiling gate
+])
+def test_health_reports_the_path_each_program_took(
+        memorized_lm, decode_kernel, page_len, path):
+    """Kernel or reference is READ from ``health()["programs"]``, not
+    inferred from the backend or the option: the shape gate's quiet
+    gather fallback (page_len 4 breaks the sublane rule) shows up as
+    what it is."""
+    eng = ServingEngine(memorized_lm, num_slots=2, max_len=32,
+                        page_len=page_len, decode_kernel=decode_kernel)
+    assert eng.health()["programs"] == {}       # nothing traced yet
+    eng.submit(PATTERN[:4], 3)
+    eng.run(max_steps=100)
+    programs = eng.health()["programs"]
+    assert programs["decode_greedy"] == path
+    assert programs["prefill"] == "flash_attention=xla_reference"
+
+
+def test_decode_logits_kernel_and_gather_read_the_same_state(
+        memorized_lm):
+    """``decode_logits``: one decode step's logits for every slot on
+    the CURRENT cache, kernel vs gather on identical state, without
+    advancing any stream (the comparison ``chip_smoke.py`` makes on
+    the chip)."""
+    m = memorized_lm
+    eng = ServingEngine(m, num_slots=2, max_len=32, page_len=8,
+                        decode_kernel="paged")
+    rids = [eng.submit(PATTERN[:4], 7), eng.submit(PATTERN[:6], 5)]
+    while len(eng.scheduler.running) < 2:
+        eng.step()
+    kernel = eng.decode_logits()
+    gather = eng.decode_logits(decode_kernel="off")
+    assert kernel.shape == (2, V)
+    np.testing.assert_allclose(kernel, gather, atol=1e-4)
+    np.testing.assert_array_equal(kernel, eng.decode_logits())  # a read
+    out = eng.run(max_steps=500)
+    for rid, n, k in zip(rids, (4, 6), (7, 5)):
+        np.testing.assert_array_equal(
+            out[rid], generate(m, PATTERN[None, :n], k,
+                               temperature=0.0)[0])
+    assert "paged_attention=gather_reference" in \
+        eng.health()["programs"]["decode_logits[off,None]"]
+    with pytest.raises(ValueError, match="paged cache"):
+        ServingEngine(m, num_slots=1, max_len=32,
+                      kv_layout="slab").decode_logits()
+
+
 def test_engine_kernel_sampled_matches_gather_engine(memorized_lm):
     """A sampled stream decoded through the kernel draws the same
     bytes as through the gather path (the logits agree far inside

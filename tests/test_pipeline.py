@@ -54,8 +54,7 @@ def test_pipeline_forward_matches_sequential():
 
     pipe = make_pipeline_fn(block, "pp", bstate)
     fn = jax.jit(shard_map(
-        pipe, mesh=mesh, in_specs=(P("pp"), P()), out_specs=P(),
-        check_vma=False))
+        pipe, mesh=mesh, in_specs=(P("pp"), P()), out_specs=P()))
     y_pipe = np.asarray(fn(stacked, x))
     np.testing.assert_allclose(y_ref, y_pipe, rtol=2e-5, atol=2e-5)
 
@@ -258,8 +257,7 @@ def test_interleaved_forward_matches_sequential():
     permuted = jax.tree_util.tree_map(lambda l: l[perm], stacked)
     pipe = make_pipeline_fn(block, "pp", bstate, virtual_stages=2)
     fn = jax.jit(shard_map(
-        pipe, mesh=mesh, in_specs=(P("pp"), P()), out_specs=P(),
-        check_vma=False))
+        pipe, mesh=mesh, in_specs=(P("pp"), P()), out_specs=P()))
     y_pipe = np.asarray(fn(permuted, x))
     np.testing.assert_allclose(y_ref, y_pipe, rtol=2e-5, atol=2e-5)
 

@@ -167,6 +167,61 @@ def test_spmd_trainer_matches_single_device_sgd():
                                rtol=1e-4, atol=1e-5)
 
 
+@pytest.mark.parametrize("mesh_shape", [{"workers": 4, "tp": 2},
+                                        {"workers": 8}])
+def test_spmd_trainer_flash_kernel_runs_per_shard(mesh_shape):
+    """XLA cannot partition a Mosaic kernel, so under SPMDTrainer the
+    flash kernel (interpreter here) runs inside a shard_map over the
+    data and tp axes: the step must hold one kernel call per shard of
+    batch/heads — no q/k/v all-gather — and train step-for-step like
+    the unsharded run."""
+    from distkeras_tpu.ops import flash_attention as fa
+    from distkeras_tpu.parallel import SingleTrainer
+    rs = np.random.RandomState(3)
+    vocab, seq = 32, 16
+    X = rs.randint(0, vocab, (64, seq)).astype(np.int32)
+    ds = Dataset({"features": X, "label": np.roll(X, -1, axis=1)})
+    kwargs = dict(batch_size=16, num_epoch=1, worker_optimizer="sgd",
+                  optimizer_kwargs={"learning_rate": 0.05},
+                  loss="sparse_categorical_crossentropy_from_logits",
+                  shuffle_each_epoch=False)
+
+    def lm():
+        return Model.build(
+            zoo.transformer_lm(vocab, d_model=16, num_heads=4,
+                               num_layers=1, mlp_ratio=2,
+                               attn_impl="flash"), (seq,), seed=5)
+
+    single = SingleTrainer(lm(), **kwargs)
+    single.train(ds)
+    seen = []
+    real = fa._flash_forward
+    def spy(q, *a, **k):
+        seen.append(q.shape)
+        return real(q, *a, **k)
+    fa._flash_forward = spy
+    try:
+        spmd = SPMDTrainer(lm(), mesh=make_mesh_2d(mesh_shape),
+                           tp_axis="tp", **kwargs)
+        spmd.train(ds)
+    finally:
+        fa._flash_forward = real
+    # the kernel saw ONE SHARD: batch / workers, heads / tp
+    want = (16 // mesh_shape["workers"], 4 // mesh_shape.get("tp", 1),
+            seq, 4)
+    assert seen and all(sh == want for sh in seen), (seen, want)
+    # state and batch really sat on all eight devices, and the epoch
+    # program can be read back: under pure data parallelism nothing in
+    # it all-gathers (q/k/v reach the kernel as the shards they are)
+    assert spmd.placement == {"params": list(range(8)),
+                              "batch": list(range(8))}
+    if "tp" not in mesh_shape:
+        assert "all-gather" not in spmd.lower_epoch().compile().as_text()
+    np.testing.assert_allclose(single.get_history().losses(),
+                               spmd.get_history().losses(),
+                               rtol=1e-4, atol=1e-5)
+
+
 def test_spmd_trainer_moe_ep():
     """MoE classification over dp×ep×tp axes (expert parallelism)."""
     rs = np.random.RandomState(2)

@@ -1,0 +1,266 @@
+"""Compile-only guard: the kernels of the main path, at real widths,
+must lower through Mosaic and XLA for a TPU v5e that is DESCRIBED, not
+attached (``jax.experimental.topologies``). Interpret-mode oracles pin
+what a kernel computes; they cannot see what the chip's compiler
+refuses — a slice off the tiling, a primitive Mosaic does not lower,
+a kernel XLA is asked to partition. Nothing here runs, so nothing here
+says a kernel is right or fast: a compile that passes is not a chip
+run (``chip_smoke.py`` is).
+
+Every public Pallas entry point in ``ops/`` has to appear in THIS file
+(``tools/lint_kernel_oracles.py``), so an interpret-only kernel cannot
+land again.
+
+The topology is described inside a module-scoped fixture that skips
+where it cannot be: only one process may load the TPU's library, so
+the call must not happen at import, in a ``skipif`` or in
+``parametrize`` — every xdist worker imports this file, only the one
+that runs it reaches the fixture. The compiles happen in this process,
+with the persistent compile cache off around them (an entry written
+for a described chip cannot be read back without one).
+"""
+
+import numpy as np
+import pytest
+
+import jax
+import jax.numpy as jnp
+from jax.sharding import (Mesh, NamedSharding, PartitionSpec as P,
+                          SingleDeviceSharding)
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+    try:
+        desc = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield desc
+    jax.config.update("jax_enable_compilation_cache", True)
+    compilation_cache.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture()
+def as_tpu(monkeypatch):
+    """Steer the repo's trace-time backend convention to its TPU branch
+    (``compat.backend_is_tpu`` sees the CPU here): every module that
+    imported the predicate gets the patched one, so the PUBLIC entry
+    points pick their kernels with ``interpret=False`` — the program
+    the chip would be handed."""
+    import distkeras_tpu.compat as compat
+    from distkeras_tpu.models import attention, decoding
+    from distkeras_tpu.ops import (decode_attention, flash_attention,
+                                   moe_kernels, paged_attention,
+                                   quant_matmul, sampling)
+    for mod in (compat, attention, decoding, decode_attention,
+                flash_attention, moe_kernels, paged_attention,
+                quant_matmul, sampling):
+        monkeypatch.setattr(mod, "backend_is_tpu", lambda: True)
+
+
+def _compile(fn, *args):
+    """Compile for the described chip; returns (kernel count, text)."""
+    text = jax.jit(fn).lower(*args).compile().as_text()
+    return text.count('custom_call_target="tpu_custom_call"'), text
+
+
+def _spec(sharding):
+    return lambda shape, dtype: jax.ShapeDtypeStruct(shape, dtype,
+                                                     sharding=sharding)
+
+
+# --- flash attention -------------------------------------------------------
+
+#: the LM_CFG attention shape (bench.py): B8 H16 S2048 D64 bf16, BHSD
+QKV = (8, 16, 2048, 64)
+
+
+def _flash_loss(q, k, v):
+    from distkeras_tpu.ops.flash_attention import flash_attention
+    return flash_attention(q, k, v, causal=True, layout="bhsd") \
+        .astype(jnp.float32).sum()
+
+
+def test_flash_attention_forward(one_chip, as_tpu):
+    q = _spec(one_chip)(QKV, jnp.bfloat16)
+    n, _ = _compile(_flash_loss, q, q, q)
+    assert n == 1
+
+
+def test_flash_attention_forward_backward(one_chip, as_tpu):
+    q = _spec(one_chip)(QKV, jnp.bfloat16)
+    n, _ = _compile(jax.grad(_flash_loss, argnums=(0, 1, 2)), q, q, q)
+    assert n == 3                       # fwd, dq, dkv
+
+
+def test_flash_attention_under_dp4_mesh(topo, as_tpu):
+    """XLA refuses to partition a Mosaic kernel; inside
+    ``flash_attention.partitioned`` (what ``SPMDTrainer`` traces under)
+    the kernel runs per shard: all three custom calls in the partition,
+    q/k/v never all-gathered."""
+    from distkeras_tpu.ops.flash_attention import partitioned
+    mesh = Mesh(np.array(topo.devices).reshape(4), ("workers",))
+    q = _spec(NamedSharding(mesh, P("workers")))(QKV, jnp.bfloat16)
+    grad = jax.grad(_flash_loss, argnums=(0, 1, 2))
+    with pytest.raises(NotImplementedError,
+                       match="cannot be automatically partitioned"):
+        _compile(grad, q, q, q)
+
+    def wrapped(q, k, v):
+        with partitioned(mesh, ("workers",), "tp"):
+            return grad(q, k, v)
+
+    n, text = _compile(wrapped, q, q, q)
+    assert n == 3
+    assert "all-gather" not in text
+
+
+# --- decode attention ------------------------------------------------------
+
+@pytest.mark.parametrize("int8", [False, True], ids=["bf16", "int8"])
+def test_decode_attention(one_chip, as_tpu, int8):
+    from distkeras_tpu.ops.decode_attention import decode_attention
+    s = _spec(one_chip)
+    bh, g, d, L = 8 * 16, 1, 64, 2560
+    q = s((bh, g, d), jnp.bfloat16)
+    kv = s((bh, L, d), jnp.int8 if int8 else jnp.bfloat16)
+    t = s((), jnp.int32)
+    if int8:
+        sc = s((bh, L), jnp.float32)
+        fn = lambda q, k, v, t, ks, vs: decode_attention(
+            q, k, v, t, k_scale=ks, v_scale=vs)
+        n, _ = _compile(fn, q, kv, kv, t, sc, sc)
+    else:
+        n, _ = _compile(decode_attention, q, kv, kv, t)
+    assert n == 1
+
+
+@pytest.mark.parametrize("page_len,int8", [(16, False), (32, True)],
+                         ids=["bf16-page16", "int8-page32"])
+def test_paged_decode_attention(one_chip, as_tpu, page_len, int8):
+    """The serving default (8 slots, max_len 2304, ``page_len=16``)
+    and the int8 pool's tiling (``page_len % 32``)."""
+    from distkeras_tpu.ops.paged_attention import paged_decode_attention
+    s = _spec(one_chip)
+    slots, hkv, d = 8, 16, 64
+    per_slot = 2304 // page_len
+    n_pages = slots * per_slot
+    q = s((slots, 1, hkv, 1, d), jnp.float32)
+    pages = s((n_pages, hkv, page_len, d),
+              jnp.int8 if int8 else jnp.bfloat16)
+    t = s((slots,), jnp.int32)
+    table = s((slots, per_slot), jnp.int32)
+    if int8:
+        sc = s((n_pages, hkv, page_len), jnp.float32)
+        fn = lambda q, k, v, t, tb, ks, vs: paged_decode_attention(
+            q, k, v, t, tb, k_scale=ks, v_scale=vs)
+        n, _ = _compile(fn, q, pages, pages, t, table, sc, sc)
+    else:
+        n, _ = _compile(paged_decode_attention, q, pages, pages, t, table)
+    assert n == 1
+
+
+# --- quantized matmul ------------------------------------------------------
+
+@pytest.mark.parametrize("bits", [8, 4], ids=["int8", "int4"])
+def test_quant_matmul(one_chip, as_tpu, bits):
+    from distkeras_tpu.ops.quant_matmul import quant_matmul
+    s = _spec(one_chip)
+    m, k, n = 8, 1024, 3072
+    wq = {"q4": s((k // 2, n), jnp.int8)} if bits == 4 \
+        else {"q": s((k, n), jnp.int8)}
+    wq["scale"] = s((n,), jnp.float32)
+    calls, _ = _compile(quant_matmul, s((m, k), jnp.bfloat16), wq)
+    assert calls == 1
+
+
+# --- fused MoE -------------------------------------------------------------
+
+def _moe_args(s, n, d, hid, e, k, dt):
+    return (s((n, d), dt), s((e, d, hid), dt), s((e, hid), dt),
+            s((e, hid, d), dt), s((e, d), dt), s((k * n,), jnp.float32),
+            s((k * n,), jnp.int32), s((k * n,), jnp.bool_))
+
+
+@pytest.mark.parametrize("dt", [jnp.bfloat16, jnp.float32],
+                         ids=["bf16", "f32"])
+@pytest.mark.parametrize("grad", [False, True], ids=["fwd", "fwd+bwd"])
+def test_fused_moe_apply_training_shape(one_chip, as_tpu, dt, grad):
+    """N=16384 tokens, d=1024, H=4096, E=8, top-2 at capacity factor
+    1.25 — the shape whose one-row gather Mosaic refused (f32: "Slice
+    shape along dimension 0 must be aligned to tiling (8), but is 1";
+    bf16: "cannot statically prove that index in dimension 0 is a
+    multiple of 8")."""
+    from distkeras_tpu.ops.moe_kernels import fused_moe_apply
+    n, d, hid, e, k = 16384, 1024, 4096, 8, 2
+    cap = int(1.25 * k * n / e)
+
+    def fwd(xt, w1, b1, w2, b2, sg, dest, keep):
+        return fused_moe_apply(xt, w1, b1, w2, b2, sg, dest, keep,
+                               capacity=cap)
+
+    def bwd(xt, w1, b1, w2, b2, sg, dest, keep):
+        return jax.grad(
+            lambda *a: fwd(*a, dest, keep).astype(jnp.float32).sum(),
+            argnums=(0, 1, 2, 3, 4, 5))(xt, w1, b1, w2, b2, sg)
+
+    calls, _ = _compile(bwd if grad else fwd,
+                        *_moe_args(_spec(one_chip), n, d, hid, e, k, dt))
+    assert calls == (3 if grad else 1)  # gather-GEMM1 (+ dx, dw1)
+
+
+@pytest.mark.parametrize("tokens", [4, 8, 32])
+def test_moe_fused_experts_decode_shape(one_chip, as_tpu, tokens):
+    """``MOE_SERVE_CFG`` (bench.py: d=512, expert width 1024, E=8,
+    bf16) at the engine's decode sizes — 4 and 8 slots, and a
+    speculative verify window (8 slots x 4) — capacity = token count
+    (``MoE.decode_apply``: drop-free by construction)."""
+    from distkeras_tpu.ops.moe_kernels import (choose_block_c,
+                                               kernel_capacity,
+                                               moe_fused_experts)
+    block_c = choose_block_c(kernel_capacity(tokens))
+    calls, _ = _compile(
+        lambda *a: moe_fused_experts("gelu", tokens, block_c, False, *a),
+        *_moe_args(_spec(one_chip), tokens, 512, 1024, 8, 2,
+                   jnp.bfloat16))
+    assert calls == 1
+
+
+# --- fused sampling --------------------------------------------------------
+
+def test_sample_epilogue_real_vocab(one_chip, as_tpu):
+    """S=8, V=32768 — the shape whose in-kernel prefix sums Mosaic
+    refused ("Unimplemented primitive in Pallas TPU lowering for
+    KernelType.TC: cumsum")."""
+    from distkeras_tpu.ops.sampling import fused_supported, sample_epilogue
+    s = _spec(one_chip)
+    slots, vocab = 8, 32768
+    assert fused_supported(vocab)
+    calls, _ = _compile(
+        sample_epilogue, s((slots, vocab), jnp.float32),
+        s((slots,), jnp.float32), s((slots,), jnp.int32),
+        s((slots,), jnp.float32), s((slots, vocab), jnp.float32))
+    assert calls == 1
+
+
+def test_sample_tokens_with_keys(one_chip, as_tpu):
+    """The engine's ``fused_sampling=True`` sampler: per-slot keys ->
+    gumbel field -> the epilogue kernel, rows padded from 5 to 8."""
+    from distkeras_tpu.ops.sampling import sample_tokens
+    s = _spec(one_chip)
+    slots, vocab = 5, 1024
+    calls, _ = _compile(
+        sample_tokens, s((slots, vocab), jnp.bfloat16),
+        s((slots,), jnp.float32), s((slots,), jnp.int32),
+        s((slots,), jnp.float32), s((slots, 2), jnp.uint32))
+    assert calls == 1

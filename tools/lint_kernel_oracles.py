@@ -1,6 +1,6 @@
 #!/usr/bin/env python
 """Static check: every Pallas kernel entry point has an
-interpret-mode oracle test.
+interpret-mode oracle test AND a compile-for-the-TPU test.
 
 The repo-wide testing convention (docs/testing.md, PR 3 onward): a
 Pallas kernel never ships on trust — some tier-1 test runs it under
@@ -21,6 +21,13 @@ exercises interpreter mode (mentions ``interpret``; the
 both match). A justified exception carries the marker comment
 ``lint: allow-no-oracle`` on the ``def`` line.
 
+Interpreter mode cannot see what the chip's compiler refuses (two
+kernels passed every oracle for twenty PRs and had never lowered
+through Mosaic), so each entry point must ALSO be named in
+``tests/test_tpu_compile.py`` — the one file that compiles the kernels
+at real widths for a described TPU. That requirement has no exemption
+mark.
+
 Exit status 1 when findings exist (wired into tier-1 as
 ``tests/test_lint_kernel_oracles.py``).
 """
@@ -38,22 +45,26 @@ ALLOW_MARK = "lint: allow-no-oracle"
 #: where kernels live and where their oracles live, repo-relative
 OPS_DIR = "distkeras_tpu/ops"
 TESTS_DIR = "tests"
+#: the compile-only file (ONE file: its topology fixture must run in
+#: one xdist worker)
+COMPILE_TEST = "tests/test_tpu_compile.py"
 
 Finding = Tuple[str, int, str]
 
 
 def _calls_in(fn: ast.AST) -> Tuple[bool, Set[str]]:
-    """(has a direct pallas_call, names of functions called)."""
+    """(has a direct pallas_call, names the function refers to).
+    Every name counts, not only a called one: a kernel wrapper handed
+    to ``functools.partial`` or ``shard_map`` is reached all the same."""
     direct = False
     names: Set[str] = set()
     for node in ast.walk(fn):
-        if not isinstance(node, ast.Call):
-            continue
-        f = node.func
-        if isinstance(f, ast.Attribute) and f.attr == "pallas_call":
+        if isinstance(node, ast.Name):
+            names.add(node.id)
+        elif isinstance(node, ast.Call) \
+                and isinstance(node.func, ast.Attribute) \
+                and node.func.attr == "pallas_call":
             direct = True
-        elif isinstance(f, ast.Name):
-            names.add(f.id)
     return direct, names
 
 
@@ -94,10 +105,12 @@ def _exempt(src_lines: List[str], lineno: int) -> bool:
 
 def check_tree(root: Path) -> List[Finding]:
     """Every kernel entry point across ``ops/`` without an
-    interpret-mode oracle test referencing it by name."""
+    interpret-mode oracle test referencing it by name, or not named in
+    the compile-only file."""
     test_texts: Dict[str, str] = {
         str(p.relative_to(root)): p.read_text()
         for p in sorted((root / TESTS_DIR).glob("test_*.py"))}
+    compile_text = test_texts.get(COMPILE_TEST, "")
     findings: List[Finding] = []
     for mod in sorted((root / OPS_DIR).glob("*.py")):
         rel = str(mod.relative_to(root))
@@ -110,9 +123,14 @@ def check_tree(root: Path) -> List[Finding]:
             continue
         lines = src.splitlines()
         for name, lineno in entries:
+            pat = re.compile(rf"\b{re.escape(name)}\b")
+            if not pat.search(compile_text):
+                findings.append((
+                    rel, lineno,
+                    f"kernel entry point '{name}' is not compiled for "
+                    f"the TPU ({COMPILE_TEST} does not name it)"))
             if _exempt(lines, lineno):
                 continue
-            pat = re.compile(rf"\b{re.escape(name)}\b")
             covered = any(
                 pat.search(text) and "interpret" in text
                 for text in test_texts.values())
@@ -133,8 +151,9 @@ def main(argv=None) -> int:
     if findings:
         print(f"{len(findings)} kernel-oracle finding(s); add an "
               f"interpret-mode test pinning the kernel against its "
-              f"XLA reference, or mark the def line with "
-              f"'# {ALLOW_MARK}'", file=sys.stderr)
+              f"XLA reference (or mark the def line with "
+              f"'# {ALLOW_MARK}'), and a compile case in "
+              f"{COMPILE_TEST}", file=sys.stderr)
         return 1
     return 0
 
