@@ -84,6 +84,8 @@ class PositionalEmbedding(Layer):
     GLOBAL positions (mirroring the RoPE handling in MultiHeadAttention).
     """
 
+    scope = "embed"
+
     def __init__(self, max_len: int, seq_axis_name: Optional[str] = None):
         self.max_len = int(max_len)
         self.seq_axis_name = seq_axis_name
@@ -431,11 +433,12 @@ class TransformerBlock(Layer):
     def apply(self, params, state, x, *, training=False, rng=None,
               segment_ids=None):
         new_state = dict(state)
-        h, new_state["norm1"] = self.norm1.apply(
-            params["norm1"], state["norm1"], x, training=training)
-        a, new_state["attn"] = self.attn.apply(
-            params["attn"], state["attn"], h, training=training,
-            segment_ids=segment_ids)
+        with jax.named_scope("attn"):
+            h, new_state["norm1"] = self.norm1.apply(
+                params["norm1"], state["norm1"], x, training=training)
+            a, new_state["attn"] = self.attn.apply(
+                params["attn"], state["attn"], h, training=training,
+                segment_ids=segment_ids)
 
         def drop(y, key):  # both residual branches share the Dropout layer
             return self._dropout.apply({}, {}, y, training=training,
@@ -450,10 +453,12 @@ class TransformerBlock(Layer):
         if use_dropout:
             a = drop(a, k_drop1)
         x = x + a
-        h, new_state["norm2"] = self.norm2.apply(
-            params["norm2"], state["norm2"], x, training=training)
-        m, new_state["mlp"] = self.mlp.apply(
-            params["mlp"], state["mlp"], h, training=training, rng=k_mlp)
+        with jax.named_scope("mlp"):
+            h, new_state["norm2"] = self.norm2.apply(
+                params["norm2"], state["norm2"], x, training=training)
+            m, new_state["mlp"] = self.mlp.apply(
+                params["mlp"], state["mlp"], h, training=training,
+                rng=k_mlp)
         if use_dropout:
             m = drop(m, k_drop2)
         return x + m, new_state

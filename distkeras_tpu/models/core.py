@@ -20,6 +20,7 @@ Design notes (TPU-first):
 
 from __future__ import annotations
 
+import contextlib
 from typing import Any, Dict, List, Optional, Sequence, Tuple
 
 import jax
@@ -97,6 +98,11 @@ class Layer:
     #: the serialized architecture config.
     trainable: bool = True
 
+    #: ``jax.named_scope`` of this layer's operations in a step program
+    #: (``embed``; a TransformerBlock opens ``attn`` and ``mlp`` itself):
+    #: what a profiler's op names are grouped by. None: no scope.
+    scope: Optional[str] = None
+
     def init(self, rng: jax.Array, input_shape: Tuple[int, ...]):
         return {}, {}, input_shape
 
@@ -118,6 +124,12 @@ class Layer:
     def __repr__(self) -> str:
         cfg = ", ".join(f"{k}={v!r}" for k, v in self.get_config().items())
         return f"{self.name}({cfg})"
+
+
+def scoped(name: Optional[str]):
+    """``jax.named_scope(name)``, or nothing for ``None``. Scopes change
+    the names of a program's operations and no compiled code."""
+    return jax.named_scope(name) if name else contextlib.nullcontext()
 
 
 @register_layer
@@ -152,6 +164,15 @@ class Sequential(Layer):
         return any(getattr(l, "accepts_segment_ids", False)
                    for l in self.layers)
 
+    def scope_of(self, i: int) -> Optional[str]:
+        """The named scope of layer ``i``: its own, and ``head`` for the
+        last layer of a stack that starts with an embedding (a language
+        model's vocabulary projection)."""
+        layers = self.layers
+        if 0 < i == len(layers) - 1 and layers[0].scope == "embed":
+            return "head"
+        return layers[i].scope
+
     def apply(self, params, state, x, *, training=False, rng=None,
               segment_ids=None):
         """``segment_ids`` ([B, S] int, packed/variable-length sequences)
@@ -174,14 +195,12 @@ class Sequential(Layer):
                 rng, sub = jax.random.split(rng)
             else:
                 sub = None
-            if segment_ids is not None and \
-                    getattr(layer, "accepts_segment_ids", False):
+            kw = ({"segment_ids": segment_ids}
+                  if segment_ids is not None
+                  and getattr(layer, "accepts_segment_ids", False) else {})
+            with scoped(self.scope_of(i)):
                 x, s = layer.apply(params[i], state[i], x,
-                                   training=training, rng=sub,
-                                   segment_ids=segment_ids)
-            else:
-                x, s = layer.apply(params[i], state[i], x,
-                                   training=training, rng=sub)
+                                   training=training, rng=sub, **kw)
             new_state.append(s)
         return x, new_state
 

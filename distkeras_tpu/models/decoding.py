@@ -45,7 +45,7 @@ from distkeras_tpu.compat import backend_is_tpu, note_path
 from distkeras_tpu.models.attention import (MultiHeadAttention,
                                             PositionalEmbedding,
                                             TransformerBlock)
-from distkeras_tpu.models.core import Model, Sequential
+from distkeras_tpu.models.core import Model, Sequential, scoped
 from distkeras_tpu.models.layers import Dropout
 from distkeras_tpu.ops.attention import NEG_INF, apply_rope
 
@@ -335,11 +335,13 @@ def _decode_attn(attn: MultiHeadAttention, p, kv, x, t):
 
 
 def _decode_block(block: TransformerBlock, p, s, kv, x, t):
-    h, _ = block.norm1.apply(p["norm1"], s["norm1"], x)
-    a, kv = _decode_attn(block.attn, p["attn"], kv, h, t)
+    with jax.named_scope("attn"):
+        h, _ = block.norm1.apply(p["norm1"], s["norm1"], x)
+        a, kv = _decode_attn(block.attn, p["attn"], kv, h, t)
     x = x + a
-    h, _ = block.norm2.apply(p["norm2"], s["norm2"], x)
-    m, _ = block.mlp.apply(p["mlp"], s["mlp"], h, training=False)
+    with jax.named_scope("mlp"):
+        h, _ = block.norm2.apply(p["norm2"], s["norm2"], x)
+        m, _ = block.mlp.apply(p["mlp"], s["mlp"], h, training=False)
     return x + m, kv
 
 
@@ -354,22 +356,24 @@ def _prefill_block(block: TransformerBlock, p, s, kv, x, positions):
 
     attn = block.attn
     dt = jnp.dtype(attn.dtype)
-    h_, _ = block.norm1.apply(p["norm1"], s["norm1"], x)
-    xc = h_.astype(dt)
-    q, k, v = _project_qkv(attn, p["attn"], xc)
-    if attn.use_rope:
-        q = apply_rope(q, positions, scale=attn.rope_scale)
-        k = apply_rope(k, positions, scale=attn.rope_scale)
-    kv = _cache_write(kv, k, v, 0)
-    ke, ve = attn._expand_kv(k, 2), attn._expand_kv(v, 2)
-    impl = "flash" if backend_is_tpu() else "xla"
-    out = _attention_compute(q, ke, ve, causal=True, impl=impl,
-                             window=attn.attn_window)
-    y = jnp.einsum("bshe,hed->bsd", out.astype(dt), p["attn"]["wo"]
-                   .astype(dt))
+    with jax.named_scope("attn"):
+        h_, _ = block.norm1.apply(p["norm1"], s["norm1"], x)
+        xc = h_.astype(dt)
+        q, k, v = _project_qkv(attn, p["attn"], xc)
+        if attn.use_rope:
+            q = apply_rope(q, positions, scale=attn.rope_scale)
+            k = apply_rope(k, positions, scale=attn.rope_scale)
+        kv = _cache_write(kv, k, v, 0)
+        ke, ve = attn._expand_kv(k, 2), attn._expand_kv(v, 2)
+        impl = "flash" if backend_is_tpu() else "xla"
+        out = _attention_compute(q, ke, ve, causal=True, impl=impl,
+                                 window=attn.attn_window)
+        y = jnp.einsum("bshe,hed->bsd", out.astype(dt), p["attn"]["wo"]
+                       .astype(dt))
     x = x + y.astype(x.dtype)
-    h_, _ = block.norm2.apply(p["norm2"], s["norm2"], x)
-    m, _ = block.mlp.apply(p["mlp"], s["mlp"], h_, training=False)
+    with jax.named_scope("mlp"):
+        h_, _ = block.norm2.apply(p["norm2"], s["norm2"], x)
+        m, _ = block.mlp.apply(p["mlp"], s["mlp"], h_, training=False)
     return x + m, kv
 
 
@@ -480,70 +484,72 @@ def _prefill_block_chunked(block: TransformerBlock, p, s, kv, x, positions,
     exist."""
     attn = block.attn
     dt = jnp.dtype(attn.dtype)
-    h_, _ = block.norm1.apply(p["norm1"], s["norm1"], x)
-    xc = h_.astype(dt)
-    q, k, v = _project_qkv(attn, p["attn"], xc)
-    if attn.use_rope:
-        q = apply_rope(q, positions, scale=attn.rope_scale)
-        k = apply_rope(k, positions, scale=attn.rope_scale)
-    kv = _cache_write(kv, k, v, t0)
-    b, q_len, nh, dh = q.shape
-    hkv = attn.kv_heads
-    g = nh // hkv
-    scale = (attn.head_dim or dh) ** -0.5
-    window = attn.attn_window
-    # (b) causal within the chunk (small: kv expansion is chunk-sized);
-    # sliding-window models window the diagonal pass too
-    ke, ve = attn._expand_kv(k, 2), attn._expand_kv(v, 2)
-    o_diag, lse_diag = _attn_lse(q, ke, ve, causal=True, scale=scale,
-                                 layout="bshd", window=window)
-    # prefix reach: everything before the chunk for full attention; only
-    # the last window-1 positions for SWA (older keys are out of every
-    # chunk query's reach)
-    lo = 0 if window is None else max(0, t0 - window + 1)
-    if t0 > lo:
-        kp, vp = _cache_prefix(kv, t0, dt, lo=lo)
-        if window is None:
-            # (a) chunk vs prefix: no causal structure (every chunk
-            # query is newer than every prefix key), so the G query
-            # heads sharing one KV head fold into the ROW axis —
-            # [B*Hkv, G*Q, D] against [B*Hkv, t0, D] — and the cache is
-            # read in its native head-major layout with no expansion
-            qg = q.reshape(b, q_len, hkv, g, dh) \
-                  .transpose(0, 2, 3, 1, 4) \
-                  .reshape(b * hkv, 1, g * q_len, dh)
-            o_pre, lse_pre = _attn_lse(
-                qg, kp.reshape(b * hkv, 1, t0, dh),
-                vp.reshape(b * hkv, 1, t0, dh),
-                causal=False, scale=scale, layout="bhsd")
-            o_pre = o_pre.reshape(b, hkv, g, q_len, dh) \
-                         .transpose(0, 3, 1, 2, 4) \
-                         .reshape(b, q_len, nh, dh)
-            # (hkv, g) are already adjacent in head order
-            # h = hkv_i*g + g_i: flatten directly — a transpose here
-            # would scramble (pos, group)
-            lse_pre = lse_pre.reshape(b, hkv, g, q_len) \
-                             .reshape(b, nh, q_len)
+    with jax.named_scope("attn"):
+        h_, _ = block.norm1.apply(p["norm1"], s["norm1"], x)
+        xc = h_.astype(dt)
+        q, k, v = _project_qkv(attn, p["attn"], xc)
+        if attn.use_rope:
+            q = apply_rope(q, positions, scale=attn.rope_scale)
+            k = apply_rope(k, positions, scale=attn.rope_scale)
+        kv = _cache_write(kv, k, v, t0)
+        b, q_len, nh, dh = q.shape
+        hkv = attn.kv_heads
+        g = nh // hkv
+        scale = (attn.head_dim or dh) ** -0.5
+        window = attn.attn_window
+        # (b) causal within the chunk (small: kv expansion is chunk-sized);
+        # sliding-window models window the diagonal pass too
+        ke, ve = attn._expand_kv(k, 2), attn._expand_kv(v, 2)
+        o_diag, lse_diag = _attn_lse(q, ke, ve, causal=True, scale=scale,
+                                     layout="bshd", window=window)
+        # prefix reach: everything before the chunk for full attention; only
+        # the last window-1 positions for SWA (older keys are out of every
+        # chunk query's reach)
+        lo = 0 if window is None else max(0, t0 - window + 1)
+        if t0 > lo:
+            kp, vp = _cache_prefix(kv, t0, dt, lo=lo)
+            if window is None:
+                # (a) chunk vs prefix: no causal structure (every chunk
+                # query is newer than every prefix key), so the G query
+                # heads sharing one KV head fold into the ROW axis —
+                # [B*Hkv, G*Q, D] against [B*Hkv, t0, D] — and the cache is
+                # read in its native head-major layout with no expansion
+                qg = q.reshape(b, q_len, hkv, g, dh) \
+                      .transpose(0, 2, 3, 1, 4) \
+                      .reshape(b * hkv, 1, g * q_len, dh)
+                o_pre, lse_pre = _attn_lse(
+                    qg, kp.reshape(b * hkv, 1, t0, dh),
+                    vp.reshape(b * hkv, 1, t0, dh),
+                    causal=False, scale=scale, layout="bhsd")
+                o_pre = o_pre.reshape(b, hkv, g, q_len, dh) \
+                             .transpose(0, 3, 1, 2, 4) \
+                             .reshape(b, q_len, nh, dh)
+                # (hkv, g) are already adjacent in head order
+                # h = hkv_i*g + g_i: flatten directly — a transpose here
+                # would scramble (pos, group)
+                lse_pre = lse_pre.reshape(b, hkv, g, q_len) \
+                                 .reshape(b, nh, q_len)
+            else:
+                # (a') SWA prefix BAND [lo, t0): the window edge crosses the
+                # band per query, so this is masked attention (the GQA fold
+                # would break the per-position mask); the band is < window
+                # keys, so expanding its kv heads in place (axis 1 of the
+                # native [B, Hkv, Lb, D] layout) is small. Round 5: closes
+                # the chunked-prefill SWA gap.
+                o_pre, lse_pre = _banded_prefix_attn(
+                    q, attn._expand_kv(kp, 1), attn._expand_kv(vp, 1),
+                    t0, lo, window, scale)
+            out = _merge_attention(
+                o_pre.transpose(0, 2, 1, 3), lse_pre,
+                o_diag.transpose(0, 2, 1, 3), lse_diag).transpose(0, 2, 1, 3)
         else:
-            # (a') SWA prefix BAND [lo, t0): the window edge crosses the
-            # band per query, so this is masked attention (the GQA fold
-            # would break the per-position mask); the band is < window
-            # keys, so expanding its kv heads in place (axis 1 of the
-            # native [B, Hkv, Lb, D] layout) is small. Round 5: closes
-            # the chunked-prefill SWA gap.
-            o_pre, lse_pre = _banded_prefix_attn(
-                q, attn._expand_kv(kp, 1), attn._expand_kv(vp, 1),
-                t0, lo, window, scale)
-        out = _merge_attention(
-            o_pre.transpose(0, 2, 1, 3), lse_pre,
-            o_diag.transpose(0, 2, 1, 3), lse_diag).transpose(0, 2, 1, 3)
-    else:
-        out = o_diag
-    y = jnp.einsum("bshe,hed->bsd", out.astype(dt),
-                   p["attn"]["wo"].astype(dt))
+            out = o_diag
+        y = jnp.einsum("bshe,hed->bsd", out.astype(dt),
+                       p["attn"]["wo"].astype(dt))
     x = x + y.astype(x.dtype)
-    h_, _ = block.norm2.apply(p["norm2"], s["norm2"], x)
-    m, _ = block.mlp.apply(p["mlp"], s["mlp"], h_, training=False)
+    with jax.named_scope("mlp"):
+        h_, _ = block.norm2.apply(p["norm2"], s["norm2"], x)
+        m, _ = block.mlp.apply(p["mlp"], s["mlp"], h_, training=False)
     return x + m, kv
 
 
@@ -575,14 +581,16 @@ def prefill_chunk_step(module: Sequential, params, state, cache, chunk,
             x, new_cache[i] = _prefill_block_chunked(
                 block, p, s, new_cache[i], x, positions, t0)
         elif isinstance(layer, PositionalEmbedding):
-            x = x + p["embeddings"][t0:t0 + q_len][None] \
-                .astype(x.dtype)
+            with jax.named_scope("embed"):
+                x = x + p["embeddings"][t0:t0 + q_len][None] \
+                    .astype(x.dtype)
         elif isinstance(layer, Dropout):
             pass                                         # eval: identity
         else:
             if i == last and x.ndim == 3:
                 x = x[:, -1:]        # head on the final position only
-            x, _ = layer.apply(p, s, x, training=False)
+            with scoped(module.scope_of(i)):
+                x, _ = layer.apply(p, s, x, training=False)
     return (x[:, -1] if final else None), new_cache
 
 
@@ -632,13 +640,15 @@ def prefill(module: Sequential, params, state, cache, prompts):
             x, new_cache[i] = _prefill_block(block, p, s, cache[i], x,
                                              positions)
         elif isinstance(layer, PositionalEmbedding):
-            x = x + p["embeddings"][:p_len][None].astype(x.dtype)
+            with jax.named_scope("embed"):
+                x = x + p["embeddings"][:p_len][None].astype(x.dtype)
         elif isinstance(layer, Dropout):
             pass                                         # eval: identity
         else:
             if i == last and x.ndim == 3:
                 x = x[:, -1:]        # head on the final position only
-            x, _ = layer.apply(p, s, x, training=False)
+            with scoped(module.scope_of(i)):
+                x, _ = layer.apply(p, s, x, training=False)
     return x[:, -1], new_cache
 
 
@@ -653,11 +663,13 @@ def decode_step(module: Sequential, params, state, cache, tok, t):
         if block is not None:
             x, new_cache[i] = _decode_block(block, p, s, kv, x, t)
         elif isinstance(layer, PositionalEmbedding):
-            x = x + p["embeddings"][t][None, None, :].astype(x.dtype)
+            with jax.named_scope("embed"):
+                x = x + p["embeddings"][t][None, None, :].astype(x.dtype)
         elif isinstance(layer, Dropout):
             pass                                         # eval: identity
         else:
-            x, _ = layer.apply(p, s, x, training=False)
+            with scoped(module.scope_of(i)):
+                x, _ = layer.apply(p, s, x, training=False)
     return x[:, 0], new_cache                            # [B, V]
 
 
@@ -869,12 +881,14 @@ def _decode_attn_slots(attn: MultiHeadAttention, p, kv, x, t):
 
 def _decode_block_slots(block: TransformerBlock, p, s, kv, x, t,
                         moe_dispatched=True, routing=None):
-    h, _ = block.norm1.apply(p["norm1"], s["norm1"], x)
-    a, kv = _decode_attn_slots(block.attn, p["attn"], kv, h, t)
+    with jax.named_scope("attn"):
+        h, _ = block.norm1.apply(p["norm1"], s["norm1"], x)
+        a, kv = _decode_attn_slots(block.attn, p["attn"], kv, h, t)
     x = x + a
-    h, _ = block.norm2.apply(p["norm2"], s["norm2"], x)
-    m = _apply_mlp_decode(block.mlp, p["mlp"], s["mlp"], h,
-                          moe_dispatched, routing)
+    with jax.named_scope("mlp"):
+        h, _ = block.norm2.apply(p["norm2"], s["norm2"], x)
+        m = _apply_mlp_decode(block.mlp, p["mlp"], s["mlp"], h,
+                              moe_dispatched, routing)
     return x + m, kv
 
 
@@ -901,11 +915,13 @@ def decode_step_slots(module: Sequential, params, state, cache, tok, t,
             x, new_cache[i] = _decode_block_slots(
                 block, p, s, kv, x, t, moe_dispatched, routing)
         elif isinstance(layer, PositionalEmbedding):
-            x = x + p["embeddings"][t][:, None, :].astype(x.dtype)
+            with jax.named_scope("embed"):
+                x = x + p["embeddings"][t][:, None, :].astype(x.dtype)
         elif isinstance(layer, Dropout):
             pass                                         # eval: identity
         else:
-            x, _ = layer.apply(p, s, x, training=False)
+            with scoped(module.scope_of(i)):
+                x, _ = layer.apply(p, s, x, training=False)
     if moe_stats is not None:
         return x[:, 0], new_cache, _moe_route_stats(
             routing, t, 1, int(moe_stats))
@@ -1089,13 +1105,15 @@ def _decode_block_slots_paged(block: TransformerBlock, p, s, kv, x, t,
                               table, page_len: int,
                               moe_dispatched=True, routing=None,
                               paged_kernel=None):
-    h, _ = block.norm1.apply(p["norm1"], s["norm1"], x)
-    a, kv = _decode_attn_slots_paged(block.attn, p["attn"], kv, h, t,
-                                     table, page_len, paged_kernel)
+    with jax.named_scope("attn"):
+        h, _ = block.norm1.apply(p["norm1"], s["norm1"], x)
+        a, kv = _decode_attn_slots_paged(block.attn, p["attn"], kv, h, t,
+                                         table, page_len, paged_kernel)
     x = x + a
-    h, _ = block.norm2.apply(p["norm2"], s["norm2"], x)
-    m = _apply_mlp_decode(block.mlp, p["mlp"], s["mlp"], h,
-                          moe_dispatched, routing)
+    with jax.named_scope("mlp"):
+        h, _ = block.norm2.apply(p["norm2"], s["norm2"], x)
+        m = _apply_mlp_decode(block.mlp, p["mlp"], s["mlp"], h,
+                              moe_dispatched, routing)
     return x + m, kv
 
 
@@ -1124,11 +1142,13 @@ def decode_step_slots_paged(module: Sequential, params, state, cache,
                 block, p, s, kv, x, t, table, page_len,
                 moe_dispatched, routing, paged_kernel)
         elif isinstance(layer, PositionalEmbedding):
-            x = x + p["embeddings"][t][:, None, :].astype(x.dtype)
+            with jax.named_scope("embed"):
+                x = x + p["embeddings"][t][:, None, :].astype(x.dtype)
         elif isinstance(layer, Dropout):
             pass                                         # eval: identity
         else:
-            x, _ = layer.apply(p, s, x, training=False)
+            with scoped(module.scope_of(i)):
+                x, _ = layer.apply(p, s, x, training=False)
     if moe_stats is not None:
         return x[:, 0], new_cache, _moe_route_stats(
             routing, t, 1, int(moe_stats))
@@ -1174,33 +1194,35 @@ def _decode_block_slots_window(block: TransformerBlock, p, s, kv, x, t,
     the post-acceptance ``commit_tree_path`` can re-write the accepted
     path at its contiguous final positions."""
     attn = block.attn
-    h, _ = block.norm1.apply(p["norm1"], s["norm1"], x)
-    dt = jnp.dtype(attn.dtype)
-    xc = h.astype(dt)
-    q, k, v = _project_qkv(attn, p["attn"], xc)          # [S, W, H, D]
-    w_len = q.shape[1]
-    if attn.use_rope:
-        pos = _window_positions(t, w_len, tree)          # [S, W]
-        q = apply_rope(q, pos, scale=attn.rope_scale)
-        k = apply_rope(k, pos, scale=attn.rope_scale)
-    if kv_out is not None:
-        kv_out.append((k, v))
-    for j in range(w_len):
+    with jax.named_scope("attn"):
+        h, _ = block.norm1.apply(p["norm1"], s["norm1"], x)
+        dt = jnp.dtype(attn.dtype)
+        xc = h.astype(dt)
+        q, k, v = _project_qkv(attn, p["attn"], xc)          # [S, W, H, D]
+        w_len = q.shape[1]
+        if attn.use_rope:
+            pos = _window_positions(t, w_len, tree)          # [S, W]
+            q = apply_rope(q, pos, scale=attn.rope_scale)
+            k = apply_rope(k, pos, scale=attn.rope_scale)
+        if kv_out is not None:
+            kv_out.append((k, v))
+        for j in range(w_len):
+            if table is None:
+                kv = _cache_write_slots(kv, k[:, j:j + 1], v[:, j:j + 1],
+                                        t + j)
+            else:
+                kv = _cache_write_pages(kv, k[:, j:j + 1], v[:, j:j + 1],
+                                        t + j, table, page_len)
         if table is None:
-            kv = _cache_write_slots(kv, k[:, j:j + 1], v[:, j:j + 1],
-                                    t + j)
+            y = _slot_attn_readout(attn, p["attn"], q, kv, t, dt, tree=tree)
         else:
-            kv = _cache_write_pages(kv, k[:, j:j + 1], v[:, j:j + 1],
-                                    t + j, table, page_len)
-    if table is None:
-        y = _slot_attn_readout(attn, p["attn"], q, kv, t, dt, tree=tree)
-    else:
-        y = _paged_attn_readout(attn, p["attn"], q, kv, t, table,
-                                page_len, dt, paged_kernel, tree=tree)
+            y = _paged_attn_readout(attn, p["attn"], q, kv, t, table,
+                                    page_len, dt, paged_kernel, tree=tree)
     x = x + y.astype(x.dtype)
-    h, _ = block.norm2.apply(p["norm2"], s["norm2"], x)
-    m = _apply_mlp_decode(block.mlp, p["mlp"], s["mlp"], h,
-                          moe_dispatched, routing)
+    with jax.named_scope("mlp"):
+        h, _ = block.norm2.apply(p["norm2"], s["norm2"], x)
+        m = _apply_mlp_decode(block.mlp, p["mlp"], s["mlp"], h,
+                              moe_dispatched, routing)
     return x + m, kv
 
 
@@ -1233,12 +1255,14 @@ def _verify_window(module: Sequential, params, state, cache, toks, t,
                 block, p, s, kv, x, t, table, page_len,
                 moe_dispatched, routing, paged_kernel, tree, kv_win)
         elif isinstance(layer, PositionalEmbedding):
-            pos = _window_positions(t, w_len, tree)      # [S, W]
-            x = x + p["embeddings"][pos].astype(x.dtype)
+            with jax.named_scope("embed"):
+                pos = _window_positions(t, w_len, tree)      # [S, W]
+                x = x + p["embeddings"][pos].astype(x.dtype)
         elif isinstance(layer, Dropout):
             pass                                         # eval: identity
         else:
-            x, _ = layer.apply(p, s, x, training=False)
+            with scoped(module.scope_of(i)):
+                x, _ = layer.apply(p, s, x, training=False)
     if kv_win is not None:
         # index-align the collected (k, v) pairs with the CACHE list
         # (blocks appended in layer order; everything else is None)

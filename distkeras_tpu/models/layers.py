@@ -621,6 +621,8 @@ class GroupNorm(Layer):
 
 @register_layer
 class Embedding(Layer):
+    scope = "embed"
+
     def __init__(self, vocab_size: int, dim: int,
                  embeddings_init: str = "uniform_scaling"):
         self.vocab_size = int(vocab_size)

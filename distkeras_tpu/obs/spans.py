@@ -11,7 +11,9 @@ process-global and lock-protected, so worker threads (serving engine,
 Bridged to ``jax.profiler.TraceAnnotation`` when available: the same
 span names show up on the host timeline in XProf/TensorBoard next to
 the device ops they enclose, so a span table (``tools/xprof_op_table.py
---spans``) and an xprof trace cross-reference by name.
+--spans``) and an xprof trace cross-reference by name. ``span(name,
+step=n)`` opens a ``StepTraceAnnotation`` instead, which is what
+XProf's step view groups by (``serving.step``).
 
 Disabled path (``obs.disable()``): one predicate check, no clock reads,
 no allocation — the overhead contract for production hot loops.
@@ -37,14 +39,15 @@ _overflow_warned = [False]
 # the xprof bridge is best-effort: jax is always importable in this
 # repo, but TraceAnnotation construction can fail on exotic backends —
 # one failure disables the bridge rather than taxing every span
-_trace_annotation = [None]
+_trace_annotation = [None]    # (TraceAnnotation, StepTraceAnnotation) | False
 
 
 def _get_annotation_cls():
     if _trace_annotation[0] is None:
         try:
             import jax
-            _trace_annotation[0] = jax.profiler.TraceAnnotation
+            _trace_annotation[0] = (jax.profiler.TraceAnnotation,
+                                    jax.profiler.StepTraceAnnotation)
         except Exception:
             _trace_annotation[0] = False
     return _trace_annotation[0]
@@ -56,10 +59,12 @@ def _enabled() -> bool:
 
 
 @contextlib.contextmanager
-def span(name: str):
+def span(name: str, step: Optional[int] = None):
     """Time the enclosed block under ``name``, nested inside whatever
     span is active on this thread. Exception-safe: the stack pops and
-    the (partial) duration records on every exit path."""
+    the (partial) duration records on every exit path. With ``step``
+    the profiler bridge opens a ``StepTraceAnnotation(name,
+    step_num=step)``; the span tree is the same either way."""
     if not _enabled():
         yield
         return
@@ -72,7 +77,8 @@ def span(name: str):
     ann = None
     if ann_cls:
         try:
-            ann = ann_cls(name)
+            ann = (ann_cls[0](name) if step is None
+                   else ann_cls[1](name, step_num=step))
             ann.__enter__()
         except Exception:
             _trace_annotation[0] = False

@@ -29,7 +29,7 @@ import contextlib
 import threading
 from typing import Dict, Optional
 
-from distkeras_tpu.obs import collectors
+from distkeras_tpu.obs import collectors, spans
 from distkeras_tpu.utils.profiling import now
 
 #: bf16 peak matmul FLOP/s per chip, keyed by the EXACT
@@ -77,7 +77,10 @@ class _NullTape:
 
     enabled = False
 
-    def phase(self, name):
+    def phase(self, name, span=None):
+        return contextlib.nullcontext()
+
+    def span(self, name):
         return contextlib.nullcontext()
 
     def train_begin(self):
@@ -152,29 +155,42 @@ class TrainingTape:
 
     # -- phases -----------------------------------------------------------
     @contextlib.contextmanager
-    def phase(self, phase: str):
+    def phase(self, phase: str, span: Optional[str] = None):
+        """Charge the enclosed block to ``phase`` and open the span
+        ``train.<span or phase>`` round it (``obs.span``: the span tree,
+        and the host line of a profiler trace). ``span`` tells apart
+        two sites of one phase: the loops' ``device`` phase is
+        ``train.dispatch`` and ``train.fetch``."""
         device = phase == "device"
         if device:
             c0 = collectors.compile_totals()["seconds"]
-        t0 = now()
-        try:
-            yield
-        finally:
-            dt = now() - t0
-            with self._lock:
-                self._phase_totals[phase] = \
-                    self._phase_totals.get(phase, 0.0) + dt
-                self._epoch_phase[phase] = \
-                    self._epoch_phase.get(phase, 0.0) + dt
-                if device:
-                    self._device_total += dt
-                    # global-totals delta over the phase window: a
-                    # concurrent thread's compile can still land here,
-                    # but a validator/serving compile OUTSIDE the phase
-                    # no longer deflates productive device time
-                    self._device_compile += (
-                        collectors.compile_totals()["seconds"] - c0)
-            self._hist.observe(dt, phase=phase)
+        with spans.span("train." + (span or phase)):
+            t0 = now()
+            try:
+                yield
+            finally:
+                dt = now() - t0
+                with self._lock:
+                    self._phase_totals[phase] = \
+                        self._phase_totals.get(phase, 0.0) + dt
+                    self._epoch_phase[phase] = \
+                        self._epoch_phase.get(phase, 0.0) + dt
+                    if device:
+                        self._device_total += dt
+                        # global-totals delta over the phase window: a
+                        # concurrent thread's compile can still land
+                        # here, but a validator/serving compile OUTSIDE
+                        # the phase no longer deflates productive
+                        # device time
+                        self._device_compile += (
+                            collectors.compile_totals()["seconds"] - c0)
+                self._hist.observe(dt, phase=phase)
+
+    def span(self, name: str):
+        """``obs.span("train.<name>")`` with no phase charged: host work
+        the tape derives as a remainder (``train.epoch_end``). Opened
+        through the tape so that ``telemetry=False`` opens none."""
+        return spans.span("train." + name)
 
     # -- recompile plumbing (delegates to the detector) -------------------
     def watch(self, name, fn):

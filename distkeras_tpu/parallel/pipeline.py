@@ -774,44 +774,48 @@ class PipelineTrainer:
             for epoch, (xb, yb, nsteps) in timed_stream(stream, tape):
                 # chaos hook: a mid-training crash at an arbitrary epoch
                 faults.point("train.epoch")
-                with tape.phase("device"):
+                with tape.phase("device", "dispatch"):
                     carry, (losses, mets) = run_epoch(carry, xb, yb)
                     carry_box[0] = carry
+                with tape.phase("device", "fetch"):
                     # the epoch-boundary fetch (one per epoch; device_get
                     # enqueues the per-leaf async copies itself)
                     losses, mets = jax.device_get(  # lint: allow-host-sync
                         (losses, mets))
-                # chaos hook: NaN-poison the epoch losses the
-                # anomaly guard watches
-                losses = faults.corrupt("train.loss", losses)
-                extra = {}
-                if validator is not None:
-                    with tape.phase("validation"):
-                        extra = val_logs(validator(carry[0]))
-                self.history.append_epoch(loss=np.asarray(losses),
-                                          **{k: np.asarray(v)
-                                             for k, v in mets.items()},
-                                          **extra)
-                saved = False
-                if manager is not None and (
-                        (epoch + 1) % self.checkpoint_every == 0
-                        or epoch == self.num_epoch - 1):
-                    with tape.phase("checkpoint"):
-                        manager.save(
-                            epoch,
-                            {"params": carry[0], "opt": carry[1]},
-                            metadata={"epoch": epoch})
-                    saved = True
-                logs = {"loss": float(np.mean(losses))}
-                logs.update({k: float(np.mean(np.asarray(v)))
-                             for k, v in mets.items()})
-                logs.update({k: float(np.asarray(v).ravel()[0])
-                             for k, v in extra.items()})
-                logs.update(tape.epoch_end(
-                    nsteps * self.batch_size * X.shape[1]))
-                if epoch == start_epoch:
-                    tape.mark_warm()
-                cbs.epoch_end(epoch, logs)
+                # history, logs and callbacks: what the tape derives as
+                # ``host_s``; validation and checkpoint nest inside
+                with tape.span("epoch_end"):
+                    # chaos hook: NaN-poison the epoch losses the
+                    # anomaly guard watches
+                    losses = faults.corrupt("train.loss", losses)
+                    extra = {}
+                    if validator is not None:
+                        with tape.phase("validation"):
+                            extra = val_logs(validator(carry[0]))
+                    self.history.append_epoch(loss=np.asarray(losses),
+                                              **{k: np.asarray(v)
+                                                 for k, v in mets.items()},
+                                              **extra)
+                    saved = False
+                    if manager is not None and (
+                            (epoch + 1) % self.checkpoint_every == 0
+                            or epoch == self.num_epoch - 1):
+                        with tape.phase("checkpoint"):
+                            manager.save(
+                                epoch,
+                                {"params": carry[0], "opt": carry[1]},
+                                metadata={"epoch": epoch})
+                        saved = True
+                    logs = {"loss": float(np.mean(losses))}
+                    logs.update({k: float(np.mean(np.asarray(v)))
+                                 for k, v in mets.items()})
+                    logs.update({k: float(np.asarray(v).ravel()[0])
+                                 for k, v in extra.items()})
+                    logs.update(tape.epoch_end(
+                        nsteps * self.batch_size * X.shape[1]))
+                    if epoch == start_epoch:
+                        tape.mark_warm()
+                    cbs.epoch_end(epoch, logs)
                 # early stop / preemption between checkpoint_every
                 # boundaries saves the final state, or resume would
                 # lose these epochs (trainers.epoch_exit: the shared

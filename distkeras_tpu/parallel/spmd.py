@@ -417,7 +417,7 @@ class SPMDTrainer(Trainer):
                     # chaos hook: a mid-training crash at an arbitrary
                     # loop iteration (tests/test_resilience.py)
                     faults.point("train.epoch")
-                    with tape.phase("device"):
+                    with tape.phase("device", "dispatch"):
                         # batches arrive device-resident from the
                         # loader thread (device_stager above); per-step
                         # losses/metrics stay on device until the
@@ -436,37 +436,43 @@ class SPMDTrainer(Trainer):
                     examples += int(S) * self.batch_size
                     if not last:
                         continue
-                    with tape.phase("device"):
+                    with tape.phase("device", "fetch"):
                         # ONE boundary fetch (collective allgather under
                         # multi-process — same count/order on every
                         # process as the per-shard fetches it replaces)
                         l_acc, m_acc = host_fetch((l_acc, m_acc))
-                    # chaos hook: NaN-poison the epoch losses the
-                    # anomaly guard watches
-                    losses = faults.corrupt(
-                        "train.loss", np.concatenate(l_acc))
-                    mets = {k: np.concatenate([m[k] for m in m_acc])
-                            for k in (m_acc[0] if m_acc else {})}
-                    l_acc, m_acc = [], []
-                    extra = {}
-                    if validator is not None:
-                        with tape.phase("validation"):
-                            extra = val_logs(host_fetch(validator(
-                                carry.params, carry.state)))
-                    self.history.append_epoch(loss=losses, **mets, **extra)
-                    saved = False
-                    if manager is not None and self._should_checkpoint(epoch):
-                        save_now(epoch)
-                        saved = True
-                    # logs derive from replicated values, so every process
-                    # sees identical callback decisions (incl. stop_training
-                    # and any collective get_weights fetch inside a callback)
-                    logs = self._epoch_logs(losses, mets, extra)
-                    logs.update(tape.epoch_end(examples))
-                    examples = 0
-                    if epoch == start_epoch:
-                        tape.mark_warm()
-                    cbs.epoch_end(epoch, logs)
+                    # history, logs and callbacks: what the tape derives as
+                    # ``host_s``; validation and checkpoint nest inside
+                    with tape.span("epoch_end"):
+                        # chaos hook: NaN-poison the epoch losses the
+                        # anomaly guard watches
+                        losses = faults.corrupt(
+                            "train.loss", np.concatenate(l_acc))
+                        mets = {k: np.concatenate([m[k] for m in m_acc])
+                                for k in (m_acc[0] if m_acc else {})}
+                        l_acc, m_acc = [], []
+                        extra = {}
+                        if validator is not None:
+                            with tape.phase("validation"):
+                                extra = val_logs(host_fetch(validator(
+                                    carry.params, carry.state)))
+                        self.history.append_epoch(loss=losses, **mets,
+                                                  **extra)
+                        saved = False
+                        if manager is not None \
+                                and self._should_checkpoint(epoch):
+                            save_now(epoch)
+                            saved = True
+                        # logs derive from replicated values, so every
+                        # process sees identical callback decisions (incl.
+                        # stop_training and any collective get_weights
+                        # fetch inside a callback)
+                        logs = self._epoch_logs(losses, mets, extra)
+                        logs.update(tape.epoch_end(examples))
+                        examples = 0
+                        if epoch == start_epoch:
+                            tape.mark_warm()
+                        cbs.epoch_end(epoch, logs)
                     # preemption is delivered per-process (SIGTERM to the
                     # job hits every worker); the stop decision below
                     # must stay consistent across processes, which holds

@@ -626,7 +626,7 @@ class SingleTrainer(Trainer):
                     # chaos hook: a mid-training crash at an arbitrary
                     # loop iteration (tests/test_resilience.py)
                     faults.point("train.epoch")
-                    with tape.phase("device"):
+                    with tape.phase("device", "dispatch"):
                         carry, outs = runner(carry, Xs, Ys)
                         # per-step loss/metric arrays STAY ON DEVICE for
                         # the whole epoch — only the D2H transfer is
@@ -640,37 +640,42 @@ class SingleTrainer(Trainer):
                     examples += int(S) * self.batch_size
                     if not last:
                         continue
-                    with tape.phase("device"):
+                    with tape.phase("device", "fetch"):
                         # ONE epoch-boundary fetch of everything the
                         # epoch accumulated (transfers already in
                         # flight); blocking here also bounds the device
                         # phase through the last dispatched program
                         l_acc, m_acc = jax.device_get(  # lint: allow-host-sync
                             (l_acc, m_acc))
-                    # chaos hook: NaN-poison the epoch losses the
-                    # anomaly guard watches (history/logs downstream)
-                    losses = faults.corrupt(
-                        "train.loss", np.concatenate(l_acc))
-                    mets = {k: np.concatenate([m[k] for m in m_acc])
-                            for k in (m_acc[0] if m_acc else {})}
-                    l_acc, m_acc = [], []
-                    extra = {}
-                    if validator is not None:
-                        with tape.phase("validation"):
-                            extra = val_logs(validator(carry.params,
-                                                       carry.state))
-                    self.history.append_epoch(loss=losses, **mets, **extra)
-                    saved = False
-                    if manager is not None and self._should_checkpoint(epoch):
-                        save_now(epoch)
-                        saved = True
-                    logs = self._epoch_logs(losses, mets, extra)
-                    logs.update(tape.epoch_end(examples))
-                    examples = 0
-                    if epoch == start_epoch:
-                        # first full epoch saw every legitimate shape
-                        tape.mark_warm()
-                    cbs.epoch_end(epoch, logs)
+                    # history, logs and callbacks: what the tape derives as
+                    # ``host_s``; validation and checkpoint nest inside
+                    with tape.span("epoch_end"):
+                        # chaos hook: NaN-poison the epoch losses the
+                        # anomaly guard watches (history/logs downstream)
+                        losses = faults.corrupt(
+                            "train.loss", np.concatenate(l_acc))
+                        mets = {k: np.concatenate([m[k] for m in m_acc])
+                                for k in (m_acc[0] if m_acc else {})}
+                        l_acc, m_acc = [], []
+                        extra = {}
+                        if validator is not None:
+                            with tape.phase("validation"):
+                                extra = val_logs(validator(carry.params,
+                                                           carry.state))
+                        self.history.append_epoch(loss=losses, **mets,
+                                                  **extra)
+                        saved = False
+                        if manager is not None \
+                                and self._should_checkpoint(epoch):
+                            save_now(epoch)
+                            saved = True
+                        logs = self._epoch_logs(losses, mets, extra)
+                        logs.update(tape.epoch_end(examples))
+                        examples = 0
+                        if epoch == start_epoch:
+                            # first full epoch saw every legitimate shape
+                            tape.mark_warm()
+                        cbs.epoch_end(epoch, logs)
                     if self._epoch_exit(
                             epoch, saved,
                             save_now if manager is not None else None):

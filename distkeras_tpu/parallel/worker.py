@@ -137,10 +137,11 @@ def make_train_step(module, loss_fn: Callable, optimizer: Optimizer,
                     params[:-1], state[:-1], xb, training=True, rng=sub)
                 from distkeras_tpu.ops.losses import \
                     fused_linear_cross_entropy
-                loss = fused_linear_cross_entropy(
-                    hidden, params[-1]["kernel"], yb,
-                    num_chunks=fused_chunks,
-                    ignore_index=ignore_index, compute_dtype=cdt)
+                with jax.named_scope("loss"):
+                    loss = fused_linear_cross_entropy(
+                        hidden, params[-1]["kernel"], yb,
+                        num_chunks=fused_chunks,
+                        ignore_index=ignore_index, compute_dtype=cdt)
                 new_state = list(t_state) + [state[-1]]
                 return loss + collect_aux_losses(new_state), \
                     (new_state, None)
@@ -148,8 +149,9 @@ def make_train_step(module, loss_fn: Callable, optimizer: Optimizer,
                                           training=True, rng=sub)
             # layer-published auxiliary losses (models.core.AUX_LOSS_KEY,
             # e.g. MoE router balance) join the optimized loss here
-            return loss_fn(yb, out) + collect_aux_losses(new_state), \
-                (new_state, out)
+            with jax.named_scope("loss"):
+                loss = loss_fn(yb, out) + collect_aux_losses(new_state)
+            return loss, (new_state, out)
 
         (loss, (new_state, out)), grads = jax.value_and_grad(
             objective, has_aux=True)(params)
@@ -198,18 +200,20 @@ def make_train_step(module, loss_fn: Callable, optimizer: Optimizer,
             loss = losses.mean()
             mets = jax.tree_util.tree_map(lambda m: m.mean(), mets_s)
 
-        updates, new_opt_state = optimizer.update(grads, carry.opt_state,
-                                                  carry.params)
-        if param_mask is not None:
-            updates = jax.tree_util.tree_map(
-                lambda m, u: jnp.where(m, u, 0.0), param_mask, updates)
+        with jax.named_scope("optimizer"):
+            updates, new_opt_state = optimizer.update(
+                grads, carry.opt_state, carry.params)
+            if param_mask is not None:
+                updates = jax.tree_util.tree_map(
+                    lambda m, u: jnp.where(m, u, 0.0), param_mask, updates)
         if state_mask is not None:
             # mask leaves are static Python bools: frozen state keeps the
             # carried value with zero compute
             new_state = jax.tree_util.tree_map(
                 lambda m, old, new: new if m else old,
                 state_mask, carry.state, new_state)
-        new_params = apply_updates(carry.params, updates)
+        with jax.named_scope("optimizer"):
+            new_params = apply_updates(carry.params, updates)
         new_carry = TrainCarry(new_params, new_state, new_opt_state, rng)
         if metric_fns:
             return new_carry, (loss, mets)
@@ -222,11 +226,11 @@ def make_epoch_runner(train_step: Callable) -> Callable:
     """Jitted scan of ``train_step`` over ``[steps, batch, ...]`` data."""
 
     @jax.jit
-    def run(carry: TrainCarry, X: jax.Array, Y: jax.Array):
+    def train_epoch(carry: TrainCarry, X: jax.Array, Y: jax.Array):
         carry, losses = lax.scan(train_step, carry, (X, Y))
         return carry, losses
 
-    return run
+    return train_epoch
 
 
 def shard_epoch_data(X, Y, num_workers: int, batch_size: int, perm=None):

@@ -77,6 +77,7 @@ pre-casting but not int8; prompts longer than
 from __future__ import annotations
 
 import itertools
+import re
 import weakref
 import zlib
 from typing import Dict, List, Optional
@@ -805,7 +806,10 @@ class ServingEngine:
         projections quantized for the fused dequant-matmul kernel.
         While the program is traced, every kernel-or-reference choice
         inside it (``compat.note_path``) lands in
-        ``program_paths[name]`` — ``health()["programs"]``."""
+        ``program_paths[name]`` — ``health()["programs"]``. The
+        program itself is named ``serving_<name>`` (anything outside
+        ``[A-Za-z0-9_]`` becomes ``_``): what a profiler's ``XLA
+        Modules`` line and the compile log call it."""
         traced = f
 
         def f(*args):
@@ -823,13 +827,15 @@ class ServingEngine:
                 return inner(
                     dequant_params_tree(params, dt, keep_attn=keep),
                     *rest)
-        if self._ep_mesh is None:
-            return jax.jit(f)
-        from jax.sharding import PartitionSpec as P
-        return jax.jit(shard_map(
-            f, mesh=self._ep_mesh,
-            in_specs=(self._ep_pspec,) + (P(),) * (n_args - 1),
-            out_specs=P()))
+        if self._ep_mesh is not None:
+            from jax.sharding import PartitionSpec as P
+            f = shard_map(
+                f, mesh=self._ep_mesh,
+                in_specs=(self._ep_pspec,) + (P(),) * (n_args - 1),
+                out_specs=P())
+        f.__name__ = f.__qualname__ = re.sub(
+            "[^A-Za-z0-9_]", "_", "serving_" + name)
+        return jax.jit(f)
 
     # --- MoE routing telemetry / admission cost ---------------------------
 
@@ -924,9 +930,10 @@ class ServingEngine:
         the step/decode path is a lint finding
         (``tools/lint_host_sync.py``). Accumulates blocking time in
         ``fetch_seconds`` for the bench's host-loop rider."""
-        t0 = self._metrics.clock()
-        out = [np.asarray(a) for a in arrays]  # lint: allow-host-sync (the lagged fetch)
-        self.fetch_seconds += self._metrics.clock() - t0
+        with obs.span("serving.decode.fetch"):
+            t0 = self._metrics.clock()
+            out = [np.asarray(a) for a in arrays]  # lint: allow-host-sync (the lagged fetch)
+            self.fetch_seconds += self._metrics.clock() - t0
         return out
 
     def _flush_pending(self, out: Optional[List[Request]] = None) -> None:
@@ -969,38 +976,39 @@ class ServingEngine:
             # keeps its host mirror — the launch never consumed it
             live = ~self._chain_dirty
             self._keys[live] = fetched[1][live]
-        toks = nxt if nxt.ndim == 2 else nxt[:, None]    # [S, count]
-        self._note_moe_route(p.moe)
-        now_ = self._metrics.clock()
-        trace_on = self.tracer.enabled
-        done_reqs: List[Request] = []
-        n_emitted = 0
-        for slot, rid in p.slots:
-            req = running.get(slot)
-            if req is None or req.rid != rid:
-                continue                     # recycled slot: discard
-            n_app = 0
-            for j in range(p.count):
-                req.generated.append(int(toks[slot, j]))
-                n_app += 1
+        with obs.span("serving.decode.consume"):
+            toks = nxt if nxt.ndim == 2 else nxt[:, None]    # [S, count]
+            self._note_moe_route(p.moe)
+            now_ = self._metrics.clock()
+            trace_on = self.tracer.enabled
+            done_reqs: List[Request] = []
+            n_emitted = 0
+            for slot, rid in p.slots:
+                req = running.get(slot)
+                if req is None or req.rid != rid:
+                    continue                     # recycled slot: discard
+                n_app = 0
+                for j in range(p.count):
+                    req.generated.append(int(toks[slot, j]))
+                    n_app += 1
+                    if req.done:
+                        break                    # stop / budget mid-window
+                n_emitted += n_app
+                self._tok[slot] = req.generated[-1]
+                if trace_on and n_app:
+                    self._trace_decode[rid] = \
+                        self._trace_decode.get(rid, 0) + n_app
+                    if self._trace_decode_t0 is None:
+                        self._trace_decode_t0 = now_
                 if req.done:
-                    break                    # stop / budget mid-window
-            n_emitted += n_app
-            self._tok[slot] = req.generated[-1]
-            if trace_on and n_app:
-                self._trace_decode[rid] = \
-                    self._trace_decode.get(rid, 0) + n_app
-                if self._trace_decode_t0 is None:
-                    self._trace_decode_t0 = now_
-            if req.done:
-                done_reqs.append(req)
-        self._decode_buf.append(
-            (len(p.slots),
-             now_ - (p.launch_t if t0 is None else t0), n_emitted))
-        if done_reqs:
-            self._flush_host_window()        # ticks precede terminals
-            for req in done_reqs:
-                self._finish(req, finished)
+                    done_reqs.append(req)
+            self._decode_buf.append(
+                (len(p.slots),
+                 now_ - (p.launch_t if t0 is None else t0), n_emitted))
+            if done_reqs:
+                self._flush_host_window()        # ticks precede terminals
+                for req in done_reqs:
+                    self._finish(req, finished)
 
     def _flush_host_window(self) -> None:
         """Apply the deferred host-work buffers to the live metrics
@@ -1350,13 +1358,17 @@ class ServingEngine:
                     def fn(params, state, cache, tok, t, tables):
                         logits, cache, moe = step(params, state, cache,
                                                   tok, t, tables)
-                        return jnp.argmax(logits, axis=-1), cache, moe
+                        with jax.named_scope("sample"):
+                            nxt = jnp.argmax(logits, axis=-1)
+                        return nxt, cache, moe
                     n_args = 6
                 else:
                     def fn(params, state, cache, tok, t):
                         logits, cache, moe = step(params, state, cache,
                                                   tok, t, None)
-                        return jnp.argmax(logits, axis=-1), cache, moe
+                        with jax.named_scope("sample"):
+                            nxt = jnp.argmax(logits, axis=-1)
+                        return nxt, cache, moe
                     n_args = 5
             else:
                 if self.fused_sampling:
@@ -1372,9 +1384,10 @@ class ServingEngine:
                     # per-slot key streams: a request's draws depend
                     # only on its own seed, not on which neighbours
                     # share the batch
-                    split = jax.vmap(jax.random.split)(keys)
-                    nxt = sampler(logits, temp, topk, topp,
-                                  split[:, 1])
+                    with jax.named_scope("sample"):
+                        split = jax.vmap(jax.random.split)(keys)
+                        nxt = sampler(logits, temp, topk, topp,
+                                      split[:, 1])
                     return nxt, cache, split[:, 0], moe
 
                 if paged:
@@ -1834,13 +1847,14 @@ class ServingEngine:
         not depend on engine scheduling."""
         if self._first_fn is None:
             @jax.jit
-            def f(logits, temp, topk, topp, rng):
+            def serving_sample_first(logits, temp, topk, topp, rng):
                 rng, sub = jax.random.split(rng)
-                tok = _sample_vec(logits, temp[None], topk[None],
-                                  topp[None], sub)
+                with jax.named_scope("sample"):
+                    tok = _sample_vec(logits, temp[None], topk[None],
+                                      topp[None], sub)
                 return tok[0], rng
 
-            self._first_fn = f
+            self._first_fn = serving_sample_first
         return self._first_fn
 
     # --- paged admission / page budget ------------------------------------
@@ -2236,61 +2250,64 @@ class ServingEngine:
         to one request, so it propagates — but it is raised before any
         engine state mutates, so ``step()`` can simply be called again
         (the failed iteration retries wholesale)."""
-        finished: List[Request] = []
-        if self._finish_buf:
-            # terminals produced by out-of-band pipeline flushes
-            # (cancel, preemption, metrics swap) since the last step
-            finished.extend(self._finish_buf)
-            self._finish_buf.clear()
-        self._expire_deadlines(finished)
-        admitted = self._admit()
-        # flight-recorder ring entry (composition-cached, window
-        # cadence in steady state — see _record_iteration). Paged
-        # engines add the free-page count: an admission stall in a
-        # post-mortem dump reads directly as "queue grew while pages
-        # sat at N" (budget starvation) vs "pages free, slots full"
-        self._record_iteration(admitted)
+        with obs.span("serving.step", step=self._iters):
+            finished: List[Request] = []
+            with obs.span("serving.admit"):
+                if self._finish_buf:
+                    # terminals produced by out-of-band pipeline flushes
+                    # (cancel, preemption, metrics swap) since the last step
+                    finished.extend(self._finish_buf)
+                    self._finish_buf.clear()
+                self._expire_deadlines(finished)
+                admitted = self._admit()
+                # flight-recorder ring entry (composition-cached, window
+                # cadence in steady state — see _record_iteration). Paged
+                # engines add the free-page count: an admission stall in a
+                # post-mortem dump reads directly as "queue grew while pages
+                # sat at N" (budget starvation) vs "pages free, slots full"
+                self._record_iteration(admitted)
 
-        req = self.scheduler.next_prefill()
-        if req is not None:
-            with self.metrics.timer.phase("prefill"), \
-                    obs.span("serving.prefill"):
-                try:
-                    self._advance_prefill(req, finished)
-                except Exception as e:
-                    self._poison(req, e, finished)
+            req = self.scheduler.next_prefill()
+            if req is not None:
+                with self.metrics.timer.phase("prefill"), \
+                        obs.span("serving.prefill"):
+                    try:
+                        self._advance_prefill(req, finished)
+                    except Exception as e:
+                        self._poison(req, e, finished)
 
-        running = self.scheduler.running
-        if running:
-            with self.metrics.timer.phase("decode"), \
-                    obs.span("serving.decode"):
-                self._advance_decode(finished)
+            running = self.scheduler.running
+            if running:
+                with self.metrics.timer.phase("decode"), \
+                        obs.span("serving.decode"):
+                    self._advance_decode(finished)
 
-        # per-iteration samples land in the deferred buffers; the live
-        # window sees them on the host-window cadence (every iteration
-        # when overlap is off) and whenever the engine drains idle
-        self._iter_buf.append((self.scheduler.queue_depth,
-                               self.scheduler.occupied))
-        self._iters += 1
-        if self._iters % self._host_window == 0 \
-                or not self.scheduler.pending:
-            self._flush_host_window()
-            if self.timeseries is not None:
-                # piggybacks on the flush cadence just paid: pure
-                # host-side registry reads, zero added device syncs
-                self.timeseries.maybe_sample(iteration=self._iters)
-        if self._iters % self._RECOMPILE_CHECK_EVERY == 0:
-            self._recompile.check()
-        if self.slo is not None \
-                and self._iters % self._SLO_EVAL_EVERY == 0:
-            self._flush_host_window()
-            self.slo.evaluate(self.metrics)
-        if self._finish_buf:
-            # a mid-iteration flush (preemption funding, deadline
-            # sweep) finished requests: return them from THIS step
-            finished.extend(self._finish_buf)
-            self._finish_buf.clear()
-        return finished
+            with obs.span("serving.flush"):
+                # per-iteration samples land in the deferred buffers; the live
+                # window sees them on the host-window cadence (every iteration
+                # when overlap is off) and whenever the engine drains idle
+                self._iter_buf.append((self.scheduler.queue_depth,
+                                       self.scheduler.occupied))
+                self._iters += 1
+                if self._iters % self._host_window == 0 \
+                        or not self.scheduler.pending:
+                    self._flush_host_window()
+                    if self.timeseries is not None:
+                        # piggybacks on the flush cadence just paid: pure
+                        # host-side registry reads, zero added device syncs
+                        self.timeseries.maybe_sample(iteration=self._iters)
+                if self._iters % self._RECOMPILE_CHECK_EVERY == 0:
+                    self._recompile.check()
+                if self.slo is not None \
+                        and self._iters % self._SLO_EVAL_EVERY == 0:
+                    self._flush_host_window()
+                    self.slo.evaluate(self.metrics)
+                if self._finish_buf:
+                    # a mid-iteration flush (preemption funding, deadline
+                    # sweep) finished requests: return them from THIS step
+                    finished.extend(self._finish_buf)
+                    self._finish_buf.clear()
+            return finished
 
     def run(self, max_steps: Optional[int] = None,
             on_degraded: str = "raise") -> Dict[int, np.ndarray]:
@@ -2689,61 +2706,64 @@ class ServingEngine:
             # rejoining the decode batch (the number the offload
             # bench's resume-latency rider compares against swap-in)
             req._resume_t0 = self.metrics.clock()
-        if paged and req.prefill_pos == 0:
-            if self.prefix is not None:
-                # pages registered since this request's admission plan
-                # (by requests ahead of it in the prefill stream) are
-                # adopted here — the burst-of-identical-prompts case
-                self._rematch_at_prefill(req)
-                self.metrics.record_prefix_lookup(
-                    getattr(req, "_shared_len", 0), p_len)
-            if getattr(req, "_shared_len", 0):
-                # prefix-cache hit: materialize the shared pages (and
-                # the copy-on-write donor) into the staging cache once,
-                # then skip straight to the first non-shared position —
-                # the shared tokens' prefill compute never runs
-                self._staging = self.pool.load_prefix(
-                    self._staging, req._load_pages, req._shared_len)
-                req.prefill_pos = req._shared_len
-                self.tracer.on_prefix_hit(req.rid, req._shared_len)
-            if getattr(req, "_donor_ref", None) is not None:
-                # the donor's content is in staging now; its hold
-                # (taken at planning so reclaim/eviction could not
-                # free it first) is no longer needed
-                self.pool.decref(req._donor_ref)
-                req._donor_ref = None
-        t0 = req.prefill_pos
-        chunk = self.prefill_chunk
-        if chunk is None:
-            q_len, final = p_len - t0, True
-        else:
-            q_len = min(chunk, p_len - t0)
-            final = t0 + q_len >= p_len
-        # a resume re-prefill never needs logits (its tokens are
-        # already decided), so every chunk runs head-less
-        fn = self._prefill_fn(q_len, t0, final and not resume)
-        chunk_toks = jnp.asarray(toks[None, t0:t0 + q_len])
-        logits, self._staging = fn(self._params, self._state,
-                                   self._staging, chunk_toks)
+        with obs.span("serving.prefill.stage"):
+            if paged and req.prefill_pos == 0:
+                if self.prefix is not None:
+                    # pages registered since this request's admission plan
+                    # (by requests ahead of it in the prefill stream) are
+                    # adopted here — the burst-of-identical-prompts case
+                    self._rematch_at_prefill(req)
+                    self.metrics.record_prefix_lookup(
+                        getattr(req, "_shared_len", 0), p_len)
+                if getattr(req, "_shared_len", 0):
+                    # prefix-cache hit: materialize the shared pages (and
+                    # the copy-on-write donor) into the staging cache once,
+                    # then skip straight to the first non-shared position —
+                    # the shared tokens' prefill compute never runs
+                    self._staging = self.pool.load_prefix(
+                        self._staging, req._load_pages, req._shared_len)
+                    req.prefill_pos = req._shared_len
+                    self.tracer.on_prefix_hit(req.rid, req._shared_len)
+                if getattr(req, "_donor_ref", None) is not None:
+                    # the donor's content is in staging now; its hold
+                    # (taken at planning so reclaim/eviction could not
+                    # free it first) is no longer needed
+                    self.pool.decref(req._donor_ref)
+                    req._donor_ref = None
+            t0 = req.prefill_pos
+            chunk = self.prefill_chunk
+            if chunk is None:
+                q_len, final = p_len - t0, True
+            else:
+                q_len = min(chunk, p_len - t0)
+                final = t0 + q_len >= p_len
+            # a resume re-prefill never needs logits (its tokens are
+            # already decided), so every chunk runs head-less
+            fn = self._prefill_fn(q_len, t0, final and not resume)
+            chunk_toks = jnp.asarray(toks[None, t0:t0 + q_len])
+        with obs.span("serving.prefill.dispatch"):
+            logits, self._staging = fn(self._params, self._state,
+                                       self._staging, chunk_toks)
         req.prefill_pos = t0 + q_len
         self.metrics.record_prefill_chunk()
         self.tracer.on_prefill_chunk(req.rid, t0, q_len)
         if not final:
             return
-        if paged:
-            # write ONLY the pages the context fills, minus the shared
-            # prefix pages that already hold identical data (the
-            # copy-on-write donor's logical page IS written — into the
-            # request's private copy)
-            self.pool.insert_pages(self._staging, req.slot,
-                                   getattr(req, "_n_shared_full", 0),
-                                   p_len)
-            if self.prefix is not None:
-                # full context pages are immutable from here (decode
-                # writes start at p_len): share them forward
-                self.prefix.register(toks, self.pool.tables[req.slot])
-        else:
-            self.pool.insert(self._staging, req.slot, n_pos=p_len)
+        with obs.span("serving.prefill.insert"):
+            if paged:
+                # write ONLY the pages the context fills, minus the shared
+                # prefix pages that already hold identical data (the
+                # copy-on-write donor's logical page IS written — into the
+                # request's private copy)
+                self.pool.insert_pages(self._staging, req.slot,
+                                       getattr(req, "_n_shared_full", 0),
+                                       p_len)
+                if self.prefix is not None:
+                    # full context pages are immutable from here (decode
+                    # writes start at p_len): share them forward
+                    self.prefix.register(toks, self.pool.tables[req.slot])
+            else:
+                self.pool.insert(self._staging, req.slot, n_pos=p_len)
         s = req.slot
         if resume:
             # re-admission after preemption: skip first-token sampling
@@ -2768,10 +2788,11 @@ class ServingEngine:
                 req._resume_t0 = None
             self.tracer.on_resume(req.rid)
             return
-        first, req.rng = self._sample_first_fn()(
-            logits, jnp.float32(req.temperature),
-            jnp.int32(req.top_k), jnp.float32(req.top_p), req.rng)
-        token = int(first)
+        with obs.span("serving.prefill.first_token"):
+            first, req.rng = self._sample_first_fn()(
+                logits, jnp.float32(req.temperature),
+                jnp.int32(req.top_k), jnp.float32(req.top_p), req.rng)
+            token = int(first)
         req.generated.append(token)
         self.metrics.record_first_token(req.rid)
         self.tracer.on_first_token(req.rid)
@@ -2844,7 +2865,8 @@ class ServingEngine:
                 look = np.zeros(self.num_slots, np.int64)
                 for slot in self.scheduler.running:
                     look[slot] = fuse - 1
-            self._ensure_decode_pages(look)
+            with obs.span("serving.decode.pages"):
+                self._ensure_decode_pages(look)
             if not self.scheduler.running:
                 return
             if spec:
@@ -2859,12 +2881,14 @@ class ServingEngine:
         t0 = self.metrics.clock()
         greedy_only = all(r.temperature <= 0.0
                           for r in self.scheduler.running.values())
-        tables = (self.pool.device_tables(),) if paged else ()
+        with obs.span("serving.decode.tables"):
+            tables = (self.pool.device_tables(),) if paged else ()
         if spec:
             self._spec_step(greedy_only, tables, finished, t0)
             return
         prev = self._pending
-        pend = self._launch_step(greedy_only, tables, fuse, prev, t0)
+        with obs.span("serving.decode.dispatch"):
+            pend = self._launch_step(greedy_only, tables, fuse, prev, t0)
         if self.overlap:
             # pipelined dispatch: the new step runs on device while the
             # host consumes the LAGGED fetch of the previous one (its
@@ -2897,16 +2921,18 @@ class ServingEngine:
                               axis=1).astype(np.int32)
         active_dev = jnp.asarray(active)
         if greedy_only:
-            cand, n_acc, self.pool.cache, moe = self._verify_fn(True)(
-                self._params, self._state, self.pool.cache, toks,
-                self._t, active_dev, *tables)
+            with obs.span("serving.decode.dispatch"):
+                cand, n_acc, self.pool.cache, moe = self._verify_fn(True)(
+                    self._params, self._state, self.pool.cache, toks,
+                    self._t, active_dev, *tables)
             cand, n_acc = self._fetch(cand, n_acc)
         else:
-            (cand, n_acc, self.pool.cache, keys,
-             moe) = self._verify_fn(False)(
-                self._params, self._state, self.pool.cache, toks,
-                self._t, active_dev, self._temp, self._topk,
-                self._topp, self._keys, *tables)
+            with obs.span("serving.decode.dispatch"):
+                (cand, n_acc, self.pool.cache, keys,
+                 moe) = self._verify_fn(False)(
+                    self._params, self._state, self.pool.cache, toks,
+                    self._t, active_dev, self._temp, self._topk,
+                    self._topp, self._keys, *tables)
             cand, n_acc, new_keys = self._fetch(cand, n_acc, keys)
             # the fetch hands back read-only views of device memory;
             # the key mirror stays host-writable (per-slot restores on
@@ -2947,35 +2973,36 @@ class ServingEngine:
         timeline, and the final verify's outcome belongs on it). One
         copy of these contracts — the two call sites diverge only in
         their ``note`` closures."""
-        now_ = self._metrics.clock()
-        trace_on = self.tracer.enabled
-        n_emitted = 0
-        done_reqs = []
-        for slot, req in list(running.items()):
-            ne = int(n_emit[slot])
-            appended = 0
-            for token in emitted[slot, :ne]:
-                req.generated.append(int(token))
-                appended += 1
+        with obs.span("serving.decode.consume"):
+            now_ = self._metrics.clock()
+            trace_on = self.tracer.enabled
+            n_emitted = 0
+            done_reqs = []
+            for slot, req in list(running.items()):
+                ne = int(n_emit[slot])
+                appended = 0
+                for token in emitted[slot, :ne]:
+                    req.generated.append(int(token))
+                    appended += 1
+                    if req.done:
+                        break           # stop token / budget mid-window
+                n_emitted += appended
+                self._tok[slot] = req.generated[-1]
+                self._t[slot] += appended
+                if trace_on:
+                    self._trace_decode[req.rid] = \
+                        self._trace_decode.get(req.rid, 0) + appended
+                    if self._trace_decode_t0 is None:
+                        self._trace_decode_t0 = now_
+                if active[slot]:
+                    note(slot, req, trace_on)
                 if req.done:
-                    break           # stop token / budget mid-window
-            n_emitted += appended
-            self._tok[slot] = req.generated[-1]
-            self._t[slot] += appended
-            if trace_on:
-                self._trace_decode[req.rid] = \
-                    self._trace_decode.get(req.rid, 0) + appended
-                if self._trace_decode_t0 is None:
-                    self._trace_decode_t0 = now_
-            if active[slot]:
-                note(slot, req, trace_on)
-            if req.done:
-                done_reqs.append(req)
-        self._decode_buf.append((len(running), now_ - t0, n_emitted))
-        if done_reqs:
-            self._flush_host_window()
-            for req in done_reqs:
-                self._finish(req, finished)
+                    done_reqs.append(req)
+            self._decode_buf.append((len(running), now_ - t0, n_emitted))
+            if done_reqs:
+                self._flush_host_window()
+                for req in done_reqs:
+                    self._finish(req, finished)
 
     def _spec_tree_step(self, finished: List[Request]) -> None:
         """One TREE draft-and-verify iteration (tree-speculation PR).
@@ -3025,27 +3052,31 @@ class ServingEngine:
         depth, anc, n_nodes = tree_ancestors(parents)
         if paged:
             look = np.where(active, n_nodes - 1, 0).astype(np.int64)
-            self._ensure_decode_pages(look)
+            with obs.span("serving.decode.pages"):
+                self._ensure_decode_pages(look)
             if not self.scheduler.running:
                 return
         t0 = self.metrics.clock()
         running = self.scheduler.running
         greedy_only = all(r.temperature <= 0.0
                           for r in running.values())
-        tables = (self.pool.device_tables(),) if paged else ()
+        with obs.span("serving.decode.tables"):
+            tables = (self.pool.device_tables(),) if paged else ()
         targs = (toks, self._t, parents, depth, anc)
         if greedy_only:
-            emitted, n_emit, self.pool.cache, moe = \
-                self._verify_tree_fn(True)(
-                    self._params, self._state, self.pool.cache, *targs,
-                    *tables)
+            with obs.span("serving.decode.dispatch"):
+                emitted, n_emit, self.pool.cache, moe = \
+                    self._verify_tree_fn(True)(
+                        self._params, self._state, self.pool.cache,
+                        *targs, *tables)
             emitted, n_emit = self._fetch(emitted, n_emit)
         else:
-            (emitted, n_emit, self.pool.cache, keys, moe) = \
-                self._verify_tree_fn(False)(
-                    self._params, self._state, self.pool.cache, *targs,
-                    self._temp, self._topk, self._topp, self._keys,
-                    *tables)
+            with obs.span("serving.decode.dispatch"):
+                (emitted, n_emit, self.pool.cache, keys, moe) = \
+                    self._verify_tree_fn(False)(
+                        self._params, self._state, self.pool.cache,
+                        *targs, self._temp, self._topk, self._topp,
+                        self._keys, *tables)
             emitted, n_emit, new_keys = self._fetch(emitted, n_emit,
                                                     keys)
             self._keys = new_keys.copy()

@@ -3,6 +3,7 @@ its token-exact duration accounting, the Chrome/Perfetto trace export,
 and the serving-engine integration points."""
 
 import json
+import re
 
 import numpy as np
 import pytest
@@ -11,6 +12,7 @@ from distkeras_tpu import obs
 from distkeras_tpu.models import Model, zoo
 from distkeras_tpu.obs.tracing import (NULL_TRACER, RequestTracer,
                                        resolve_tracer)
+from distkeras_tpu.parallel.worker import make_epoch_runner
 from distkeras_tpu.serving import ServingEngine, ServingMetrics
 
 
@@ -262,14 +264,11 @@ def test_engine_merges_request_summaries_into_component(tiny_lm):
     eng = ServingEngine(tiny_lm, num_slots=1, max_len=24)
     rid = eng.submit(PATTERN[:4], 3)
     eng.run(max_steps=200)
-    # earlier engines may still be alive and own the plain "serving"
-    # name; THIS engine's component is whichever serving* entry holds
-    # our rid
-    comps = obs.telemetry_snapshot()["components"]
-    mine = [c for n, c in comps.items() if n.startswith("serving")
-            and rid in c.get("requests", {})]
-    assert len(mine) == 1
-    assert mine[0]["requests"][rid]["state"] == "finished"
+    # earlier engines may still be alive, own the plain "serving" name
+    # and hold the same rid (rids start at 0 in every engine): THIS
+    # engine's component is the one registered under its own name
+    mine = obs.telemetry_snapshot()["components"][eng._component_name]
+    assert mine["requests"][rid]["state"] == "finished"
 
 
 def test_engine_tracer_records_queue_depth_and_slot(tiny_lm):
@@ -313,3 +312,206 @@ def test_engine_cancel_and_timeout_land_in_timeline(tiny_lm):
     r1 = eng.submit(PATTERN[:4], 5)
     eng.cancel(r1)
     assert eng.tracer.summaries()[r1]["state"] == "cancelled"
+
+
+# --- spans on the profiler's clock (obs.span) and program names -------------
+
+SERVING_SPANS = {
+    "serving.admit": {}, "serving.flush": {},
+    "serving.prefill": {"serving.prefill.stage", "serving.prefill.dispatch",
+                        "serving.prefill.insert",
+                        "serving.prefill.first_token"},
+    "serving.decode": {"serving.decode.pages", "serving.decode.tables",
+                       "serving.decode.dispatch", "serving.decode.fetch",
+                       "serving.decode.consume"}}
+
+
+def _paged_engine_30_steps(tiny_lm):
+    eng = ServingEngine(tiny_lm, num_slots=2, max_len=32, page_len=4)
+    eng.submit(PATTERN[:6], 12)
+    eng.submit(PATTERN[:9], 10)
+    for _ in range(30):
+        eng.step()
+    return eng
+
+
+def test_engine_step_opens_the_documented_spans(tiny_lm):
+    obs.reset_spans()
+    eng = _paged_engine_30_steps(tiny_lm)
+    tree = obs.span_summary()
+    assert set(tree) == {"serving.step"}
+    step = tree["serving.step"]
+    assert step["count"] == eng._iters == 30
+    kids = step["children"]
+    assert set(kids) == set(SERVING_SPANS)
+    for name, below in SERVING_SPANS.items():
+        assert set(kids[name]["children"]) == set(below), name
+        assert sum(c["total_s"] for c in kids[name]["children"].values()) \
+            <= kids[name]["total_s"]
+    assert sum(c["total_s"] for c in kids.values()) <= step["total_s"]
+    assert kids["serving.admit"]["count"] == 30
+    assert kids["serving.flush"]["count"] == 30
+    # the count of a span is the count of that work: two prompts, one
+    # whole-prompt chunk each; a step launched for every decode iteration
+    # and all but the last fetched one iteration later
+    assert kids["serving.prefill"]["count"] == 2
+    decode = kids["serving.decode"]
+    n = decode["count"]
+    assert decode["children"]["serving.decode.dispatch"]["count"] == n
+    assert decode["children"]["serving.decode.fetch"]["count"] == n - 1
+    assert decode["children"]["serving.decode.consume"]["count"] == n - 1
+
+
+def test_engine_step_opens_no_span_with_obs_disabled(tiny_lm):
+    obs.reset_spans()
+    obs.disable()
+    try:
+        eng = _paged_engine_30_steps(tiny_lm)
+        assert eng._iters == 30
+    finally:
+        obs.enable()
+    assert obs.span_summary() == {}
+
+
+def test_span_with_step_nests_like_any_other():
+    obs.reset_spans()
+    with obs.span("outer", step=3):
+        assert obs.current_path() == ("outer",)
+        with obs.span("inner"):
+            assert obs.current_path() == ("outer", "inner")
+        with obs.span("inner", step=4):
+            pass
+    assert obs.current_path() == ()
+    tree = obs.span_summary()
+    assert tree["outer"]["count"] == 1
+    assert tree["outer"]["children"]["inner"]["count"] == 2
+    assert tree["outer"]["children"]["inner"]["total_s"] \
+        <= tree["outer"]["total_s"]
+
+
+def _train_two_epochs(**kw):
+    from distkeras_tpu.data.dataset import Dataset
+    from distkeras_tpu.parallel.trainers import SingleTrainer
+    rs = np.random.RandomState(0)
+    X = rs.rand(128, 8).astype(np.float32)
+    y = (X.sum(axis=1) > 4).astype(np.int32)
+    model = Model.build(zoo.mlp((8,), num_classes=2), (8,), seed=0)
+    tr = SingleTrainer(
+        model, worker_optimizer="sgd", learning_rate=0.1,
+        loss="sparse_categorical_crossentropy_from_logits",
+        batch_size=32, num_epoch=2, **kw)
+    tr.train(Dataset({"features": X, "label": y}))
+    return tr
+
+
+def test_trainer_epoch_loop_opens_the_train_spans(tmp_path):
+    obs.reset_spans()
+    tr = _train_two_epochs(checkpoint_dir=str(tmp_path))
+    tree = obs.span_summary()
+    assert set(tree) == {"train.data_wait", "train.dispatch", "train.fetch",
+                         "train.epoch_end"}
+    # one epoch program an epoch; the stream is asked once more at its end
+    assert tree["train.dispatch"]["count"] == 2
+    assert tree["train.fetch"]["count"] == 2
+    assert tree["train.data_wait"]["count"] == 3
+    assert tree["train.epoch_end"]["count"] == 2
+    assert set(tree["train.epoch_end"]["children"]) == {"train.checkpoint"}
+    # the tape's own account is what it was: one ``device`` phase
+    phases = tr.tape.snapshot()["phases_s"]
+    assert set(phases) == {"data_wait", "device", "checkpoint"}
+    assert phases["device"] == pytest.approx(
+        tree["train.dispatch"]["total_s"] + tree["train.fetch"]["total_s"],
+        rel=0.05)
+
+
+@pytest.mark.parametrize("how", ["telemetry_false", "obs_disabled"])
+def test_trainer_opens_no_span_without_telemetry(how):
+    obs.reset_spans()
+    if how == "telemetry_false":
+        _train_two_epochs(telemetry=False)
+    else:
+        obs.disable()
+        try:
+            _train_two_epochs()
+        finally:
+            obs.enable()
+    assert obs.span_summary() == {}
+
+
+def _spec_engine(tiny_lm, **kw):
+    from distkeras_tpu.serving import NgramDraft
+    return ServingEngine(tiny_lm, num_slots=2, max_len=32, page_len=4,
+                         draft=NgramDraft(), spec_k=3, **kw)
+
+
+@pytest.mark.parametrize("build,name", [
+    (lambda m: ServingEngine(m, max_len=32)._decode_fn(True),
+     "serving_decode_greedy"),
+    (lambda m: ServingEngine(m, max_len=32)._decode_fn(False),
+     "serving_decode_sampled"),
+    (lambda m: ServingEngine(m, max_len=32, fuse_steps=4)._fused_fn(True),
+     "serving_decode_fused_greedy"),
+    (lambda m: ServingEngine(m, max_len=32)._prefill_fn(6, 0, True),
+     "serving_prefill"),
+    (lambda m: ServingEngine(m, max_len=32)._sample_first_fn(),
+     "serving_sample_first"),
+    (lambda m: _spec_engine(m)._verify_fn(True), "serving_verify_greedy"),
+    (lambda m: _spec_engine(m, spec_tree=True, spec_width=2)
+     ._verify_tree_fn(False), "serving_verify_tree_sampled"),
+    (lambda m: make_epoch_runner(lambda c, b: (c, 0.0)), "train_epoch"),
+], ids=lambda v: v if isinstance(v, str) else "")
+def test_programs_are_named_for_the_profiler(tiny_lm, build, name):
+    """What ``XLA Modules`` of a profiler trace and the compile log print:
+    ``jit_<name>``."""
+    assert build(tiny_lm).__name__ == name
+
+
+def test_decode_logits_program_name_is_an_identifier(tiny_lm):
+    eng = ServingEngine(tiny_lm, num_slots=2, max_len=32, page_len=4)
+    eng.submit(PATTERN[:6], 4)
+    eng.step()
+    eng.decode_logits(decode_kernel="off")
+    fn = eng._logits_fns["off", None]
+    assert fn.__name__ == "serving_decode_logits_off_None_"
+    assert fn.__name__.isidentifier()
+
+
+@pytest.fixture(scope="module")
+def step_program_texts(tiny_lm):
+    """The lowered train step and paged decode step of the tiny LM, with the
+    operations' names (``jit(<program>)/<scope>/...``)."""
+    import jax
+    from distkeras_tpu.ops.losses import get_loss
+    from distkeras_tpu.ops.optimizers import get_optimizer
+    from distkeras_tpu.parallel.worker import (TrainCarry, make_epoch_runner,
+                                               make_train_step)
+    opt = get_optimizer("adam", learning_rate=1e-3)
+    step = make_train_step(
+        tiny_lm.module,
+        get_loss("sparse_categorical_crossentropy_from_logits"), opt)
+    carry = TrainCarry(tiny_lm.params, tiny_lm.state,
+                       opt.init(tiny_lm.params), jax.random.PRNGKey(0))
+    x = np.zeros((2, 2, S), np.int32)
+    train = make_epoch_runner(step).lower(carry, x, x).as_text(
+        debug_info=True)
+    eng = ServingEngine(tiny_lm, num_slots=2, max_len=32, page_len=4)
+    decode = eng._decode_fn(True).lower(
+        eng._params, eng._state, eng.pool.cache, eng._tok, eng._t,
+        eng.pool.device_tables()).as_text(debug_info=True)
+    return {"train_epoch": train, "serving_decode_greedy": decode}
+
+
+@pytest.mark.parametrize("program,scope", [
+    ("train_epoch", "embed"), ("train_epoch", "attn"),
+    ("train_epoch", "mlp"), ("train_epoch", "head"),
+    ("train_epoch", "loss"), ("train_epoch", "optimizer"),
+    ("serving_decode_greedy", "embed"), ("serving_decode_greedy", "attn"),
+    ("serving_decode_greedy", "mlp"), ("serving_decode_greedy", "head"),
+    ("serving_decode_greedy", "sample")])
+def test_step_programs_carry_the_named_scopes(step_program_texts, program,
+                                              scope):
+    """For XProf's op profile; nothing in ``benchmarks/`` reads them yet."""
+    text = step_program_texts[program]
+    assert f"module @jit_{program}" in text
+    # ``.../attn/...``, or ``jvp(attn)/...`` where the step differentiates
+    assert re.search(rf'[/("]{scope}[/)"]', text)
