@@ -693,7 +693,6 @@ def bench_serving(num_slots: int, prompt_len: int, new_tokens: int,
         extra = (probe.pool.device_tables(),) \
             if probe.kv_layout == "paged" else ()
         tok, t = probe._tok.copy(), probe._t.copy()
-        cache = probe.pool.cache
         # stay inside every slot's cache range (prefill serialization
         # already consumed a few decode steps on the earliest slots) —
         # the clamp is authoritative: steps past max_len would skip the
@@ -705,8 +704,12 @@ def bench_serving(num_slots: int, prompt_len: int, new_tokens: int,
                 f"prefill ramp (max_len={max_len}, t={t.tolist()})")
         t0 = time.perf_counter()
         for _ in range(steps):
-            nxt, cache, _moe = fn(probe._params, probe._state, cache,
-                                  tok, t, *extra)
+            # the program donates its cache: rebind the probe's pool in
+            # the same statement, as the engine does, or the next pass
+            # prefills into deleted buffers
+            nxt, probe.pool.cache, _moe = fn(
+                probe._params, probe._state, probe.pool.cache, tok, t,
+                *extra)
             tok = np.asarray(nxt)
             t = t + 1
         rate = num_slots * steps / (time.perf_counter() - t0)

@@ -943,6 +943,44 @@ def decode_step_slots(module: Sequential, params, state, cache, tok, t,
 # bitwise identical wherever the views hold identical values.
 
 
+def _page_row_ids(plane, pp, off):
+    """[S, H] indices of rows ``[pp, :, off]`` of a pool plane
+    [N, H, rows, ...] seen as [N*H*rows, ...]; a ``pp`` of N or more
+    (the sentinel) lands past the end."""
+    _, h, rows = plane.shape[:3]
+    return (pp[:, None] * h + jnp.arange(h)[None, :]) * rows + off[:, None]
+
+
+def _flat_rows(plane):
+    n, h, rows = plane.shape[:3]
+    return plane.reshape((n * h * rows,) + plane.shape[3:])
+
+
+def _read_page_rows(plane, pp, off):
+    """``plane[pp, :, off]`` through the flattened plane (see
+    ``_write_page_rows``: the two-index gather pays the same relayout
+    of the whole plane as the two-index scatter)."""
+    return _flat_rows(plane)[_page_row_ids(plane, pp, off)]
+
+
+def _write_page_rows(plane, pp, off, vals):
+    """``plane.at[pp, :, off].set(vals, mode="drop")`` for a plane of
+    the page pool — payload [N, H, rows, D] with per-slot values
+    [S, H, D], or scales [N, H, rows] with [S, H] — written as ONE
+    scatter of S*H rows into the plane seen as [N*H*rows, ...]. Same
+    bytes land in the same places (a ``pp`` of N or more still falls
+    off the end and drops), but the two-index form makes the TPU
+    compiler move the WHOLE plane into a layout with the heads behind
+    the rows and back again — two copies of the pool every decode
+    step, donated or not — where rows of the flattened plane are
+    written in place (``tests/test_tpu_compile.py`` holds the compiled
+    step to that for each pool dtype)."""
+    ids = _page_row_ids(plane, pp, off).reshape(-1)
+    flat = _flat_rows(plane).at[ids].set(
+        vals.reshape((-1,) + plane.shape[3:]), mode="drop")
+    return flat.reshape(plane.shape)
+
+
 def _cache_write_pages(kv, k, v, t, table, page_len: int):
     """Write one [S, 1, H, D] k/v decode slab at per-slot positions
     ``t`` ([S] int) into the paged pool [N, H, page_len, D] through the
@@ -976,31 +1014,30 @@ def _cache_write_pages(kv, k, v, t, table, page_len: int):
         prow = off % half
         hi = (off >= half)[:, None, None]                # [S, 1, 1]
         gp = jnp.clip(pp, 0, n_pages - 1)
-        out = {"k_scale": kv["k_scale"].at[pp, :, off].set(
-                   sk, mode="drop"),
-               "v_scale": kv["v_scale"].at[pp, :, off].set(
-                   sv, mode="drop"),
+        out = {"k_scale": _write_page_rows(kv["k_scale"], pp, off, sk),
+               "v_scale": _write_page_rows(kv["v_scale"], pp, off, sv),
                "q4": kv["q4"]}
         for key, q in (("k", qk), ("v", qv)):
-            cur = kv[key][gp, :, prow].astype(jnp.int32) & 255
+            cur = _read_page_rows(kv[key], gp, prow).astype(jnp.int32) \
+                & 255
             nib = q.astype(jnp.int32) & 15
             b = jnp.where(hi, (cur & 0x0F) | (nib << 4),
                           (cur & 0xF0) | nib)
             b = (b - 256 * (b > 127)).astype(jnp.int8)
-            out[key] = kv[key].at[pp, :, prow].set(b, mode="drop")
+            out[key] = _write_page_rows(kv[key], pp, prow, b)
         return out
     if "k_scale" in kv:
         qk, sk = _quantize_kv(kh)
         qv, sv = _quantize_kv(vh)
         return {
-            "k": kv["k"].at[pp, :, off].set(qk, mode="drop"),
-            "v": kv["v"].at[pp, :, off].set(qv, mode="drop"),
-            "k_scale": kv["k_scale"].at[pp, :, off].set(sk, mode="drop"),
-            "v_scale": kv["v_scale"].at[pp, :, off].set(sv, mode="drop")}
-    return {"k": kv["k"].at[pp, :, off].set(
-                kh.astype(kv["k"].dtype), mode="drop"),
-            "v": kv["v"].at[pp, :, off].set(
-                vh.astype(kv["v"].dtype), mode="drop")}
+            "k": _write_page_rows(kv["k"], pp, off, qk),
+            "v": _write_page_rows(kv["v"], pp, off, qv),
+            "k_scale": _write_page_rows(kv["k_scale"], pp, off, sk),
+            "v_scale": _write_page_rows(kv["v_scale"], pp, off, sv)}
+    return {"k": _write_page_rows(kv["k"], pp, off,
+                                  kh.astype(kv["k"].dtype)),
+            "v": _write_page_rows(kv["v"], pp, off,
+                                  vh.astype(kv["v"].dtype))}
 
 
 def _gather_pages(kv, table):

@@ -68,10 +68,18 @@ batched onto a deferred per-window cadence. Outputs stay
 token-identical (byte-identical for sampled streams) to the
 synchronous loop (``overlap=False``) — the oracle suite pins it.
 
-Remaining deliberate scope: cache-buffer donation is a TPU-latency
-follow-up; weight trees support ``weights_dtype="auto"``-style
-pre-casting but not int8; prompts longer than
-``max_len - max_new_tokens`` are rejected at submit.
+One pool on the device: every program that takes a KV cache and
+returns its successor DONATES it (``_jit_serving``; the pool's own
+insert/restore programs in ``kv_pool`` and the draft model's in
+``speculation`` too), so a step writes the page pool in place and the
+value passed in is deleted — every call site rebinds ``pool.cache`` /
+``_staging`` from the result before anything reads it. ``decode_logits()``
+alone keeps its pool. ``health()["programs"]`` says which
+(``kv_cache=donated`` / ``kv_cache=kept``).
+
+Remaining deliberate scope: weight trees support
+``weights_dtype="auto"``-style pre-casting but not int8; prompts longer
+than ``max_len - max_new_tokens`` are rejected at submit.
 """
 
 from __future__ import annotations
@@ -794,19 +802,28 @@ class ServingEngine:
         self._params = jax.device_put(self._params, shardings)
 
     def _jit_serving(self, f, n_args: int, name: str,
-                     keep_attn: bool = False):
+                     keep_attn: bool = False, donate_cache: bool = True):
         """Compile one serving program: plain ``jax.jit``, or — under
         expert parallelism — ``jit(shard_map(f))`` with the params
         (always argument 0) split by the expert specs and every other
         argument/output replicated (the MoE psum makes outputs agree
-        across the axis). Under ``weight_quant`` every program first
+        across the axis). Every program's signature is ``(params,
+        state, cache, ...)`` and the KV cache — argument 2, the page
+        pool, the slab pool or the batch-1 staging cache — is DONATED:
+        the program writes its successor into the same buffers, the
+        value passed in is deleted, and the caller rebinds it from the
+        result before anything reads it. The one exception is
+        ``decode_logits()``, which drops the step's cache writes and so
+        must find the pool alive afterwards (``donate_cache=False``).
+        Under ``weight_quant`` every program first
         dequantizes the qdict tree in-graph; ``keep_attn`` (the
         decode/fused programs, whose only attention-weight consumers
         are ``_project_qkv`` / ``_attn_out``) leaves the attention
         projections quantized for the fused dequant-matmul kernel.
         While the program is traced, every kernel-or-reference choice
         inside it (``compat.note_path``) lands in
-        ``program_paths[name]`` — ``health()["programs"]``. The
+        ``program_paths[name]`` — ``health()["programs"]`` — beside
+        ``kv_cache=donated`` (or ``kv_cache=kept``). The
         program itself is named ``serving_<name>`` (anything outside
         ``[A-Za-z0-9_]`` becomes ``_``): what a profiler's ``XLA
         Modules`` line and the compile log call it."""
@@ -815,6 +832,8 @@ class ServingEngine:
         def f(*args):
             with record_paths() as paths:
                 out = traced(*args)
+            paths.add("kv_cache=donated" if donate_cache
+                      else "kv_cache=kept")
             self.program_paths[name] = ", ".join(sorted(paths))
             return out
 
@@ -835,7 +854,7 @@ class ServingEngine:
                 out_specs=P())
         f.__name__ = f.__qualname__ = re.sub(
             "[^A-Za-z0-9_]", "_", "serving_" + name)
-        return jax.jit(f)
+        return jax.jit(f, donate_argnums=(2,) if donate_cache else ())
 
     # --- MoE routing telemetry / admission cost ---------------------------
 
@@ -2249,7 +2268,17 @@ class ServingEngine:
         output. A decode-step error is batch-wide and not attributable
         to one request, so it propagates — but it is raised before any
         engine state mutates, so ``step()`` can simply be called again
-        (the failed iteration retries wholesale)."""
+        (the failed iteration retries wholesale).
+
+        Both hold for errors raised BEFORE a program is dispatched
+        (tracing, argument checks, the ``serving.prefill`` /
+        ``serving.decode`` fault points): every program donates its
+        cache, so one that fails once dispatched has consumed it. A
+        consumed staging cache is rebuilt (it held one request's
+        prefill, and that request is the one cancelled); a consumed
+        pool held every stream's KV and cannot be, so ``step()`` raises
+        a ``RuntimeError`` that says so rather than serve from deleted
+        buffers."""
         with obs.span("serving.step", step=self._iters):
             finished: List[Request] = []
             with obs.span("serving.admit"):
@@ -2280,7 +2309,11 @@ class ServingEngine:
             if running:
                 with self.metrics.timer.phase("decode"), \
                         obs.span("serving.decode"):
-                    self._advance_decode(finished)
+                    try:
+                        self._advance_decode(finished)
+                    except Exception as e:
+                        self._recover_donated(e)
+                        raise
 
             with obs.span("serving.flush"):
                 # per-iteration samples land in the deferred buffers; the live
@@ -2375,8 +2408,24 @@ class ServingEngine:
         other stream untouched."""
         if req.state in TERMINAL_STATES:
             raise err    # already terminal — nothing to isolate
+        self._recover_donated(err)
         self._terminate(req, RequestState.CANCELLED, finished, error=err)
         self.metrics.record_cancelled(req.rid)
+
+    def _recover_donated(self, err: Exception) -> None:
+        """After a failed program: a donated cache that the failure
+        consumed (dispatched, never rebound) reads ``is_deleted()``.
+        The staging cache is rebuilt; a lost pool is fatal."""
+        def lost(cache):
+            return any(isinstance(x, jax.Array) and x.is_deleted()
+                       for x in jax.tree_util.tree_leaves(cache))
+        if lost(self.pool.cache):
+            raise RuntimeError(
+                "a serving program failed after it was dispatched and "
+                "took the donated KV pool with it: every stream's cache "
+                "is gone, build a new engine") from err
+        if lost(self._staging):
+            self._staging = self.pool.make_request_cache()
 
     def cancel(self, rid: int) -> Request:
         """Cancel an in-flight request by id (client disconnect etc.);
@@ -2650,7 +2699,7 @@ class ServingEngine:
             fn = self._logits_fns[decode_kernel, moe_decode] = \
                 self._jit_serving(
                     f, 6, f"decode_logits[{decode_kernel},{moe_decode}]",
-                    keep_attn=True)
+                    keep_attn=True, donate_cache=False)
         return np.asarray(fn(
             self._params, self._state, self.pool.cache,
             _snap(self._tok), _snap(self._t), self.pool.device_tables()))

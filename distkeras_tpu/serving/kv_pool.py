@@ -56,6 +56,7 @@ consumers:
 from __future__ import annotations
 
 import itertools
+from functools import partial
 from typing import Dict, List, Optional, Tuple
 
 import jax
@@ -67,12 +68,13 @@ from distkeras_tpu.models.decoding import (init_cache, pack_int4,
                                            unpack_int4)
 
 
-@jax.jit
+@partial(jax.jit, donate_argnums=0)
 def _insert_row(pool, req_cache, slot):
     """Write a batch-1 request cache into pool row ``slot`` (``slot``
     is traced — one compiled program serves every slot index). The
     request cache may be SHORTER than the row (the prompt-length
-    prefix): only its positions are written."""
+    prefix): only its positions are written. ``pool`` is donated:
+    the row is written in place."""
     def write(pl, rq):
         return lax.dynamic_update_slice(
             pl, rq.astype(pl.dtype), (slot,) + (0,) * (pl.ndim - 1))
@@ -83,8 +85,12 @@ class KVPool:
     """S-slot slab-pooled KV cache over ``module``'s attention layers.
 
     ``cache`` is the live device pytree (the exact structure
-    ``decode_step_slots`` consumes); ``insert`` replaces it — callers
-    must not hold on to the old value."""
+    ``decode_step_slots`` consumes). Every program that advances it
+    (``insert`` here, the engine's step programs) DONATES it: the new
+    value reuses the old one's buffers and the old arrays are deleted,
+    not merely stale — a handle kept across such a call raises on its
+    next read. Rebind ``cache`` from the program's result; copy first
+    what must outlive it."""
 
     def __init__(self, module, num_slots: int, max_len: int,
                  dtype=jnp.float32):
@@ -139,7 +145,7 @@ class KVPool:
 #: unallocated sentinel (scatter drops, gather clamps into masked range)
 
 
-@jax.jit
+@partial(jax.jit, donate_argnums=0)
 def _write_pages(pool, staging, table):
     """Scatter staging pages into the pool: logical page ``p`` of the
     batch-1 staging cache lands on physical page ``table[p]``; sentinel
@@ -148,7 +154,9 @@ def _write_pages(pool, staging, table):
     is encoded by the sentinel, not by program shape. int4 pools
     (``"q4"`` marker) nibble-pack the payload pages here: the staging
     cache stays unpacked (one int8 byte per entry, the shared dequant
-    contract), the POOL planes are where the 2x byte saving lives."""
+    contract), the POOL planes are where the 2x byte saving lives.
+    ``pool`` is donated (the pages are written in place, the marker
+    leaf passes through aliased); ``staging`` lives on."""
     def write(pl, st, packed):
         page_len = 2 * pl.shape[2] if packed else pl.shape[2]
         if st.ndim == 4:
@@ -179,26 +187,29 @@ def _write_pages(pool, staging, table):
 def _gather_rows(pool, ids):
     """Gather physical pages ``ids`` out of every pool plane — the D2H
     offload read. One compiled program per (structure, n) pair, the
-    same bounded cardinality as the per-``n_pos`` insert programs."""
+    same bounded cardinality as the per-``n_pos`` insert programs.
+    Donates nothing: the pool lives on, and the gathered rows are
+    buffers of their own that later donating steps cannot touch."""
     return jax.tree_util.tree_map(lambda p: p[ids], pool)
 
 
-@jax.jit
+@partial(jax.jit, donate_argnums=0)
 def _scatter_rows(pool, ids, vals):
     """Scatter host page payloads ``vals`` into pool rows ``ids`` —
     the H2D restore write (byte-identical: storage dtypes in, storage
-    dtypes out, no recompute anywhere)."""
+    dtypes out, no recompute anywhere). ``pool`` is donated."""
     return jax.tree_util.tree_map(
         lambda p, v: p.at[ids].set(v.astype(p.dtype)), pool, vals)
 
 
-@jax.jit
+@partial(jax.jit, donate_argnums=0)
 def _load_pages(staging, pool, table, valid):
     """Gather pool pages into the batch-1 staging cache: logical page
     ``p`` becomes ``pool[table[p]]`` where ``valid[p]``, else keeps the
     staging content. The prefix-cache hit path: shared pages (and a
     copy-on-write donor) materialize as the staging prefix the
-    remaining prefill chunks attend to."""
+    remaining prefill chunks attend to. ``staging`` is donated, the
+    pool never."""
     def load(st, pl, packed):
         g = pl[table]                        # [P, H, page_len(/2), D?]
         if packed:
@@ -237,7 +248,16 @@ class PagedKVPool:
     consumes; ``tables`` is the host ``[S, P]`` int32 page-table array
     (``device_tables()`` returns the cached device mirror, invalidated
     by any mutation). A table entry of ``num_pages`` is the
-    unallocated sentinel."""
+    unallocated sentinel.
+
+    ``cache`` is DONATED to every program that advances it
+    (``insert_pages``, ``restore_pages``, the engine's decode, verify
+    and fused programs): there is one pool on the device, written in
+    place, and the arrays of the old value are deleted, not merely
+    stale — a handle kept across such a call raises on its next read.
+    ``load_prefix`` and ``offload_pages`` only read the pool
+    (``load_prefix`` donates the STAGING cache it is handed); the
+    offload snapshot is a buffer of its own."""
 
     def __init__(self, module, num_slots: int, max_len: int,
                  page_len: int = 16, num_pages: Optional[int] = None,

@@ -473,7 +473,8 @@ class DraftModel(DraftSource):
     def _prefill_fn(self, n: int):
         """Head-less whole-context chunk prefill at batch 1 (the draft
         only ever needs cache entries, never logits). One program per
-        context length, LRU-capped like the engine's."""
+        context length, LRU-capped like the engine's. The staging
+        cache (argument 2) is donated, as in every serving program."""
         fn = self._prefill_fns.pop(n, None)
         if fn is None:
             from distkeras_tpu.models.decoding import prefill_chunk_step
@@ -485,7 +486,7 @@ class DraftModel(DraftSource):
                                               final=False)
                 return cache
 
-            fn = jax.jit(f)
+            fn = jax.jit(f, donate_argnums=2)
         self._prefill_fns[n] = fn
         while len(self._prefill_fns) > self.MAX_PREFILL_PROGRAMS:
             self._prefill_fns.pop(next(iter(self._prefill_fns)))
@@ -496,7 +497,9 @@ class DraftModel(DraftSource):
         ``lax.top_k`` id matrix ``[S, width]`` (beam-style trees —
         column 0 is the argmax the greedy chain follows). One program
         per distinct width (the engine's per-request widths share the
-        engine-level cap, so the set is tiny)."""
+        engine-level cap, so the set is tiny). The draft pool
+        (argument 2) is donated: callers rebind ``pool.cache`` from
+        the result."""
         fn = self._step_fns.get(width)
         if fn is None:
             from distkeras_tpu.models.decoding import \
@@ -506,7 +509,6 @@ class DraftModel(DraftSource):
             module = self.module
             page_len = self.pool.page_len
 
-            @jax.jit
             def fn(params, state, cache, tok, t, tables):
                 logits, cache = decode_step_slots_paged(
                     module, params, state, cache, tok, t, tables,
@@ -515,7 +517,7 @@ class DraftModel(DraftSource):
                     return jnp.argmax(logits, axis=-1), cache
                 return lax.top_k(logits, width)[1], cache
 
-            self._step_fns[width] = fn
+            fn = self._step_fns[width] = jax.jit(fn, donate_argnums=2)
         return fn
 
     def _heal(self, requests, tok, t) -> None:

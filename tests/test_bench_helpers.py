@@ -356,3 +356,18 @@ def test_compile_cache_dir_env_wins_else_repo_local(monkeypatch, env_dir):
         monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", env_dir)
         assert compat.enable_compile_cache() == env_dir
         assert updates == []
+
+
+def test_bench_serving_probe_survives_a_second_pass(monkeypatch):
+    """The raw-loop probe drives the donating decode program by hand on
+    an engine it reuses across passes: it has to rebind the probe's
+    pool as the engine does, or the second pass prefills into deleted
+    buffers (one pass, as the CPU smoke runs, cannot see it)."""
+    monkeypatch.setattr(bench, "LM_CFG", dict(
+        d_model=32, num_heads=2, num_layers=1, mlp_ratio=2, vocab=64,
+        seq=32))
+    rates, raws, summaries, _slo, _trace = bench.bench_serving(
+        num_slots=2, prompt_len=4, new_tokens=12, n_requests=3,
+        n_passes=2)
+    assert len(rates) == len(raws) == 2 and min(raws) > 0
+    assert all(s["requests_cancelled"] == 0 for s in summaries)

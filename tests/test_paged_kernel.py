@@ -187,8 +187,9 @@ def test_health_reports_the_path_each_program_took(
     eng.submit(PATTERN[:4], 3)
     eng.run(max_steps=100)
     programs = eng.health()["programs"]
-    assert programs["decode_greedy"] == path
-    assert programs["prefill"] == "flash_attention=xla_reference"
+    assert programs["decode_greedy"] == f"kv_cache=donated, {path}"
+    assert programs["prefill"] == \
+        "flash_attention=xla_reference, kv_cache=donated"
 
 
 def test_decode_logits_kernel_and_gather_read_the_same_state(
@@ -213,8 +214,8 @@ def test_decode_logits_kernel_and_gather_read_the_same_state(
         np.testing.assert_array_equal(
             out[rid], generate(m, PATTERN[None, :n], k,
                                temperature=0.0)[0])
-    assert "paged_attention=gather_reference" in \
-        eng.health()["programs"]["decode_logits[off,None]"]
+    assert eng.health()["programs"]["decode_logits[off,None]"] == \
+        "kv_cache=kept, paged_attention=gather_reference"
     with pytest.raises(ValueError, match="paged cache"):
         ServingEngine(m, num_slots=1, max_len=32,
                       kv_layout="slab").decode_logits()

@@ -170,6 +170,88 @@ def test_paged_decode_attention(one_chip, as_tpu, page_len, int8):
     assert n == 1
 
 
+@pytest.mark.parametrize("dtype,rows", [(jnp.bfloat16, 16), (jnp.int8, 32),
+                                        (jnp.int8, 8)],
+                         ids=["bf16", "int8", "int4-packed"])
+def test_page_write_moves_no_plane(one_chip, dtype, rows):
+    """The decode step's KV write into a DONATED pool plane at the
+    serve cell's size ([2048, 16, 16, 128] bf16, 16 slots) is compiled
+    in place: the plane is aliased to the result and the program holds
+    no copy of it. (``plane.at[pp, :, off].set(...)`` compiles to a
+    relayout of the whole plane and back: 45 ms of ``copy`` a decode
+    step at this size, ``PERF.md`` §6, PR 27.)"""
+    from distkeras_tpu.models.decoding import _write_page_rows
+    s = _spec(one_chip)
+    plane = (2048, 16, rows, 128)
+    compiled = jax.jit(_write_page_rows, donate_argnums=0).lower(
+        s(plane, dtype), s((16,), jnp.int32), s((16,), jnp.int32),
+        s((16, 16, 128), dtype)).compile()
+    text = compiled.as_text()
+    assert "may-alias" in text.split("\n", 1)[0]
+    dims = ",".join(map(str, plane))
+    moved = [line for line in text.splitlines()
+             if " copy(" in line and (f"[{dims}]" in line
+                                      or f"[{2048 * 16 * rows},128]" in line)]
+    assert not moved, moved[:2]
+    assert compiled.memory_analysis().temp_size_in_bytes < 1 << 20
+
+
+@pytest.mark.parametrize(
+    "dtype,page_len", [(jnp.bfloat16, 16), (jnp.bfloat16, 8), ("int8", 32),
+                       ("int4", 64), ("int4", 32)],
+    ids=["bf16", "bf16-half-tile", "int8", "int4", "int4-gather-path"])
+def test_decode_step_moves_no_pool_plane(one_chip, as_tpu, dtype, page_len):
+    """The WHOLE paged decode step at the serve cell's widths (16 slots
+    x 2048 positions, 16 heads of 128) on a donated pool: every cache
+    leaf is aliased to the result and no copy in the program is the
+    size of a payload plane — the int4 pool's read-modify-write gather
+    and a page shorter than a bf16 tile included. (The f32 scale
+    planes, 1/32 of an int8 pool's bytes, enter in a layout of the
+    compiler's choosing and pay one copy in and one out whichever way
+    they are indexed. int4 at page_len 32 packs to 16 rows, which the
+    kernel refuses: that case is the ``_gather_pages`` readout.)"""
+    import re
+    from distkeras_tpu.models import Model, zoo
+    from distkeras_tpu.models.decoding import (_resolve_head_dims,
+                                               decode_step_slots_paged,
+                                               init_cache)
+    m = Model.build(zoo.transformer_lm(
+        256, d_model=2048, num_heads=16, num_layers=1, mlp_ratio=1,
+        max_len=2048, use_rope=False, norm="layernorm",
+        dtype="bfloat16"), (16,), seed=0)
+    module = m.module
+    _resolve_head_dims(module, m.params)
+    slots, s = 16, _spec(one_chip)
+    cache = jax.eval_shape(lambda: init_cache(
+        module, slots * 2048 // page_len, page_len, dtype, check_len=2048))
+    if dtype == "int4":              # PagedKVPool packs two rows a byte
+        cache = [kv and {k: jax.ShapeDtypeStruct(
+            a.shape[:2] + (a.shape[2] // 2,) + a.shape[3:], a.dtype)
+            if k in "kv" else a for k, a in kv.items()} for kv in cache]
+
+    def on_chip(tree):
+        return jax.tree_util.tree_map(lambda a: s(a.shape, a.dtype), tree)
+
+    def step(params, state, cache, tok, t, table):
+        return decode_step_slots_paged(module, params, state, cache, tok,
+                                       t, table, page_len)
+
+    text = jax.jit(step, donate_argnums=2).lower(
+        on_chip(m.params), on_chip(m.state), on_chip(cache),
+        s((slots,), jnp.int32), s((slots,), jnp.int32),
+        s((slots, 2048 // page_len), jnp.int32)).compile().as_text()
+    leaves = jax.tree_util.tree_leaves(cache)
+    assert text.split("\n", 1)[0].count("may-alias") == len(leaves)
+    planes = {int(np.prod(a.shape)) for a in leaves if a.ndim == 4}
+    moved = [line.strip()[:160] for line in text.splitlines()
+             if (dims := re.search(r"= \w+\[([\d,]+)\]\S* copy\(", line))
+             and int(np.prod(list(map(int, dims.group(1).split(",")))))
+             in planes]
+    assert not moved, moved[:2]
+    kernels = text.count('custom_call_target="tpu_custom_call"')
+    assert kernels == (0 if (dtype, page_len) == ("int4", 32) else 1)
+
+
 # --- quantized matmul ------------------------------------------------------
 
 @pytest.mark.parametrize("bits", [8, 4], ids=["int8", "int4"])
