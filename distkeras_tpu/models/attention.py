@@ -131,7 +131,7 @@ class PositionalEmbedding(Layer):
 
 def _attention_compute(q, k, v, *, causal, impl, axis_name=None,
                        ring_block_size=None, window=None,
-                       segment_ids=None):
+                       segment_ids=None, block_len=None):
     """Dispatch on attention implementation. q/k/v are BSHD.
 
     ``segment_ids`` (packed sequences) flows to EVERY impl (round 4):
@@ -148,7 +148,12 @@ def _attention_compute(q, k, v, *, causal, impl, axis_name=None,
     if impl == "flash":
         from distkeras_tpu.ops.flash_attention import flash_attention
         return flash_attention(q, k, v, causal=causal, window=window,
-                               segment_ids=segment_ids)
+                               segment_ids=segment_ids,
+                               block_len=block_len)
+    if block_len is not None and impl != "xla":
+        raise ValueError(
+            f"block-causal attention is not supported with "
+            f"attn_impl={impl!r}")
     if window is not None and impl in ("ring", "ulysses",
                                        "ulysses_flash"):
         raise ValueError(
@@ -179,7 +184,8 @@ def _attention_compute(q, k, v, *, causal, impl, axis_name=None,
             segment_ids=segment_ids)
     note_path("flash_attention", "xla_reference")
     return dot_product_attention(q, k, v, causal=causal, window=window,
-                                 segment_ids=segment_ids)
+                                 segment_ids=segment_ids,
+                                 block_len=block_len)
 
 
 @register_layer
@@ -196,6 +202,15 @@ class MultiHeadAttention(Layer):
     matmul-dominated either way); the payoff is serving — the KV cache
     shrinks by the group factor (``models.decoding`` sizes it by
     ``num_kv_heads``).
+
+    ``qk_norm`` adds an RMSNorm (epsilon 1e-6, one learned scale of
+    ``head_dim``) over every head's query and key before RoPE.
+    ``rope_base`` is RoPE's theta. ``block_len=B`` makes the causal
+    mask BLOCK-causal (position ``i`` sees key ``j`` iff
+    ``j // B <= i // B``): the attention of a block-diffusion language
+    model, which ``ServingEngine`` decodes a block at a time. Not with
+    a window or a sequence-parallel ``attn_impl``; the flash kernel
+    runs it forward only.
     """
 
     def __init__(self, num_heads: int, head_dim: Optional[int] = None,
@@ -206,8 +221,19 @@ class MultiHeadAttention(Layer):
                  ring_block_size: Optional[int] = None,
                  num_kv_heads: Optional[int] = None,
                  rope_scale: float = 1.0,
-                 attn_window: Optional[int] = None):
+                 attn_window: Optional[int] = None,
+                 qk_norm: bool = False, rope_base: float = 10000.0,
+                 block_len: Optional[int] = None):
         self.rope_scale = float(rope_scale)
+        self.rope_base = float(rope_base)
+        self.qk_norm = bool(qk_norm)
+        self.block_len = None if block_len is None else int(block_len)
+        if self.block_len is not None and (
+                self.block_len < 1 or not causal
+                or attn_window is not None):
+            raise ValueError(
+                "block_len must be >= 1 and needs causal=True and no "
+                "attn_window")
         #: causal sliding window (Mistral-style SWA): each query attends
         #: to at most the last attn_window keys. None = full causal.
         self.attn_window = (int(attn_window) if attn_window is not None
@@ -255,7 +281,24 @@ class MultiHeadAttention(Layer):
             "wv": w2d(ks[2], d_model, hkv * dh).reshape(d_model, hkv, dh),
             "wo": w2d(ks[3], h * dh, d_model).reshape(h, dh, d_model),
         }
+        if self.qk_norm:
+            params["q_norm"] = jnp.ones((dh,))
+            params["k_norm"] = jnp.ones((dh,))
         return params, {}, tuple(input_shape)
+
+    def rope(self, x, positions, layout: str = "bshd"):
+        """RoPE with this layer's base and position scale."""
+        return apply_rope(x, positions, base=self.rope_base,
+                          layout=layout, scale=self.rope_scale)
+
+    def normed_qk(self, params, q, k):
+        """The per-head RMSNorm of queries and keys (``qk_norm``); the
+        head dimension is last in every layout."""
+        if not self.qk_norm:
+            return q, k
+        norm = RMSNorm(1e-6)
+        return (norm.apply({"scale": params["q_norm"]}, {}, q)[0],
+                norm.apply({"scale": params["k_norm"]}, {}, k)[0])
 
     def _expand_kv(self, t, head_axis: int):
         """Broadcast grouped K/V heads up to num_heads for the kernels."""
@@ -285,32 +328,34 @@ class MultiHeadAttention(Layer):
             q = jnp.einsum("bsd,dhe->bhse", xc, params["wq"].astype(dt))
             k = jnp.einsum("bsd,dhe->bhse", xc, params["wk"].astype(dt))
             v = jnp.einsum("bsd,dhe->bhse", xc, params["wv"].astype(dt))
+            q, k = self.normed_qk(params, q, k)
             if self.use_rope:
-                q = apply_rope(q, positions, layout="bhsd",
-                               scale=self.rope_scale)
-                k = apply_rope(k, positions, layout="bhsd",
-                               scale=self.rope_scale)
+                q = self.rope(q, positions, layout="bhsd")
+                k = self.rope(k, positions, layout="bhsd")
             k, v = self._expand_kv(k, 1), self._expand_kv(v, 1)
             from distkeras_tpu.ops.flash_attention import flash_attention
             out = flash_attention(q, k, v, causal=self.causal,
                                   layout="bhsd", window=self.attn_window,
-                                  segment_ids=segment_ids)
+                                  segment_ids=segment_ids,
+                                  block_len=self.block_len)
             y = jnp.einsum("bhse,hed->bsd", out, params["wo"].astype(dt))
             return y.astype(x.dtype), state
 
         q = jnp.einsum("bsd,dhe->bshe", xc, params["wq"].astype(dt))
         k = jnp.einsum("bsd,dhe->bshe", xc, params["wk"].astype(dt))
         v = jnp.einsum("bsd,dhe->bshe", xc, params["wv"].astype(dt))
+        q, k = self.normed_qk(params, q, k)
         if self.use_rope:
-            q = apply_rope(q, positions, scale=self.rope_scale)
-            k = apply_rope(k, positions, scale=self.rope_scale)
+            q = self.rope(q, positions)
+            k = self.rope(k, positions)
         k, v = self._expand_kv(k, 2), self._expand_kv(v, 2)
         out = _attention_compute(q, k, v, causal=self.causal,
                                  impl=impl,
                                  axis_name=self.seq_axis_name,
                                  ring_block_size=self.ring_block_size,
                                  window=self.attn_window,
-                                 segment_ids=segment_ids)
+                                 segment_ids=segment_ids,
+                                 block_len=self.block_len)
         y = jnp.einsum("bshe,hed->bsd", out, params["wo"].astype(dt))
         return y.astype(x.dtype), state
 
@@ -323,43 +368,64 @@ class MultiHeadAttention(Layer):
                 "ring_block_size": self.ring_block_size,
                 "num_kv_heads": self.num_kv_heads,
                 "rope_scale": self.rope_scale,
-                "attn_window": self.attn_window}
+                "attn_window": self.attn_window,
+                "qk_norm": self.qk_norm, "rope_base": self.rope_base,
+                "block_len": self.block_len}
 
 
 @register_layer
 class TransformerMLP(Layer):
-    """Position-wise MLP with the standard column→row TP-splittable pair."""
+    """Position-wise MLP with the standard column→row TP-splittable pair.
+
+    ``gated`` makes it the gated form (SwiGLU with ``activation="silu"``):
+    ``w2(act(x w1) * (x w3))``, three matrices; ``use_bias=False`` drops
+    ``b1``/``b2`` (gated MLPs are published without)."""
 
     def __init__(self, hidden_dim: int, activation: str = "gelu",
                  dtype: str = "float32",
-                 kernel_init: str = "glorot_uniform"):
+                 kernel_init: str = "glorot_uniform",
+                 gated: bool = False, use_bias: bool = True):
         self.hidden_dim = int(hidden_dim)
         self.activation = activation
         self.dtype = dtype
         self.kernel_init = kernel_init
+        self.gated = bool(gated)
+        self.use_bias = bool(use_bias)
 
     def init(self, rng, input_shape):
         d = input_shape[-1]
-        k1, k2 = jax.random.split(rng)
+        k1, k2, k3 = jax.random.split(rng, 3)
         params = {
             "w1": init_weights(self.kernel_init, k1, (d, self.hidden_dim)),
-            "b1": jnp.zeros((self.hidden_dim,)),
             "w2": init_weights(self.kernel_init, k2, (self.hidden_dim, d)),
-            "b2": jnp.zeros((d,)),
         }
+        if self.gated:
+            params["w3"] = init_weights(self.kernel_init, k3,
+                                        (d, self.hidden_dim))
+        if self.use_bias:
+            params["b1"] = jnp.zeros((self.hidden_dim,))
+            params["b2"] = jnp.zeros((d,))
         return params, {}, tuple(input_shape)
 
     def apply(self, params, state, x, *, training=False, rng=None):
         dt = jnp.dtype(self.dtype)
         act = get_activation(self.activation)
-        h = act(x.astype(dt) @ params["w1"].astype(dt) +
-                params["b1"].astype(dt))
-        y = h @ params["w2"].astype(dt) + params["b2"].astype(dt)
+        xc = x.astype(dt)
+        h = xc @ params["w1"].astype(dt)
+        if self.use_bias:
+            h = h + params["b1"].astype(dt)
+        h = act(h)
+        if self.gated:
+            h = h * (xc @ params["w3"].astype(dt))
+        y = h @ params["w2"].astype(dt)
+        if self.use_bias:
+            y = y + params["b2"].astype(dt)
         return y.astype(x.dtype), state
 
     def get_config(self):
         return {"hidden_dim": self.hidden_dim, "activation": self.activation,
-                "dtype": self.dtype, "kernel_init": self.kernel_init}
+                "dtype": self.dtype, "kernel_init": self.kernel_init,
+                "gated": self.gated, "use_bias": self.use_bias}
 
 
 @register_layer
@@ -383,8 +449,19 @@ class TransformerBlock(Layer):
                  ring_block_size: Optional[int] = None,
                  num_kv_heads: Optional[int] = None,
                  rope_scale: float = 1.0,
-                 attn_window: Optional[int] = None):
+                 attn_window: Optional[int] = None,
+                 qk_norm: bool = False, rope_base: float = 10000.0,
+                 block_len: Optional[int] = None,
+                 mlp_dim: Optional[int] = None, mlp_gated: bool = False,
+                 mlp_bias: bool = True):
         self.num_heads = int(num_heads)
+        self.qk_norm = bool(qk_norm)
+        self.rope_base = float(rope_base)
+        self.block_len = block_len
+        #: the MLP's stated hidden width (None: ``mlp_ratio * d_model``)
+        self.mlp_dim = None if mlp_dim is None else int(mlp_dim)
+        self.mlp_gated = bool(mlp_gated)
+        self.mlp_bias = bool(mlp_bias)
         self.num_kv_heads = num_kv_heads
         self.rope_scale = float(rope_scale)
         self.attn_window = attn_window
@@ -409,7 +486,8 @@ class TransformerBlock(Layer):
             num_heads, head_dim=head_dim, causal=causal, use_rope=use_rope,
             dtype=dtype, attn_impl=attn_impl, seq_axis_name=seq_axis_name,
             ring_block_size=ring_block_size, num_kv_heads=num_kv_heads,
-            rope_scale=rope_scale, attn_window=attn_window)
+            rope_scale=rope_scale, attn_window=attn_window,
+            qk_norm=qk_norm, rope_base=rope_base, block_len=block_len)
         self.mlp = mlp_layer  # resolved in init once d_model is known
 
     def init(self, rng, input_shape):
@@ -418,9 +496,10 @@ class TransformerBlock(Layer):
             # re-resolve on every init: the hidden dim tracks d_model, so a
             # block instance re-initialized at a different width must not
             # keep the previous width's MLP
-            self.mlp = TransformerMLP(self.mlp_ratio * d_model,
-                                      activation=self.activation,
-                                      dtype=self.dtype)
+            self.mlp = TransformerMLP(
+                self.mlp_dim or self.mlp_ratio * d_model,
+                activation=self.activation, dtype=self.dtype,
+                gated=self.mlp_gated, use_bias=self.mlp_bias)
         ks = jax.random.split(rng, 4)
         p, s = {}, {}
         for name, layer, k in (("norm1", self.norm1, ks[0]),
@@ -478,7 +557,10 @@ class TransformerBlock(Layer):
                "ring_block_size": self.ring_block_size,
                "num_kv_heads": self.num_kv_heads,
                "rope_scale": self.rope_scale,
-               "attn_window": self.attn_window}
+               "attn_window": self.attn_window,
+               "qk_norm": self.qk_norm, "rope_base": self.rope_base,
+               "block_len": self.block_len, "mlp_dim": self.mlp_dim,
+               "mlp_gated": self.mlp_gated, "mlp_bias": self.mlp_bias}
         if self._mlp_override is not None:
             cfg["mlp_layer"] = layer_spec(self._mlp_override)
         return cfg

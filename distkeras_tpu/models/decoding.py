@@ -47,7 +47,7 @@ from distkeras_tpu.models.attention import (MultiHeadAttention,
                                             TransformerBlock)
 from distkeras_tpu.models.core import Model, Sequential, scoped
 from distkeras_tpu.models.layers import Dropout
-from distkeras_tpu.ops.attention import NEG_INF, apply_rope
+from distkeras_tpu.ops.attention import NEG_INF
 
 
 def _decode_block_of(layer):
@@ -64,6 +64,18 @@ def _decode_block_of(layer):
                                                TransformerBlock):
         return layer.inner
     return None
+
+
+def block_len_of(module: Sequential) -> Optional[int]:
+    """The block length of a block-causal model (every attention layer
+    states the same one), or None for a causal model."""
+    lens = {blk.attn.block_len for blk in
+            (_decode_block_of(layer) for layer in module.layers)
+            if blk is not None}
+    if len(lens) > 1:
+        raise ValueError(
+            f"attention layers disagree on block_len: {sorted(map(str, lens))}")
+    return lens.pop() if lens else None
 
 
 def init_cache(module: Sequential, batch: int, max_len: int,
@@ -283,8 +295,8 @@ def _decode_attn(attn: MultiHeadAttention, p, kv, x, t):
     q, k, v = _project_qkv(attn, p, xc)
     if attn.use_rope:
         pos = jnp.full((1,), t)
-        q = apply_rope(q, pos, scale=attn.rope_scale)
-        k = apply_rope(k, pos, scale=attn.rope_scale)
+        q = attn.rope(q, pos)
+        k = attn.rope(k, pos)
     kv = _cache_write(kv, k, v, t)
     scale = (attn.head_dim or q.shape[-1]) ** -0.5
     b = q.shape[0]
@@ -361,13 +373,14 @@ def _prefill_block(block: TransformerBlock, p, s, kv, x, positions):
         xc = h_.astype(dt)
         q, k, v = _project_qkv(attn, p["attn"], xc)
         if attn.use_rope:
-            q = apply_rope(q, positions, scale=attn.rope_scale)
-            k = apply_rope(k, positions, scale=attn.rope_scale)
+            q = attn.rope(q, positions)
+            k = attn.rope(k, positions)
         kv = _cache_write(kv, k, v, 0)
         ke, ve = attn._expand_kv(k, 2), attn._expand_kv(v, 2)
         impl = "flash" if backend_is_tpu() else "xla"
         out = _attention_compute(q, ke, ve, causal=True, impl=impl,
-                                 window=attn.attn_window)
+                                 window=attn.attn_window,
+                                 block_len=attn.block_len)
         y = jnp.einsum("bshe,hed->bsd", out.astype(dt), p["attn"]["wo"]
                        .astype(dt))
     x = x + y.astype(x.dtype)
@@ -391,11 +404,13 @@ def _merge_attention(o_a, lse_a, o_b, lse_b):
 
 
 def _attn_lse(q, k, v, *, causal: bool, scale: float, layout: str,
-              window=None):
+              window=None, block_len=None):
     """Attention WITH its log-sum-exp: the real flash kernel on TPU, a
     plain XLA softmax path elsewhere (the chunked-prefill building block;
     interpreter-mode Pallas is too slow for long-prefix CPU tests).
-    Layouts as in ``ops.flash_attention`` ('bshd'/'bhsd')."""
+    Layouts as in ``ops.flash_attention`` ('bshd'/'bhsd').
+    ``block_len`` makes a causal pass block-causal (the chunk starts on
+    a block boundary, so chunk-local positions give the same blocks)."""
     from distkeras_tpu.ops.flash_attention import _flash_forward
     if backend_is_tpu():
         note_path("flash_attention", "kernel")
@@ -405,7 +420,8 @@ def _attn_lse(q, k, v, *, causal: bool, scale: float, layout: str,
                       and window is None) else 512
         bk = 1024 if window is None else 512
         return _flash_forward(q, k, v, scale, causal, bq, bk, False,
-                              layout == "bhsd", window)
+                              layout == "bhsd", window,
+                              block_len=block_len if causal else None)
     note_path("flash_attention", "xla_reference")
     if layout == "bshd":
         qh = q.transpose(0, 2, 1, 3)
@@ -418,6 +434,8 @@ def _attn_lse(q, k, v, *, causal: bool, scale: float, layout: str,
     if causal:
         sq, sk = s.shape[-2], s.shape[-1]
         qpos = jnp.arange(sq)[:, None] + (sk - sq)
+        if block_len is not None:
+            qpos = (qpos // block_len) * block_len + (block_len - 1)
         s = jnp.where(qpos >= jnp.arange(sk)[None, :], s, NEG_INF)
         if window is not None:
             s = jnp.where(jnp.arange(sk)[None, :] > qpos - window, s,
@@ -471,7 +489,7 @@ def _cache_prefix(kv, upto: int, dt, lo: int = 0):
 
 
 def _prefill_block_chunked(block: TransformerBlock, p, s, kv, x, positions,
-                           t0: int):
+                           t0: int, routing=None, kv_only: bool = False):
     """One chunk of one TransformerBlock (round 5, VERDICT r4 #5): the
     chunk's queries attend to (a) the ALREADY-WRITTEN cache prefix
     [0, t0) — one non-causal flash pass, with the GQA group folded into
@@ -481,7 +499,13 @@ def _prefill_block_chunked(block: TransformerBlock, p, s, kv, x, positions,
     pass plus a masked PREFIX BAND of the last ``window - 1`` positions
     (``_banded_prefix_attn``). Activation memory is O(chunk), not O(P):
     the [B, P, H, D] per-layer q/k/v of the one-pass prefill never
-    exist."""
+    exist.
+
+    ``kv_only``: the block stops once its keys and values are written
+    (the deepest block of a chunk whose output nothing reads).
+    ``routing`` (a list): expert layers take the drop-free dispatched
+    path of the decode steps and append their routing to it
+    (``_apply_mlp_decode``); None leaves the layer its own ``apply``."""
     attn = block.attn
     dt = jnp.dtype(attn.dtype)
     with jax.named_scope("attn"):
@@ -489,9 +513,11 @@ def _prefill_block_chunked(block: TransformerBlock, p, s, kv, x, positions,
         xc = h_.astype(dt)
         q, k, v = _project_qkv(attn, p["attn"], xc)
         if attn.use_rope:
-            q = apply_rope(q, positions, scale=attn.rope_scale)
-            k = apply_rope(k, positions, scale=attn.rope_scale)
+            q = attn.rope(q, positions)
+            k = attn.rope(k, positions)
         kv = _cache_write(kv, k, v, t0)
+        if kv_only:
+            return x, kv
         b, q_len, nh, dh = q.shape
         hkv = attn.kv_heads
         g = nh // hkv
@@ -500,8 +526,13 @@ def _prefill_block_chunked(block: TransformerBlock, p, s, kv, x, positions,
         # (b) causal within the chunk (small: kv expansion is chunk-sized);
         # sliding-window models window the diagonal pass too
         ke, ve = attn._expand_kv(k, 2), attn._expand_kv(v, 2)
+        if attn.block_len is not None and t0 % attn.block_len:
+            raise ValueError(
+                f"a block-causal prefill chunk starts on a block boundary "
+                f"(t0 {t0}, block_len {attn.block_len})")
         o_diag, lse_diag = _attn_lse(q, ke, ve, causal=True, scale=scale,
-                                     layout="bshd", window=window)
+                                     layout="bshd", window=window,
+                                     block_len=attn.block_len)
         # prefix reach: everything before the chunk for full attention; only
         # the last window-1 positions for SWA (older keys are out of every
         # chunk query's reach)
@@ -549,12 +580,13 @@ def _prefill_block_chunked(block: TransformerBlock, p, s, kv, x, positions,
     x = x + y.astype(x.dtype)
     with jax.named_scope("mlp"):
         h_, _ = block.norm2.apply(p["norm2"], s["norm2"], x)
-        m, _ = block.mlp.apply(p["mlp"], s["mlp"], h_, training=False)
+        m = _apply_mlp_decode(block.mlp, p["mlp"], s["mlp"], h_,
+                              routing is not None, routing)
     return x + m, kv
 
 
 def prefill_chunk_step(module: Sequential, params, state, cache, chunk,
-                       t0: int, *, final: bool):
+                       t0: int, *, final: bool, routing=None):
     """ONE ``[B, q_len]`` chunk through the whole stack — the resumable
     unit of :func:`prefill_chunked`, factored out (this PR) so the
     serving engine can interleave prompt chunks between decode
@@ -563,8 +595,12 @@ def prefill_chunk_step(module: Sequential, params, state, cache, chunk,
     per-layer chunk pass branches on it in Python); positions
     ``[0, t0)`` of ``cache`` must already be written. Returns
     ``(last_logits [B, V] if final else None, cache)`` — non-final
-    chunks stop after the deepest attention block: the final norm +
-    vocab head only matter for the last chunk's logits (review r5)."""
+    chunks stop at the deepest attention block's K/V write: its
+    readout and MLP, the final norm and the vocab head only matter for
+    the last chunk's logits (review r5). ``routing`` (a list the
+    caller owns; the block-diffusion engine's prefill) makes the expert
+    layers drop-free and collects ``(num_experts, (topi, full))`` of
+    each one that ran, for :func:`routing_counts`."""
     new_cache = list(cache)
     last_block = max((i for i, l in enumerate(module.layers)
                       if _decode_block_of(l) is not None), default=-1)
@@ -579,7 +615,8 @@ def prefill_chunk_step(module: Sequential, params, state, cache, chunk,
         block = _decode_block_of(layer)
         if block is not None:
             x, new_cache[i] = _prefill_block_chunked(
-                block, p, s, new_cache[i], x, positions, t0)
+                block, p, s, new_cache[i], x, positions, t0, routing,
+                kv_only=not final and i == last_block)
         elif isinstance(layer, PositionalEmbedding):
             with jax.named_scope("embed"):
                 x = x + p["embeddings"][t0:t0 + q_len][None] \
@@ -782,7 +819,8 @@ def _window_positions(t, w_len: int, tree):
     return t[:, None] + tree["depth"]                    # [S, W]
 
 
-def _window_valid_mask(t, w_len: int, L: int, tree, window):
+def _window_valid_mask(t, w_len: int, L: int, tree, window,
+                       full_window: bool = False):
     """[S, W, L] attention validity for the windowed readout.
 
     Chain (``tree`` None): window query j admits cache positions
@@ -795,8 +833,15 @@ def _window_valid_mask(t, w_len: int, L: int, tree, window):
     stay invisible exactly like the chain's future positions. Sentinel
     slots (t out of range) admit garbage either way; their logits are
     discarded by contract. ``window`` adds the SWA band around each
-    query's own position (``t + depth``)."""
+    query's own position (``t + depth``).
+
+    ``full_window`` (block diffusion: the window is one block of a
+    block-causal model): every window query admits the committed
+    prefix and ALL ``W`` window positions, ``<= t + W - 1``."""
     ar = jnp.arange(L)[None, None, :]                    # [1, 1, L]
+    if full_window:
+        last = (t + (w_len - 1))[:, None, None]          # [S, 1, 1]
+        return jnp.broadcast_to(ar <= last, (t.shape[0], w_len, L))
     if tree is None:
         pos = t[:, None] + jnp.arange(w_len)             # [S, W]
         valid = ar <= pos[:, :, None]
@@ -829,7 +874,7 @@ def _attn_out(p, out, dt):
 
 
 def _slot_attn_readout(attn: MultiHeadAttention, p, q, kv, t, dt,
-                       tree=None):
+                       tree=None, full_window: bool = False):
     """Masked per-slot attention of the projected decode queries against
     a logically contiguous ``[S, H, L, D]`` kv view — a slab pool or a
     page gather in logical-position order — plus the output projection.
@@ -856,7 +901,8 @@ def _slot_attn_readout(attn: MultiHeadAttention, p, q, kv, t, dt,
     qg = (q.astype(jnp.float32) * scale).reshape(
         b, w_len, hkv, g, dh)                        # [S, W, Hkv, G, D]
     s = _decode_scores(qg, kv)                       # [S, Hkv, G, W, L]
-    valid = _window_valid_mask(t, w_len, L, tree, attn.attn_window)
+    valid = _window_valid_mask(t, w_len, L, tree, attn.attn_window,
+                               full_window)
     s = jnp.where(valid[:, None, None, :, :], s, NEG_INF)
     w = jax.nn.softmax(s, axis=-1)
     out = _decode_mix(w, kv).astype(dt)              # [S, W, Hkv, G, D]
@@ -872,8 +918,8 @@ def _decode_attn_slots(attn: MultiHeadAttention, p, kv, x, t):
     xc = x.astype(dt)
     q, k, v = _project_qkv(attn, p, xc)
     if attn.use_rope:
-        q = apply_rope(q, t[:, None], scale=attn.rope_scale)
-        k = apply_rope(k, t[:, None], scale=attn.rope_scale)
+        q = attn.rope(q, t[:, None])
+        k = attn.rope(k, t[:, None])
     kv = _cache_write_slots(kv, k, v, t)
     y = _slot_attn_readout(attn, p, q, kv, t, dt)
     return y.astype(x.dtype), kv
@@ -1088,7 +1134,8 @@ def _use_paged_kernel(kv, page_len: int, paged_kernel) -> bool:
 
 
 def _paged_attn_readout(attn: MultiHeadAttention, p, q, kv, t, table,
-                        page_len: int, dt, paged_kernel, tree=None):
+                        page_len: int, dt, paged_kernel, tree=None,
+                        full_window: bool = False):
     """Readout for the paged decode/verify paths: the Pallas
     paged-attention kernel (K/V gathered HBM -> VMEM through the page
     table inside the kernel — no materialized [S, H, L, D] view) when
@@ -1101,7 +1148,7 @@ def _paged_attn_readout(attn: MultiHeadAttention, p, q, kv, t, table,
         note_path("paged_attention", "gather_reference")
         return _slot_attn_readout(attn, p, q,
                                   _gather_pages(kv, table), t, dt,
-                                  tree=tree)
+                                  tree=tree, full_window=full_window)
     from distkeras_tpu.ops.paged_attention import paged_decode_attention
     note_path("paged_attention", "kernel")
     b, w_len, nh, dh = q.shape
@@ -1116,6 +1163,7 @@ def _paged_attn_readout(attn: MultiHeadAttention, p, q, kv, t, table,
         qg, kv["k"], kv["v"], t, table, scale=scale,
         window=attn.attn_window,
         anc=None if tree is None else tree["anc"],
+        full_window=full_window,
         interpret=None if backend_is_tpu() else True, **sc)
     out = o.reshape(b, w_len, nh, dh).astype(dt)
     return _attn_out(p, out, dt)
@@ -1130,8 +1178,8 @@ def _decode_attn_slots_paged(attn: MultiHeadAttention, p, kv, x, t,
     xc = x.astype(dt)
     q, k, v = _project_qkv(attn, p, xc)
     if attn.use_rope:
-        q = apply_rope(q, t[:, None], scale=attn.rope_scale)
-        k = apply_rope(k, t[:, None], scale=attn.rope_scale)
+        q = attn.rope(q, t[:, None])
+        k = attn.rope(k, t[:, None])
     kv = _cache_write_pages(kv, k, v, t, table, page_len)
     y = _paged_attn_readout(attn, p, q, kv, t, table, page_len, dt,
                             paged_kernel)
@@ -1216,7 +1264,7 @@ def _decode_block_slots_window(block: TransformerBlock, p, s, kv, x, t,
                                table=None, page_len: int = 0,
                                moe_dispatched=True, routing=None,
                                paged_kernel=None, tree=None,
-                               kv_out=None):
+                               kv_out=None, kv_only: bool = False):
     """One TransformerBlock over a [S, W, d] window at per-slot
     positions ``t .. t+W-1``: project the window's q/k/v, write ALL W
     positions into the cache (slab one-hot writes, or page-table
@@ -1229,7 +1277,9 @@ def _decode_block_slots_window(block: TransformerBlock, p, s, kv, x, t,
     columns ``t + j``) and attend through the ancestor mask. The
     per-layer roped k/v land in ``kv_out`` (a list the caller owns) so
     the post-acceptance ``commit_tree_path`` can re-write the accepted
-    path at its contiguous final positions."""
+    path at its contiguous final positions. ``kv_only``: the block
+    stops once the window's K/V are written (the deepest block of a
+    pass that needs no logits)."""
     attn = block.attn
     with jax.named_scope("attn"):
         h, _ = block.norm1.apply(p["norm1"], s["norm1"], x)
@@ -1239,8 +1289,8 @@ def _decode_block_slots_window(block: TransformerBlock, p, s, kv, x, t,
         w_len = q.shape[1]
         if attn.use_rope:
             pos = _window_positions(t, w_len, tree)          # [S, W]
-            q = apply_rope(q, pos, scale=attn.rope_scale)
-            k = apply_rope(k, pos, scale=attn.rope_scale)
+            q = attn.rope(q, pos)
+            k = attn.rope(k, pos)
         if kv_out is not None:
             kv_out.append((k, v))
         for j in range(w_len):
@@ -1250,11 +1300,22 @@ def _decode_block_slots_window(block: TransformerBlock, p, s, kv, x, t,
             else:
                 kv = _cache_write_pages(kv, k[:, j:j + 1], v[:, j:j + 1],
                                         t + j, table, page_len)
+        if kv_only:
+            return x, kv
+        # a block-causal model's window is one whole block (the
+        # engine's block-diffusion pass): bidirectional inside it
+        full = attn.block_len is not None
+        if full and (tree is not None or w_len != attn.block_len):
+            raise ValueError(
+                f"a block-causal model's window is one block of "
+                f"{attn.block_len} positions with no tree (got {w_len})")
         if table is None:
-            y = _slot_attn_readout(attn, p["attn"], q, kv, t, dt, tree=tree)
+            y = _slot_attn_readout(attn, p["attn"], q, kv, t, dt, tree=tree,
+                                   full_window=full)
         else:
             y = _paged_attn_readout(attn, p["attn"], q, kv, t, table,
-                                    page_len, dt, paged_kernel, tree=tree)
+                                    page_len, dt, paged_kernel, tree=tree,
+                                    full_window=full)
     x = x + y.astype(x.dtype)
     with jax.named_scope("mlp"):
         h, _ = block.norm2.apply(p["norm2"], s["norm2"], x)
@@ -1358,6 +1419,90 @@ def verify_step_slots_paged(module: Sequential, params, state, cache,
     return _verify_window(module, params, state, cache, toks, t,
                           table, page_len, moe_dispatched, moe_stats,
                           paged_kernel, tree=tree)
+
+
+# --- block diffusion (serving engine, block-diffusion PR) -------------------
+#
+# A block-causal model (``MultiHeadAttention(block_len=B)``) generates
+# by denoising one block of B positions at a time. A PASS runs the
+# block's B tokens (mask tokens where nothing is fixed yet) through the
+# stack at positions ``t .. t+B-1`` against the cached blocks: the same
+# [S, W] window machinery as the speculative verify, with the window
+# attending bidirectionally to itself (``full_window``). Every pass
+# writes the block's K/V at its positions; only the pass that runs once
+# no mask is left (the commit pass) leaves values later blocks may
+# read, and it needs no logits.
+
+
+def routing_counts(routing):
+    """``int32[2]`` of one program's expert layers, from the routing
+    they collected (``(num_experts, (topi, full))`` each): the rows
+    they routed, and the experts that owned at least one row, both
+    summed over the layers. What the program ran, said by the program:
+    a layer that did not run collected nothing."""
+    rows = sum(topi.size for _e, (topi, _full) in routing)
+    touched = jnp.zeros((), jnp.int32)
+    for e, (topi, _full) in routing:
+        owned = jnp.zeros((e,), jnp.int32).at[topi.reshape(-1)].add(1)
+        touched = touched + jnp.sum(owned > 0, dtype=jnp.int32)
+    return jnp.stack([jnp.asarray(rows, jnp.int32), touched])
+
+
+def block_pass_slots_paged(module: Sequential, params, state, cache,
+                           toks, t, table, page_len: int, *,
+                           head: bool = True,
+                           moe_dispatched: bool = True,
+                           paged_kernel=None):
+    """One block-diffusion pass over the paged pool: toks [S, B] int
+    (the block as it stands), t [S] int (the block's first position;
+    the engine's sentinel for an idle slot writes nothing and yields
+    garbage), table [S, P] page tables.
+
+    With ``head`` returns ``(best [S, B] int32, conf [S, B] f32, cache,
+    routed)``: at every position the most probable token and its
+    log-probability (best logit less the log-sum-exp over the
+    vocabulary, float32) — the engine fixes the most confident masked
+    positions from them. Without ``head`` (a pass in which every live
+    slot only commits) the stack stops at the deepest attention
+    block's K/V write and the return is ``(cache, routed)``.
+    ``routed`` is :func:`routing_counts` of the expert layers that ran
+    (zeros for a model without dispatched experts)."""
+    x = toks
+    w_len = toks.shape[1]
+    new_cache = list(cache)
+    routing = []
+    last_block = max((i for i, l in enumerate(module.layers)
+                      if _decode_block_of(l) is not None), default=-1)
+    for i, layer in enumerate(module.layers):
+        if not head and i > last_block:
+            break
+        p, s, kv = params[i], state[i], cache[i]
+        block = _decode_block_of(layer)
+        if block is not None:
+            # without the head nothing reads what the last block makes
+            # past its K/V, which are written from the attention's input
+            x, new_cache[i] = _decode_block_slots_window(
+                block, p, s, kv, x, t, table, page_len, moe_dispatched,
+                routing, paged_kernel,
+                kv_only=not head and i == last_block)
+        elif isinstance(layer, PositionalEmbedding):
+            with jax.named_scope("embed"):
+                pos = _window_positions(t, w_len, None)
+                x = x + p["embeddings"][pos].astype(x.dtype)
+        elif isinstance(layer, Dropout):
+            pass                                         # eval: identity
+        else:
+            with scoped(module.scope_of(i)):
+                x, _ = layer.apply(p, s, x, training=False)
+    routed = routing_counts(routing)
+    if not head:
+        return new_cache, routed
+    with jax.named_scope("sample"):
+        logits = x.astype(jnp.float32)                   # [S, B, V]
+        best = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+        conf = jnp.max(logits, axis=-1) \
+            - jax.scipy.special.logsumexp(logits, axis=-1)
+    return best, conf, new_cache, routed
 
 
 def tree_walk(logits, toks, parents, *, temperature=None, top_k=None,
@@ -1733,12 +1878,13 @@ def _project_qkv(attn: MultiHeadAttention, p, xc):
     quantized (``ServingEngine(weight_quant=)`` — ``ops.quant_matmul``
     qdicts; the kernel unpacks int8/int4 bytes in-register, so the
     float weights never touch HBM), the three separate einsums
-    otherwise."""
+    otherwise. Queries and keys come back with the layer's per-head
+    norm applied (``qk_norm``), before RoPE."""
     if "wqkv" in p:
         qkv = jnp.einsum("bsd,dhe->bshe", xc, p["wqkv"].astype(xc.dtype))
         h, hkv = attn.num_heads, attn.kv_heads
-        return (qkv[:, :, :h], qkv[:, :, h:h + hkv],
-                qkv[:, :, h + hkv:])
+        q, k = attn.normed_qk(p, qkv[:, :, :h], qkv[:, :, h:h + hkv])
+        return q, k, qkv[:, :, h + hkv:]
     if isinstance(p["wq"], dict):
         from distkeras_tpu.ops.quant_matmul import quant_matmul
         b, s_len, d = xc.shape
@@ -1748,13 +1894,14 @@ def _project_qkv(attn: MultiHeadAttention, p, xc):
             y = quant_matmul(x2, wdict).astype(xc.dtype)
             return y.reshape(b, s_len, heads, -1)
 
-        return (proj(p["wq"], attn.num_heads),
-                proj(p["wk"], attn.kv_heads),
-                proj(p["wv"], attn.kv_heads))
+        q, k = attn.normed_qk(p, proj(p["wq"], attn.num_heads),
+                              proj(p["wk"], attn.kv_heads))
+        return q, k, proj(p["wv"], attn.kv_heads)
     dt = xc.dtype
     q = jnp.einsum("bsd,dhe->bshe", xc, p["wq"].astype(dt))
     k = jnp.einsum("bsd,dhe->bshe", xc, p["wk"].astype(dt))
     v = jnp.einsum("bsd,dhe->bshe", xc, p["wv"].astype(dt))
+    q, k = attn.normed_qk(p, q, k)
     return q, k, v
 
 
@@ -1838,6 +1985,11 @@ def generate(model: Model, prompts, max_new_tokens: int,
     if not isinstance(module, Sequential):
         raise TypeError("generate() expects a Sequential LM "
                         f"(got {type(module).__name__})")
+    if block_len_of(module) is not None:
+        raise ValueError(
+            "generate() decodes one token a step; a block-causal "
+            "(block-diffusion) model is decoded by ServingEngine, a "
+            "block of tokens at a time")
     prompts = jnp.asarray(prompts)
     if prompts.ndim != 2:
         raise ValueError(f"prompts must be [B, P], got {prompts.shape}")

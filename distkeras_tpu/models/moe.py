@@ -43,6 +43,17 @@ for the Spark design) — this is a TPU-native addition. Design:
       (``compat.backend_is_tpu`` — the repo's one backend convention);
       tests force the interpreter via ``moe_kernels.force_interpret``.
 
+    - ``dispatch="grouped"`` (serving of many small experts: top-8 of
+      128 at width 768): DROP-FREE without a capacity. The ``n * k``
+      routed rows are laid out sorted by expert, each expert's group
+      padded to whole tiles (at most ``E`` tiles of padding, skipped),
+      and one program per tile multiplies it with that tile's expert
+      (``ops/moe_kernels.py::grouped_experts``; off a TPU the same
+      layout through plain XLA). Every routed row is computed once,
+      only experts that own a row are read, and a token's output never
+      depends on who shares its batch. Bias-free experts only, gated
+      or not; inference only (no custom gradient).
+
   * Expert parallelism: under GSPMD (``SPMDTrainer``) the stacked expert
     einsums partition on the expert axis automatically from the weight
     shardings. Under ``shard_map`` (``expert_axis_name``) tokens are
@@ -113,8 +124,13 @@ class MoE(Layer):
                  aux_loss_weight: float = 0.0,
                  dispatch: str = "dense",
                  capacity_factor: float = 1.25,
-                 expert_unroll: bool = False):
+                 expert_unroll: bool = False,
+                 gated: bool = False, use_bias: bool = True):
         self.num_experts = int(num_experts)
+        #: gated experts (SwiGLU with ``activation="silu"``):
+        #: ``w2(act(x w1) * (x w3))``; ``use_bias=False`` drops b1/b2
+        self.gated = bool(gated)
+        self.use_bias = bool(use_bias)
         self.hidden_dim = int(hidden_dim)
         self.top_k = int(top_k)
         self.activation = activation
@@ -127,10 +143,14 @@ class MoE(Layer):
         # pushing the router away from expert collapse. Published via the
         # AUX_LOSS_KEY state channel (parallel.worker picks it up).
         self.aux_loss_weight = float(aux_loss_weight)
-        if dispatch not in ("dense", "tokens", "fused"):
+        if dispatch not in ("dense", "tokens", "fused", "grouped"):
             raise ValueError(
-                "dispatch must be 'dense', 'tokens' or 'fused', "
-                f"got {dispatch!r}")
+                "dispatch must be 'dense', 'tokens', 'fused' or "
+                f"'grouped', got {dispatch!r}")
+        if dispatch == "grouped" and (use_bias or expert_axis_name):
+            raise ValueError(
+                "dispatch='grouped' runs bias-free experts on one chip "
+                "(use_bias=False, no expert_axis_name)")
         self.dispatch = dispatch
         # expert capacity = ceil(top_k * tokens / E) * capacity_factor:
         # at 1.0 a perfectly balanced router drops nothing; the default
@@ -161,11 +181,15 @@ class MoE(Layer):
                         for k in jax.random.split(k1, e)])
         w2 = jnp.stack([init_weights(self.kernel_init, k, (hid, d))
                         for k in jax.random.split(k2, e)])
-        params = {
-            "gate": init_weights(self.kernel_init, kg, (d, e)),
-            "w1": w1, "b1": jnp.zeros((e, hid)),
-            "w2": w2, "b2": jnp.zeros((e, d)),
-        }
+        params = {"gate": init_weights(self.kernel_init, kg, (d, e)),
+                  "w1": w1, "w2": w2}
+        if self.gated:
+            params["w3"] = jnp.stack(
+                [init_weights(self.kernel_init, k, (d, hid))
+                 for k in jax.random.split(jax.random.fold_in(k1, 1), e)])
+        if self.use_bias:
+            params["b1"] = jnp.zeros((e, hid))
+            params["b2"] = jnp.zeros((e, d))
         state = {}
         if self.aux_loss_weight:
             state[AUX_LOSS_KEY] = jnp.zeros((), jnp.float32)
@@ -249,10 +273,21 @@ class MoE(Layer):
         dt = jnp.dtype(self.dtype)
         act = get_activation(self.activation)
         w1 = params["w1"].astype(dt)
-        b1 = params["b1"].astype(dt)
         w2 = params["w2"].astype(dt)
-        b2 = params["b2"].astype(dt)
         e_here = xe.shape[0]
+        if self.gated or not self.use_bias:
+            # gated and bias-free experts: the plain stacked products
+            h = act(jnp.einsum("ecd,edf->ecf", xe, w1)
+                    + (params["b1"].astype(dt)[:, None, :]
+                       if self.use_bias else 0))
+            if self.gated:
+                h = h * jnp.einsum("ecd,edf->ecf", xe,
+                                   params["w3"].astype(dt))
+            return jnp.einsum("ecf,efd->ecd", h, w2) \
+                + (params["b2"].astype(dt)[:, None, :]
+                   if self.use_bias else 0)
+        b1 = params["b1"].astype(dt)
+        b2 = params["b2"].astype(dt)
         unroll = self.expert_unroll
         if unroll and self._expert_axis_sharded(params["w1"]):
             import warnings
@@ -323,6 +358,9 @@ class MoE(Layer):
         e, k = self.num_experts, self.top_k
         c = self._capacity(n) if capacity is None else int(capacity)
         full, topi, gates, mask = self._route(x, params["gate"])
+        # the fused kernels are written for biased single-activation
+        # experts; gated or bias-free ones take the XLA tokens floor
+        fused = fused and self.use_bias and not self.gated
 
         dest, _st, sg, keep = _dispatch_plan(
             topi.reshape(n, k), gates.reshape(n, k), e, c)
@@ -406,6 +444,40 @@ class MoE(Layer):
             return out.reshape(b, s, d), full, mask, topi
         return out.reshape(b, s, d), full, mask
 
+    def _apply_grouped(self, params, x):
+        """``dispatch="grouped"``: route, lay the ``n * k`` routed rows
+        out by expert in whole tiles, multiply each tile with its
+        expert, and sum each token's k rows under its gates (module
+        doc). Returns ``(out [B, S, d], full, mask, topi)`` like
+        ``_apply_dispatched(return_routing=True)``."""
+        from distkeras_tpu.ops import moe_kernels
+        dt = jnp.dtype(self.dtype)
+        b, s, d = x.shape
+        n, e, k = b * s, self.num_experts, self.top_k
+        full, topi, gates, mask = self._route(x, params["gate"])
+        rows = moe_kernels.grouped_block_rows(n * k, e)
+        dest, tile_expert, used, _counts = moe_kernels.grouped_layout(
+            topi.reshape(n * k).astype(jnp.int32), e, rows)
+        m = moe_kernels.grouped_tiles(n * k, e, rows) * rows
+        # row -> token (padding rows read token 0: finite, never summed)
+        row_token = jnp.zeros((m,), jnp.int32).at[dest].set(
+            jnp.arange(n * k, dtype=jnp.int32) // k, unique_indices=True)
+        x_rows = x.reshape(n, d).astype(dt)[row_token]
+        kw = dict(block_rows=rows, activation=self.activation)
+        w = [params[name].astype(dt) for name in
+             (("w1", "w2", "w3") if self.gated else ("w1", "w2"))]
+        if moe_kernels.fused_supported():
+            note_path("moe", "grouped_kernel")
+            y_rows = moe_kernels.grouped_experts(
+                x_rows, tile_expert, used, *w, **kw)
+        else:
+            note_path("moe", "grouped_xla_reference")
+            y_rows = moe_kernels.grouped_experts_reference(
+                x_rows, tile_expert, used, *w, **kw)
+        y = y_rows[dest].reshape(n, k, d).astype(jnp.float32)
+        out = jnp.sum(y * gates.reshape(n, k, 1), axis=1)
+        return out.reshape(b, s, d).astype(dt), full, mask, topi
+
     def decode_apply(self, params, x, *, return_routing=False):
         """Decode-specialized dispatched MoE (the serving engine's
         per-step path; MoE-serving PR).
@@ -441,15 +513,28 @@ class MoE(Layer):
         — for expert-load/entropy telemetry."""
         from distkeras_tpu.ops import moe_kernels
         b, s, _d = x.shape
-        out, full, _mask, topi = self._apply_dispatched(
-            params, x, fused=moe_kernels.fused_supported(),
-            capacity=b * s, return_routing=True)
+        if self.dispatch == "grouped":
+            # drop-free already, and with no capacity to size
+            out, full, _mask, topi = self._apply_grouped(params, x)
+        else:
+            out, full, _mask, topi = self._apply_dispatched(
+                params, x, fused=moe_kernels.fused_supported(),
+                capacity=b * s, return_routing=True)
         if return_routing:
             return out.astype(x.dtype), (topi, full)
         return out.astype(x.dtype)
 
     def apply(self, params, state, x, *, training=False, rng=None):
         dt = jnp.dtype(self.dtype)
+
+        if self.dispatch == "grouped":
+            out, full, mask, _topi = self._apply_grouped(params, x)
+            new_state = state
+            if self.aux_loss_weight and training:
+                new_state = dict(state)
+                new_state[AUX_LOSS_KEY] = (self.aux_loss_weight *
+                                           self._balance_loss(full, mask))
+            return out.astype(x.dtype), new_state
 
         if self.dispatch in ("tokens", "fused"):
             use_fused = False
@@ -476,9 +561,15 @@ class MoE(Layer):
         # local experts: [El, ...] slice when sharded over the expert axis
         h = jnp.einsum("bsd,edf->besf", xc, params["w1"].astype(dt))
         act = get_activation(self.activation)
-        h = act(h + params["b1"].astype(dt)[None, :, None, :])
+        if self.use_bias:
+            h = h + params["b1"].astype(dt)[None, :, None, :]
+        h = act(h)
+        if self.gated:
+            h = h * jnp.einsum("bsd,edf->besf", xc,
+                               params["w3"].astype(dt))
         y = jnp.einsum("besf,efd->besd", h, params["w2"].astype(dt))
-        y = y + params["b2"].astype(dt)[None, :, None, :]
+        if self.use_bias:
+            y = y + params["b2"].astype(dt)[None, :, None, :]
 
         if self.expert_axis_name is None:
             out = jnp.einsum("bse,besd->bsd", probs.astype(dt), y)
@@ -509,7 +600,8 @@ class MoE(Layer):
                 "aux_loss_weight": self.aux_loss_weight,
                 "dispatch": self.dispatch,
                 "capacity_factor": self.capacity_factor,
-                "expert_unroll": self.expert_unroll}
+                "expert_unroll": self.expert_unroll,
+                "gated": self.gated, "use_bias": self.use_bias}
 
 
 def moe_all_to_all(moe: MoE, params, x, *, axis_name: str):

@@ -165,7 +165,14 @@ def transformer_lm(vocab_size: int, d_model: int = 512, num_heads: int = 8,
                    moe_dispatch: str = "dense",
                    moe_capacity_factor: float = 1.25,
                    moe_expert_unroll: bool = False,
-                   remat: Optional[str] = None) -> Sequential:
+                   remat: Optional[str] = None,
+                   head_dim: Optional[int] = None, qk_norm: bool = False,
+                   rope_base: float = 10000.0,
+                   block_len: Optional[int] = None,
+                   mlp_dim: Optional[int] = None,
+                   mlp_activation: str = "gelu", mlp_gated: bool = False,
+                   mlp_bias: bool = True,
+                   moe_top_k: int = 2) -> Sequential:
     """Decoder-only causal transformer LM — the long-context flagship.
 
     Absent from the reference (no attention models; SURVEY §5.7); this is
@@ -187,6 +194,20 @@ def transformer_lm(vocab_size: int, d_model: int = 512, num_heads: int = 8,
     checkpoint policy ("nothing" | "dots" | "dots_no_batch") — the
     explicit activation-memory policy for deep/long-context training
     (see ``Remat``'s docstring for the trade-offs).
+
+    ``head_dim`` states a head size other than ``d_model // num_heads``;
+    ``qk_norm`` puts an RMSNorm over every head's query and key before
+    RoPE; ``rope_base`` is RoPE's theta. ``block_len=B`` makes attention
+    BLOCK-causal (``j // B <= i // B``): a block-diffusion language
+    model, which ``ServingEngine`` decodes a block of ``B`` tokens at a
+    time by denoising (docs/serving.md §Block diffusion).
+    ``mlp_dim`` states the MLP's (or each expert's) hidden width instead
+    of ``mlp_ratio * d_model``; ``mlp_gated`` makes it the gated form
+    ``w2(act(x w1) * (x w3))`` (SwiGLU with ``mlp_activation="silu"``),
+    ``mlp_bias=False`` drops its biases. ``moe_top_k`` experts of
+    ``num_experts`` take each token (gates renormalised over the k);
+    ``moe_dispatch="grouped"`` is the drop-free serving dispatch for
+    many small experts (``models/moe.py``).
     """
     from distkeras_tpu.models.attention import (
         LayerNorm, PositionalEmbedding, RMSNorm, TransformerBlock)
@@ -203,18 +224,23 @@ def transformer_lm(vocab_size: int, d_model: int = 512, num_heads: int = 8,
         mlp_layer = None
         if moe_every and num_experts and (i + 1) % moe_every == 0:
             from distkeras_tpu.models.moe import MoE
-            mlp_layer = MoE(num_experts, mlp_ratio * d_model,
+            mlp_layer = MoE(num_experts, mlp_dim or mlp_ratio * d_model,
+                            top_k=moe_top_k, activation=mlp_activation,
                             dtype=dtype, expert_axis_name=moe_expert_axis,
                             aux_loss_weight=moe_aux_loss_weight,
                             dispatch=moe_dispatch,
                             capacity_factor=moe_capacity_factor,
-                            expert_unroll=moe_expert_unroll)
+                            expert_unroll=moe_expert_unroll,
+                            gated=mlp_gated, use_bias=mlp_bias)
         block = TransformerBlock(
-            num_heads, mlp_ratio=mlp_ratio, causal=True, use_rope=use_rope,
+            num_heads, mlp_ratio=mlp_ratio, head_dim=head_dim, causal=True,
+            use_rope=use_rope, activation=mlp_activation,
             norm=norm, dtype=dtype, attn_impl=attn_impl,
             seq_axis_name=seq_axis_name, mlp_layer=mlp_layer,
             num_kv_heads=num_kv_heads, rope_scale=rope_scale,
-            attn_window=attn_window)
+            attn_window=attn_window, qk_norm=qk_norm, rope_base=rope_base,
+            block_len=block_len, mlp_dim=mlp_dim, mlp_gated=mlp_gated,
+            mlp_bias=mlp_bias)
         if remat is not None:
             from distkeras_tpu.models.blocks import Remat
             block = Remat(block, policy=remat)

@@ -36,11 +36,20 @@ def causal_mask(q_len: int, k_len: int, q_offset: int = 0,
     return q_pos >= k_pos
 
 
+def block_causal_mask(q_len: int, k_len: int, block_len: int) -> jnp.ndarray:
+    """Boolean [q_len, k_len] mask of block-causal attention: query ``i``
+    sees key ``j`` iff ``j // block_len <= i // block_len``."""
+    q_blk = jnp.arange(q_len)[:, None] // block_len
+    k_blk = jnp.arange(k_len)[None, :] // block_len
+    return q_blk >= k_blk
+
+
 def dot_product_attention(q, k, v, *, causal: bool = False,
                           mask: Optional[jnp.ndarray] = None,
                           scale: Optional[float] = None,
                           window: Optional[int] = None,
-                          segment_ids: Optional[jnp.ndarray] = None
+                          segment_ids: Optional[jnp.ndarray] = None,
+                          block_len: Optional[int] = None
                           ) -> jnp.ndarray:
     """Reference (pure-XLA) attention. BSHD in, BSHD out.
 
@@ -54,6 +63,11 @@ def dot_product_attention(q, k, v, *, causal: bool = False,
     ``segment_ids``: [B, S] int — packed/variable-length sequences.
     Attention is restricted to positions with EQUAL ids (cross-segment
     scores are masked to NEG_INF), composing with ``causal``/``window``.
+
+    ``block_len=B`` (requires ``causal``) makes the mask BLOCK-causal:
+    query ``i`` sees key ``j`` iff ``j // B <= i // B`` — bidirectional
+    inside a block of B positions, causal over blocks (block-diffusion
+    language models).
     The convention: give padding its own id (e.g. -1); padded rows then
     attend only to each other and the loss masks them out
     (``losses.masked_sparse_categorical_crossentropy_from_logits``).
@@ -63,11 +77,16 @@ def dot_product_attention(q, k, v, *, causal: bool = False,
         scale = head_dim ** -0.5
     if window is not None and not causal:
         raise ValueError("window requires causal=True")
+    if block_len is not None and not causal:
+        raise ValueError("block_len requires causal=True")
     # [B, H, Sq, Sk] scores in f32
     s = jnp.einsum("bqhd,bkhd->bhqk", q, k,
                    preferred_element_type=jnp.float32) * scale
     if causal:
-        allowed = causal_mask(q.shape[1], k.shape[1])
+        if block_len is None:
+            allowed = causal_mask(q.shape[1], k.shape[1])
+        else:
+            allowed = block_causal_mask(q.shape[1], k.shape[1], block_len)
         if window is not None:
             q_pos = jnp.arange(q.shape[1])[:, None]
             k_pos = jnp.arange(k.shape[1])[None, :]

@@ -130,7 +130,8 @@ def _mask_dispatch(run, need, masked_fn, clear_fn):
 
 
 def _fwd_kernel(*refs, scale: float, causal: bool, k_len: int,
-                window=None, nkw=None, has_seg: bool = False):
+                window=None, nkw=None, has_seg: bool = False,
+                block_len=None):
     """One (batch*head, q_block, k_block) program.
 
     Block shapes: q_ref [1, bq, D]; k_ref/v_ref [1, bk, D];
@@ -143,6 +144,14 @@ def _fwd_kernel(*refs, scale: float, causal: bool, k_len: int,
     ``_k_base(qi) + ki`` (see ``_window_kblocks``). With ``has_seg``
     two extra [1, blk, 1] int32 refs carry packed segment ids; scores
     with unequal ids are masked (packed-sequence support).
+
+    ``block_len`` (static; ``_flash_forward`` holds it to a divisor of
+    both tile sizes) turns the causal mask into the BLOCK-causal one: a
+    query sees every key up to the end of its own block of
+    ``block_len`` positions. Tiles are whole blocks, so which tiles
+    run and which need a mask is the causal rule unchanged; only the
+    mask's comparison differs, and with ``block_len`` None the traced
+    kernel is the causal kernel as it was.
     """
     if has_seg:
         (q_ref, k_ref, v_ref, qseg_ref, kseg_ref,
@@ -185,7 +194,10 @@ def _fwd_kernel(*refs, scale: float, causal: bool, k_len: int,
                  lax.broadcasted_iota(jnp.int32, s.shape, 0))
         k_pos = (kb * block_k +
                  lax.broadcasted_iota(jnp.int32, s.shape, 1))
-        if causal:
+        if causal and block_len is not None:
+            q_end = (q_pos // block_len) * block_len + (block_len - 1)
+            s = jnp.where(q_end >= k_pos, s, NEG_INF)
+        elif causal:
             s = jnp.where(q_pos >= k_pos, s, NEG_INF)
         if window is not None:
             s = jnp.where(k_pos > q_pos - window, s, NEG_INF)
@@ -255,7 +267,7 @@ def _seg_blocks(segment_ids, sq_p: int, sk_p: int):
 
 def _flash_forward(q, k, v, scale: float, causal: bool, block_q: int,
                    block_k: int, interpret: bool, bhsd: bool = False,
-                   window=None, segment_ids=None):
+                   window=None, segment_ids=None, block_len=None):
     if bhsd:
         b, h, sq, d = q.shape
         sk = k.shape[2]
@@ -268,9 +280,20 @@ def _flash_forward(q, k, v, scale: float, causal: bool, block_q: int,
     # second-to-last dim % 8 == 0, so a raw min(block, seq) would fail to
     # lower for seq in (block, 8k) that isn't a multiple of 8 — the padder
     # below then pads seq up to the rounded block
-    round8 = lambda n: max(8, -(-n // 8) * 8)
+    # (block-causal: tiles are whole blocks too, so round to both)
+    unit = 8 if block_len is None else math.lcm(8, int(block_len))
+    round8 = lambda n: max(unit, -(-n // unit) * unit)
+    if block_len is not None:
+        block_q, block_k = round8(block_q), round8(block_k)
     block_q = min(block_q, round8(sq))
     block_k = min(block_k, round8(sk))
+    if block_len is not None and (not causal or window is not None
+                                  or block_q % block_len
+                                  or block_k % block_len or sq != sk):
+        raise ValueError(
+            f"block-causal flash attention needs causal=True, no window, "
+            f"q and k of one length and tiles of whole blocks (block_len "
+            f"{block_len}, tiles {block_q}/{block_k}, lengths {sq}/{sk})")
     qp = _pad_seq(q, block_q, seq_axis)
     kp = _pad_seq(k, block_k, seq_axis)
     vp = _pad_seq(v, block_k, seq_axis)
@@ -296,7 +319,8 @@ def _flash_forward(q, k, v, scale: float, causal: bool, block_q: int,
     kernel = functools.partial(_fwd_kernel, scale=scale, causal=causal,
                                k_len=sk, window=window,
                                nkw=nkw if remap else None,
-                               has_seg=segment_ids is not None)
+                               has_seg=segment_ids is not None,
+                               block_len=block_len)
 
     def k_map(bh, qi, ki):
         if remap:
@@ -796,7 +820,8 @@ def flash_attention(q, k, v, *, causal: bool = False,
                     bwd: Optional[str] = None,
                     layout: str = "bshd",
                     window: Optional[int] = None,
-                    segment_ids: Optional[jnp.ndarray] = None
+                    segment_ids: Optional[jnp.ndarray] = None,
+                    block_len: Optional[int] = None
                     ) -> jnp.ndarray:
     """Flash attention, BSHD in/out by default. Differentiable (custom
     VJP). ``layout="bhsd"`` takes/returns [B, H, S, D] — the kernel's
@@ -826,6 +851,12 @@ def flash_attention(q, k, v, *, causal: bool = False,
     restricted to equal ids) through every path: forward, both Pallas
     backward kernels, the XLA-scan backward, and the fused-XLA fallback.
     See ``ops.attention.dot_product_attention`` for the convention.
+
+    ``block_len``: block-causal attention (a query sees every key up
+    to the end of its own block of ``block_len`` positions; needs
+    ``causal``, no window, no segment ids). FORWARD ONLY — it is the
+    serving prefill of a block-diffusion model and bypasses the custom
+    VJP; the causal kernels and their backward are untouched by it.
     """
     if layout not in ("bshd", "bhsd"):
         raise ValueError(f"layout must be 'bshd' or 'bhsd', got {layout!r}")
@@ -859,16 +890,23 @@ def flash_attention(q, k, v, *, causal: bool = False,
         if not causal:
             raise ValueError("window requires causal=True")
 
+    if block_len is not None and (not causal or window is not None
+                                  or segment_ids is not None):
+        raise ValueError("block_len requires causal=True and neither a "
+                         "window nor segment ids")
+
     def _xla_fallback():
         note_path("flash_attention", "xla_reference")
         if bhsd:
             t = lambda x: x.transpose(0, 2, 1, 3)
             return t(dot_product_attention(t(q), t(k), t(v), causal=causal,
                                            scale=scale, window=window,
-                                           segment_ids=segment_ids))
+                                           segment_ids=segment_ids,
+                                           block_len=block_len))
         return dot_product_attention(q, k, v, causal=causal, scale=scale,
                                      window=window,
-                                     segment_ids=segment_ids)
+                                     segment_ids=segment_ids,
+                                     block_len=block_len)
 
     on_tpu = backend_is_tpu()
     if interpret is None:
@@ -884,6 +922,9 @@ def flash_attention(q, k, v, *, causal: bool = False,
         raise ValueError(f"bwd must be 'pallas' or 'xla', got {bwd!r}")
     note_path("flash_attention", "interpreted_kernel" if interpret
               else "kernel")
+    if block_len is not None:
+        return _flash_forward(q, k, v, scale, True, block_q, block_k,
+                              interpret, bhsd, block_len=block_len)[0]
     kernel = functools.partial(
         _flash, scale=scale, causal=causal, block_q=block_q,
         block_k=block_k, interpret=interpret, bwd=bwd, bhsd=bhsd,

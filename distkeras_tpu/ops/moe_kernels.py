@@ -533,3 +533,162 @@ def fused_moe_apply(xt, w1, b1, w2, b2, sg, dest, keep, *,
     return moe_fused_experts(activation, int(capacity), int(block_c),
                              bool(interpret), xt, w1, b1, w2, b2,
                              sg, dest, keep)
+
+
+# --- grouped experts: drop-free, rows sorted by expert (serving) -------------
+#
+# Many small experts with top-k of many (top-8 of 128 at width 768) make
+# the capacity construction above a poor fit for serving: a capacity that
+# never drops is every row (E x n rows of work for k x n of routing), and
+# dense routing computes all E experts for every token. The grouped form
+# lays the ``A = n * k`` routed rows out sorted by expert, each expert's
+# group padded up to whole tiles of ``block_rows`` rows, and runs one
+# program per tile against that tile's expert: every routed row is
+# computed exactly once, nothing is dropped whatever shares the batch,
+# only experts that own a row are read, and an expert whose rows span
+# several consecutive tiles is read once (the block index does not
+# change between them). The padding is stated: at most ``E`` tiles' worth
+# of rows beyond ``A`` (``grouped_tiles``), whose programs are skipped.
+
+
+def grouped_block_rows(assignments: int, num_experts: int) -> int:
+    """Rows per tile for ``assignments`` routed rows over
+    ``num_experts``: the power of two nearest above the mean group,
+    held to [16, 128] (16 is a bf16 sublane tile, 128 the MXU's)."""
+    mean = max(1, -(-int(assignments) // int(num_experts)))
+    return int(min(128, max(16, 1 << (mean - 1).bit_length())))
+
+
+def grouped_tiles(assignments: int, num_experts: int,
+                  block_rows: int) -> int:
+    """The static tile count: ``sum_e ceil(c_e / block_rows)`` never
+    passes ``A // block_rows + E`` whatever the routing."""
+    return int(assignments) // int(block_rows) + int(num_experts)
+
+
+def grouped_layout(flat_experts, num_experts: int, block_rows: int):
+    """Where each routed row goes. ``flat_experts`` [A] int32 is the
+    expert of every assignment (token-major: assignment ``a`` belongs to
+    token ``a // k``). Returns ``(dest [A], tile_expert [T], used,
+    counts [E])``: the row of each assignment in the sorted, tile-padded
+    layout; the expert each tile computes (tiles past the ``used`` ones
+    repeat the last live expert, so they fetch nothing new); the number
+    of live tiles; assignments per expert. No sort: the position inside
+    an expert's group is an exclusive running count (the
+    ``models.moe._dispatch_plan`` construction)."""
+    a = flat_experts.shape[0]
+    tiles = grouped_tiles(a, num_experts, block_rows)
+    onehot = jax.nn.one_hot(flat_experts, num_experts, dtype=jnp.int32)
+    ranks = jnp.cumsum(onehot, axis=0) - onehot
+    pos = jnp.take_along_axis(ranks, flat_experts[:, None], axis=1)[:, 0]
+    counts = onehot.sum(axis=0)
+    padded = -(-counts // block_rows) * block_rows
+    ends = jnp.cumsum(padded)
+    dest = (ends - padded)[flat_experts] + pos
+    used = ends[-1] // block_rows
+    last_live = jnp.max(jnp.where(counts > 0,
+                                  jnp.arange(num_experts), 0))
+    tile_expert = jnp.searchsorted(
+        ends, jnp.arange(tiles, dtype=ends.dtype) * block_rows,
+        side="right")
+    tile_expert = jnp.minimum(tile_expert, last_live).astype(jnp.int32)
+    return dest.astype(jnp.int32), tile_expert, used.astype(jnp.int32), \
+        counts
+
+
+def _grouped_kernel(te_ref, used_ref, *refs, act_name: str, gated: bool):
+    if gated:
+        x_ref, w1_ref, w3_ref, w2_ref, o_ref = refs
+    else:
+        x_ref, w1_ref, w2_ref, o_ref = refs
+        w3_ref = None
+    live = pl.program_id(0) < used_ref[0]
+
+    @pl.when(live)
+    def _compute():
+        x = x_ref[...]
+        h = get_activation(act_name)(
+            jnp.dot(x, w1_ref[0], preferred_element_type=jnp.float32))
+        if gated:
+            h = h * jnp.dot(x, w3_ref[0],
+                            preferred_element_type=jnp.float32)
+        o_ref[...] = jnp.dot(h.astype(x.dtype), w2_ref[0],
+                             preferred_element_type=jnp.float32
+                             ).astype(o_ref.dtype)
+
+    @pl.when(jnp.logical_not(live))
+    def _skip():
+        o_ref[...] = jnp.zeros_like(o_ref)
+
+
+def grouped_experts(x_rows, tile_expert, used, w1, w2, w3=None, *,
+                    block_rows: int, activation: str,
+                    interpret: Optional[bool] = None):
+    """``act(x w1[e]) (* x w3[e]) w2[e]`` for rows already laid out by
+    :func:`grouped_layout`: ``x_rows`` [T * block_rows, d], stacked
+    expert weights ``w1``/``w3`` [E, d, f], ``w2`` [E, f, d] (no
+    biases). One program per tile, the tile's expert chosen through the
+    scalar-prefetched ``tile_expert``; tiles at or past ``used`` write
+    zeros and read nothing new. A whole expert (three matrices) sits
+    in VMEM, double-buffered: the scoped limit is stated from the
+    shapes (``_compiler_params``)."""
+    m, d = x_rows.shape
+    e, _, f = w1.shape
+    tiles = m // block_rows
+    gated = w3 is not None
+    if interpret is None:
+        interpret = _FORCE_INTERPRET or not backend_is_tpu()
+
+    def x_map(t, te, used):
+        return (jnp.minimum(t, jnp.maximum(used[0] - 1, 0)), 0)
+
+    def w_map(t, te, used):
+        return (te[t], 0, 0)
+
+    in_specs = [pl.BlockSpec((block_rows, d), x_map),
+                pl.BlockSpec((1, d, f), w_map)]
+    operands = [x_rows, w1]
+    if gated:
+        in_specs.append(pl.BlockSpec((1, d, f), w_map))
+        operands.append(w3)
+    in_specs.append(pl.BlockSpec((1, f, d), w_map))
+    operands.append(w2)
+    wdt, xdt = w1.dtype, x_rows.dtype
+    pipelined = ((3 if gated else 2) * _nbytes((d, f), wdt)
+                 + 2 * _nbytes((block_rows, d), xdt))
+    resident = (3 * _nbytes((block_rows, f), jnp.float32)
+                + _nbytes((block_rows, d), jnp.float32))
+    need = 2 * pipelined + resident + (4 << 20)
+    return pl.pallas_call(
+        functools.partial(_grouped_kernel, act_name=activation,
+                          gated=gated),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2, grid=(tiles,), in_specs=in_specs,
+            out_specs=pl.BlockSpec((block_rows, d),
+                                   lambda t, te, used: (t, 0))),
+        out_shape=jax.ShapeDtypeStruct((m, d), xdt),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",),
+            vmem_limit_bytes=max(16 << 20, need)),
+        name="moe_grouped_experts", interpret=interpret,
+    )(tile_expert, jnp.reshape(used, (1,)).astype(jnp.int32), *operands)
+
+
+def grouped_experts_reference(x_rows, tile_expert, used, w1, w2, w3=None,
+                              *, block_rows: int, activation: str):
+    """The same product in plain XLA, tile by tile with each tile's
+    expert gathered: the off-TPU path and the kernel's oracle (it
+    gathers ``T`` whole experts, so it is for small shapes)."""
+    m, d = x_rows.shape
+    tiles = m // block_rows
+    xt = x_rows.reshape(tiles, block_rows, d)
+    act = get_activation(activation)
+    h = act(jnp.einsum("tbd,tdf->tbf", xt, w1[tile_expert],
+                       preferred_element_type=jnp.float32))
+    if w3 is not None:
+        h = h * jnp.einsum("tbd,tdf->tbf", xt, w3[tile_expert],
+                           preferred_element_type=jnp.float32)
+    y = jnp.einsum("tbf,tfd->tbd", h.astype(x_rows.dtype), w2[tile_expert],
+                   preferred_element_type=jnp.float32)
+    live = (jnp.arange(tiles) < used)[:, None, None]
+    return jnp.where(live, y, 0.0).astype(x_rows.dtype).reshape(m, d)

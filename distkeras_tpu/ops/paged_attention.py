@@ -127,7 +127,8 @@ def _unpack4(b, dt):
 
 def _kernel(t_ref, tb_ref, *refs, scale: float, page_len: int,
             g: int, w_len: int, hkv: int, window, quantized: bool,
-            int4: bool, n_pages: int, tree: bool):
+            int4: bool, n_pages: int, tree: bool,
+            full_window: bool = False):
     if tree:
         anc_ref, refs = refs[0], refs[1:]
     else:
@@ -167,7 +168,12 @@ def _kernel(t_ref, tb_ref, *refs, scale: float, page_len: int,
         j_idx = lax.broadcasted_iota(jnp.int32, (rows, page_len), 0) // g
         pos = start + lax.broadcasted_iota(
             jnp.int32, (rows, page_len), 1)
-        if anc_ref is None:
+        if full_window:
+            # block-diffusion window: every window query sees the
+            # committed prefix and ALL W window positions (a separate
+            # specialisation: the causal window's trace is unchanged)
+            valid = pos <= t + (w_len - 1)
+        elif anc_ref is None:
             valid = pos <= t + j_idx
             if window is not None:
                 valid = jnp.logical_and(valid, pos > t + j_idx - window)
@@ -242,6 +248,7 @@ def paged_decode_attention(q, k_pages, v_pages, t, table, *,
                            scale: Optional[float] = None,
                            window: Optional[int] = None,
                            k_scale=None, v_scale=None, anc=None,
+                           full_window: bool = False,
                            interpret: Optional[bool] = None):
     """Window decode attention straight off the page pool.
 
@@ -261,8 +268,16 @@ def paged_decode_attention(q, k_pages, v_pages, t, table, *,
     ancestors; the engine derives the mask from the draft's
     parent-index vectors). SWA models derive each node's own position
     from its ancestor count (``t + depth``). A lower-triangular ``anc``
-    reproduces the plain window-causal mask exactly."""
+    reproduces the plain window-causal mask exactly.
+
+    ``full_window`` (block diffusion): every window query admits the
+    committed prefix and ALL ``W`` window positions ``t .. t+W-1`` —
+    attention is bidirectional inside the window (no ``window``/``anc``
+    with it)."""
     s, w_len, hkv, g, d = q.shape
+    if full_window and (window is not None or anc is not None):
+        raise ValueError("full_window takes neither a sliding window "
+                         "nor a tree mask")
     n_pages, _, payload_rows, _ = k_pages.shape
     n_logical = table.shape[1]
     quantized = k_scale is not None
@@ -341,7 +356,7 @@ def paged_decode_attention(q, k_pages, v_pages, t, table, *,
         _kernel, scale=float(scale), page_len=int(page_len), g=int(g),
         w_len=int(w_len), hkv=int(hkv), window=window,
         quantized=quantized, int4=int4, n_pages=int(n_pages),
-        tree=anc is not None)
+        tree=anc is not None, full_window=bool(full_window))
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
         grid=(s, n_logical),

@@ -171,7 +171,7 @@ class ShardingRules:
     def _rule_MultiHeadAttention(self, layer, params):
         tp_q = self._tp(params["wq"].shape[1])
         tp_kv = self._tp(params["wk"].shape[1])
-        return {
+        out = {
             "wq": self._maybe_fsdp(P(None, tp_q, None), params["wq"].shape),
             "wk": self._maybe_fsdp(P(None, tp_kv, None),
                                    params["wk"].shape),
@@ -179,17 +179,23 @@ class ShardingRules:
                                    params["wv"].shape),
             "wo": self._maybe_fsdp(P(tp_q, None, None), params["wo"].shape),
         }
+        # per-head q/k norm scales [Dh]: replicated
+        out.update({k: P() for k in ("q_norm", "k_norm") if k in params})
+        return out
 
     # Transformer MLP: w1 [d, hidden] column, w2 [hidden, d] row.
     def _rule_TransformerMLP(self, layer, params):
         hidden = params["w1"].shape[-1]
         tp = self._tp(hidden)
-        return {
+        specs = {
             "w1": self._maybe_fsdp(P(None, tp), params["w1"].shape),
             "b1": P(tp),
             "w2": self._maybe_fsdp(P(tp, None), params["w2"].shape),
             "b2": P(),
         }
+        if "w3" in params:                # gated: the second column half
+            specs["w3"] = self._maybe_fsdp(P(None, tp), params["w3"].shape)
+        return {k: v for k, v in specs.items() if k in params}
 
     # MoE: expert-parallel on the expert axis; hidden additionally tp-sharded
     # (the column→row split inside each expert).
@@ -213,13 +219,15 @@ class ShardingRules:
                 "expert_unroll=False for GSPMD expert parallelism, or "
                 "use shard_map EP (expert_axis_name) where the unroll "
                 "is safe.", stacklevel=2)
-        return {
+        specs = {
             "gate": P(),
             "w1": P(ep, None, tp),
             "b1": P(ep, tp),
             "w2": P(ep, tp, None),
             "b2": P(ep, None),
+            "w3": P(ep, None, tp),        # gated experts' up-projection
         }
+        return {k: v for k, v in specs.items() if k in params}
 
     # Remat is a transparent wrapper: its params ARE the inner layer's
     def _rule_Remat(self, layer, params):

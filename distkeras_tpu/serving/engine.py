@@ -103,6 +103,8 @@ from distkeras_tpu.obs.tracing import resolve_tracer
 from distkeras_tpu.models.core import Model, Sequential
 from distkeras_tpu.models.decoding import (_attn_compute_dtype,
                                            _decode_block_of,
+                                           block_len_of,
+                                           block_pass_slots_paged,
                                            _resolve_head_dims,
                                            _sample_vec, _serving_params,
                                            commit_tree_path,
@@ -110,7 +112,7 @@ from distkeras_tpu.models.decoding import (_attn_compute_dtype,
                                            decode_step_slots,
                                            decode_step_slots_paged,
                                            prefill, prefill_chunk_step,
-                                           tree_walk,
+                                           routing_counts, tree_walk,
                                            verify_step_slots,
                                            verify_step_slots_paged)
 from distkeras_tpu.models.moe import MoE
@@ -309,6 +311,27 @@ class ServingEngine:
       per-chip expert-weight traffic shrinks with mesh size; the MoE
       combine psums over the axis inside the program.
 
+    Block diffusion (docs/serving.md §Block diffusion): a model built
+    block-causal (``zoo.transformer_lm(block_len=B)``) is decoded a
+    block of ``B`` tokens at a time. The whole blocks of the prompt are
+    prefilled; each pass runs a stream's current block (mask tokens
+    where nothing is fixed) against its cached blocks and fixes the
+    most confident masked positions; once no mask is left the block is
+    appended to ``Request.generated`` (whole, cut at the budget or the
+    stop token) and one more pass, the commit pass, writes the K/V
+    that later blocks read. Slots sit at different passes of
+    different blocks in one batched program.
+
+    * ``denoising_steps`` — passes that fix tokens, per block
+      (default: the block length, one token a pass); the ``B`` tokens
+      are spread evenly over them, earlier passes taking the remainder
+      (``low_confidence_static`` remasking).
+    * ``mask_token`` — the id a not-yet-fixed position is fed as;
+      required for a block-causal model.
+
+    Greedy only, paged pool only, no speculation, fused windows or
+    host offload; the prefix cache shares at block boundaries.
+
     A dispatched-MoE engine also feeds MoE telemetry: per-expert load
     and router-entropy gauges (``ServingMetrics.record_moe_route``), a
     ``moe_route`` tracer event on the decode cadence, and a smoothed
@@ -343,7 +366,9 @@ class ServingEngine:
                  ep_mesh=None,
                  overlap: bool = True, fuse_steps: int = 0,
                  fused_sampling: bool = False,
-                 engine_id: Optional[str] = None):
+                 engine_id: Optional[str] = None,
+                 denoising_steps: Optional[int] = None,
+                 mask_token: Optional[int] = None):
         module = model.module
         if not isinstance(module, Sequential):
             raise TypeError("ServingEngine expects a Sequential LM "
@@ -429,6 +454,46 @@ class ServingEngine:
         # expert telemetry rides only on the dispatched path (the dense
         # baseline keeps generate()'s exact program shape)
         self._moe_stats_on = self._moe_dispatched
+        # --- block diffusion (block-diffusion PR) -----------------------
+        #: block length of a block-causal model (None: causal, one
+        #: token a step)
+        self.block_len = block_len_of(module)
+        if self.block_len is None:
+            if denoising_steps is not None or mask_token is not None:
+                raise ValueError(
+                    "denoising_steps / mask_token are for a block-causal "
+                    "model (zoo.transformer_lm(block_len=...))")
+        else:
+            b_len = self.block_len
+            steps = b_len if denoising_steps is None \
+                else int(denoising_steps)
+            if not 1 <= steps <= b_len:
+                raise ValueError(
+                    f"denoising_steps must be in [1, block_len={b_len}], "
+                    f"got {denoising_steps}")
+            if mask_token is None:
+                raise ValueError(
+                    "a block-causal model needs mask_token (the id a "
+                    "not-yet-fixed position is fed as)")
+            if kv_layout != "paged" or draft is not None or fuse_steps \
+                    or host_kv_pages or self.max_len % b_len \
+                    or page_len % b_len:
+                raise ValueError(
+                    "block diffusion runs on the paged pool with max_len "
+                    "and page_len whole multiples of the block length, "
+                    "without draft, fuse_steps or host_kv_pages")
+            self.denoising_steps = steps
+            self.mask_token = int(mask_token)
+            #: tokens fixed by each denoising pass of a block
+            #: (``low_confidence_static``: even, remainder first)
+            self._fix_schedule = np.array(
+                [b_len // steps + (i < b_len % steps)
+                 for i in range(steps)], np.int64)
+            # the expert telemetry of the one-token step has no reader
+            # here: a pass reports its own counts (rows, experts)
+            self._moe_stats_on = False
+        #: routing counts of block-diffusion prefills not yet read
+        self._prefill_routed: list = []
         self._moe_conc: Optional[float] = None   # routing-concentration EMA
         self._moe_iter = 0                       # stats-throttle counter
         self._setup_expert_parallel(ep_mesh)
@@ -487,6 +552,11 @@ class ServingEngine:
                     f"prefix_granularity must be >= 1, "
                     f"got {prefix_granularity}")
             self._prefix_granularity = int(prefix_granularity)
+            if self.block_len is not None:
+                # K/V inside a block depend on the whole block: a
+                # partial-page match is only valid in whole blocks
+                self._prefix_granularity = int(np.lcm(
+                    self._prefix_granularity, self.block_len))
             # cost-aware scheduling: priority classes + preemption; the
             # engine gates admission on the free-page budget below
             scheduler = PriorityScheduler(self.num_slots,
@@ -640,6 +710,16 @@ class ServingEngine:
             [np.array(jax.random.PRNGKey(0))] * s)       # [S, key]
 
         self._step_fns = {}                  # greedy_only -> jit
+        self._block_fns = {}                 # with head? -> jit
+        if self.block_len is not None:
+            # per-slot block state (host): the block as it stands, which
+            # positions are still masked, which pass fixed each, and how
+            # many denoising passes the block has had
+            bl = self.block_len
+            self._blk_tok = np.full((s, bl), self.mask_token, np.int32)
+            self._blk_masked = np.zeros((s, bl), bool)
+            self._blk_pass = np.full((s, bl), -1, np.int32)
+            self._blk_step = np.zeros(s, np.int64)
         #: program name -> the kernel-or-reference choices made while
         #: it was traced (``_jit_serving``); read it in ``health()``
         self.program_paths: Dict[str, str] = {}
@@ -793,7 +873,9 @@ class ServingEngine:
             if blk is None or not isinstance(blk.mlp, MoE) \
                     or blk.mlp.expert_axis_name is None:
                 continue
-            for kk in ("w1", "b1", "w2", "b2"):
+            for kk in ("w1", "b1", "w2", "b2", "w3"):
+                if kk not in pspec[i]["mlp"]:
+                    continue             # bias-free / ungated experts
                 pspec[i]["mlp"][kk] = P(axis)
                 shardings[i]["mlp"][kk] = NamedSharding(ep_mesh, P(axis))
         self._ep_mesh, self._ep_axis, self._ep_pspec = ep_mesh, axis, pspec
@@ -951,6 +1033,12 @@ class ServingEngine:
         ``fetch_seconds`` for the bench's host-loop rider."""
         with obs.span("serving.decode.fetch"):
             t0 = self._metrics.clock()
+            if len(arrays) > 1:
+                # several outputs of one step: start every copy before
+                # waiting for the first, or each waits out a round trip
+                # of its own with the device idle behind it
+                for a in arrays:
+                    a.copy_to_host_async()
             out = [np.asarray(a) for a in arrays]  # lint: allow-host-sync (the lagged fetch)
             self.fetch_seconds += self._metrics.clock() - t0
         return out
@@ -1285,6 +1373,10 @@ class ServingEngine:
         if deadline_s is not None and float(deadline_s) <= 0:
             raise ValueError(
                 f"deadline_s must be > 0, got {deadline_s}")
+        if self.block_len is not None and float(temperature) > 0.0:
+            raise ValueError(
+                "block diffusion decodes greedily (temperature 0): a "
+                "position is fixed to its most probable token")
         if self.kv_layout == "paged":
             # a request whose worst case exceeds the whole pool could
             # never finish — even after preempting everything else
@@ -1838,12 +1930,26 @@ class ServingEngine:
         staging caches match generate's bit-for-bit; interior chunks are
         ``prefill_chunk_step``. With a fixed ``prefill_chunk`` the
         interior chunks share ceil(max_len/chunk) programs; the ragged
-        FINAL chunk is per-prompt-length (see MAX_PREFILL_PROGRAMS)."""
+        FINAL chunk is per-prompt-length (see MAX_PREFILL_PROGRAMS). A
+        block-diffusion engine's prefill returns a third value, the
+        ``routing_counts`` of the expert layers it ran."""
         key = (q_len, t0, final)
         fn = self._prefill_fns.pop(key, None)
         if fn is None:
             module = self.module
-            if t0 == 0 and final:
+            if self.block_len is not None:
+                # block diffusion: never a head (``final`` is False), the
+                # expert layers drop-free as in the passes, and what they
+                # routed beside the cache
+                dispatched = self._moe_dispatched
+
+                def f(params, state, cache, chunk):
+                    routing = [] if dispatched else None
+                    _, cache = prefill_chunk_step(
+                        module, params, state, cache, chunk, t0,
+                        final=final, routing=routing)
+                    return None, cache, routing_counts(routing or [])
+            elif t0 == 0 and final:
                 def f(params, state, cache, chunk):
                     return prefill(module, params, state, cache, chunk)
             else:
@@ -1908,6 +2014,18 @@ class ServingEngine:
                 break
         return admitted
 
+    def _context_of(self, req: Request) -> np.ndarray:
+        """The tokens whose KV must be in cache before ``req`` (re)joins
+        decode. One token a step: ``Request.context_tokens``. Block
+        diffusion: the WHOLE blocks of prompt + generated (every shown
+        block included: a block is shown when it is whole, a pass
+        before its K/V are committed); what is left over, under a
+        block of the prompt, opens the first generated block."""
+        if self.block_len is None:
+            return req.context_tokens
+        toks = req.tokens
+        return toks[:len(toks) // self.block_len * self.block_len]
+
     def _page_plan(self, req: Request) -> Optional[Dict]:
         """Fund ``req``'s (re)admission from the page budget: prefix-
         match its context, reclaim cache-only pages if the private
@@ -1937,11 +2055,12 @@ class ServingEngine:
             priv = [pool.alloc_page() for _ in range(n)]
             return {"restore": True, "full": [], "priv": priv,
                     "shared_len": 0, "donor": None}
-        toks = req.context_tokens
+        toks = self._context_of(req)
         # context + 1: the first decode write (position len(toks))
-        # must land on an allocated page
+        # must land on an allocated page (block diffusion: the page
+        # holds the whole first block, page_len % block_len == 0)
         n_logical = pool.pages_for(len(toks) + 1)
-        if self.prefix is not None:
+        if self.prefix is not None and len(toks):
             full, shared_len, donor = self._match_prefix(toks)
         else:
             full, shared_len, donor = [], 0, None
@@ -1996,7 +2115,7 @@ class ServingEngine:
         chain now covers for the shared ones, return the privates to
         the budget."""
         pool = self.pool
-        toks = req.context_tokens
+        toks = self._context_of(req)
         full, shared_len, donor = self._match_prefix(toks)
         if shared_len <= getattr(req, "_shared_len", 0):
             return
@@ -2260,6 +2379,13 @@ class ServingEngine:
         chunk, run one decode step over all slots. Returns requests that
         reached a terminal state during this iteration (FINISHED,
         TIMED_OUT or CANCELLED — check ``req.state``).
+
+        What a step yields a stream: one token (plain decode; with
+        ``overlap`` seen one iteration late), ``fuse_steps`` tokens (a
+        fused window), 1..k+1 (a speculative verify), or — a
+        block-causal model — nothing for ``denoising_steps - 1`` passes
+        and then a whole block of ``block_len`` tokens at once
+        (``_block_step``): ``Request.generated`` grows by whole blocks.
 
         Error isolation: an exception while advancing ONE request's
         prefill (a poisoned prompt, an injected ``serving.prefill``
@@ -2645,6 +2771,11 @@ class ServingEngine:
                 "expert_parallel": (None if self._ep_mesh is None
                                     else int(self._ep_mesh.shape[
                                         self._ep_axis]))}
+        if self.block_len is not None:
+            out["block_diffusion"] = {
+                "block_len": self.block_len,
+                "denoising_steps": self.denoising_steps,
+                "mask_token": self.mask_token}
         if self.kv_layout == "paged":
             pool = self.pool
             out["pages"] = {
@@ -2679,6 +2810,9 @@ class ServingEngine:
         garbage."""
         if self.kv_layout != "paged":
             raise ValueError("decode_logits reads the paged cache")
+        if self.block_len is not None:
+            raise ValueError("decode_logits is a one-token step; a "
+                             "block-causal model has none")
         self._flush_pending()
         self._ensure_decode_pages()
         fn = self._logits_fns.get((decode_kernel, moe_decode))
@@ -2746,8 +2880,14 @@ class ServingEngine:
             return
         # paged context = prompt, or prompt + generated[:-1] after a
         # preemption (the resumable-prefill recompute path)
-        toks = req.context_tokens if paged else req.prompt
+        toks = self._context_of(req) if paged else req.prompt
         p_len = len(toks)
+        blockdiff = self.block_len is not None
+        if blockdiff and p_len == 0:
+            # the prompt is under one block: nothing to prefill, the
+            # whole of it opens the first generated block
+            self._open_block(req, 0)
+            return
         resume = paged and bool(req.generated)
         if resume and req.prefill_pos == 0 \
                 and getattr(req, "_resume_t0", None) is None:
@@ -2787,12 +2927,18 @@ class ServingEngine:
                 q_len = min(chunk, p_len - t0)
                 final = t0 + q_len >= p_len
             # a resume re-prefill never needs logits (its tokens are
-            # already decided), so every chunk runs head-less
-            fn = self._prefill_fn(q_len, t0, final and not resume)
+            # already decided), so every chunk runs head-less; nor does
+            # block diffusion (position i predicts token i: the first
+            # denoising pass reads the block's own logits)
+            fn = self._prefill_fn(q_len, t0,
+                                  final and not resume and not blockdiff)
             chunk_toks = jnp.asarray(toks[None, t0:t0 + q_len])
         with obs.span("serving.prefill.dispatch"):
-            logits, self._staging = fn(self._params, self._state,
-                                       self._staging, chunk_toks)
+            logits, self._staging, *routed = fn(
+                self._params, self._state, self._staging, chunk_toks)
+        # a block-diffusion prefill's routing counts: read with the next
+        # pass's results (``_block_step``), not waited for here
+        self._prefill_routed += routed
         req.prefill_pos = t0 + q_len
         self.metrics.record_prefill_chunk()
         self.tracer.on_prefill_chunk(req.rid, t0, q_len)
@@ -2814,6 +2960,9 @@ class ServingEngine:
             else:
                 self.pool.insert(self._staging, req.slot, n_pos=p_len)
         s = req.slot
+        if blockdiff:
+            self._open_block(req, p_len)
+            return
         if resume:
             # re-admission after preemption: skip first-token sampling
             # (TTFT fired long ago), restore the decode vectors and the
@@ -2860,6 +3009,164 @@ class ServingEngine:
         self._chain_dirty[s] = True        # host owns the next input
         self._begin_draft(req, toks)
 
+    # --- block diffusion (block-diffusion PR) -----------------------------
+
+    def _open_block(self, req: Request, cached: int) -> None:
+        """``req`` joins the decode batch with ``cached`` positions (whole
+        blocks) in its pages: its slot's block starts there, holding
+        what is left of prompt + generated (under a block, and only
+        before the first generated block) and mask tokens after it."""
+        s = req.slot
+        rest = req.tokens[cached:]
+        self.scheduler.to_decoding(req)
+        self._comp_ver += 1
+        self._t[s] = cached
+        self._blk_tok[s] = self.mask_token
+        self._blk_tok[s, :len(rest)] = rest
+        self._blk_masked[s] = np.arange(self.block_len) >= len(rest)
+        self._blk_pass[s] = -1
+        self._blk_step[s] = 0
+        if req.generated:
+            self.tracer.on_resume(req.rid)
+
+    def block_positions(self) -> Dict[int, tuple]:
+        """Per decoding slot of a block-diffusion engine: ``(first
+        position of its current block, masked positions left in it)``;
+        0 left means the slot's next pass is its commit pass."""
+        return {slot: (int(self._t[slot]),
+                       int(self._blk_masked[slot].sum()))
+                for slot in self.scheduler.running}
+
+    def _block_fn(self, head: bool):
+        """The two block-pass programs: ``denoise`` (with the
+        vocabulary head: most probable token and its log-probability
+        at every position of every slot's block; slots that only
+        commit ride it) and ``commit`` (no head: run when every live
+        slot only commits). Both write the block's K/V in place."""
+        fn = self._block_fns.get(head)
+        if fn is None:
+            module, page_len = self.module, self.page_len
+            pk, dispatched = self._paged_kernel, self._moe_dispatched
+
+            def fn(params, state, cache, toks, t, tables):
+                return block_pass_slots_paged(
+                    module, params, state, cache, toks, t, tables,
+                    page_len, head=head, moe_dispatched=dispatched,
+                    paged_kernel=pk)
+
+            name = "denoise" if head else "commit"
+            fn = self._jit_serving(fn, 6, name)
+            self._block_fns[head] = fn
+            self._recompile.watch("serving." + name, fn)
+        return fn
+
+    def _block_step(self, finished: List[Request]) -> None:
+        """One block-diffusion pass over the decode batch. A slot
+        whose block still has masked positions DENOISES: the pass's
+        most confident masked positions (``_fix_schedule``) are fixed
+        to their most probable tokens; when none is left the block is
+        shown to its client (appended to ``Request.generated`` with
+        the pass that fixed each token in ``Request.fixed_pass``). A
+        slot whose block is whole COMMITS: the pass's K/V writes are
+        the block's cache entries, and the next block opens. A request
+        that meets its budget or stop token when its block is shown
+        finishes without a commit pass: nothing will read that block.
+        Synchronous: the next pass's input depends on this one's
+        choice, so the fetch is in the iteration (like a speculative
+        verify)."""
+        bl = self.block_len
+        with obs.span("serving.decode.pages"):
+            look = np.zeros(self.num_slots, np.int64)
+            look[list(self.scheduler.running)] = bl - 1
+            self._ensure_decode_pages(look)
+        running = self.scheduler.running
+        if not running:
+            return
+        t0 = self.metrics.clock()
+        with obs.span("serving.decode.tables"):
+            tables = self.pool.device_tables()
+        slots = np.fromiter(running.keys(), np.int64, len(running))
+        denoising = self._blk_masked[slots].any(axis=1)
+        head = bool(denoising.any())
+        kind = "denoise" if head else "commit"
+        with obs.span("serving.decode." + kind):
+            with obs.span("serving.decode.dispatch"):
+                out = self._block_fn(head)(
+                    self._params, self._state, self.pool.cache,
+                    _snap(self._blk_tok), _snap(self._t), tables)
+            prefills, self._prefill_routed = self._prefill_routed, []
+            if head:
+                best, conf, self.pool.cache, routed = out
+                best, conf, routed, *prefills = self._fetch(
+                    best, conf, routed, *prefills)
+            else:
+                self.pool.cache, routed = out
+                routed, *prefills = self._fetch(routed, *prefills)
+        if "serving." + kind not in self._warmed:
+            self._warmed.add("serving." + kind)
+            self._recompile.mark_warm("serving." + kind)
+        with obs.span("serving.decode.consume"):
+            # what the program says its expert layers did: every row of
+            # the pass is routed, idle slots' too
+            self.metrics.record_block_pass(
+                kind, int(denoising.sum()), int((~denoising).sum()),
+                *map(int, routed))
+            for rows, touched in prefills:
+                self.metrics.record_block_prefill(int(rows), int(touched))
+            n_emitted = 0
+            done_reqs = []
+            for slot, req in list(running.items()):
+                masked = self._blk_masked[slot]
+                if not masked.any():
+                    # committed: the block's K/V are cache now
+                    self._t[slot] += bl
+                    self._blk_tok[slot] = self.mask_token
+                    self._blk_masked[slot] = True
+                    self._blk_pass[slot] = -1
+                    self._blk_step[slot] = 0
+                    continue
+                step = int(self._blk_step[slot])
+                n_fix = min(int(self._fix_schedule[step]),
+                            int(masked.sum()))
+                # the n_fix most confident masked positions (ties: the
+                # earliest position, as a stable sort leaves them)
+                order = np.argsort(
+                    np.where(masked, -conf[slot], np.inf), kind="stable")
+                for pos in order[:n_fix]:
+                    self._blk_tok[slot, pos] = best[slot, pos]
+                    self._blk_pass[slot, pos] = step
+                    masked[pos] = False
+                self._blk_step[slot] = step + 1
+                if masked.any():
+                    continue
+                # whole: a client may see it
+                first = not req.generated
+                appended = 0
+                for pos in np.nonzero(self._blk_pass[slot] >= 0)[0]:
+                    req.generated.append(int(self._blk_tok[slot, pos]))
+                    req.fixed_pass.append(int(self._blk_pass[slot, pos]))
+                    appended += 1
+                    if req.done:
+                        break           # budget / stop token mid-block
+                n_emitted += appended
+                self.metrics.record_block_commit(appended)
+                if first:
+                    self.metrics.record_first_token(req.rid)
+                    self.tracer.on_first_token(req.rid)
+                if self.tracer.enabled:
+                    self._trace_decode[req.rid] = \
+                        self._trace_decode.get(req.rid, 0) + appended
+                    if self._trace_decode_t0 is None:
+                        self._trace_decode_t0 = t0
+                if req.done:
+                    done_reqs.append(req)
+            self._decode_buf.append(
+                (len(running), self._metrics.clock() - t0, n_emitted))
+            if done_reqs:
+                self._flush_host_window()
+                for req in done_reqs:
+                    self._finish(req, finished)
+
     def _begin_draft(self, req: Request, context) -> None:
         """Hand the draft source this request's context the moment it
         joins decode. A source that cannot serve the slot (its own
@@ -2877,6 +3184,9 @@ class ServingEngine:
         # error leaves the iteration wholesale-retryable (see step()
         # docstring)
         faults.point("serving.decode")
+        if self.block_len is not None:
+            self._block_step(finished)
+            return
         paged = self.kv_layout == "paged"
         spec = bool(self._spec_slots())
         if spec:

@@ -4,7 +4,12 @@ of which a single ``generate()`` call can even express.
 Per request: TTFT (submit -> first token — prefill queueing + prompt
 ingestion), TPOT (mean seconds per generated token after the first —
 the streaming-cadence number the ``tpot_p99`` SLO reads) and
-end-to-end latency. Per engine iteration: queue depth,
+end-to-end latency. A step does not always yield one token a stream:
+speculation yields 1..k+1, and a block-diffusion engine yields nothing
+for ``denoising_steps - 1`` passes and then a whole block, so TTFT is
+submit -> the first BLOCK a client may see and TPOT is per token of
+the committed blocks (``summary()["block_diffusion"]`` has the passes,
+blocks and tokens behind it). Per engine iteration: queue depth,
 slot occupancy, decoding-slot count and decode wall time (the
 steady-state tokens/s series ``bench.py --model serving`` reduces).
 Phase wall-clock (prefill vs decode) rides on
@@ -149,6 +154,25 @@ class ServingMetrics:
         self._moe_conc = self.registry.gauge(
             "serving.moe_concentration")
         self._moe_experts = 0            # label-set bound, for summary
+        # block diffusion (block-diffusion PR): passes by kind (the
+        # denoise program carries committing slots too; "commit" counts
+        # passes that ran head-less because every live slot was
+        # committing), slot-passes by what the slot did, blocks and
+        # tokens a client was shown, and what the expert layers did in
+        # those passes and (kind="prefill") in the prefills (rows
+        # routed; experts that owned a row, summed over the expert
+        # layers that ran: the programs' own counts)
+        self._bd_passes = self.registry.counter("serving.blockdiff_passes")
+        self._bd_slot_passes = self.registry.counter(
+            "serving.blockdiff_slot_passes")
+        self._bd_blocks = self.registry.counter(
+            "serving.blockdiff_blocks_committed")
+        self._bd_tokens = self.registry.counter(
+            "serving.blockdiff_tokens_committed")
+        self._bd_rows = self.registry.counter(
+            "serving.blockdiff_rows_routed")
+        self._bd_experts = self.registry.counter(
+            "serving.blockdiff_experts_touched")
         #: exact (tokens, seconds) aggregation per decoding-slot count —
         #: bounded by the slot count, and authoritative for
         #: ``decode_tokens_per_sec`` (the labeled counters mirror it for
@@ -309,6 +333,34 @@ class ServingMetrics:
         self._moe_entropy.set(float(entropy))
         self._moe_conc.set(float(concentration))
 
+    def record_block_pass(self, kind: str, denoising: int, committing: int,
+                          rows_routed: int, experts_touched: int) -> None:
+        """One block-diffusion pass: ``kind`` is the program that ran
+        (``"denoise"`` or ``"commit"``), ``denoising`` / ``committing``
+        the live slots that did either in it, ``rows_routed`` the rows
+        its expert layers routed (live or not: every row of the pass
+        is computed) and ``experts_touched`` the experts that owned at
+        least one of them, summed over the expert layers."""
+        self._bd_passes.inc(kind=kind)
+        self._bd_slot_passes.inc(int(denoising), kind="denoise")
+        self._bd_slot_passes.inc(int(committing), kind="commit")
+        self._bd_rows.inc(int(rows_routed), kind="pass")
+        self._bd_experts.inc(int(experts_touched), kind="pass")
+
+    def record_block_prefill(self, rows_routed: int,
+                             experts_touched: int) -> None:
+        """What the expert layers of one block-diffusion prefill
+        program did (as ``record_block_pass`` counts a pass's)."""
+        self._bd_rows.inc(int(rows_routed), kind="prefill")
+        self._bd_experts.inc(int(experts_touched), kind="prefill")
+
+    def record_block_commit(self, n_tokens: int) -> None:
+        """One whole block became visible to its client: ``n_tokens``
+        new tokens on ``Request.generated`` (under the block length
+        where the prompt opened the block or the budget closed it)."""
+        self._bd_blocks.inc()
+        self._bd_tokens.inc(int(n_tokens))
+
     # --- per-iteration ----------------------------------------------------
 
     def record_prefill_chunk(self) -> None:
@@ -455,6 +507,25 @@ class ServingMetrics:
                    if n >= min_occupancy)
         return toks / secs if secs > 0 else None
 
+    def _block_diffusion(self) -> Optional[Dict]:
+        passes = {k: int(self._bd_passes.value(kind=k))
+                  for k in ("denoise", "commit")}
+        if not sum(passes.values()):
+            return None
+        return {"passes": passes,
+                "slot_passes": {
+                    k: int(self._bd_slot_passes.value(kind=k))
+                    for k in ("denoise", "commit")},
+                "blocks_committed": int(self._bd_blocks.value()),
+                "tokens_committed": int(self._bd_tokens.value()),
+                "rows_routed": int(self._bd_rows.value(kind="pass")),
+                "experts_touched": int(
+                    self._bd_experts.value(kind="pass")),
+                "prefill_rows_routed": int(
+                    self._bd_rows.value(kind="prefill")),
+                "prefill_experts_touched": int(
+                    self._bd_experts.value(kind="prefill"))}
+
     @staticmethod
     def _pcts(hist) -> Optional[Dict[str, float]]:
         stats = hist.stats()
@@ -517,6 +588,9 @@ class ServingMetrics:
                 "expert_load": self.moe_expert_load,
                 "router_entropy": self._moe_entropy.value(),
                 "concentration": self._moe_conc.value()}),
+            # block diffusion (keys ADDED by the block-diffusion PR):
+            # None until a pass ran
+            "block_diffusion": self._block_diffusion(),
             "acceptance_rate": self.acceptance_rate,
             "speculation": {
                 "proposed": self.spec_proposed,

@@ -101,7 +101,14 @@ class Request:
     state: RequestState = RequestState.QUEUED
     slot: Optional[int] = None
     prefill_pos: int = 0                 # prompt positions ingested
+    #: tokens a client may see so far. A plain decode step appends one,
+    #: a speculative verify 1..k+1, and a block-diffusion engine
+    #: nothing for ``denoising_steps - 1`` passes and then a whole
+    #: block at once (cut at ``max_new_tokens`` / the stop token)
     generated: List[int] = field(default_factory=list)
+    #: block diffusion only, parallel to ``generated``: the denoising
+    #: pass of its block (0-based) that fixed each token
+    fixed_pass: List[int] = field(default_factory=list)
     rng: object = None                   # per-request PRNG key (engine)
     deadline_s: Optional[float] = None   # submit->finish budget (engine
     #                                      clock); None = no deadline

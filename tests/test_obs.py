@@ -601,7 +601,10 @@ def test_serving_summary_keys_are_backward_compatible():
         # host KV offload tally ADDED by the offload PR (page-swap
         # traffic + per-path resume latencies; zeros/None without a
         # host tier)
-        "offload"}
+        "offload",
+        # passes, blocks and tokens of a block-diffusion engine ADDED
+        # by the block-diffusion PR (None until a pass ran)
+        "block_diffusion"}
 
 
 # --- integration: prefetch gauges -------------------------------------------

@@ -346,3 +346,144 @@ def test_sample_tokens_with_keys(one_chip, as_tpu):
         s((slots,), jnp.float32), s((slots,), jnp.int32),
         s((slots,), jnp.float32), s((slots, 2), jnp.uint32))
     assert calls == 1
+
+
+# --- block diffusion at the SDAR cell's widths -----------------------------
+# d_model 2048, 32 query heads over 4 KV heads of 128, 128 gated experts of
+# width 768 with top-8, vocabulary 151,936, blocks of 4, 32 slots x 2048.
+
+@pytest.mark.parametrize("tokens", [128, 1024], ids=["pass", "prefill"])
+def test_grouped_experts_sdar_widths(one_chip, as_tpu, tokens):
+    """The grouped expert product: one pass of 32 slots x 4 positions and a
+    1,024-token prefill, top-8 of 128 (a whole expert, three matrices of
+    2048 x 768, sits in VMEM double-buffered: past the 16 MiB default)."""
+    from distkeras_tpu.ops.moe_kernels import (grouped_block_rows,
+                                               grouped_experts,
+                                               grouped_tiles)
+    s = _spec(one_chip)
+    e, d, f, a = 128, 2048, 768, tokens * 8
+    rows = grouped_block_rows(a, e)
+    tiles = grouped_tiles(a, e, rows)
+    assert rows == (16 if tokens == 128 else 64)
+    fn = lambda x, te, used, w1, w2, w3: grouped_experts(
+        x, te, used, w1, w2, w3, block_rows=rows, activation="silu")
+    n, _ = _compile(fn, s((tiles * rows, d), jnp.bfloat16),
+                    s((tiles,), jnp.int32), s((), jnp.int32),
+                    s((e, d, f), jnp.bfloat16), s((e, f, d), jnp.bfloat16),
+                    s((e, d, f), jnp.bfloat16))
+    assert n == 1
+
+
+def test_paged_full_window_sdar_widths(one_chip, as_tpu):
+    from distkeras_tpu.ops.paged_attention import paged_decode_attention
+    s = _spec(one_chip)
+    slots, hkv, g, d, w = 32, 4, 8, 128, 4
+    pages = s((slots * 128, hkv, 16, d), jnp.bfloat16)
+    fn = lambda q, k, v, t, tb: paged_decode_attention(
+        q, k, v, t, tb, full_window=True)
+    n, _ = _compile(fn, s((slots, w, hkv, g, d), jnp.float32), pages, pages,
+                    s((slots,), jnp.int32), s((slots, 128), jnp.int32))
+    assert n == 1
+
+
+@pytest.mark.parametrize("seq", [1024, 896, 4])
+def test_block_causal_flash_sdar_widths(one_chip, as_tpu, seq):
+    from distkeras_tpu.ops.flash_attention import flash_attention
+    q = _spec(one_chip)((1, seq, 32, 128), jnp.bfloat16)
+    fn = lambda q, k, v: flash_attention(q, k, v, causal=True, block_len=4)
+    n, _ = _compile(fn, q, q, q)
+    assert n == 1
+
+
+@pytest.mark.parametrize("head", [True, False], ids=["denoise", "commit"])
+def test_block_pass_moves_no_pool_plane(one_chip, as_tpu, head):
+    """One whole block-diffusion pass (one layer of the cell's widths, the
+    whole vocabulary) on a donated pool: both kernels in the program,
+    every cache leaf aliased, no copy the size of a pool plane. (Without
+    the head the deepest block stops at its K/V write: the commit program
+    of this one-layer model holds no kernel at all.)"""
+    import re
+    from distkeras_tpu.models import zoo
+    from distkeras_tpu.models.decoding import (_resolve_head_dims,
+                                               block_pass_slots_paged,
+                                               init_cache)
+    module = zoo.transformer_lm(
+        151936, d_model=2048, num_heads=32, num_layers=1, max_len=2048,
+        num_kv_heads=4, head_dim=128, qk_norm=True, rope_base=1e6,
+        block_len=4, mlp_dim=768, mlp_activation="silu", mlp_gated=True,
+        mlp_bias=False, moe_every=1, num_experts=128, moe_top_k=8,
+        moe_dispatch="grouped", dtype="bfloat16")
+    params, state = jax.eval_shape(
+        lambda k: module.init(k, (16,))[:2], jax.random.PRNGKey(0))
+    slots, page_len, s = 32, 16, _spec(one_chip)
+    cache = jax.eval_shape(lambda: init_cache(
+        module, slots * 2048 // page_len, page_len, jnp.bfloat16,
+        check_len=2048))
+
+    def on_chip(tree, dtype=None):
+        return jax.tree_util.tree_map(
+            lambda a: s(a.shape, dtype if dtype is not None and a.ndim >= 2
+                        else a.dtype), tree)
+
+    def step(params, state, cache, toks, t, table):
+        return block_pass_slots_paged(module, params, state, cache, toks,
+                                      t, table, page_len, head=head)
+
+    text = jax.jit(step, donate_argnums=2).lower(
+        on_chip(params, jnp.bfloat16), on_chip(state), on_chip(cache),
+        s((slots, 4), jnp.int32), s((slots,), jnp.int32),
+        s((slots, 2048 // page_len), jnp.int32)).compile().as_text()
+    leaves = jax.tree_util.tree_leaves(cache)
+    assert text.split("\n", 1)[0].count("may-alias") == len(leaves)
+    planes = {int(np.prod(a.shape)) for a in leaves if a.ndim == 4}
+    moved = [line.strip()[:160] for line in text.splitlines()
+             if (dims := re.search(r"= \w+\[([\d,]+)\]\S* copy\(", line))
+             and int(np.prod(list(map(int, dims.group(1).split(",")))))
+             in planes]
+    assert not moved, moved[:2]
+    assert text.count('custom_call_target="tpu_custom_call"') \
+        == (2 if head else 0)
+
+
+@pytest.mark.parametrize("t0,chunk", [(0, 1024), (128, 896)],
+                         ids=["whole", "after_a_shared_template"])
+def test_block_diffusion_prefill_counts_what_it_runs(one_chip, as_tpu, t0,
+                                                     chunk):
+    """The block-diffusion engine's prefill at the cell's widths, two layers:
+    no head, the deepest block stops at its K/V write, and the program
+    returns what its expert layers routed. So the kernels in it are the
+    first layer's alone: its flash passes (the chunk itself; the cached
+    prefix too where there is one) and its grouped experts."""
+    from distkeras_tpu.models import zoo
+    from distkeras_tpu.models.decoding import (init_cache, prefill_chunk_step,
+                                               routing_counts)
+    module = zoo.transformer_lm(
+        151936, d_model=2048, num_heads=32, num_layers=2, max_len=2048,
+        num_kv_heads=4, head_dim=128, qk_norm=True, rope_base=1e6,
+        block_len=4, mlp_dim=768, mlp_activation="silu", mlp_gated=True,
+        mlp_bias=False, moe_every=1, num_experts=128, moe_top_k=8,
+        moe_dispatch="grouped", dtype="bfloat16")
+    params, state = jax.eval_shape(
+        lambda k: module.init(k, (16,))[:2], jax.random.PRNGKey(0))
+    s = _spec(one_chip)
+    cache = jax.eval_shape(
+        lambda: init_cache(module, 1, 2048, jnp.bfloat16))
+
+    def on_chip(tree, dtype=None):
+        return jax.tree_util.tree_map(
+            lambda a: s(a.shape, dtype if dtype is not None and a.ndim >= 2
+                        else a.dtype), tree)
+
+    def step(params, state, cache, toks):
+        routing = []
+        _, cache = prefill_chunk_step(module, params, state, cache, toks,
+                                      t0, final=False, routing=routing)
+        assert len(routing) == 1
+        return cache, routing_counts(routing)
+
+    text = jax.jit(step, donate_argnums=2).lower(
+        on_chip(params, jnp.bfloat16), on_chip(state), on_chip(cache),
+        s((1, chunk), jnp.int32)).compile().as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') \
+        == (3 if t0 else 2)
+
