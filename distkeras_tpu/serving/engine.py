@@ -577,6 +577,8 @@ class ServingEngine:
         #: host-offload odometer snapshot (pool counts cumulatively;
         #: _flush_host_window publishes per-window deltas)
         self._off_seen = (0, 0, 0)
+        #: likewise for the prefix cache's eviction odometers
+        self._evict_seen = (0, 0, 0)
         # bounded admission (load shedding): submits past max_queue
         # raise AdmissionRejected instead of growing the queue without
         # bound under overload; None keeps the open-queue behavior
@@ -1144,6 +1146,14 @@ class ServingEngine:
                 if po > so or pr > sr:
                     m.record_offload(po - so, pr - sr, ob - sb)
                     self._off_seen = (po, pr, ob)
+                if self.prefix is not None:
+                    now = (self.prefix.evictions,
+                           self.prefix.evict_examined,
+                           self.prefix.evictable_queries)
+                    if now != self._evict_seen:
+                        m.record_prefix_eviction(*(
+                            a - b for a, b in zip(now, self._evict_seen)))
+                        self._evict_seen = now
         if self._decode_buf:
             for n, dt, toks in self._decode_buf:
                 m.record_decode(n, dt, n_tokens=toks)

@@ -376,6 +376,12 @@ class PagedKVPool:
         self._pending_host: List[Dict] = []
         #: lazy-fence odometer (tests pin laziness through it)
         self.host_fences = 0
+        #: called with a page id whenever that page's holder count
+        #: passes between 1 and 2 (``incref``, ``decref``,
+        #: ``release_slot``): ``PrefixCache`` sets it, because "only
+        #: the cache holds this page" is a fact its eviction index
+        #: keeps and only the pool sees change
+        self._ref_listener = None
 
     @staticmethod
     def _page_bytes(module, page_len: int, dtype, max_len: int) -> int:
@@ -452,15 +458,19 @@ class PagedKVPool:
         return pid
 
     def incref(self, pid: int) -> None:
-        self.ref[pid] += 1
+        self.ref[pid] = n = self.ref[pid] + 1
+        if n == 2 and self._ref_listener is not None:
+            self._ref_listener(pid)
 
     def decref(self, pid: int) -> None:
-        self.ref[pid] -= 1
-        if self.ref[pid] < 0:
+        self.ref[pid] = n = self.ref[pid] - 1
+        if n < 0:
             raise RuntimeError(
                 f"page {pid} refcount went negative (double free)")
-        if self.ref[pid] == 0:
+        if n == 0:
             self._free.append(pid)
+        elif n == 1 and self._ref_listener is not None:
+            self._ref_listener(pid)
 
     def assign(self, slot: int, logical: int, pid: int) -> None:
         """Point ``tables[slot, logical]`` at ``pid`` (the caller has
@@ -481,17 +491,23 @@ class PagedKVPool:
         this runs on the serving loop's finish/preempt path."""
         row = self.tables[slot]
         pages = row[row < self.num_pages]
+        crossed = ()
         if pages.size:
             self.ref[pages] -= 1    # a row never repeats a page
-            if (self.ref[pages] < 0).any():
+            left = self.ref[pages]
+            if (left < 0).any():
                 raise RuntimeError(
                     f"slot {slot} release drove a page refcount "
                     "negative (double free)")
             # freed pages return in row (logical) order — the same
             # deterministic order the per-page decref loop produced
-            self._free.extend(pages[self.ref[pages] == 0].tolist())
+            self._free.extend(pages[left == 0].tolist())
+            if self._ref_listener is not None:
+                crossed = pages[left == 1].tolist()
         self.tables[slot] = self.num_pages
         self._dirty()
+        for pid in crossed:
+            self._ref_listener(pid)
         return int(pages.size)
 
     # -- host offload tier --------------------------------------------------
@@ -645,7 +661,8 @@ class PagedKVPool:
 
 
 class _Node:
-    __slots__ = ("nid", "page", "parent", "key", "last_used", "host")
+    __slots__ = ("nid", "page", "parent", "key", "last_used", "host",
+                 "pinned", "blocked", "counted")
 
     def __init__(self, nid, page, parent, key, last_used):
         self.nid = nid
@@ -654,6 +671,89 @@ class _Node:
         self.parent = parent             # host page id instead)
         self.key = key
         self.last_used = last_used
+        # the eviction index's view (``PrefixCache._index``):
+        self.pinned = False              # on the device, a slot reads it
+        self.blocked = 0                 # children with a pin at or below
+        self.counted = 0                 # 0 not cache-only, 1 spill-only,
+        #                                  2 droppable
+
+
+class _LRUHeap:
+    """Min-heap of trie nodes on ``(last_used, nid)`` that knows where
+    each node sits, so a node is added, re-keyed after a touch or
+    taken out of the middle in O(log n), and ``top()`` is the least
+    recently used member with nothing stale to skip. ``nid`` breaks
+    ties the way a scan of ``PrefixCache._nodes`` with a strict ``<``
+    does: the node created first."""
+
+    __slots__ = ("_a", "_at")
+
+    def __init__(self):
+        self._a: List[_Node] = []
+        self._at: Dict[int, int] = {}    # nid -> index in _a
+
+    def top(self) -> Optional[_Node]:
+        return self._a[0] if self._a else None
+
+    def keep(self, node: _Node, member: bool) -> None:
+        """Make ``node`` a member (at the place its key now has) or
+        not one."""
+        if not member:
+            self.discard(node)
+            return
+        i = self._at.get(node.nid)
+        if i is None:
+            i = len(self._a)
+            self._a.append(node)
+        self._settle(node, i)
+
+    def moved(self, node: _Node) -> None:
+        """``node``'s key changed: re-place it if it is a member."""
+        i = self._at.get(node.nid)
+        if i is not None:
+            self._settle(node, i)
+
+    def discard(self, node: _Node) -> None:
+        i = self._at.pop(node.nid, None)
+        if i is None:
+            return
+        last = self._a.pop()
+        if last is not node:
+            self._settle(last, i)
+
+    def _settle(self, node: _Node, i: int) -> None:
+        """Place ``node`` starting from the hole at index ``i``: up
+        while it is older than its parent, then down while a child is
+        older than it."""
+        a, at = self._a, self._at
+        key = (node.last_used, node.nid)
+        while i:
+            up = (i - 1) >> 1
+            above = a[up]
+            if (above.last_used, above.nid) < key:
+                break
+            a[i] = above
+            at[above.nid] = i
+            i = up
+        n = len(a)
+        while True:
+            c = 2 * i + 1
+            if c >= n:
+                break
+            below = a[c]
+            if c + 1 < n:
+                right = a[c + 1]
+                if (right.last_used, right.nid) \
+                        < (below.last_used, below.nid):
+                    c += 1
+                    below = right
+            if key < (below.last_used, below.nid):
+                break
+            a[i] = below
+            at[below.nid] = i
+            i = c
+        a[i] = node
+        at[node.nid] = i
 
 
 class PrefixCache:
@@ -687,7 +787,44 @@ class PrefixCache:
     the effective cache capacity is device + host pages. Only when
     the host tier is full (or absent) does a victim drop outright;
     sustained pressure then unwinds the OLDEST host-resident leaves
-    first, exposing their parents for spilling in turn."""
+    first, exposing their parents for spilling in turn.
+
+    THE EVICTION INDEX. Which page to give back, and how many could
+    be, are answered from an index kept where the facts change, not
+    by walking the trie. A node is CACHE-ONLY when it is on the
+    device and ``pool.ref[page] == 1``, PINNED when it is on the
+    device and a slot (or a swap snapshot) holds the page too, and a
+    LEAF when it has no children. Invariants, true between any two
+    calls:
+
+    * ``_spill_lru`` holds exactly the cache-only nodes, ``_drop_lru``
+      the cache-only leaves, ``_host_leaf_lru`` the host-resident
+      leaves, each a heap on ``(last_used, nid)``: its top is the
+      node the scan ``evict_one`` used to make would have chosen
+      (least ``last_used``, the node created first among equals);
+    * ``node.pinned`` says whether the node is pinned, and
+      ``node.blocked`` counts its children that are pinned or have a
+      pinned node anywhere below them; a cache-only node is DROPPABLE
+      when ``blocked == 0`` (leaf-first dropping can reach it) and
+      SPILL-ONLY otherwise; ``_n_cache_only`` and ``_n_droppable``
+      count them.
+
+    They are restored by ``_index(node)`` after every change to a
+    node's page, tier or children (``register``, ``_drop``,
+    ``_restore_node``, the spill), by ``_touch`` where ``last_used``
+    moves (``match``, ``register``), and by ``_ref_crossed`` when the
+    pool sees a page's holders pass between 1 and 2 (``incref`` /
+    ``decref`` / ``release_slot``: the cache registers itself as the
+    pool's ``_ref_listener``: one cache a pool). So every change to
+    ``pool.ref`` must go through those pool methods. Cost: a heap move is O(log n); a pin
+    or unpin walks up only while an ancestor's verdict flips, at most
+    the chain's depth (``pages_per_slot``) and one step where the
+    parent is pinned too, which is how a slot holds a chain.
+    ``evict_one`` reads three heap tops a round and
+    ``evictable_pages`` two counters: neither grows with the trie.
+    ``evictions``, ``evict_examined`` (heap tops read choosing
+    victims plus nodes visited keeping the counts) and
+    ``evictable_queries`` are the odometers that show it."""
 
     def __init__(self, pool: PagedKVPool):
         self._pool = pool
@@ -703,7 +840,7 @@ class PrefixCache:
         #: routing signal (serving router): first-page key -> how many
         #: times ``match()`` served a chain rooted at that page. The
         #: dict is bounded by the root's live children (entries die
-        #: with their node in ``evict_one``)
+        #: with their node in ``_drop``)
         self._hits: Dict[bytes, int] = {}
         #: device page id -> owning node: the O(1) residency probe the
         #: engine's prefix-aware swap snapshot consults (tree-spec PR
@@ -712,6 +849,22 @@ class PrefixCache:
         self._by_page: Dict[int, _Node] = {}
         self._nid = itertools.count(1)
         self._tick = itertools.count()
+        # the eviction index (class doc)
+        self._spill_lru = _LRUHeap()
+        self._drop_lru = _LRUHeap()
+        self._host_leaf_lru = _LRUHeap()
+        self._lrus = (self._spill_lru, self._drop_lru,
+                      self._host_leaf_lru)
+        self._n_cache_only = 0
+        self._n_droppable = 0
+        #: odometers, cumulative since construction (the engine
+        #: publishes per-window deltas into ServingMetrics): device
+        #: pages given back, heap tops and nodes looked at to choose
+        #: them and to keep the counts, ``evictable_pages()`` calls
+        self.evictions = 0
+        self.evict_examined = 0
+        self.evictable_queries = 0
+        pool._ref_listener = self._ref_crossed
 
     def __len__(self) -> int:
         return len(self._nodes)
@@ -719,6 +872,60 @@ class PrefixCache:
     def resident(self, pid: int) -> bool:
         """Is device page ``pid`` held by a cache node right now?"""
         return int(pid) in self._by_page
+
+    # -- the eviction index (class doc) -------------------------------------
+
+    def _index(self, node: _Node) -> None:
+        """Bring the index up to date with ``node``'s own facts: its
+        pin (told to the ancestors if it changed), its count, and its
+        place in or out of the three heaps."""
+        page = node.page
+        pinned = page is not None and self._pool.ref[page] != 1
+        if pinned != node.pinned:
+            node.pinned = pinned
+            if not node.blocked:         # its own verdict just flipped
+                self._block(node.parent, 1 if pinned else -1)
+        self._count(node)
+        cache_only = page is not None and not pinned
+        leaf = not self._children[node.nid]
+        self._spill_lru.keep(node, cache_only)
+        self._drop_lru.keep(node, cache_only and leaf)
+        self._host_leaf_lru.keep(node, page is None and leaf)
+
+    def _count(self, node: _Node) -> None:
+        counted = (0 if node.page is None or node.pinned
+                   else 1 if node.blocked else 2)
+        if counted != node.counted:
+            self._n_cache_only += (counted > 0) - (node.counted > 0)
+            self._n_droppable += (counted == 2) - (node.counted == 2)
+            node.counted = counted
+
+    def _block(self, nid: int, delta: int) -> None:
+        """A child of node ``nid`` began (+1) or ceased (-1) to have a
+        pin at or below it. Walks up only while the verdict flips."""
+        crossed = 1 if delta > 0 else 0
+        while nid:
+            node = self._nodes[nid]
+            self.evict_examined += 1
+            node.blocked += delta
+            if node.blocked != crossed:
+                return                   # blocked before and after
+            self._count(node)
+            if node.pinned:
+                return                   # its own pin decides above it
+            nid = node.parent
+
+    def _touch(self, node: _Node, tick: int) -> None:
+        node.last_used = tick
+        for lru in self._lrus:
+            lru.moved(node)
+
+    def _ref_crossed(self, pid: int) -> None:
+        """The pool's ``_ref_listener``: page ``pid`` went from one
+        holder to two or back."""
+        node = self._by_page.get(int(pid))
+        if node is not None:
+            self._index(node)
 
     # -- router affinity signal ---------------------------------------------
 
@@ -765,7 +972,7 @@ class PrefixCache:
                 break
             if node.page is None and not self._restore_node(node):
                 break                    # host-resident, no device page
-            node.last_used = tick
+            self._touch(node, tick)
             if parent == 0:
                 # affinity hit counter: this chain's root page served
                 # a match (the router's "hot prefix" signal)
@@ -791,7 +998,7 @@ class PrefixCache:
                 and not self._restore_node(donor):
             donor = None                 # spilled donor, pool full
         if donor is not None:
-            donor.last_used = tick
+            self._touch(donor, tick)
             return pages, pos + best, donor.page
         return pages, pos, None
 
@@ -809,6 +1016,7 @@ class PrefixCache:
         node.host = None
         node.page = pid
         self._by_page[pid] = node
+        self._index(node)
         return True
 
     def register(self, tokens, table_row) -> int:
@@ -842,6 +1050,7 @@ class PrefixCache:
                     self._by_page[pid] = node
                     pool.free_host([node.host])
                     node.host = None
+                    self._index(node)
             if node is None:
                 pid = int(table_row[j])
                 if pid >= pool.num_pages:
@@ -855,7 +1064,10 @@ class PrefixCache:
                 pool.incref(pid)
                 self._by_page[pid] = node
                 added += 1
-            node.last_used = tick
+                self._index(node)
+                if parent and len(ch) == 1:      # no longer a leaf
+                    self._index(self._nodes[parent])
+            self._touch(node, tick)
             parent = node.nid
         return added
 
@@ -876,6 +1088,15 @@ class PrefixCache:
             self._pool.decref(node.page)
         else:
             self._pool.free_host([node.host])
+        # the index: the node leaves it, its parent may be a leaf now
+        for lru in self._lrus:
+            lru.discard(node)
+        self._n_cache_only -= node.counted > 0
+        self._n_droppable -= node.counted == 2
+        if node.pinned or node.blocked:
+            self._block(node.parent, -1)
+        if node.parent and not self._children[node.parent]:
+            self._index(self._nodes[node.parent])
 
     def evict_one(self) -> bool:
         """Free ONE device page held only by the cache. With a pool
@@ -891,21 +1112,11 @@ class PrefixCache:
         slot)."""
         pool = self._pool
         while True:
-            spill = drop = host_leaf = None
-            for node in self._nodes.values():
-                leaf = not self._children.get(node.nid)
-                if node.page is None:
-                    if leaf and (host_leaf is None or
-                                 node.last_used < host_leaf.last_used):
-                        host_leaf = node
-                    continue
-                if pool.ref[node.page] != 1:
-                    continue                      # a slot still reads it
-                if spill is None or node.last_used < spill.last_used:
-                    spill = node
-                if leaf and (drop is None
-                             or node.last_used < drop.last_used):
-                    drop = node
+            # the three choices, each the top of its heap (class doc)
+            spill = self._spill_lru.top()
+            drop = self._drop_lru.top()
+            host_leaf = self._host_leaf_lru.top()
+            self.evict_examined += 3
             if spill is not None and pool.host_free_pages > 0:
                 hids = pool.offload_pages([spill.page])
                 if hids is not None:
@@ -913,9 +1124,12 @@ class PrefixCache:
                     pool.decref(spill.page)
                     spill.page = None
                     spill.host = hids[0]
+                    self._index(spill)
+                    self.evictions += 1
                     return True
             if drop is not None:
                 self._drop(drop)
+                self.evictions += 1
                 return True
             if spill is None or host_leaf is None:
                 # no device page to free at all (spill is None: the
@@ -942,29 +1156,10 @@ class PrefixCache:
         host pool's free capacity. Callers check this BEFORE
         reclaiming toward a target — a reclaim that cannot reach its
         goal would drain the whole reusable cache for nothing."""
-        memo: Dict[int, bool] = {}
-
-        def ok(nid: int) -> bool:
-            got = memo.get(nid)
-            if got is not None:
-                return got
-            node = self._nodes[nid]
-            memo[nid] = res = (
-                (node.page is None
-                 or self._pool.ref[node.page] == 1)
-                and all(ok(c.nid)
-                        for c in self._children.get(nid, {}).values()))
-            return res
-
-        droppable = spill_only = 0
-        for node in self._nodes.values():
-            if node.page is None or self._pool.ref[node.page] != 1:
-                continue
-            if ok(node.nid):
-                droppable += 1
-            else:
-                spill_only += 1
-        return droppable + min(spill_only, self._pool.host_free_pages)
+        self.evictable_queries += 1
+        spill_only = self._n_cache_only - self._n_droppable
+        return self._n_droppable + min(spill_only,
+                                       self._pool.host_free_pages)
 
     def reclaim(self, n_pages: int) -> int:
         """Evict until ``n_pages`` pages were freed (or nothing more is

@@ -95,6 +95,15 @@ class ServingMetrics:
             "serving.prefix_hit_tokens")
         self._prefix_lookup_toks = self.registry.counter(
             "serving.prefix_lookup_tokens")
+        # the prefix cache's eviction index (PrefixCache's class doc):
+        # pages it gave back, heap tops and nodes it looked at to
+        # choose them and keep its counts, evictable_pages() calls
+        self._prefix_evictions = self.registry.counter(
+            "serving.prefix_evictions")
+        self._prefix_evict_examined = self.registry.counter(
+            "serving.prefix_evict_examined")
+        self._prefix_evictable_queries = self.registry.counter(
+            "serving.prefix_evictable_queries")
         self._preempted = self.registry.counter(
             "serving.requests_preempted")
         # host KV offload tier (offload PR): pages swapped D2H on
@@ -257,6 +266,15 @@ class ServingMetrics:
         if hit_tokens > 0:
             self._prefix_hits.inc()
             self._prefix_hit_toks.inc(int(hit_tokens))
+
+    def record_prefix_eviction(self, evictions: int, examined: int,
+                               queries: int) -> None:
+        """The prefix cache's eviction work since the last flush (the
+        engine publishes per-window DELTAS of the cache's cumulative
+        odometers, as for the host tier's)."""
+        self._prefix_evictions.inc(int(evictions))
+        self._prefix_evict_examined.inc(int(examined))
+        self._prefix_evictable_queries.inc(int(queries))
 
     def record_pages(self, free: int, shared: int,
                      fragmentation: float) -> None:
@@ -577,7 +595,14 @@ class ServingMetrics:
             "prefix_cache": {
                 "lookups": int(self._prefix_lookups.value()),
                 "hits": int(self._prefix_hits.value()),
-                "hit_rate": self.prefix_hit_rate},
+                "hit_rate": self.prefix_hit_rate,
+                # keys ADDED with the eviction index: examined per
+                # eviction stays in single digits at any trie size
+                "evictions": int(self._prefix_evictions.value()),
+                "evict_examined": int(
+                    self._prefix_evict_examined.value()),
+                "evictable_queries": int(
+                    self._prefix_evictable_queries.value())},
             # speculative decoding (keys ADDED by the spec-decode PR):
             # aggregate acceptance plus the per-slot-per-iteration
             # acceptance-rate percentiles bench records
