@@ -686,12 +686,11 @@ def bench_serving(num_slots: int, prompt_len: int, new_tokens: int,
                 f"ramp outlasted the slot capacity (max_len={max_len}, "
                 f"prompt_len={prompt_len}, chunk={prefill_chunk})")
         # greedy variant: the trace's requests are greedy, so this is
-        # the exact program the engine's own iterations run (paged
-        # engines pass their page tables; steps past the allocated
-        # pages drop their writes, which costs the same scatter)
+        # the exact program the engine's own iterations run (steps
+        # past the allocated pages drop their writes, which costs the
+        # same scatter)
         fn = probe._decode_fn(True)
-        extra = (probe.pool.device_tables(),) \
-            if probe.kv_layout == "paged" else ()
+        tables = probe.pool.device_tables()
         tok, t = probe._tok.copy(), probe._t.copy()
         # stay inside every slot's cache range (prefill serialization
         # already consumed a few decode steps on the earliest slots) —
@@ -709,7 +708,7 @@ def bench_serving(num_slots: int, prompt_len: int, new_tokens: int,
             # prefills into deleted buffers
             nxt, probe.pool.cache, _moe = fn(
                 probe._params, probe._state, probe.pool.cache, tok, t,
-                *extra)
+                tables)
             tok = np.asarray(nxt)
             t = t + 1
         rate = num_slots * steps / (time.perf_counter() - t0)
@@ -836,161 +835,6 @@ def bench_loadgen(scale: float, num_slots: int, max_len: int,
                       == scenario_report.to_json(rep2))
     paths = scenario_report.save_report(rep1, out_dir)
     return rep1, paths, trace_path, deterministic
-
-
-def bench_paged_vs_slab(slab_slots: int, prompt_len: int,
-                        new_tokens: int, n_requests: int, page_len: int,
-                        prefix_frac: float, n_passes: int,
-                        slot_mult: int = 4, max_len_factor: int = 3,
-                        cfg=None):
-    """Paged vs slab KV cache at EQUAL HBM budget (the paged-cache
-    PR's acceptance bench): the slab engine gets ``slab_slots`` worst-
-    case ``[max_len]`` rows; the paged engine gets the SAME token
-    capacity as pages (``slab_slots * ceil(max_len/page_len)``) but
-    ``slot_mult``x the decode-batch slots — admission is page-budget
-    bound, so extra concurrency materializes exactly when real
-    lengths/prefix sharing leave pages free.
-
-    ``max_len_factor`` models the production provisioning gap the slab
-    layout dies on: the service's ``max_len`` contract is
-    ``factor * (prompt + new)`` while the TYPICAL request (what this
-    trace submits) uses ``1/factor`` of it. The slab pool must reserve
-    the contract per slot; the paged pool packs actual lengths, so the
-    same HBM carries ~``factor``x the streams (times the
-    prefix-sharing discount) — exactly ROADMAP item 2's memory →
-    throughput conversion.
-
-    Two open-loop workloads, same seeded arrival trace offered to both
-    engines at ~4x the measured slab decode capacity (both saturate;
-    sustained req/s is capacity, not load):
-
-      * ``prefix_heavy`` — every prompt = one shared template
-        (``prefix_frac`` of the prompt) + a unique tail, the
-        production system-prompt shape prefix caching exists for;
-      * ``prefix_free`` — fully random prompts (sharing never fires;
-        this isolates the packing win from the caching win).
-
-    Returns ``{workload: {paged_req_s, slab_req_s, ratio,
-    prefix_hit_rate, preemptions}}`` with per-pass lists riding along.
-    """
-    from distkeras_tpu.models import Model, zoo
-    from distkeras_tpu.serving import ServingEngine, ServingMetrics
-
-    cfg = cfg or LM_CFG
-    model = Model.build(zoo.transformer_lm(
-        cfg["vocab"], d_model=cfg["d_model"], num_heads=cfg["num_heads"],
-        num_layers=cfg["num_layers"], mlp_ratio=cfg["mlp_ratio"],
-        use_rope=True, dtype="bfloat16"), (cfg["seq"],), seed=0)
-    max_len = int(max_len_factor) * (prompt_len + new_tokens)
-    pages_per = -(-max_len // page_len)
-    num_pages = slab_slots * pages_per           # the equal-HBM budget
-    rs = np.random.RandomState(0)
-    shared_len = int(prefix_frac * prompt_len)
-    template = rs.randint(0, cfg["vocab"], (shared_len,)).astype(np.int32)
-
-    def make_prompts(kind):
-        if kind == "prefix_heavy":
-            return [np.concatenate([
-                template,
-                rs.randint(0, cfg["vocab"],
-                           (prompt_len - shared_len,)).astype(np.int32)])
-                for _ in range(n_requests)]
-        return [rs.randint(0, cfg["vocab"], (prompt_len,))
-                .astype(np.int32) for _ in range(n_requests)]
-
-    def build(layout):
-        if layout == "paged":
-            # page-granular prefix matching: partial-match lengths are
-            # data-dependent, and every distinct length would compile a
-            # novel ragged prefill program INSIDE the timed drive
-            return ServingEngine(model, num_slots=slab_slots * slot_mult,
-                                 max_len=max_len, page_len=page_len,
-                                 num_pages=num_pages,
-                                 prefix_granularity=page_len)
-        return ServingEngine(model, num_slots=slab_slots,
-                             max_len=max_len, kv_layout="slab")
-
-    # arrival rate from the SLAB engine's measured decode cadence (the
-    # baseline's capacity), identical trace offered to both layouts
-    probe = build("slab")
-    probe.submit(rs.randint(0, cfg["vocab"], (prompt_len,))
-                 .astype(np.int32), new_tokens)
-    probe.run(max_steps=100_000)
-    warm = [dt for _, dt in probe.metrics.decode_samples[1:]]
-    step_dt = statistics.median(warm) if warm else 1e-3
-    # offered WELL past both engines' capacity (8x the slab's decode
-    # rate): sustained req/s then measures capacity, not the trace
-    mean_ia = step_dt * new_tokens / (8.0 * slab_slots)
-
-    def drive(eng, prompts, arrivals):
-        eng.metrics = ServingMetrics()
-        t0 = time.perf_counter()
-        j = 0
-        while j < n_requests or eng.scheduler.pending:
-            now = time.perf_counter() - t0
-            while j < n_requests and arrivals[j] <= now:
-                eng.submit(prompts[j], new_tokens)
-                j += 1
-            if eng.scheduler.pending:
-                eng.step()
-            elif j < n_requests:               # open-loop idle gap
-                time.sleep(min(arrivals[j] - now, 1e-3))
-        makespan = time.perf_counter() - t0
-        return n_requests / makespan, eng.metrics
-
-    out = {}
-    # ONE warmed engine pair reused across BOTH workloads (bench
-    # hygiene, spec-decode PR): rebuilding per trace variant re-paid
-    # every prefill/insert/decode compile on the second workload
-    engines = {"paged": build("paged"), "slab": build("slab")}
-    for kind in ("prefix_heavy", "prefix_free"):
-        prompts = make_prompts(kind)
-        # warm both OUTSIDE the timed passes with two representative
-        # requests: the second one exercises the prefix-hit path on
-        # the paged engine (registered pages from the first), so the
-        # ragged-resume prefill and page-load programs compile here,
-        # not inside a timed drive (a formality after the first
-        # workload — the programs are already live)
-        for eng in engines.values():
-            for p in prompts[:2]:
-                eng.submit(p, new_tokens)
-                eng.run(max_steps=100_000)
-        rates = {"paged": [], "slab": []}
-        hit_rates, preemptions = [], []
-        for i in range(n_passes):
-            arrivals = np.cumsum(
-                rs.exponential(mean_ia, size=n_requests))
-            for layout, eng in engines.items():
-                r, m = drive(eng, prompts, arrivals)
-                rates[layout].append(r)
-                if layout == "paged":
-                    hit_rates.append(m.prefix_hit_rate)
-                    preemptions.append(m.requests_preempted)
-            print(f"{kind} pass {i}: paged {rates['paged'][-1]:.2f} "
-                  f"req/s vs slab {rates['slab'][-1]:.2f} req/s "
-                  f"({rates['paged'][-1] / rates['slab'][-1]:.2f}x)",
-                  file=sys.stderr, flush=True)
-        paged_med = statistics.median(rates["paged"])
-        slab_med = statistics.median(rates["slab"])
-        out[kind] = {
-            "paged_req_s": round(paged_med, 3),
-            "slab_req_s": round(slab_med, 3),
-            "ratio": round(paged_med / slab_med, 3),
-            "paged_passes": [round(r, 3) for r in rates["paged"]],
-            "slab_passes": [round(r, 3) for r in rates["slab"]],
-            "prefix_hit_rate": (
-                None if not hit_rates or hit_rates[-1] is None
-                else round(hit_rates[-1], 3)),
-            "preemptions": int(sum(preemptions)),
-        }
-        # drain the prefix cache between workloads (all requests have
-        # finished, so every registered page is cache-only and
-        # evictable): the next kind starts with a clean page budget
-        # instead of the previous kind's resident template pages
-        if engines["paged"].prefix is not None:
-            engines["paged"].prefix.reclaim(
-                engines["paged"].pool.num_pages)
-    return out
 
 
 def bench_paged_kernel(num_slots: int, seq_len: int, page_len: int,
@@ -1638,8 +1482,8 @@ def bench_serving_router(num_slots: int, prompt_len: int,
     num_pages = num_slots * priv + (shared // page_len) + 2
 
     def build(eid):
-        # page-granular partial matching: same compile-hazard hygiene
-        # as bench_paged_vs_slab (no novel ragged programs mid-drive)
+        # page-granular partial matching: no novel ragged programs
+        # mid-drive
         return ServingEngine(model, num_slots=num_slots,
                              max_len=max_len, page_len=page_len,
                              num_pages=num_pages,
@@ -2989,49 +2833,9 @@ def _run_mode(mode, args, on_accel, peak, device_kind):
         rates, raws, summaries, slo_statuses, trace_path = bench_serving(
             num_slots, prompt_len, new_tokens, n_requests, n_passes,
             prefill_chunk=chunk)
-        # paged-vs-slab at equal HBM (paged-cache PR): its own record
-        # line + tripwire rider; acceptance >= 2x on the prefix-heavy
-        # trace on accelerators, >= 1.0x recorded on the CPU smoke
-        if on_accel:
-            pvs_args = dict(slab_slots=4, prompt_len=192, new_tokens=64,
-                            n_requests=32, page_len=16,
-                            prefix_frac=0.75, n_passes=3, slot_mult=4,
-                            max_len_factor=4)
-        else:
-            pvs_args = dict(slab_slots=2, prompt_len=12, new_tokens=6,
-                            n_requests=10, page_len=4,
-                            prefix_frac=0.75, n_passes=1, slot_mult=3,
-                            max_len_factor=3)
-        try:
-            pvs = bench_paged_vs_slab(**pvs_args)
-            heavy = pvs["prefix_heavy"]
-            _emit({
-                "metric": "serving_paged_vs_slab_req_per_sec",
-                "value": heavy["paged_req_s"],
-                "unit": "req/sec",
-                # the acceptance ratio: sustained paged req/s over the
-                # slab engine's at the SAME page/slab HBM budget on the
-                # prefix-heavy open-loop trace (>= 2.0 on accelerators;
-                # the below-anchor tripwire flags < 0.9)
-                "vs_baseline": heavy["ratio"],
-                "prefix_heavy": heavy,
-                "prefix_free": pvs["prefix_free"],
-                "criterion": "paged sustains >= 2x slab requests at "
-                             "equal HBM on the prefix-heavy trace "
-                             "(CPU smoke: >= 1.0x recorded)",
-                "note": "same seeded open-loop exponential trace "
-                        "offered to both engines at ~4x slab decode "
-                        "capacity; paged gets slot_mult x the slots "
-                        "but the identical token capacity in pages",
-                **{k: v for k, v in pvs_args.items()},
-                "device_kind": device_kind,
-            })
-        except Exception:
-            traceback.print_exc(file=sys.stderr)
         # decode-kernel rider (decode-kernel PR): paged step time with
         # the page-table Pallas kernel vs the _gather_pages reference.
-        # On accelerators vs_baseline is the measured step speedup
-        # (the >= 2x paged-vs-slab accelerator target leans on it); on
+        # On accelerators vs_baseline is the measured step speedup; on
         # the CPU smoke the kernel only exists interpreted, so the
         # rider records the gather rate with an interpret-mode
         # numerical identity check and ratio 1.0.

@@ -97,7 +97,7 @@ def init_cache(module: Sequential, batch: int, max_len: int,
 
     ``dtype="int4"`` (this PR) extends the ladder one more rung: entries
     quantize to 4-bit symmetric (``scale = max|x| / 7``). In THIS
-    unpacked request/slab cache the payload still occupies one int8 byte
+    unpacked contiguous cache the payload still occupies one int8 byte
     per entry holding a value in [-7, 7] — the dequant contract
     (``q * scale``) is byte-for-byte the int8 contract, so every cache
     read path is shared verbatim; the 2x byte saving is realized where
@@ -140,8 +140,8 @@ def init_cache(module: Sequential, batch: int, max_len: int,
                     "v_scale": jnp.zeros(shape[:3], jnp.float32)}
                 if int4:
                     # structural marker, not data: 4-dim so every
-                    # blind cache tree_map (slab row insert/slice,
-                    # offload gather/scatter) stays shape-compatible
+                    # blind cache tree_map (row slices, offload
+                    # gather/scatter) stays shape-compatible
                     kv["q4"] = jnp.zeros((1, 1, 1, 1), jnp.int8)
                 cache.append(kv)
             else:
@@ -710,11 +710,13 @@ def decode_step(module: Sequential, params, state, cache, tok, t):
     return x[:, 0], new_cache                            # [B, V]
 
 
-# --- slot-level decode (serving engine, this PR) ---------------------------
+# --- slot-level decode ------------------------------------------------------
 #
 # Continuous batching runs ONE compiled step over a fixed pool of S slots
 # whose sequences are at DIFFERENT positions: ``t`` becomes a [S] vector.
-# The per-slot variants below mirror the scalar-``t`` functions exactly —
+# The engine runs the PAGED steps further down; the contiguous-cache
+# steps here are the reference the tests compare them with. The per-slot
+# variants below mirror the scalar-``t`` functions exactly —
 # same projections, same storage-dtype contractions — with three changes:
 # the cache write selects each slot's own position (a one-hot select, so a
 # slot whose ``t`` is out of range, the engine's free-slot sentinel,
@@ -786,7 +788,8 @@ def _cache_write_slots(kv, k, v, t):
     """Write one [S, 1, H, D] k/v decode slab at PER-SLOT positions
     ``t`` ([S] int) into the head-major [S, H, L, D] cache. Slot ``s``
     writes position ``t[s]``; ``t[s] >= L`` (the engine's free/prefilling
-    sentinel) writes nothing."""
+    sentinel) writes nothing. Contiguous-cache reference; the engine
+    does not call this."""
     kh = k.transpose(0, 2, 1, 3)                         # [S, H, 1, D]
     vh = v.transpose(0, 2, 1, 3)
     L = kv["k"].shape[2]
@@ -876,10 +879,10 @@ def _attn_out(p, out, dt):
 def _slot_attn_readout(attn: MultiHeadAttention, p, q, kv, t, dt,
                        tree=None, full_window: bool = False):
     """Masked per-slot attention of the projected decode queries against
-    a logically contiguous ``[S, H, L, D]`` kv view — a slab pool or a
-    page gather in logical-position order — plus the output projection.
-    Shared by the slab and paged decode paths so the two are bitwise
-    identical wherever the view holds identical values.
+    a logically contiguous ``[S, H, L, D]`` kv view — a contiguous cache
+    or a page gather in logical-position order — plus the output projection.
+    Shared by the reference and the paged gather path so the two are
+    bitwise identical wherever the view holds identical values.
 
     ``q`` is ``[S, W, H, D]`` for a W-position window at per-slot
     positions ``t .. t+W-1`` (the speculative-verify step; W = 1 is the
@@ -911,9 +914,10 @@ def _slot_attn_readout(attn: MultiHeadAttention, p, q, kv, t, dt,
 
 
 def _decode_attn_slots(attn: MultiHeadAttention, p, kv, x, t):
-    """One-token attention against the pooled cache at per-slot
+    """One-token attention against the contiguous cache at per-slot
     positions. x: [S, 1, d]; t: [S]. The einsum/storage-dtype path of
-    ``_decode_attn`` with a [S, L] validity mask."""
+    ``_decode_attn`` with a [S, L] validity mask. Contiguous-cache
+    reference; the engine does not call this."""
     dt = jnp.dtype(attn.dtype)
     xc = x.astype(dt)
     q, k, v = _project_qkv(attn, p, xc)
@@ -927,6 +931,7 @@ def _decode_attn_slots(attn: MultiHeadAttention, p, kv, x, t):
 
 def _decode_block_slots(block: TransformerBlock, p, s, kv, x, t,
                         moe_dispatched=True, routing=None):
+    """Contiguous-cache reference; the engine does not call this."""
     with jax.named_scope("attn"):
         h, _ = block.norm1.apply(p["norm1"], s["norm1"], x)
         a, kv = _decode_attn_slots(block.attn, p["attn"], kv, h, t)
@@ -940,7 +945,9 @@ def _decode_block_slots(block: TransformerBlock, p, s, kv, x, t,
 
 def decode_step_slots(module: Sequential, params, state, cache, tok, t,
                       *, moe_dispatched: bool = True, moe_stats=None):
-    """One token through the stack at PER-SLOT positions: tok [S] int,
+    """Contiguous-cache reference; the engine does not call this.
+
+    One token through the stack at PER-SLOT positions: tok [S] int,
     t [S] int; returns ([S, V] logits, cache). Slots whose ``t`` is out
     of cache range (the serving engine's free-slot sentinel) produce
     garbage logits and write nothing — the engine discards them
@@ -982,9 +989,9 @@ def decode_step_slots(module: Sequential, params, state, cache, tok, t,
 # marks an unallocated logical page). The decode step is ONE compiled
 # program regardless of which pages a slot owns: the table is a traced
 # argument, writes scatter through it (out-of-range drops, so the
-# free-slot position sentinel writes nothing, exactly like the slab
+# free-slot position sentinel writes nothing, exactly like the reference's
 # one-hot write), and reads gather the slot's pages back into the same
-# logically contiguous [S, H, L, D] view the slab step consumes — the
+# logically contiguous [S, H, L, D] view the reference step consumes — the
 # shared ``_slot_attn_readout`` epilogue then makes the two paths
 # bitwise identical wherever the views hold identical values.
 
@@ -1091,14 +1098,14 @@ def _gather_pages(kv, table):
     into a logically contiguous [S, H, P*page_len, D] cache (scale
     planes [S, H, P*page_len]). Sentinel table entries clamp to the
     last physical page — harmless garbage, masked by the ``<= t``
-    validity mask exactly like a slab row's stale tail."""
+    validity mask exactly like a contiguous row's stale tail."""
     out = {}
     for key in ("k", "v"):
         pg = kv[key][table]                  # [S, P, H, page_len, D]
         if "q4" in kv:
             # packed int4 pages gather as [S, P, H, page_len//2, D]
             # bytes; unpacking along the page-position axis restores
-            # the unpacked int4-valued int8 plane the shared slab
+            # the unpacked int4-valued int8 plane the shared
             # readout dequantizes (q * scale — same contract as int8)
             pg = unpack_int4(pg)
         s, p, h, pl, d = pg.shape
@@ -1139,7 +1146,7 @@ def _paged_attn_readout(attn: MultiHeadAttention, p, q, kv, t, table,
     """Readout for the paged decode/verify paths: the Pallas
     paged-attention kernel (K/V gathered HBM -> VMEM through the page
     table inside the kernel — no materialized [S, H, L, D] view) when
-    enabled, else ``_gather_pages`` + the shared slab readout (the
+    enabled, else ``_gather_pages`` + the shared readout (the
     off-TPU/interpret fallback and the kernel's oracle). ``tree``
     forwards the ancestor-mask window (tree-speculation PR) — the
     kernel takes the ``[S, W, W]`` mask as an operand; the gather path
@@ -1250,10 +1257,10 @@ def decode_step_slots_paged(module: Sequential, params, state, cache,
 # distribution AFTER consuming window token j, so the longest prefix of
 # drafts matching the target's own choices is accepted and the
 # (m+1)-th candidate comes free — between 1 and k+1 tokens per target
-# pass. Cache contract: every window position's K/V is written (slab
-# one-hot / page-table scatter, same sentinels as the 1-token steps);
+# pass. Cache contract: every window position's K/V is written
+# (page-table scatter, same sentinels as the 1-token steps);
 # positions past the accepted count hold rejected-draft garbage, which
-# is EXACTLY the slab stale-tail situation — masked (`<= t + j`) until
+# is EXACTLY the stale-tail situation — masked (`<= t + j`) until
 # the stream's own later writes overwrite them, position by position,
 # before the mask ever admits them. No explicit rollback needed; an
 # unallocated page simply drops the write (the engine only lets a slot
@@ -1267,9 +1274,9 @@ def _decode_block_slots_window(block: TransformerBlock, p, s, kv, x, t,
                                kv_out=None, kv_only: bool = False):
     """One TransformerBlock over a [S, W, d] window at per-slot
     positions ``t .. t+W-1``: project the window's q/k/v, write ALL W
-    positions into the cache (slab one-hot writes, or page-table
-    scatters when ``table`` is given), then run the shared windowed
-    readout.
+    positions into the cache (page-table scatters; one-hot writes
+    into a contiguous cache when ``table`` is None, the reference
+    :func:`verify_step_slots`), then run the shared windowed readout.
 
     ``tree`` (tree-speculation PR): rope each node at its ROOT-PATH
     position ``t + depth[j]`` (that is where it lands if accepted —
@@ -1377,7 +1384,9 @@ def _verify_window(module: Sequential, params, state, cache, toks, t,
 def verify_step_slots(module: Sequential, params, state, cache, toks, t,
                       *, moe_dispatched: bool = True, moe_stats=None,
                       tree=None):
-    """Batched speculative VERIFY against the slab pool: toks [S, W]
+    """Contiguous-cache reference; the engine does not call this.
+
+    Batched speculative VERIFY against a contiguous cache: toks [S, W]
     int (window token 0 is the slot's pending decode input, tokens
     1..W-1 its drafts), t [S] int per-slot window start positions;
     returns ([S, W, V] logits, cache). ``logits[:, j]`` is the target
@@ -1584,11 +1593,12 @@ def commit_tree_path(cache, kv_win, path, t, n_emit, table=None,
     accepted node at depth d belongs at ``t + d`` (and was roped
     there — ``depth[path[d]] == d`` by construction). This pass
     gathers each layer's window k/v along ``path`` and re-writes
-    depths ``0 .. n_emit-1`` through the established slot/page
-    writers; depths past the accepted path route to an out-of-range
-    position, where the one-hot write misses and the page scatter
-    drops — rejected branches stay exactly the stale-tail garbage the
-    masks already cover, healed by the stream's own later writes.
+    depths ``0 .. n_emit-1`` through the page writer (``table`` None:
+    the tests' contiguous reference); depths past the accepted path
+    route to an out-of-range position, where the one-hot write misses
+    and the page scatter drops — rejected branches stay exactly the
+    stale-tail garbage the masks already cover, healed by the stream's
+    own later writes.
     Chain-shaped trees re-write identical bytes (the accepted node AT
     depth d IS window column d), so a width-1 tree's cache equals the
     linear verify's bit for bit."""
@@ -1635,12 +1645,12 @@ def commit_tree_path(cache, kv_win, path, t, n_emit, table=None,
 
 
 def decode_fused_slots(module: Sequential, params, state, cache, tok, t,
-                       stop, num_steps: int, table=None,
-                       page_len: int = 0, *, temperature=None,
+                       stop, num_steps: int, table,
+                       page_len: int, *, temperature=None,
                        top_k=None, top_p=None, keys=None,
                        moe_dispatched: bool = True, moe_stats=None,
                        paged_kernel=None, sampler=None):
-    """``num_steps`` consecutive ``decode_step_slots[_paged]``
+    """``num_steps`` consecutive ``decode_step_slots_paged``
     iterations as one compiled scan. tok/t: [S] ints (per-slot pending
     input and write position); ``stop``: [S] int per-slot stop tokens
     (-1 = never). Greedy when ``temperature`` is None; otherwise
@@ -1670,15 +1680,11 @@ def decode_fused_slots(module: Sequential, params, state, cache, tok, t,
             cache, cur, tcur, done = carry
         else:
             cache, cur, tcur, done, ks = carry
-        kw = dict(moe_dispatched=moe_dispatched, moe_stats=moe_stats)
-        if table is not None:
-            out = decode_step_slots_paged(module, params, state, cache,
-                                          cur, tcur, table, page_len,
-                                          paged_kernel=paged_kernel,
-                                          **kw)
-        else:
-            out = decode_step_slots(module, params, state, cache, cur,
-                                    tcur, **kw)
+        out = decode_step_slots_paged(module, params, state, cache,
+                                      cur, tcur, table, page_len,
+                                      paged_kernel=paged_kernel,
+                                      moe_dispatched=moe_dispatched,
+                                      moe_stats=moe_stats)
         if stats_on:
             logits, cache, st = out
         else:

@@ -7,9 +7,7 @@ tables, but the decode step still paid one large HBM round trip per
 iteration: ``models.decoding._gather_pages`` gathered each slot's
 pages back into a logically contiguous ``[S, H, L, D]`` view in HBM
 before ``_slot_attn_readout`` ran — writing AND re-reading the whole
-resident working set every step, which is why the equal-HBM
-paged-vs-slab bench sat at ~1.4x instead of the >= 2x accelerator
-target (ROADMAP item 3a).
+resident working set every step (ROADMAP item 3a).
 
 This kernel removes that copy. The grid is ``(S, P)`` — one program
 per (slot, logical page) — and the PAGE TABLE IS THE INDEX MAP: the
@@ -17,7 +15,7 @@ k/v BlockSpecs look up ``table[s, p]`` from the scalar-prefetch
 operand and DMA the physical page HBM -> VMEM directly. Scores,
 masking, online softmax and the value mix all happen on that one
 streaming read; nothing intermediate ever touches HBM. Structure
-mirrors the proven slab-decode kernel (``ops.decode_attention``):
+mirrors the contiguous-cache decode kernel (``ops.decode_attention``):
 per-program state in VMEM scratch carried across the ``arbitrary``
 page dimension, init at page 0, finalize at the last page, Hkv heads
 unrolled inside the program so per-program DMA amortizes.

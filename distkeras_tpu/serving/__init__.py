@@ -14,8 +14,7 @@ per-step decode kernels and an actual serving workload:
                    preemption into a swap and multiply prefix-cache
                    capacity — plus ``PrefixCache`` (hash-consed
                    shared prompt prefixes, copy-on-write partial
-                   pages, spill-to-host eviction) and the legacy
-                   slab ``KVPool``
+                   pages, spill-to-host eviction)
     scheduler.py   admission queue + per-request state machine
                    (queued -> prefilling -> decoding -> finished) with
                    slot allocation/release; ``PriorityScheduler`` adds
@@ -40,7 +39,7 @@ per-step decode kernels and an actual serving workload:
                    self-drafting, zero extra weights) and
                    ``DraftModel`` (a small LM with its own paged KV) —
                    verified k-at-a-time by one batched target pass
-                   (``models.decoding.verify_step_slots[_paged]``),
+                   (``models.decoding.verify_step_slots_paged``),
                    linearly or as per-slot token TREES
                    (``propose_tree`` + the ancestor-mask window,
                    ``ServingEngine(spec_tree=)``)
@@ -83,8 +82,8 @@ from distkeras_tpu.serving.loadgen import (ChaosSpec,  # noqa: F401
                                            diurnal_burst_scenario,
                                            flash_crowd_chaos_scenario,
                                            replay, synthesize)
-from distkeras_tpu.serving.kv_pool import (KVPool,  # noqa: F401
-                                           PagedKVPool, PrefixCache)
+from distkeras_tpu.serving.kv_pool import (PagedKVPool,  # noqa: F401
+                                           PrefixCache)
 from distkeras_tpu.serving.metrics import ServingMetrics  # noqa: F401
 from distkeras_tpu.serving.scheduler import (AdmissionRejected,  # noqa: F401
                                              FIFOScheduler,

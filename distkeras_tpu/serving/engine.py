@@ -3,10 +3,10 @@
 The Orca/vLLM iteration-level serving pattern on this repo's
 prefill/decode machinery:
 
-  * A paged KV cache (``kv_pool.PagedKVPool``, the default
-    ``kv_layout="paged"``) stays resident on device: fixed-size pages
-    allocated on demand per request, per-slot page tables driving one
-    compiled ``decode_step_slots_paged`` over ALL slots — shapes are
+  * A paged KV cache (``kv_pool.PagedKVPool``) stays resident on
+    device: fixed-size pages allocated on demand per request, per-slot
+    page tables driving one compiled
+    ``decode_step_slots_paged`` over ALL slots — shapes are
     static (the table is a traced argument), the jit compiles once per
     engine per sampler variant (argmax-only for all-greedy batches,
     the full per-slot sampler for mixed ones), and requests at
@@ -19,10 +19,7 @@ prefill/decode machinery:
     ``prefill_chunk_step``, token-identically. Identical prompt
     prefixes hash-cons onto shared read-only pages
     (``kv_pool.PrefixCache``): prefill skips the shared positions, a
-    partially matched page is served copy-on-write. The legacy slab
-    pool (``kv_layout="slab"``: one ``[S, max_len]`` row per slot,
-    FIFO admission, no preemption) remains for comparison — the paged
-    data plane is benched against it at equal HBM in ``bench.py``.
+    partially matched page is served copy-on-write.
   * Requests admit into free slots; a new request's prompt prefills
     into a batch-1 staging cache — chunked (``prefill_chunk``), one
     chunk per engine iteration, interleaved between decode steps so a
@@ -109,21 +106,17 @@ from distkeras_tpu.models.decoding import (_attn_compute_dtype,
                                            _sample_vec, _serving_params,
                                            commit_tree_path,
                                            decode_fused_slots,
-                                           decode_step_slots,
                                            decode_step_slots_paged,
                                            prefill, prefill_chunk_step,
                                            routing_counts, tree_walk,
-                                           verify_step_slots,
                                            verify_step_slots_paged)
 from distkeras_tpu.models.moe import MoE
 from distkeras_tpu.resilience import faults
-from distkeras_tpu.serving.kv_pool import (KVPool, PagedKVPool,
-                                           PrefixCache)
+from distkeras_tpu.serving.kv_pool import PagedKVPool, PrefixCache
 from distkeras_tpu.serving.speculation import (DraftSource,
                                                tree_ancestors)
 from distkeras_tpu.serving.metrics import ServingMetrics
 from distkeras_tpu.serving.scheduler import (AdmissionRejected,
-                                             FIFOScheduler,
                                              PriorityScheduler, Request,
                                              RequestState,
                                              TERMINAL_STATES)
@@ -190,7 +183,7 @@ class ServingEngine:
     ``max_len`` is the per-request cache capacity: every request needs
     ``len(prompt) + max_new_tokens <= max_len``.
 
-    Paged-cache knobs (``kv_layout="paged"``, the default):
+    Paged-cache knobs:
 
     * ``page_len`` — positions per KV page. Smaller pages waste less
       tail (fragmentation is < ``page_len`` positions per request) and
@@ -198,8 +191,8 @@ class ServingEngine:
       table entries and scatter/gather indices. 16 is the vLLM-era
       sweet spot for the einsum path (docs/serving.md §Paged KV).
     * ``num_pages`` — the HBM budget, in pages. Default
-      ``num_slots * ceil(max_len / page_len)`` (worst-case capacity
-      parity with the slab pool); size it DOWN to actual traffic and
+      ``num_slots * ceil(max_len / page_len)`` (every slot's worst
+      case at once); size it DOWN to actual traffic and
       let cost-aware admission + preemption absorb the tail.
     * ``host_kv_pages`` — the HOST page pool (offload tier, docs/
       serving.md §Host KV offload). When > 0, preemption victims swap
@@ -329,8 +322,8 @@ class ServingEngine:
     * ``mask_token`` — the id a not-yet-fixed position is fed as;
       required for a block-causal model.
 
-    Greedy only, paged pool only, no speculation, fused windows or
-    host offload; the prefix cache shares at block boundaries.
+    Greedy only, no speculation, fused windows or host offload; the
+    prefix cache shares at block boundaries.
 
     A dispatched-MoE engine also feeds MoE telemetry: per-expert load
     and router-entropy gauges (``ServingMetrics.record_moe_route``), a
@@ -350,7 +343,7 @@ class ServingEngine:
                  metrics: Optional[ServingMetrics] = None,
                  max_queue: Optional[int] = None,
                  tracer=None, slo=None,
-                 kv_layout: str = "paged", page_len: int = 16,
+                 page_len: int = 16,
                  num_pages: Optional[int] = None,
                  host_kv_pages: int = 0,
                  decode_kernel: str = "auto",
@@ -475,12 +468,12 @@ class ServingEngine:
                 raise ValueError(
                     "a block-causal model needs mask_token (the id a "
                     "not-yet-fixed position is fed as)")
-            if kv_layout != "paged" or draft is not None or fuse_steps \
+            if draft is not None or fuse_steps \
                     or host_kv_pages or self.max_len % b_len \
                     or page_len % b_len:
                 raise ValueError(
-                    "block diffusion runs on the paged pool with max_len "
-                    "and page_len whole multiples of the block length, "
+                    "block diffusion needs max_len and page_len whole "
+                    "multiples of the block length, and runs "
                     "without draft, fuse_steps or host_kv_pages")
             self.denoising_steps = steps
             self.mask_token = int(mask_token)
@@ -498,10 +491,6 @@ class ServingEngine:
         self._moe_iter = 0                       # stats-throttle counter
         self._setup_expert_parallel(ep_mesh)
 
-        if kv_layout not in ("paged", "slab"):
-            raise ValueError(
-                f"kv_layout must be 'paged' or 'slab', got {kv_layout!r}")
-        self.kv_layout = kv_layout
         # paged-attention decode kernel (decode-kernel PR): "auto" =
         # the Pallas page-table kernel on TPU, the _gather_pages
         # reference elsewhere; "paged" forces the kernel (interpreter
@@ -514,60 +503,33 @@ class ServingEngine:
         self.decode_kernel = decode_kernel
         self._paged_kernel = {"auto": None, "paged": True,
                               "off": False}[decode_kernel]
-        if kv_layout == "slab":
-            # loud-validation convention: paged-only options must not
-            # silently no-op on a slab engine
-            if host_kv_pages:
-                raise ValueError(
-                    "host_kv_pages needs kv_layout='paged' (the slab "
-                    "pool has no page-granular offload)")
-            if decode_kernel != "auto":
-                raise ValueError(
-                    "decode_kernel applies to the paged readout only; "
-                    "a slab engine always uses the einsum path")
-            if hbm_budget is not None:
-                raise ValueError(
-                    "hbm_budget needs kv_layout='paged' (the slab pool "
-                    "has no page budget to size)")
-        if kv_layout == "paged":
-            # hbm_budget sizes the page pool from a device-memory
-            # envelope: the resident WEIGHT bytes (quantized or not —
-            # this is where int4 weights + int4 KV pages compound into
-            # more admitted streams) are reserved off the top and the
-            # remainder becomes whole pages
-            reserve = (sum(np.asarray(l).nbytes for l in
-                           jax.tree_util.tree_leaves(self._params))
-                       if hbm_budget is not None else 0)
-            self.pool = PagedKVPool(module, self.num_slots, self.max_len,
-                                    page_len=page_len,
-                                    num_pages=num_pages,
-                                    host_pages=host_kv_pages,
-                                    dtype=cache_dtype,
-                                    hbm_budget=hbm_budget,
-                                    reserve_bytes=reserve)
-            self.page_len = self.pool.page_len
-            self.prefix = PrefixCache(self.pool) if prefix_cache else None
-            if prefix_granularity < 1:
-                raise ValueError(
-                    f"prefix_granularity must be >= 1, "
-                    f"got {prefix_granularity}")
-            self._prefix_granularity = int(prefix_granularity)
-            if self.block_len is not None:
-                # K/V inside a block depend on the whole block: a
-                # partial-page match is only valid in whole blocks
-                self._prefix_granularity = int(np.lcm(
-                    self._prefix_granularity, self.block_len))
-            # cost-aware scheduling: priority classes + preemption; the
-            # engine gates admission on the free-page budget below
-            scheduler = PriorityScheduler(self.num_slots,
-                                          max_queue=max_queue)
-        else:
-            self.pool = KVPool(module, self.num_slots, self.max_len,
-                               cache_dtype)
-            self.page_len = None
-            self.prefix = None
-            scheduler = FIFOScheduler(self.num_slots,
-                                      max_queue=max_queue)
+        # hbm_budget sizes the page pool from a device-memory
+        # envelope: the resident WEIGHT bytes (quantized or not —
+        # this is where int4 weights + int4 KV pages compound into
+        # more admitted streams) are reserved off the top and the
+        # remainder becomes whole pages
+        reserve = (sum(np.asarray(l).nbytes for l in
+                       jax.tree_util.tree_leaves(self._params))
+                   if hbm_budget is not None else 0)
+        self.pool = PagedKVPool(module, self.num_slots, self.max_len,
+                                page_len=page_len,
+                                num_pages=num_pages,
+                                host_pages=host_kv_pages,
+                                dtype=cache_dtype,
+                                hbm_budget=hbm_budget,
+                                reserve_bytes=reserve)
+        self.page_len = self.pool.page_len
+        self.prefix = PrefixCache(self.pool) if prefix_cache else None
+        if prefix_granularity < 1:
+            raise ValueError(
+                f"prefix_granularity must be >= 1, "
+                f"got {prefix_granularity}")
+        self._prefix_granularity = int(prefix_granularity)
+        if self.block_len is not None:
+            # K/V inside a block depend on the whole block: a
+            # partial-page match is only valid in whole blocks
+            self._prefix_granularity = int(np.lcm(
+                self._prefix_granularity, self.block_len))
         # ONE reusable batch-1 prefill staging cache: positions past the
         # current prompt hold a previous request's stale entries, which
         # is safe — insert copies only the pages/rows the prompt filled,
@@ -579,10 +541,13 @@ class ServingEngine:
         self._off_seen = (0, 0, 0)
         #: likewise for the prefix cache's eviction odometers
         self._evict_seen = (0, 0, 0)
+        # cost-aware scheduling: priority classes + preemption; the
+        # engine gates admission on the free-page budget (_admit).
         # bounded admission (load shedding): submits past max_queue
         # raise AdmissionRejected instead of growing the queue without
         # bound under overload; None keeps the open-queue behavior
-        self.scheduler = scheduler
+        self.scheduler = PriorityScheduler(self.num_slots,
+                                           max_queue=max_queue)
 
         # --- zero-bubble loop state (zero-bubble PR) --------------------
         self.overlap = bool(overlap)
@@ -893,7 +858,7 @@ class ServingEngine:
         argument/output replicated (the MoE psum makes outputs agree
         across the axis). Every program's signature is ``(params,
         state, cache, ...)`` and the KV cache — argument 2, the page
-        pool, the slab pool or the batch-1 staging cache — is DONATED:
+        pool or the batch-1 staging cache — is DONATED:
         the program writes its successor into the same buffers, the
         value passed in is deleted, and the caller rebinds it from the
         result before anything reads it. The one exception is
@@ -1132,28 +1097,27 @@ class ServingEngine:
             for qd, occ in self._iter_buf:
                 m.record_iteration(qd, occ, self.num_slots)
             self._iter_buf.clear()
-            if self.kv_layout == "paged":
-                m.record_pages(self.pool.free_pages,
-                               self.pool.shared_pages,
-                               self._fragmentation())
-                # host-tier odometers: the pool counts cumulatively;
-                # the metrics WINDOW gets deltas so window swaps stay
-                # honest (the record_pages gauge discipline)
-                po, pr, ob = (self.pool.pages_offloaded,
-                              self.pool.pages_restored,
-                              self.pool.offload_bytes)
-                so, sr, sb = self._off_seen
-                if po > so or pr > sr:
-                    m.record_offload(po - so, pr - sr, ob - sb)
-                    self._off_seen = (po, pr, ob)
-                if self.prefix is not None:
-                    now = (self.prefix.evictions,
-                           self.prefix.evict_examined,
-                           self.prefix.evictable_queries)
-                    if now != self._evict_seen:
-                        m.record_prefix_eviction(*(
-                            a - b for a, b in zip(now, self._evict_seen)))
-                        self._evict_seen = now
+            m.record_pages(self.pool.free_pages,
+                           self.pool.shared_pages,
+                           self._fragmentation())
+            # host-tier odometers: the pool counts cumulatively;
+            # the metrics WINDOW gets deltas so window swaps stay
+            # honest (the record_pages gauge discipline)
+            po, pr, ob = (self.pool.pages_offloaded,
+                          self.pool.pages_restored,
+                          self.pool.offload_bytes)
+            so, sr, sb = self._off_seen
+            if po > so or pr > sr:
+                m.record_offload(po - so, pr - sr, ob - sb)
+                self._off_seen = (po, pr, ob)
+            if self.prefix is not None:
+                now = (self.prefix.evictions,
+                       self.prefix.evict_examined,
+                       self.prefix.evictable_queries)
+                if now != self._evict_seen:
+                    m.record_prefix_eviction(*(
+                        a - b for a, b in zip(now, self._evict_seen)))
+                    self._evict_seen = now
         if self._decode_buf:
             for n, dt, toks in self._decode_buf:
                 m.record_decode(n, dt, n_tokens=toks)
@@ -1267,13 +1231,13 @@ class ServingEngine:
             if greedy_only:
                 nxt, cache, moe = self._fused_fn(True)(
                     self._params, self._state, self.pool.cache, tok,
-                    t_dev, _snap(self._stop), *tables)
+                    t_dev, _snap(self._stop), tables)
             else:
                 nxt, cache, keys, moe = self._fused_fn(False)(
                     self._params, self._state, self.pool.cache, tok,
                     t_dev, _snap(self._stop), _snap(self._temp),
                     _snap(self._topk), _snap(self._topp),
-                    self._merge_keys(prev, dirty), *tables)
+                    self._merge_keys(prev, dirty), tables)
             last, count = nxt[:, -1], fuse
             warm = ("serving.decode_fused_greedy" if greedy_only
                     else "serving.decode_fused_sampled")
@@ -1281,13 +1245,13 @@ class ServingEngine:
             if greedy_only:
                 nxt, cache, moe = self._decode_fn(True)(
                     self._params, self._state, self.pool.cache, tok,
-                    t_dev, *tables)
+                    t_dev, tables)
             else:
                 nxt, cache, keys, moe = self._decode_fn(False)(
                     self._params, self._state, self.pool.cache, tok,
                     t_dev, _snap(self._temp), _snap(self._topk),
                     _snap(self._topp),
-                    self._merge_keys(prev, dirty), *tables)
+                    self._merge_keys(prev, dirty), tables)
             last, count = nxt, 1
             warm = ("serving.decode_greedy" if greedy_only
                     else "serving.decode_sampled")
@@ -1321,10 +1285,8 @@ class ServingEngine:
         elif self._iters % self._host_window:
             return                      # steady state: window cadence
         decoding, prefilling = self._rec_cache[1]
-        extra = ({"pages_free": self.pool.free_pages}
-                 if self.kv_layout == "paged" else {})
-        if self.kv_layout == "paged" \
-                and self.pool.host_cache is not None:
+        extra = {"pages_free": self.pool.free_pages}
+        if self.pool.host_cache is not None:
             # host-pool occupancy in the flight-recorder ring: a
             # post-mortem distinguishes "swaps stopped because the
             # host tier filled" from "preemptions stopped"
@@ -1354,10 +1316,9 @@ class ServingEngine:
         returned request). Raises ``AdmissionRejected`` when the engine
         was built with ``max_queue`` and the wait queue is full.
 
-        ``priority`` (paged engine): lower admits first — 0
-        interactive, 1 standard (default), 2 batch. A queued priority-0
-        request may PREEMPT lower-priority decoding streams when the
-        page budget is short; ignored by the slab engine's FCFS.
+        ``priority``: lower admits first — 0 interactive, 1 standard
+        (default), 2 batch. A queued priority-0 request may PREEMPT
+        lower-priority decoding streams when the page budget is short.
 
         ``speculate`` (engines built with ``draft=``): whether this
         request joins draft-and-verify decode iterations. ``None``
@@ -1387,15 +1348,14 @@ class ServingEngine:
             raise ValueError(
                 "block diffusion decodes greedily (temperature 0): a "
                 "position is fixed to its most probable token")
-        if self.kv_layout == "paged":
-            # a request whose worst case exceeds the whole pool could
-            # never finish — even after preempting everything else
-            worst = self.pool.pages_for(prompt.size + max_new_tokens)
-            if worst > self.pool.num_pages:
-                raise ValueError(
-                    f"request needs up to {worst} pages but the pool "
-                    f"holds {self.pool.num_pages}; raise num_pages or "
-                    "lower max_new_tokens")
+        # a request whose worst case exceeds the whole pool could
+        # never finish — even after preempting everything else
+        worst = self.pool.pages_for(prompt.size + max_new_tokens)
+        if worst > self.pool.num_pages:
+            raise ValueError(
+                f"request needs up to {worst} pages but the pool "
+                f"holds {self.pool.num_pages}; raise num_pages or "
+                "lower max_new_tokens")
         if speculate and self._draft is None:
             raise ValueError(
                 "speculate=True needs an engine built with a draft "
@@ -1453,7 +1413,6 @@ class ServingEngine:
         fn = self._step_fns.get(greedy_only)
         if fn is None:
             module = self.module
-            paged = self.kv_layout == "paged"
             page_len = self.page_len
             moe_kw = dict(
                 moe_dispatched=self._moe_dispatched,
@@ -1462,35 +1421,22 @@ class ServingEngine:
             pk = self._paged_kernel
 
             def step(params, state, cache, tok, t, tables):
-                if paged:
-                    out = decode_step_slots_paged(
-                        module, params, state, cache, tok, t, tables,
-                        page_len, paged_kernel=pk, **moe_kw)
-                else:
-                    out = decode_step_slots(
-                        module, params, state, cache, tok, t, **moe_kw)
+                out = decode_step_slots_paged(
+                    module, params, state, cache, tok, t, tables,
+                    page_len, paged_kernel=pk, **moe_kw)
                 # every variant returns a routing-stats slot (None on
                 # MoE-free / dense-baseline engines) so call sites
                 # unpack one shape
                 return out if stats_on else (out + (None,))
 
             if greedy_only:
-                if paged:
-                    def fn(params, state, cache, tok, t, tables):
-                        logits, cache, moe = step(params, state, cache,
-                                                  tok, t, tables)
-                        with jax.named_scope("sample"):
-                            nxt = jnp.argmax(logits, axis=-1)
-                        return nxt, cache, moe
-                    n_args = 6
-                else:
-                    def fn(params, state, cache, tok, t):
-                        logits, cache, moe = step(params, state, cache,
-                                                  tok, t, None)
-                        with jax.named_scope("sample"):
-                            nxt = jnp.argmax(logits, axis=-1)
-                        return nxt, cache, moe
-                    n_args = 5
+                def fn(params, state, cache, tok, t, tables):
+                    logits, cache, moe = step(params, state, cache,
+                                              tok, t, tables)
+                    with jax.named_scope("sample"):
+                        nxt = jnp.argmax(logits, axis=-1)
+                    return nxt, cache, moe
+                n_args = 6
             else:
                 if self.fused_sampling:
                     from distkeras_tpu.ops.sampling import sample_tokens
@@ -1498,8 +1444,8 @@ class ServingEngine:
                 else:
                     sampler = _sample_vec
 
-                def body(params, state, cache, tok, t, temp, topk, topp,
-                         keys, tables):
+                def fn(params, state, cache, tok, t, temp, topk, topp,
+                       keys, tables):
                     logits, cache, moe = step(params, state, cache,
                                               tok, t, tables)
                     # per-slot key streams: a request's draws depend
@@ -1510,15 +1456,7 @@ class ServingEngine:
                         nxt = sampler(logits, temp, topk, topp,
                                       split[:, 1])
                     return nxt, cache, split[:, 0], moe
-
-                if paged:
-                    fn, n_args = body, 10
-                else:
-                    def fn(params, state, cache, tok, t, temp, topk,
-                           topp, keys):
-                        return body(params, state, cache, tok, t, temp,
-                                    topk, topp, keys, None)
-                    n_args = 9
+                n_args = 10
 
             fn = self._jit_serving(
                 fn, n_args, "decode_greedy" if greedy_only
@@ -1538,7 +1476,6 @@ class ServingEngine:
         fn = self._fused_fns.get(greedy_only)
         if fn is None:
             module = self.module
-            paged = self.kv_layout == "paged"
             page_len = self.page_len
             k = self.fuse_steps
             moe_kw = dict(
@@ -1548,45 +1485,27 @@ class ServingEngine:
             stats_on = self._moe_stats_on
 
             if greedy_only:
-                def body(params, state, cache, tok, t, stop, tables):
+                def fn(params, state, cache, tok, t, stop, tables):
                     toks, cache, _, moe = decode_fused_slots(
                         module, params, state, cache, tok, t, stop, k,
-                        table=tables, page_len=page_len or 0, **moe_kw)
+                        table=tables, page_len=page_len, **moe_kw)
                     return toks, cache, (moe if stats_on else None)
-
-                if paged:
-                    def fn(params, state, cache, tok, t, stop, tables):
-                        return body(params, state, cache, tok, t, stop,
-                                    tables)
-                    n_args = 7
-                else:
-                    def fn(params, state, cache, tok, t, stop):
-                        return body(params, state, cache, tok, t, stop,
-                                    None)
-                    n_args = 6
+                n_args = 7
             else:
                 if self.fused_sampling:
                     from distkeras_tpu.ops.sampling import sample_tokens
                     moe_kw = dict(moe_kw, sampler=sample_tokens)
 
-                def body(params, state, cache, tok, t, stop, temp,
-                         topk, topp, keys, tables):
+                def fn(params, state, cache, tok, t, stop, temp,
+                       topk, topp, keys, tables):
                     toks, cache, keys, moe = decode_fused_slots(
                         module, params, state, cache, tok, t, stop, k,
-                        table=tables, page_len=page_len or 0,
+                        table=tables, page_len=page_len,
                         temperature=temp, top_k=topk, top_p=topp,
                         keys=keys, **moe_kw)
                     return toks, cache, keys, \
                         (moe if stats_on else None)
-
-                if paged:
-                    fn, n_args = body, 11
-                else:
-                    def fn(params, state, cache, tok, t, stop, temp,
-                           topk, topp, keys):
-                        return body(params, state, cache, tok, t, stop,
-                                    temp, topk, topp, keys, None)
-                    n_args = 10
+                n_args = 11
 
             fn = self._jit_serving(
                 fn, n_args, "decode_fused_greedy" if greedy_only
@@ -1622,7 +1541,6 @@ class ServingEngine:
         fn = self._spec_fns.get(greedy_only)
         if fn is None:
             module = self.module
-            paged = self.kv_layout == "paged"
             page_len = self.page_len
             k = self.spec_k
             moe_kw = dict(
@@ -1632,13 +1550,9 @@ class ServingEngine:
             pk = self._paged_kernel
 
             def vstep(params, state, cache, toks, t, tables):
-                if paged:
-                    out = verify_step_slots_paged(
-                        module, params, state, cache, toks, t, tables,
-                        page_len, paged_kernel=pk, **moe_kw)
-                else:
-                    out = verify_step_slots(
-                        module, params, state, cache, toks, t, **moe_kw)
+                out = verify_step_slots_paged(
+                    module, params, state, cache, toks, t, tables,
+                    page_len, paged_kernel=pk, **moe_kw)
                 return out if stats_on else (out + (None,))
 
             def accept(cand, toks, active):
@@ -1650,22 +1564,15 @@ class ServingEngine:
                 return jnp.where(active, n_acc, 0)
 
             if greedy_only:
-                def body(params, state, cache, toks, t, active, tables):
+                def fn(params, state, cache, toks, t, active, tables):
                     logits, cache, moe = vstep(params, state, cache,
                                                toks, t, tables)
                     cand = jnp.argmax(logits, axis=-1)     # [S, k+1]
                     return cand, accept(cand, toks, active), cache, moe
-
-                if paged:
-                    fn, n_args = body, 7
-                else:
-                    def fn(params, state, cache, toks, t, active):
-                        return body(params, state, cache, toks, t,
-                                    active, None)
-                    n_args = 6
+                n_args = 7
             else:
-                def body(params, state, cache, toks, t, active, temp,
-                         topk, topp, keys, tables):
+                def fn(params, state, cache, toks, t, active, temp,
+                       topk, topp, keys, tables):
                     logits, cache, moe = vstep(params, state, cache,
                                                toks, t, tables)
                     cands, carries = [], []
@@ -1685,16 +1592,7 @@ class ServingEngine:
                     new_keys = jnp.stack(carries, axis=1)[
                         jnp.arange(cand.shape[0]), n_acc]
                     return cand, n_acc, cache, new_keys, moe
-
-                if paged:
-                    fn, n_args = body, 11
-                else:
-                    def fn(params, state, cache, toks, t, active, temp,
-                           topk, topp, keys):
-                        return body(params, state, cache, toks, t,
-                                    active, temp, topk, topp, keys,
-                                    None)
-                    n_args = 10
+                n_args = 11
 
             fn = self._jit_serving(
                 fn, n_args, "verify_greedy" if greedy_only
@@ -1708,7 +1606,7 @@ class ServingEngine:
     def _verify_tree_fn(self, greedy_only: bool):
         """The TREE counterparts of ``_verify_fn``'s two variants: one
         program runs the tree-masked verify forward
-        (``verify_step_slots[_paged]`` with the ancestor mask), the
+        (``verify_step_slots_paged`` with the ancestor mask), the
         in-program acceptance walk (``tree_walk`` — greedy argmax
         descent, or the exact point-mass rejection-sampling walk with
         one PRNG split per emitted token), and the accepted-path cache
@@ -1721,7 +1619,6 @@ class ServingEngine:
         fn = self._tree_fns.get(greedy_only)
         if fn is None:
             module = self.module
-            paged = self.kv_layout == "paged"
             page_len = self.page_len
             moe_kw = dict(
                 moe_dispatched=self._moe_dispatched,
@@ -1732,14 +1629,9 @@ class ServingEngine:
             def vstep(params, state, cache, toks, t, depth, anc,
                       tables):
                 tree = {"depth": depth, "anc": anc}
-                if paged:
-                    out = verify_step_slots_paged(
-                        module, params, state, cache, toks, t, tables,
-                        page_len, tree=tree, paged_kernel=pk, **moe_kw)
-                else:
-                    out = verify_step_slots(
-                        module, params, state, cache, toks, t,
-                        tree=tree, **moe_kw)
+                out = verify_step_slots_paged(
+                    module, params, state, cache, toks, t, tables,
+                    page_len, tree=tree, paged_kernel=pk, **moe_kw)
                 if stats_on:
                     logits, cache, kvw, moe = out
                 else:
@@ -1747,8 +1639,8 @@ class ServingEngine:
                 return logits, cache, kvw, moe
 
             if greedy_only:
-                def body(params, state, cache, toks, t, parents, depth,
-                         anc, tables):
+                def fn(params, state, cache, toks, t, parents, depth,
+                       anc, tables):
                     logits, cache, kvw, moe = vstep(
                         params, state, cache, toks, t, depth, anc,
                         tables)
@@ -1756,20 +1648,12 @@ class ServingEngine:
                         logits, toks, parents)
                     cache = commit_tree_path(
                         cache, kvw, path, t, n_emit, table=tables,
-                        page_len=page_len or 0)
+                        page_len=page_len)
                     return emitted, n_emit, cache, moe
-
-                if paged:
-                    fn, n_args = body, 9
-                else:
-                    def fn(params, state, cache, toks, t, parents,
-                           depth, anc):
-                        return body(params, state, cache, toks, t,
-                                    parents, depth, anc, None)
-                    n_args = 8
+                n_args = 9
             else:
-                def body(params, state, cache, toks, t, parents, depth,
-                         anc, temp, topk, topp, keys, tables):
+                def fn(params, state, cache, toks, t, parents, depth,
+                       anc, temp, topk, topp, keys, tables):
                     logits, cache, kvw, moe = vstep(
                         params, state, cache, toks, t, depth, anc,
                         tables)
@@ -1778,18 +1662,9 @@ class ServingEngine:
                         top_k=topk, top_p=topp, keys=keys)
                     cache = commit_tree_path(
                         cache, kvw, path, t, n_emit, table=tables,
-                        page_len=page_len or 0)
+                        page_len=page_len)
                     return emitted, n_emit, cache, new_keys, moe
-
-                if paged:
-                    fn, n_args = body, 13
-                else:
-                    def fn(params, state, cache, toks, t, parents,
-                           depth, anc, temp, topk, topp, keys):
-                        return body(params, state, cache, toks, t,
-                                    parents, depth, anc, temp, topk,
-                                    topp, keys, None)
-                    n_args = 12
+                n_args = 13
             fn = self._jit_serving(
                 fn, n_args, "verify_tree_greedy" if greedy_only
                 else "verify_tree_sampled")
@@ -1995,17 +1870,12 @@ class ServingEngine:
     # --- paged admission / page budget ------------------------------------
 
     def _admit(self) -> List[Request]:
-        """Admission for this iteration. Slab: FCFS into free slots.
-        Paged: cost-aware — the highest-priority queued request admits
+        """Admission for this iteration, cost-aware: the
+        highest-priority queued request admits
         while a slot AND its context's page budget are available
         (prefix-cache hits cost nothing: shared pages are reused, not
         allocated); when the budget is short, a strictly-higher-
         priority arrival preempts lower-priority decoding streams."""
-        if self.kv_layout != "paged":
-            admitted = self.scheduler.admit()
-            if admitted:
-                self._comp_ver += 1
-            return admitted
         admitted: List[Request] = []
         sch = self.scheduler
         while sch.free_slots:
@@ -2245,7 +2115,6 @@ class ServingEngine:
         # context and duplicates nothing on resume.
         swapped = 0
         if victim.state is RequestState.DECODING \
-                and self.kv_layout == "paged" \
                 and self.pool.host_cache is not None:
             row = self.pool.tables[slot]
             logical = np.where(row < self.pool.num_pages)[0]
@@ -2597,10 +2466,6 @@ class ServingEngine:
         req = self._requests[rid]
         if req.state in (RequestState.PREFILLING,
                          RequestState.DECODING):
-            if self.kv_layout != "paged":
-                raise RuntimeError(
-                    "transfer_out of an admitted request needs the "
-                    "paged engine (the resumable re-prefill path)")
             self._preempt(req)
             if req.state in TERMINAL_STATES:
                 return None          # the pipeline flush finished it
@@ -2652,16 +2517,11 @@ class ServingEngine:
                 f"prompt ({prompt.size}) + max_new_tokens "
                 f"({req.max_new_tokens}) exceeds the slot capacity "
                 f"max_len={self.max_len}")
-        if req.generated and self.kv_layout != "paged":
+        worst = self.pool.pages_for(prompt.size + req.max_new_tokens)
+        if worst > self.pool.num_pages:
             raise ValueError(
-                "transfer_in of a decode-progress request needs the "
-                "paged engine (the resumable re-prefill path)")
-        if self.kv_layout == "paged":
-            worst = self.pool.pages_for(prompt.size + req.max_new_tokens)
-            if worst > self.pool.num_pages:
-                raise ValueError(
-                    f"request needs up to {worst} pages but the pool "
-                    f"holds {self.pool.num_pages}")
+                f"request needs up to {worst} pages but the pool "
+                f"holds {self.pool.num_pages}")
         req.prompt = prompt
         req.rid = next(self._rid)
         req.slot = None
@@ -2713,8 +2573,7 @@ class ServingEngine:
             self._chain_dirty[req.slot] = True
             if self._draft is not None:
                 self._draft.end_slot(req.slot)
-            if self.kv_layout == "paged":
-                self.pool.release_slot(req.slot)
+            self.pool.release_slot(req.slot)
         if getattr(req, "_donor_ref", None) is not None:
             # admitted with a copy-on-write donor hold but terminated
             # before its prefill turn consumed it
@@ -2786,23 +2645,22 @@ class ServingEngine:
                 "block_len": self.block_len,
                 "denoising_steps": self.denoising_steps,
                 "mask_token": self.mask_token}
-        if self.kv_layout == "paged":
-            pool = self.pool
-            out["pages"] = {
-                "total": pool.num_pages, "free": pool.free_pages,
-                "shared": pool.shared_pages,
-                "page_len": pool.page_len,
-                "fragmentation": round(self._fragmentation(), 4),
-                # host offload tier (additive key): None when off
-                "host": (None if pool.host_cache is None else {
-                    "total": pool.host_pages,
-                    "free": pool.host_free_pages,
-                    "offloaded": pool.pages_offloaded,
-                    "restored": pool.pages_restored})}
-            out["prefix_cache"] = (
-                None if self.prefix is None else {
-                    "nodes": len(self.prefix),
-                    "hit_rate": m.prefix_hit_rate})
+        pool = self.pool
+        out["pages"] = {
+            "total": pool.num_pages, "free": pool.free_pages,
+            "shared": pool.shared_pages,
+            "page_len": pool.page_len,
+            "fragmentation": round(self._fragmentation(), 4),
+            # host offload tier (additive key): None when off
+            "host": (None if pool.host_cache is None else {
+                "total": pool.host_pages,
+                "free": pool.host_free_pages,
+                "offloaded": pool.pages_offloaded,
+                "restored": pool.pages_restored})}
+        out["prefix_cache"] = (
+            None if self.prefix is None else {
+                "nodes": len(self.prefix),
+                "hit_rate": m.prefix_hit_rate})
         return out
 
     def decode_logits(self, decode_kernel: Optional[str] = None,
@@ -2818,8 +2676,6 @@ class ServingEngine:
         engine's own choices, which is the point: a kernel and its
         reference read the SAME state. Rows of free slots are
         garbage."""
-        if self.kv_layout != "paged":
-            raise ValueError("decode_logits reads the paged cache")
         if self.block_len is not None:
             raise ValueError("decode_logits is a one-token step; a "
                              "block-causal model has none")
@@ -2855,8 +2711,7 @@ class ServingEngine:
         # poisoned-request isolation in step(); an injected stall is the
         # slow-prefill scenario (queue grows, deadlines/shedding engage)
         faults.point("serving.prefill")
-        paged = self.kv_layout == "paged"
-        swap = getattr(req, "_swap", None) if paged else None
+        swap = getattr(req, "_swap", None)
         if swap is not None:
             # swap-in resume (offload PR): the preemption snapshot
             # copies H2D into the pages _apply_page_plan already wired
@@ -2888,9 +2743,9 @@ class ServingEngine:
             self.tracer.on_swap_in(req.rid, len(dev))
             self.tracer.on_resume(req.rid)
             return
-        # paged context = prompt, or prompt + generated[:-1] after a
+        # context = prompt, or prompt + generated[:-1] after a
         # preemption (the resumable-prefill recompute path)
-        toks = self._context_of(req) if paged else req.prompt
+        toks = self._context_of(req)
         p_len = len(toks)
         blockdiff = self.block_len is not None
         if blockdiff and p_len == 0:
@@ -2898,7 +2753,7 @@ class ServingEngine:
             # whole of it opens the first generated block
             self._open_block(req, 0)
             return
-        resume = paged and bool(req.generated)
+        resume = bool(req.generated)
         if resume and req.prefill_pos == 0 \
                 and getattr(req, "_resume_t0", None) is None:
             # re-prefill resume clock: first recompute chunk ->
@@ -2906,7 +2761,7 @@ class ServingEngine:
             # bench's resume-latency rider compares against swap-in)
             req._resume_t0 = self.metrics.clock()
         with obs.span("serving.prefill.stage"):
-            if paged and req.prefill_pos == 0:
+            if req.prefill_pos == 0:
                 if self.prefix is not None:
                     # pages registered since this request's admission plan
                     # (by requests ahead of it in the prefill stream) are
@@ -2955,20 +2810,17 @@ class ServingEngine:
         if not final:
             return
         with obs.span("serving.prefill.insert"):
-            if paged:
-                # write ONLY the pages the context fills, minus the shared
-                # prefix pages that already hold identical data (the
-                # copy-on-write donor's logical page IS written — into the
-                # request's private copy)
-                self.pool.insert_pages(self._staging, req.slot,
-                                       getattr(req, "_n_shared_full", 0),
-                                       p_len)
-                if self.prefix is not None:
-                    # full context pages are immutable from here (decode
-                    # writes start at p_len): share them forward
-                    self.prefix.register(toks, self.pool.tables[req.slot])
-            else:
-                self.pool.insert(self._staging, req.slot, n_pos=p_len)
+            # write ONLY the pages the context fills, minus the shared
+            # prefix pages that already hold identical data (the
+            # copy-on-write donor's logical page IS written — into the
+            # request's private copy)
+            self.pool.insert_pages(self._staging, req.slot,
+                                   getattr(req, "_n_shared_full", 0),
+                                   p_len)
+            if self.prefix is not None:
+                # full context pages are immutable from here (decode
+                # writes start at p_len): share them forward
+                self.prefix.register(toks, self.pool.tables[req.slot])
         s = req.slot
         if blockdiff:
             self._open_block(req, p_len)
@@ -3197,7 +3049,6 @@ class ServingEngine:
         if self.block_len is not None:
             self._block_step(finished)
             return
-        paged = self.kv_layout == "paged"
         spec = bool(self._spec_slots())
         if spec:
             # draft proposals read host-side token state, so a
@@ -3214,44 +3065,43 @@ class ServingEngine:
             # — the whole iteration lives in _spec_tree_step
             self._spec_tree_step(finished)
             return
-        if paged:
-            # page growth happens BEFORE the step (a write with no page
-            # would silently drop); may preempt streams out of
-            # ``running``, so the batch composition reads after it.
-            # Speculating slots demand pages for their whole verify
-            # window up front (only as far as their budget can
-            # consume); a fused window demands pages for all
-            # ``fuse_steps`` write positions
-            look = None
-            if spec:
-                look = np.zeros(self.num_slots, np.int64)
-                for slot, r in self.scheduler.running.items():
-                    if self._spec_eligible(r):
-                        look[slot] = min(
-                            self.spec_k,
-                            r.max_new_tokens - len(r.generated) - 1)
-            elif fuse:
-                look = np.zeros(self.num_slots, np.int64)
-                for slot in self.scheduler.running:
-                    look[slot] = fuse - 1
-            with obs.span("serving.decode.pages"):
-                self._ensure_decode_pages(look)
-            if not self.scheduler.running:
-                return
-            if spec:
-                spec = bool(self._spec_slots())  # preemption may have
-                #                                  evicted speculators
-            elif fuse and self.scheduler.queue_depth:
-                # funding the window preempted a stream: quiescence is
-                # gone, fall back to single-step and rejoin later (the
-                # pre-grown pages stay — they are legitimate write
-                # positions)
-                fuse = 0
+        # page growth happens BEFORE the step (a write with no page
+        # would silently drop); may preempt streams out of
+        # ``running``, so the batch composition reads after it.
+        # Speculating slots demand pages for their whole verify
+        # window up front (only as far as their budget can
+        # consume); a fused window demands pages for all
+        # ``fuse_steps`` write positions
+        look = None
+        if spec:
+            look = np.zeros(self.num_slots, np.int64)
+            for slot, r in self.scheduler.running.items():
+                if self._spec_eligible(r):
+                    look[slot] = min(
+                        self.spec_k,
+                        r.max_new_tokens - len(r.generated) - 1)
+        elif fuse:
+            look = np.zeros(self.num_slots, np.int64)
+            for slot in self.scheduler.running:
+                look[slot] = fuse - 1
+        with obs.span("serving.decode.pages"):
+            self._ensure_decode_pages(look)
+        if not self.scheduler.running:
+            return
+        if spec:
+            spec = bool(self._spec_slots())  # preemption may have
+            #                                  evicted speculators
+        elif fuse and self.scheduler.queue_depth:
+            # funding the window preempted a stream: quiescence is
+            # gone, fall back to single-step and rejoin later (the
+            # pre-grown pages stay — they are legitimate write
+            # positions)
+            fuse = 0
         t0 = self.metrics.clock()
         greedy_only = all(r.temperature <= 0.0
                           for r in self.scheduler.running.values())
         with obs.span("serving.decode.tables"):
-            tables = (self.pool.device_tables(),) if paged else ()
+            tables = self.pool.device_tables()
         if spec:
             self._spec_step(greedy_only, tables, finished, t0)
             return
@@ -3293,7 +3143,7 @@ class ServingEngine:
             with obs.span("serving.decode.dispatch"):
                 cand, n_acc, self.pool.cache, moe = self._verify_fn(True)(
                     self._params, self._state, self.pool.cache, toks,
-                    self._t, active_dev, *tables)
+                    self._t, active_dev, tables)
             cand, n_acc = self._fetch(cand, n_acc)
         else:
             with obs.span("serving.decode.dispatch"):
@@ -3301,7 +3151,7 @@ class ServingEngine:
                  moe) = self._verify_fn(False)(
                     self._params, self._state, self.pool.cache, toks,
                     self._t, active_dev, self._temp, self._topk,
-                    self._topp, self._keys, *tables)
+                    self._topp, self._keys, tables)
             cand, n_acc, new_keys = self._fetch(cand, n_acc, keys)
             # the fetch hands back read-only views of device memory;
             # the key mirror stays host-writable (per-slot restores on
@@ -3391,7 +3241,6 @@ class ServingEngine:
         the stream's tree (``_adapt_tree``). Streams whose tree ends
         up empty ride the program as plain decode steps."""
         W = self.spec_window
-        paged = self.kv_layout == "paged"
         running = self.scheduler.running
         s_n = self.num_slots
         toks = np.zeros((s_n, W), np.int32)
@@ -3419,25 +3268,24 @@ class ServingEngine:
                                      toks, parents, active, depth_v,
                                      width_v, budget_v)
         depth, anc, n_nodes = tree_ancestors(parents)
-        if paged:
-            look = np.where(active, n_nodes - 1, 0).astype(np.int64)
-            with obs.span("serving.decode.pages"):
-                self._ensure_decode_pages(look)
-            if not self.scheduler.running:
-                return
+        look = np.where(active, n_nodes - 1, 0).astype(np.int64)
+        with obs.span("serving.decode.pages"):
+            self._ensure_decode_pages(look)
+        if not self.scheduler.running:
+            return
         t0 = self.metrics.clock()
         running = self.scheduler.running
         greedy_only = all(r.temperature <= 0.0
                           for r in running.values())
         with obs.span("serving.decode.tables"):
-            tables = (self.pool.device_tables(),) if paged else ()
+            tables = self.pool.device_tables()
         targs = (toks, self._t, parents, depth, anc)
         if greedy_only:
             with obs.span("serving.decode.dispatch"):
                 emitted, n_emit, self.pool.cache, moe = \
                     self._verify_tree_fn(True)(
                         self._params, self._state, self.pool.cache,
-                        *targs, *tables)
+                        *targs, tables)
             emitted, n_emit = self._fetch(emitted, n_emit)
         else:
             with obs.span("serving.decode.dispatch"):
@@ -3445,7 +3293,7 @@ class ServingEngine:
                     self._verify_tree_fn(False)(
                         self._params, self._state, self.pool.cache,
                         *targs, self._temp, self._topk, self._topp,
-                        self._keys, *tables)
+                        self._keys, tables)
             emitted, n_emit, new_keys = self._fetch(emitted, n_emit,
                                                     keys)
             self._keys = new_keys.copy()
@@ -3498,10 +3346,9 @@ class ServingEngine:
         self._chain_dirty[slot] = True
         if self._draft is not None:
             self._draft.end_slot(slot)
-        if self.kv_layout == "paged":
-            # pages return to the budget; registered prompt-prefix
-            # pages survive under the prefix cache's own refcount
-            self.pool.release_slot(slot)
+        # pages return to the budget; registered prompt-prefix
+        # pages survive under the prefix cache's own refcount
+        self.pool.release_slot(slot)
         self.metrics.record_finish(req.rid, len(req.generated))
         self.tracer.on_terminal(req.rid, RequestState.FINISHED.value,
                                 len(req.generated))

@@ -1,17 +1,13 @@
-"""KV cache pools for the serving engine: the legacy slab pool and the
-block-pooled PAGED cache that replaced it as the engine default.
+"""The serving engine's KV cache: a block-pooled PAGED cache.
 
-``KVPool`` (slab) reserves one resident ``[S, max_len]`` buffer row per
-slot: occupancy is bounded by WORST-CASE length, so a pool sized for
-8K-token requests wastes ~94% of its HBM on a workload whose median
-request is 500 tokens. ``PagedKVPool`` is the vLLM/PagedAttention fix:
+``PagedKVPool`` is the vLLM/PagedAttention layout:
 one fixed pool of ``[num_pages, Hkv, page_len, Dh]`` pages per layer,
 a per-slot page table mapping logical position ``t`` to physical page
 ``table[slot, t // page_len]``, pages allocated on demand as requests
 grow and returned the moment they finish. Occupancy tracks ACTUAL
-tokens (within ``page_len`` rounding), which is what turns memory into
-throughput: at equal HBM the paged pool admits however many requests
-fit their real lengths, not ``HBM / max_len``.
+tokens (within ``page_len`` rounding), not worst-case length, which is
+what turns memory into throughput: the pool admits however many
+requests fit their real lengths, not ``HBM / max_len``.
 
 On top of the pool, ``PrefixCache`` hash-conses shared prompt
 prefixes: finished requests register their full (immutable) prompt
@@ -29,7 +25,7 @@ table points at it plus (for registered prefix pages) the cache node;
 alone holds (``ref == 1``) are reclaimable LRU-leaf-first when
 allocation pressure needs them.
 
-Both pools compose with the int8 quantized cache (``dtype="int8"``):
+The pool composes with the int8 quantized cache (``dtype="int8"``):
 payload and per-token-per-head scale planes share the page tables and
 move together through every insert/load/gather program.
 
@@ -62,83 +58,9 @@ from typing import Dict, List, Optional, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax import lax
 
 from distkeras_tpu.models.decoding import (init_cache, pack_int4,
                                            unpack_int4)
-
-
-@partial(jax.jit, donate_argnums=0)
-def _insert_row(pool, req_cache, slot):
-    """Write a batch-1 request cache into pool row ``slot`` (``slot``
-    is traced — one compiled program serves every slot index). The
-    request cache may be SHORTER than the row (the prompt-length
-    prefix): only its positions are written. ``pool`` is donated:
-    the row is written in place."""
-    def write(pl, rq):
-        return lax.dynamic_update_slice(
-            pl, rq.astype(pl.dtype), (slot,) + (0,) * (pl.ndim - 1))
-    return jax.tree_util.tree_map(write, pool, req_cache)
-
-
-class KVPool:
-    """S-slot slab-pooled KV cache over ``module``'s attention layers.
-
-    ``cache`` is the live device pytree (the exact structure
-    ``decode_step_slots`` consumes). Every program that advances it
-    (``insert`` here, the engine's step programs) DONATES it: the new
-    value reuses the old one's buffers and the old arrays are deleted,
-    not merely stale — a handle kept across such a call raises on its
-    next read. Rebind ``cache`` from the program's result; copy first
-    what must outlive it."""
-
-    def __init__(self, module, num_slots: int, max_len: int,
-                 dtype=jnp.float32):
-        if num_slots < 1:
-            raise ValueError(f"num_slots must be >= 1, got {num_slots}")
-        if max_len < 1:
-            raise ValueError(f"max_len must be >= 1, got {max_len}")
-        self._module = module
-        self.num_slots = int(num_slots)
-        self.max_len = int(max_len)
-        self.dtype = dtype
-        # init_cache validates max_len against the position table up
-        # front (out-of-range gathers CLAMP under jit — silent wrong-
-        # position logits otherwise)
-        self.cache = init_cache(module, self.num_slots, self.max_len,
-                                dtype)
-
-    def make_request_cache(self):
-        """A batch-1 cache with the pool's exact per-position layout —
-        what per-request prefill fills and ``insert`` consumes."""
-        return init_cache(self._module, 1, self.max_len, self.dtype)
-
-    def insert(self, req_cache, slot: int,
-               n_pos: Optional[int] = None) -> None:
-        """Copy a batch-1 request cache (layout of
-        ``make_request_cache``) into row ``slot``. ``n_pos`` bounds the
-        copy to the positions the prompt actually filled — the full-row
-        write (the pre-paged behavior, kept when ``n_pos`` is None) was
-        a measurable admit-latency tax at large ``max_len``: it moved
-        ``max_len``/prompt_len times the bytes the admit needed. The
-        stale tail beyond ``n_pos`` is safe either way: the slot's own
-        decode writes position t before the attention mask admits it.
-        Like the ragged final prefill chunk, each distinct ``n_pos``
-        is its own compiled program (same cardinality, prompt lengths).
-        """
-        if not 0 <= slot < self.num_slots:
-            raise ValueError(
-                f"slot {slot} out of range [0, {self.num_slots})")
-        if n_pos is not None:
-            if not 0 < n_pos <= self.max_len:
-                raise ValueError(
-                    f"n_pos must be in (0, {self.max_len}], got {n_pos}")
-            req_cache = jax.tree_util.tree_map(
-                lambda x: x[:, :, :n_pos], req_cache)
-        self.cache = _insert_row(self.cache, req_cache, slot)
-
-
-# --- paged pool -------------------------------------------------------------
 
 
 #: refcount slot for "no page": table entries >= num_pages are the
@@ -304,7 +226,7 @@ class PagedKVPool:
                     f"hbm_budget {hbm_budget} - reserve {reserve_bytes}"
                     f" does not fit one {self.page_bytes}-byte page")
         if num_pages is None:
-            # capacity parity with the slab pool by default; real
+            # every slot's worst case at once by default; real
             # deployments size this to the HBM budget and rely on
             # cost-aware admission + preemption
             num_pages = self.num_slots * self.pages_per_slot

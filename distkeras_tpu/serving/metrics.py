@@ -82,8 +82,8 @@ class ServingMetrics:
         self._decode_secs = self.registry.counter("serving.decode_seconds")
         # paged-KV accounting (paged-cache PR): page-budget gauges set
         # once per iteration, prefix-cache hit counters, preemptions.
-        # Gauges stay unset (None) on a slab engine — summary keys are
-        # additive and layout-honest
+        # Gauges stay unset (None) until the engine's first iteration
+        # is recorded — summary keys are additive
         self._pages_free = self.registry.gauge("serving.pages_free")
         self._pages_shared = self.registry.gauge("serving.pages_shared")
         self._page_frag = self.registry.gauge(
@@ -570,7 +570,8 @@ class ServingMetrics:
             "requests_cancelled": self.requests_cancelled,
             # paged-KV tally (keys ADDED by the paged-cache PR): page
             # budget at the last iteration, prefix-cache hit rate,
-            # preemption count; "pages" is None on a slab engine
+            # preemption count; "pages" is None before the first
+            # recorded iteration
             "requests_preempted": self.requests_preempted,
             # serving-router tally (key ADDED by the router PR):
             # live departures to another replica

@@ -69,9 +69,8 @@ class ReplicaUnavailable(AdmissionRejected):
 class EngineReplica:
     """One ``ServingEngine`` + lifecycle + placement signals.
 
-    The wrapped engine must use the paged KV layout: the router's
-    handoff and failover paths re-enter through the resumable
-    re-prefill machinery, which is paged-only. ``name`` defaults to the
+    The router's handoff and failover paths re-enter the engine
+    through its resumable re-prefill machinery. ``name`` defaults to the
     engine's ``engine_id`` and becomes the replica's label on every
     process-global record (ring entries, tracer timelines, telemetry
     component ``serving[<name>]`` — pass ``engine_id=<name>`` at engine
@@ -79,11 +78,6 @@ class EngineReplica:
 
     def __init__(self, engine: ServingEngine, *, name: Optional[str] = None,
                  role: str = "both"):
-        if engine.kv_layout != "paged":
-            raise ValueError(
-                "EngineReplica needs a paged-KV engine "
-                "(kv_layout='paged'): handoff/failover re-enter "
-                "through the resumable re-prefill path")
         if role not in ("both", "prefill", "decode"):
             raise ValueError(
                 f"role must be 'both', 'prefill' or 'decode', "

@@ -5,7 +5,8 @@ The scheduler is pure host-side bookkeeping — it never touches device
 arrays. Two policies (docs/serving.md; degradation semantics in
 docs/resilience.md):
 
-``FIFOScheduler`` (the slab-pool engine's policy, deliberately simple):
+``FIFOScheduler`` (``PriorityScheduler``'s base; no engine uses it
+alone):
 
   * FCFS admission: queued requests take free slots in arrival order.
   * BOUNDED queue: with ``max_queue`` set, a submit past the bound
@@ -22,7 +23,7 @@ docs/resilience.md):
   * Double-release is a loud error, never a silent double-free: two
     requests sharing one KV slot would corrupt both streams.
 
-``PriorityScheduler`` (the paged-pool engine's cost-aware policy):
+``PriorityScheduler`` (the engine's cost-aware policy):
 
   * Priority classes: lower ``Request.priority`` admits first
     (0 = interactive, 1 = standard, 2 = batch by convention; any int
@@ -30,8 +31,8 @@ docs/resilience.md):
     resume AT THE FRONT of their class (they hold progress).
   * Admission is budgeted: the engine admits head-of-line requests
     while ``peek()`` fits the free-PAGE budget (plus a free slot),
-    not merely while slots exist — the slab policy's failure mode was
-    admitting by worst-case slot count while HBM sat idle.
+    not merely while slots exist — admitting by worst-case slot
+    count leaves HBM idle.
   * PREEMPTION: ``preempt()`` ejects a DECODING request back to the
     queue (state → QUEUED, slot freed, generated tokens kept). The
     engine preempts when a decode step needs a page and none is free,

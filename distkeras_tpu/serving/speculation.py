@@ -5,7 +5,7 @@ parameter set plus the KV pages to emit ONE token per slot. Speculative
 decoding (Leviathan et al.) amortizes one target-model pass over ``k``
 candidate tokens: a cheap DRAFT proposes ``d_1..d_k`` per slot, the
 target scores the whole ``[tok, d_1, .., d_k]`` window in one batched
-verify step (``models.decoding.verify_step_slots[_paged]``), and the
+verify step (``models.decoding.verify_step_slots_paged``), and the
 longest prefix of drafts matching the target's own choices is accepted
 — plus the target's next candidate for free. High-acceptance streams
 emit up to ``k + 1`` tokens per target pass; the worst case emits the
@@ -46,7 +46,7 @@ decoding for the acceptance math.
 TREE SPECULATION (tree-speculation PR): the engine can also drive
 ``propose_tree`` — a per-slot token TREE (SpecInfer/Medusa-style
 multi-chain drafts) verified through ONE tree-masked window
-(``models.decoding.verify_step_slots[_tree kwarg]``). A tree raises
+(``models.decoding.verify_step_slots_paged(tree=)``). A tree raises
 expected accepted-tokens-per-verify over a single chain exactly when
 the chain's next token is AMBIGUOUS: several plausible continuations
 exist and the linear draft can only bet on one. ``NgramDraft`` trees

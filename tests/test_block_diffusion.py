@@ -494,8 +494,7 @@ def test_stop_token_ends_a_stream_inside_a_block():
     (dict(), "needs mask_token"),
     (dict(mask_token=MASK, denoising_steps=5), "denoising_steps must be"),
     (dict(mask_token=MASK, page_len=6), "whole multiples"),
-    (dict(mask_token=MASK, kv_layout="slab"), "paged pool"),
-    (dict(mask_token=MASK, fuse_steps=2), "paged pool"),
+    (dict(mask_token=MASK, fuse_steps=2), "without draft, fuse_steps"),
 ])
 def test_engine_states_what_block_diffusion_needs(kwargs, match):
     with pytest.raises(ValueError, match=match):

@@ -1,6 +1,6 @@
 """MoE-native serving (MoE-serving PR): the dispatched decode path's
-token-identity oracles against dense-routing ``generate()`` — slab +
-paged layouts, int8 cache, speculative verify windows, preempt/resume —
+token-identity oracles against dense-routing ``generate()`` — int8
+cache, speculative verify windows, preempt/resume —
 plus the drop-free ``MoE.decode_apply`` unit contract, shard_map
 expert-parallel decode on the 8-device CPU mesh, expert-load telemetry
 and the MoE-aware admission headroom."""
@@ -13,13 +13,15 @@ import jax.numpy as jnp
 from jax.sharding import Mesh
 
 from distkeras_tpu.models import Model, zoo
-from distkeras_tpu.models.decoding import (decode_step_slots, generate,
-                                           init_cache,
+from distkeras_tpu.models.decoding import (decode_step_slots,
+                                           decode_step_slots_paged,
+                                           generate, init_cache,
                                            _resolve_head_dims)
 from distkeras_tpu.models.moe import MoE
 from distkeras_tpu.ops import moe_kernels
 from distkeras_tpu.serving import (NgramDraft, Request, ServingEngine,
                                    ServingMetrics)
+from paged_layout import assert_same_cache, scrambled_tables, to_pages
 
 V, S = 29, 12
 PATTERN = np.array([3, 1, 4, 1, 5, 9, 2, 6, 5, 3, 5, 8])
@@ -118,16 +120,6 @@ def test_oracle_paged_staggered_arrivals(memorized_moe_lm):
         ref = generate(m, prompts[i][None], max_new_tokens=budgets[i],
                        temperature=0.0)
         np.testing.assert_array_equal(out[rid], ref[0])
-
-
-def test_oracle_slab_layout(memorized_moe_lm):
-    m = memorized_moe_lm
-    eng = ServingEngine(m, num_slots=2, max_len=32, kv_layout="slab")
-    rid = eng.submit(PATTERN[:4], 7)
-    out = eng.run(max_steps=300)
-    ref = generate(m, PATTERN[None, :4], max_new_tokens=7,
-                   temperature=0.0)
-    np.testing.assert_array_equal(out[rid], ref[0])
 
 
 def test_oracle_int8_cache(memorized_moe_lm):
@@ -349,3 +341,40 @@ def test_decode_step_slots_moe_stats_mask_sentinels():
     load = np.asarray(stats["expert_load"])
     # 2 MoE layers x 1 live token x top-2 = 4 assignments
     assert load.sum() == 4.0
+
+
+def test_decode_step_slots_paged_moe_matches_contiguous():
+    """The dispatched MoE step the engine runs (paged, scattered
+    physical pages, one inert slot) against its reference
+    ``decode_step_slots``: logits of the live slots, the routing
+    stats, and the cache in logical order."""
+    L, page_len = 16, 4
+    m = _moe_lm(seed=4)
+    _resolve_head_dims(m.module, m.params)
+    cache = init_cache(m.module, 3, L)
+    rs = np.random.RandomState(2)
+    for step in range(3):              # slots 0 and 1 at depths 3 and 2
+        tok = jnp.asarray(rs.randint(0, V, 3).astype(np.int32))
+        t = jnp.asarray(np.array([step, step if step < 2 else L, L],
+                                 np.int32))
+        _, cache = decode_step_slots(m.module, m.params, m.state, cache,
+                                     tok, t)
+    tok = jnp.asarray(np.array([3, 1, 4], np.int32))
+    t = jnp.asarray(np.array([3, 2, L], np.int32))    # slot 2 inert
+    tables, n_pages = scrambled_tables(3, L // page_len, seed=9)
+    ref_lg, ref_cache, ref_st = decode_step_slots(
+        m.module, m.params, m.state, cache, tok, t, moe_stats=L)
+    got_lg, got_cache, got_st = decode_step_slots_paged(
+        m.module, m.params, m.state,
+        to_pages(cache, tables, page_len, n_pages), tok, t,
+        jnp.asarray(tables), page_len, moe_stats=L)
+    np.testing.assert_allclose(np.asarray(got_lg)[:2],
+                               np.asarray(ref_lg)[:2], atol=1e-5)
+    load = np.asarray(got_st["expert_load"])
+    assert load.sum() == 2 * 2 * 2      # 2 layers x 2 live x top-2
+    np.testing.assert_array_equal(load,
+                                  np.asarray(ref_st["expert_load"]))
+    np.testing.assert_allclose(np.asarray(got_st["router_entropy"]),
+                               np.asarray(ref_st["router_entropy"]),
+                               rtol=1e-5)
+    assert_same_cache(ref_cache, got_cache, tables)

@@ -587,7 +587,7 @@ def test_serving_summary_keys_are_backward_compatible():
         # the tpot_p99 objective)
         "tpot_s",
         # paged-KV tally ADDED by the paged-cache PR ("pages" is None
-        # on a slab engine / before any iteration)
+        # before any iteration)
         "requests_preempted", "pages", "prefix_cache",
         # speculative decoding ADDED by the spec-decode PR
         # ("acceptance_rate" is None before any verify ran)

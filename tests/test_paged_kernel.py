@@ -216,9 +216,6 @@ def test_decode_logits_kernel_and_gather_read_the_same_state(
                                temperature=0.0)[0])
     assert eng.health()["programs"]["decode_logits[off,None]"] == \
         "kv_cache=kept, paged_attention=gather_reference"
-    with pytest.raises(ValueError, match="paged cache"):
-        ServingEngine(m, num_slots=1, max_len=32,
-                      kv_layout="slab").decode_logits()
 
 
 def test_engine_kernel_sampled_matches_gather_engine(memorized_lm):

@@ -494,9 +494,6 @@ def test_router_telemetry_and_health_views(memorized_lm):
 
 def test_replica_validation(memorized_lm):
     m = memorized_lm
-    with pytest.raises(ValueError, match="paged"):
-        EngineReplica(ServingEngine(m, num_slots=1, max_len=32,
-                                    kv_layout="slab"))
     with pytest.raises(ValueError, match="role"):
         EngineReplica(_engine(m, "rv0"), role="verifier")
     with pytest.raises(ValueError, match="duplicate"):

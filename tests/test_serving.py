@@ -14,10 +14,11 @@ from distkeras_tpu.models import Model, zoo
 from distkeras_tpu.models.decoding import (decode_step, decode_step_slots,
                                            generate, init_cache,
                                            _resolve_head_dims)
-from distkeras_tpu.serving import (FIFOScheduler, KVPool, PagedKVPool,
+from distkeras_tpu.serving import (FIFOScheduler, PagedKVPool,
                                    PriorityScheduler, Request,
                                    RequestState, ServingEngine,
                                    ServingMetrics)
+from paged_layout import assert_same_cache, scrambled_tables, to_pages
 
 V, S = 29, 12
 PATTERN = np.array([3, 1, 4, 1, 5, 9, 2, 6, 5, 3, 5, 8])
@@ -212,9 +213,9 @@ def test_decode_step_slots_staggered_positions_match_scalar():
                                np.concatenate(refs, axis=0), atol=2e-5)
 
 
-def _advance(m, row_toks, depth):
+def _advance(m, row_toks, depth, cap=S, dtype=jnp.float32):
     """Scalar-decode a single row ``depth`` steps; returns its cache."""
-    c = init_cache(m.module, 1, S)
+    c = init_cache(m.module, 1, cap, dtype)
     for t in range(depth):
         _, c = decode_step(m.module, m.params, m.state, c,
                            jnp.asarray(row_toks[:, t]), t)
@@ -259,26 +260,6 @@ def test_prefill_program_cache_is_lru_capped(memorized_lm):
 # --- kv pool ----------------------------------------------------------------
 
 
-def test_kv_pool_insert_places_request_rows():
-    m = Model.build(
-        zoo.transformer_lm(V, d_model=16, num_heads=2, num_layers=2,
-                           mlp_ratio=2, use_rope=True), (S,), seed=1)
-    _resolve_head_dims(m.module, m.params)
-    pool = KVPool(m.module, num_slots=3, max_len=10)
-    req = pool.make_request_cache()
-    req = jax.tree_util.tree_map(
-        lambda x: jnp.full_like(x, 7.0), req)
-    pool.insert(req, 1)
-    for layer in pool.cache:
-        if layer is None:
-            continue
-        arr = np.asarray(layer["k"])
-        assert (arr[1] == 7.0).all()
-        assert (arr[0] == 0.0).all() and (arr[2] == 0.0).all()
-    with pytest.raises(ValueError, match="slot"):
-        pool.insert(req, 3)
-
-
 def test_kv_pool_rejects_capacity_beyond_position_table():
     m = Model.build(
         zoo.transformer_lm(V, d_model=16, num_heads=2, num_layers=1,
@@ -286,7 +267,7 @@ def test_kv_pool_rejects_capacity_beyond_position_table():
         (S,), seed=1)
     _resolve_head_dims(m.module, m.params)
     with pytest.raises(ValueError, match="too small"):
-        KVPool(m.module, num_slots=2, max_len=17)
+        PagedKVPool(m.module, num_slots=2, max_len=17, page_len=4)
 
 
 # --- scheduler --------------------------------------------------------------
@@ -417,19 +398,6 @@ def test_paged_small_pages_oracle_matches_generate(memorized_lm):
         np.testing.assert_array_equal(out[rid], ref[0])
 
 
-def test_slab_layout_oracle_still_matches_generate(memorized_lm):
-    """The legacy slab pool stays selectable and token-identical (the
-    equal-HBM bench baseline)."""
-    m = memorized_lm
-    eng = ServingEngine(m, num_slots=2, max_len=32, kv_layout="slab")
-    assert isinstance(eng.pool, KVPool) and eng.prefix is None
-    rid = eng.submit(PATTERN[:4], 7)
-    out = eng.run(max_steps=300)
-    ref = generate(m, PATTERN[None, :4], max_new_tokens=7,
-                   temperature=0.0)
-    np.testing.assert_array_equal(out[rid], ref[0])
-
-
 def test_paged_int8_cache_shares_tables_with_scales(memorized_lm):
     """int8 quantized cache x paged pool: payload AND scale planes move
     through the same page tables — token-identical to generate() with
@@ -449,51 +417,39 @@ def test_paged_int8_cache_shares_tables_with_scales(memorized_lm):
     np.testing.assert_array_equal(out[rid2], ref2[0])
 
 
-def test_decode_step_slots_paged_matches_slab_logits():
-    """The paged decode step over scattered physical pages must produce
-    the slab step's logits: same values in logical order after the
-    gather, same masked attention."""
+@pytest.mark.parametrize("page_len", [4, 8])
+@pytest.mark.parametrize("cache_dtype", ["float32", "int8"])
+def test_decode_step_slots_paged_matches_contiguous_logits(cache_dtype,
+                                                           page_len):
+    """The paged decode step over scattered physical pages against its
+    reference, ``decode_step_slots`` on the same values in one
+    contiguous cache: the same logits, and the same cache in logical
+    order after the step's write (payload and, for int8, scales)."""
     from distkeras_tpu.models.decoding import decode_step_slots_paged
+    L = 16
     m = Model.build(
         zoo.transformer_lm(V, d_model=32, num_heads=4, num_layers=2,
-                           mlp_ratio=2, use_rope=True), (S,), seed=4)
+                           mlp_ratio=2, use_rope=True), (L,), seed=4)
     _resolve_head_dims(m.module, m.params)
     rs = np.random.RandomState(1)
     toks = rs.randint(0, V, (2, 8)).astype(np.int32)
-    slab = [None if a is None else
-            {k: jnp.concatenate([a[k], b[k]], axis=0) for k in a}
-            for a, b in zip(_advance(m, toks[0:1], 4),
-                            _advance(m, toks[1:2], 2))]
-    page_len = 4
-    n_logical = S // page_len                    # 3 logical pages/slot
-    # scrambled physical placement: slot 0 -> pages [5, 2, 0],
-    # slot 1 -> pages [1, 4, 3]
-    tables = np.array([[5, 2, 0], [1, 4, 3]], np.int32)
-    paged = []
-    for layer in slab:
-        if layer is None:
-            paged.append(None)
-            continue
-        entry = {}
-        for k, arr in layer.items():
-            arr = np.asarray(arr)                # [2, H, S, ...]
-            pool = np.zeros((6,) + arr.shape[1:2]
-                            + (page_len,) + arr.shape[3:], arr.dtype)
-            for slot in range(2):
-                for j in range(n_logical):
-                    pool[tables[slot, j]] = \
-                        arr[slot, :, j * page_len:(j + 1) * page_len]
-            entry[k] = jnp.asarray(pool)
-        paged.append(entry)
+    contiguous = [None if a is None else
+                  {k: jnp.concatenate([a[k], b[k]], axis=0) for k in a}
+                  for a, b in zip(
+                      _advance(m, toks[0:1], 4, L, cache_dtype),
+                      _advance(m, toks[1:2], 2, L, cache_dtype))]
+    tables, n_pages = scrambled_tables(2, L // page_len, seed=3)
+    paged = to_pages(contiguous, tables, page_len, n_pages)
     tok = jnp.asarray(np.stack([toks[0, 4], toks[1, 2]]))
     t = jnp.asarray(np.array([4, 2], np.int32))
-    ref_logits, _ = decode_step_slots(m.module, m.params, m.state,
-                                      slab, tok, t)
-    got_logits, _ = decode_step_slots_paged(
+    ref_logits, ref_cache = decode_step_slots(
+        m.module, m.params, m.state, contiguous, tok, t)
+    got_logits, got_cache = decode_step_slots_paged(
         m.module, m.params, m.state, paged, tok,
         t, jnp.asarray(tables), page_len)
     np.testing.assert_allclose(np.asarray(got_logits),
                                np.asarray(ref_logits), atol=1e-5)
+    assert_same_cache(ref_cache, got_cache, tables)
 
 
 def test_prefix_sharing_skips_prefill_and_matches_generate(memorized_lm):
@@ -787,7 +743,7 @@ def test_engine_priority_admission_preempts_lower_class(memorized_lm):
 def test_paged_pool_refcounts_and_partial_insert():
     """PagedKVPool unit contract: alloc/incref/decref accounting,
     release returns pages, and insert touches ONLY the pages the
-    prompt fills (the slab pool's full-row admit write, fixed)."""
+    prompt fills."""
     m = Model.build(
         zoo.transformer_lm(V, d_model=16, num_heads=2, num_layers=2,
                            mlp_ratio=2, use_rope=True), (S,), seed=1)
@@ -822,33 +778,9 @@ def test_paged_pool_refcounts_and_partial_insert():
         pool.decref(p1)
 
 
-def test_slab_insert_writes_only_prompt_positions():
-    """Satellite fix on the legacy pool: admit writes the prompt's
-    rows, not all max_len positions."""
-    m = Model.build(
-        zoo.transformer_lm(V, d_model=16, num_heads=2, num_layers=2,
-                           mlp_ratio=2, use_rope=True), (S,), seed=1)
-    _resolve_head_dims(m.module, m.params)
-    pool = KVPool(m.module, num_slots=3, max_len=10)
-    pool.cache = jax.tree_util.tree_map(
-        lambda x: jnp.full_like(x, 9.0), pool.cache)
-    req = jax.tree_util.tree_map(
-        lambda x: jnp.full_like(x, 7.0), pool.make_request_cache())
-    pool.insert(req, 1, n_pos=3)
-    for layer in pool.cache:
-        if layer is None:
-            continue
-        arr = np.asarray(layer["k"])
-        assert (arr[1][:, :3] == 7.0).all()
-        assert (arr[1][:, 3:] == 9.0).all()      # tail untouched
-        assert (arr[0] == 9.0).all() and (arr[2] == 9.0).all()
-    with pytest.raises(ValueError, match="n_pos"):
-        pool.insert(req, 1, n_pos=11)
-
-
 def test_page_metrics_summary_and_health(memorized_lm):
     """Satellite: page-accounting gauges + prefix hit counters land in
-    summary() and health(); the slab engine honestly reports None."""
+    summary() and health(), with or without a prefix cache."""
     m = memorized_lm
     eng = ServingEngine(m, num_slots=2, max_len=32, page_len=4)
     eng.submit(np.tile(PATTERN, 2)[:9], 5)
@@ -864,11 +796,14 @@ def test_page_metrics_summary_and_health(memorized_lm):
     assert h["pages"]["page_len"] == 4
     assert h["prefix_cache"]["nodes"] == len(eng.prefix)
     assert h["requests"]["preempted"] == 0
-    slab = ServingEngine(m, num_slots=1, max_len=16, kv_layout="slab")
-    slab.submit(PATTERN[:4], 3)
-    slab.run(max_steps=200)
-    assert slab.metrics.summary()["pages"] is None
-    assert "pages" not in slab.health()
+    bare = ServingEngine(m, num_slots=1, max_len=16, prefix_cache=False)
+    assert bare.metrics.summary()["pages"] is None   # no iteration yet
+    bare.submit(PATTERN[:4], 3)
+    bare.run(max_steps=200)
+    assert bare.metrics.summary()["pages"]["free"] == bare.pool.num_pages
+    h = bare.health()
+    assert h["pages"]["free"] == bare.pool.num_pages
+    assert h["prefix_cache"] is None
 
 
 def test_preemption_lands_in_flight_recorder(memorized_lm):
@@ -996,3 +931,42 @@ def test_engine_records_serving_metrics(memorized_lm):
     assert s["queue_depth"]["max"] >= 1        # third request queued
     assert s["phases"]["prefill"]["count"] == s["prefill_chunks"]
     assert s["decode_tokens_per_sec"] > 0
+
+
+# --- one KV layout ----------------------------------------------------------
+
+
+def _refuses_a_layout_choice(m):
+    """No option selects a layout, and none is accepted and ignored."""
+    import distkeras_tpu.serving as serving
+    for layout in ("slab", "paged"):
+        with pytest.raises(TypeError, match="kv_layout"):
+            ServingEngine(m, num_slots=1, max_len=16, kv_layout=layout)
+    assert not hasattr(serving, "KVPool")
+    eng = ServingEngine(m, num_slots=1, max_len=16)
+    assert isinstance(eng.pool, PagedKVPool)
+    assert isinstance(eng.scheduler, PriorityScheduler)
+    assert not hasattr(eng, "kv_layout")
+
+
+def _engine_cannot_reach_the_reference(m):
+    """The contiguous step functions are the tests' reference: nothing
+    in ``serving/engine.py`` imports or names them."""
+    import ast
+    import distkeras_tpu.serving.engine as engine
+    tree = ast.parse(open(engine.__file__).read())
+    named = {n.id for n in ast.walk(tree) if isinstance(n, ast.Name)} \
+        | {n.attr for n in ast.walk(tree) if isinstance(n, ast.Attribute)} \
+        | {a.name for n in ast.walk(tree)
+           if isinstance(n, (ast.Import, ast.ImportFrom))
+           for a in n.names}
+    assert "decode_step_slots_paged" in named        # the lint can see
+    assert not named & {"decode_step_slots", "verify_step_slots",
+                        "KVPool", "FIFOScheduler"}
+
+
+@pytest.mark.parametrize("check", [_refuses_a_layout_choice,
+                                   _engine_cannot_reach_the_reference],
+                         ids=["behaviour", "imports"])
+def test_engine_has_one_kv_layout(memorized_lm, check):
+    check(memorized_lm)

@@ -105,7 +105,7 @@ def spied(monkeypatch):
                     2 if donate_cache else None, log)
 
     monkeypatch.setattr(ServingEngine, "_jit_serving", engine_program)
-    for name, donates in (("_write_pages", 0), ("_insert_row", 0),
+    for name, donates in (("_write_pages", 0),
                           ("_scatter_rows", 0), ("_load_pages", 0),
                           ("_gather_rows", None)):
         monkeypatch.setattr(kv_pool, name, _Spy(getattr(kv_pool, name),
@@ -153,8 +153,6 @@ SCENARIOS = {
               "draft_prefill", "draft_decode"]),
     "weight_quant": (dict(page_len=4, weight_quant="int8"), [GREEDY],
                      ["decode_greedy", "prefill"]),
-    "slab": (dict(kv_layout="slab"), [GREEDY],
-             ["decode_greedy", "prefill", "_insert_row"]),
     "offload": (dict(page_len=4, num_pages=8, prefix_cache=False,
                      host_kv_pages=16),
                 [[(REP[:5], 16, {}), (REP[:6], 15, {})]],
@@ -394,7 +392,7 @@ def test_pool_programs_donate_by_name():
     (``prefill_share.serve``: ``_write_pages``, ``_load_pages``) and a
     kept handle fails loudly, not quietly."""
     pool = {"k": jnp.zeros((4, 2, 4, 8))}
-    for name in ("_write_pages", "_load_pages", "_insert_row",
+    for name in ("_write_pages", "_load_pages",
                  "_scatter_rows", "_gather_rows"):
         assert getattr(kv_pool, name).__name__ == name
     new = kv_pool._scatter_rows(pool, jnp.asarray([1]),

@@ -3,7 +3,7 @@ dispatch (``overlap=True``, the engine default) and the fused
 multi-step window (``fuse_steps=K``) must produce TOKEN-IDENTICAL
 outputs (byte-identical for sampled streams) to the synchronous
 launch-and-wait loop and to standalone ``generate()`` — across
-slab/paged layouts, int8 cache, speculation, MoE dispatched decode and
+int8 cache, speculation, MoE dispatched decode and
 preempt/resume — plus the lagged-fetch edge cases: stop tokens
 mid-window and mid-fused-scan, preemption during a fused window
 (fall back to single-step, rejoin identically), cancel/metrics-swap

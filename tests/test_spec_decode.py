@@ -1,7 +1,7 @@
 """Speculative decoding in the continuous-batching engine (spec-decode
 PR): the oracle contract — greedy speculative outputs token-identical
-per request to standalone ``generate()`` across BOTH draft sources and
-BOTH KV layouts, sampled streams byte-identical to plain decode — plus
+per request to standalone ``generate()`` across BOTH draft sources,
+sampled streams byte-identical to plain decode — plus
 verify-step units, n-gram lookup units, acceptance-EMA degradation,
 draft-pool starvation isolation, and metrics/tracer coverage."""
 
@@ -14,9 +14,12 @@ from distkeras_tpu.models import Model, zoo
 from distkeras_tpu.models.decoding import (_resolve_head_dims,
                                            decode_step_slots, generate,
                                            init_cache,
-                                           verify_step_slots)
+                                           verify_step_slots,
+                                           verify_step_slots_paged)
 from distkeras_tpu.serving import (DraftModel, DraftSource, NgramDraft,
                                    ServingEngine)
+from distkeras_tpu.serving.speculation import tree_ancestors
+from paged_layout import assert_same_cache, scrambled_tables, to_pages
 
 V, S = 29, 12
 PATTERN = np.array([3, 1, 4, 1, 5, 9, 2, 6, 5, 3, 5, 8])
@@ -90,6 +93,60 @@ def test_verify_step_slots_matches_sequential_decode():
         for key in a:
             np.testing.assert_allclose(np.asarray(a[key]),
                                        np.asarray(b[key]), atol=3e-5)
+
+
+@pytest.mark.parametrize("cache_dtype", ["float32", "int8"])
+@pytest.mark.parametrize("window", ["linear", "tree"])
+def test_verify_step_slots_paged_matches_contiguous(window, cache_dtype):
+    """The engine's verify, ``verify_step_slots_paged`` over scattered
+    physical pages, against its reference ``verify_step_slots`` on the
+    same values in one contiguous cache: logits at every window
+    position, the cache in logical order after the window's writes
+    and, for a tree, the roped window K/V the commit reads."""
+    L, page_len, W = 16, 4, 4
+    m = Model.build(
+        zoo.transformer_lm(V, d_model=32, num_heads=4, num_layers=2,
+                           mlp_ratio=2, use_rope=True), (L,), seed=4)
+    _resolve_head_dims(m.module, m.params)
+    rs = np.random.RandomState(0)
+    toks = rs.randint(0, V, (2, 10)).astype(np.int32)
+    hist = [3, 2]                       # staggered per-slot depths
+    cache = init_cache(m.module, 2, L, cache_dtype)
+    for step in range(max(hist)):
+        tk = np.array([toks[i, min(step, hist[i] - 1)]
+                       for i in range(2)], np.int32)
+        tv = np.array([step if step < hist[i] else L
+                       for i in range(2)], np.int32)
+        _, cache = decode_step_slots(m.module, m.params, m.state, cache,
+                                     jnp.asarray(tk), jnp.asarray(tv))
+    win = np.stack([toks[0, hist[0]:hist[0] + W],
+                    toks[1, hist[1]:hist[1] + W]], 0)
+    kw = {}
+    if window == "tree":
+        # root(0) -> 1 -> 2, root -> 3: a branch no chain expresses
+        parents = np.tile(np.array([-1, 0, 1, 0], np.int32), (2, 1))
+        depth, anc, _ = tree_ancestors(parents)
+        kw["tree"] = {"depth": jnp.asarray(depth),
+                      "anc": jnp.asarray(anc)}
+    t = jnp.asarray(np.array(hist, np.int32))
+    tables, n_pages = scrambled_tables(2, L // page_len, seed=5)
+    ref = verify_step_slots(m.module, m.params, m.state, cache,
+                            jnp.asarray(win), t, **kw)
+    got = verify_step_slots_paged(
+        m.module, m.params, m.state,
+        to_pages(cache, tables, page_len, n_pages), jnp.asarray(win), t,
+        jnp.asarray(tables), page_len, **kw)
+    assert len(ref) == len(got) == (3 if window == "tree" else 2)
+    np.testing.assert_allclose(np.asarray(got[0]), np.asarray(ref[0]),
+                               atol=3e-5)
+    assert_same_cache(ref[1], got[1], tables)
+    if window == "tree":
+        for a, b in zip(ref[2], got[2]):
+            assert (a is None) == (b is None)
+            if a is not None:
+                for x, y in zip(a, b):
+                    np.testing.assert_array_equal(np.asarray(x),
+                                                  np.asarray(y))
 
 
 def test_verify_step_sentinel_slot_writes_nothing():
@@ -178,27 +235,6 @@ def test_greedy_draft_model_spec_matches_generate(memorized_lm):
     np.testing.assert_array_equal(
         out[r1], generate(m, PATTERN[None, :5], 10, temperature=0.0)[0])
     assert eng.metrics.summary()["acceptance_rate"] > 0.8
-
-
-@pytest.mark.parametrize("spec_tree", [False, True])
-def test_greedy_spec_slab_layout_matches_generate(memorized_lm, spec_tree):
-    """The slab pool speculates too (verify_step_slots, one-hot window
-    writes): token identity + acceptance on the legacy layout."""
-    m = memorized_lm
-    eng = ServingEngine(m, num_slots=2, max_len=48, kv_layout="slab",
-                        draft=NgramDraft(), spec_k=3, **_tree(spec_tree))
-    r0 = eng.submit(np.tile(PATTERN, 2)[:10], 12)
-    r1 = eng.submit(np.tile(PATTERN, 2)[:14], 8)
-    out = eng.run(max_steps=800)
-    np.testing.assert_array_equal(
-        out[r0],
-        generate(m, np.tile(PATTERN, 2)[None, :10], 12,
-                 temperature=0.0)[0])
-    np.testing.assert_array_equal(
-        out[r1],
-        generate(m, np.tile(PATTERN, 2)[None, :14], 8,
-                 temperature=0.0)[0])
-    assert eng.metrics.summary()["speculation"]["accepted"] > 0
 
 
 @pytest.mark.parametrize("spec_tree", [False, True])

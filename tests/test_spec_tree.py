@@ -21,11 +21,13 @@ from distkeras_tpu.models.decoding import (_resolve_head_dims,
                                            commit_tree_path,
                                            decode_step_slots, generate,
                                            init_cache, tree_walk,
-                                           verify_step_slots)
+                                           verify_step_slots,
+                                           verify_step_slots_paged)
 from distkeras_tpu.serving import (DraftModel, DraftSource, NgramDraft,
                                    ServingEngine)
 from distkeras_tpu.serving.speculation import (build_token_tree,
                                                tree_ancestors)
+from paged_layout import assert_same_cache, scrambled_tables, to_pages
 
 V, S = 29, 12
 PATTERN = np.array([3, 1, 4, 1, 5, 9, 2, 6, 5, 3, 5, 8])
@@ -170,6 +172,53 @@ def test_walk_and_commit_match_sequential_cache(small_lm):
                                jnp.asarray((t + 2).astype(np.int32)))
     np.testing.assert_allclose(np.asarray(nxt), np.asarray(ref),
                                atol=3e-5)
+
+
+def test_commit_tree_path_paged_matches_contiguous_commit(small_lm):
+    """The engine's commit (``commit_tree_path`` through a page table)
+    against the table-less reference the test above anchors to
+    sequential decode: after a walk that accepts a branch and one that
+    stops at the root, the committed K/V in logical order are equal —
+    accepted depths re-written, everything past them left as the
+    verify window wrote it."""
+    m = small_lm
+    L, page_len, W = 16, 4, 4
+    rs = np.random.RandomState(0)
+    toks = rs.randint(0, V, (2, 12)).astype(np.int32)
+    hist = [3, 2]
+    cache = _warm_cache(m, toks, hist, cap=L)
+    t = jnp.asarray(np.array(hist, np.int32))
+    lg0, _ = decode_step_slots(m.module, m.params, m.state, cache,
+                               jnp.asarray(toks[:, 0]), t)
+    arg0 = np.asarray(jnp.argmax(lg0, -1)).astype(np.int32)
+    win = np.zeros((2, W), np.int32)
+    win[:, 0] = toks[:, 0]
+    win[:, 1] = (arg0 + 5) % V               # wrong depth-1 branch
+    win[:, 2] = arg0                         # slot 0 walks into it
+    win[1, 2] = (arg0[1] + 9) % V            # slot 1 finds no child
+    win[:, 3] = 1
+    parents = np.tile(np.array([-1, 0, 0, 2], np.int32), (2, 1))
+    depth, anc, _ = tree_ancestors(parents)
+    tree = {"depth": jnp.asarray(depth), "anc": jnp.asarray(anc)}
+    tables, n_pages = scrambled_tables(2, L // page_len, seed=7)
+    lg, c_ref, kv_ref = verify_step_slots(
+        m.module, m.params, m.state, cache, jnp.asarray(win), t,
+        tree=tree)
+    _, c_pg, kv_pg = verify_step_slots_paged(
+        m.module, m.params, m.state,
+        to_pages(cache, tables, page_len, n_pages), jnp.asarray(win), t,
+        jnp.asarray(tables), page_len, tree=tree)
+    _, ne, path, _ = tree_walk(lg, jnp.asarray(win), jnp.asarray(parents))
+    assert np.asarray(ne).tolist()[1] == 1 and np.asarray(ne)[0] >= 2
+    ref = commit_tree_path(c_ref, kv_ref, path, t, ne)
+    got = commit_tree_path(c_pg, kv_pg, path, t, ne,
+                           table=jnp.asarray(tables), page_len=page_len)
+    assert_same_cache(ref, got, tables)
+    # the commit re-wrote the accepted path
+    assert any(not np.array_equal(np.asarray(a[key]),
+                                  np.asarray(before[key]))
+               for a, before in zip(ref, c_ref) if a is not None
+               for key in a)
 
 
 def test_paged_kernel_tree_mask_matches_gather_reference():

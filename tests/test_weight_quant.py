@@ -229,9 +229,8 @@ def test_engine_weight_quant_validates():
     m = _tiny_lm()
     with pytest.raises(ValueError, match="weight_quant"):
         ServingEngine(m, num_slots=1, max_len=32, weight_quant="fp8")
-    with pytest.raises(ValueError, match="paged"):
-        ServingEngine(m, num_slots=1, max_len=32, kv_layout="slab",
-                      hbm_budget=1 << 20)
+    with pytest.raises(ValueError, match="does not fit one"):
+        ServingEngine(m, num_slots=1, max_len=32, hbm_budget=1)
 
 
 def test_generate_int4_weights_close_to_float(memorized_lm):
