@@ -1470,10 +1470,10 @@ def block_pass_slots_paged(module: Sequential, params, state, cache,
     With ``head`` returns ``(best [S, B] int32, conf [S, B] f32, cache,
     routed)``: at every position the most probable token and its
     log-probability (best logit less the log-sum-exp over the
-    vocabulary, float32) — the engine fixes the most confident masked
-    positions from them. Without ``head`` (a pass in which every live
-    slot only commits) the stack stops at the deepest attention
-    block's K/V write and the return is ``(cache, routed)``.
+    vocabulary, float32) — :func:`fix_most_confident` fixes the most
+    confident masked positions from them. Without ``head`` (a pass in
+    which every live slot only commits) the stack stops at the deepest
+    attention block's K/V write and the return is ``(cache, routed)``.
     ``routed`` is :func:`routing_counts` of the expert layers that ran
     (zeros for a model without dispatched experts)."""
     x = toks
@@ -1512,6 +1512,54 @@ def block_pass_slots_paged(module: Sequential, params, state, cache,
         conf = jnp.max(logits, axis=-1) \
             - jax.scipy.special.logsumexp(logits, axis=-1)
     return best, conf, new_cache, routed
+
+
+def fix_most_confident(toks, masked, fixed_pass, best, conf, n_fix, step):
+    """The denoising choice of one pass, per slot: the ``n_fix [S]``
+    most confident positions still ``masked [S, B]`` take their most
+    probable token (``best``), ``fixed_pass`` records the slot's
+    denoising ``step [S]`` there, and they stop being masked. Returns
+    ``(toks, masked, fixed_pass)`` after the fix; a slot with ``n_fix``
+    0 (one that commits, or an idle one) keeps its rows.
+
+    The order is that of a stable ascending sort of ``-conf`` with
+    unmasked positions at ``+inf`` (equal confidences: the earliest
+    position first; a NaN last, as numpy sorts it), taken as each
+    position's RANK in that order by comparing every pair: B is a
+    block, and no sort runs on the device."""
+    key = jnp.where(masked, -conf.astype(jnp.float32), jnp.inf)
+    a, b = key[:, :, None], key[:, None, :]          # b before a?
+    nan_a, nan_b = jnp.isnan(a), jnp.isnan(b)
+    idx = jnp.arange(key.shape[1])
+    before = (b < a) | (nan_a & ~nan_b) \
+        | (((b == a) | (nan_a & nan_b)) & (idx[None, :] < idx[:, None]))
+    rank = jnp.sum(before, axis=2, dtype=jnp.int32)
+    fix = rank < n_fix[:, None]
+    return (jnp.where(fix, best, toks), masked & ~fix,
+            jnp.where(fix, step[:, None].astype(fixed_pass.dtype),
+                      fixed_pass))
+
+
+def block_denoise_slots_paged(module: Sequential, params, state, cache,
+                              toks, masked, fixed_pass, n_fix, step, t,
+                              table, page_len: int, *,
+                              moe_dispatched: bool = True,
+                              paged_kernel=None):
+    """A denoising pass WITH its choice (the serving engine's
+    ``denoise`` program): :func:`block_pass_slots_paged` with the
+    vocabulary head over the blocks as they stand, then
+    :func:`fix_most_confident` on its float32 log-probabilities. Takes
+    and returns the block state as arrays of the device, so the next
+    pass can be queued on this one's result before the host has read
+    anything: ``(toks, masked, fixed_pass, cache, routed)``."""
+    best, conf, cache, routed = block_pass_slots_paged(
+        module, params, state, cache, toks, t, table, page_len,
+        head=True, moe_dispatched=moe_dispatched,
+        paged_kernel=paged_kernel)
+    with jax.named_scope("sample"):
+        toks, masked, fixed_pass = fix_most_confident(
+            toks, masked, fixed_pass, best, conf, n_fix, step)
+    return toks, masked, fixed_pass, cache, routed
 
 
 def tree_walk(logits, toks, parents, *, temperature=None, top_k=None,

@@ -100,6 +100,7 @@ from distkeras_tpu.obs.tracing import resolve_tracer
 from distkeras_tpu.models.core import Model, Sequential
 from distkeras_tpu.models.decoding import (_attn_compute_dtype,
                                            _decode_block_of,
+                                           block_denoise_slots_paged,
                                            block_len_of,
                                            block_pass_slots_paged,
                                            _resolve_head_dims,
@@ -171,6 +172,34 @@ class _PendingStep:
         self.slots = slots                   # tuple of (slot, rid)
         self.covers = {s: r for s, r in slots}
         self.count = count                   # tokens per covered slot
+        self.launch_t = launch_t
+
+
+class _PendingPass:
+    """One launched-but-unread block-diffusion pass: the block state
+    its program returned (``blk``: tokens and the pass that fixed each,
+    arrays of the device; None for a head-less commit pass), its and
+    the earlier prefills' routing counts, and what the host knew at
+    launch: the program that ran, the ``(slot, rid)`` pairs that rode
+    it (a slot recycled in the meantime discards its rows), the live
+    slots that denoised and committed, the slots whose block this pass
+    makes whole (``shown``), and whether the pass before it was still
+    unread when this one was dispatched."""
+
+    __slots__ = ("kind", "blk", "routed", "prefills", "slots", "shown",
+                 "denoising", "committing", "overlapped", "launch_t")
+
+    def __init__(self, kind, blk, routed, prefills, slots, shown,
+                 denoising, committing, overlapped, launch_t):
+        self.kind = kind
+        self.blk = blk
+        self.routed = routed
+        self.prefills = prefills
+        self.slots = slots                   # tuple of (slot, rid)
+        self.shown = shown                   # tuple of (slot, rid)
+        self.denoising = denoising
+        self.committing = committing
+        self.overlapped = overlapped
         self.launch_t = launch_t
 
 
@@ -679,14 +708,27 @@ class ServingEngine:
         self._step_fns = {}                  # greedy_only -> jit
         self._block_fns = {}                 # with head? -> jit
         if self.block_len is not None:
-            # per-slot block state (host): the block as it stands, which
-            # positions are still masked, which pass fixed each, and how
-            # many denoising passes the block has had
+            # per-slot block state. The blocks as they stand live on the
+            # DEVICE (``_blk_dev``: tokens, which positions are still
+            # masked, which pass fixed each): every denoise program
+            # takes the last one's and returns its successor, so a pass
+            # is queued on the one before it without a read. The host
+            # keeps what it knows without a result, the schedule being
+            # static: the denoising passes a block has had, the masked
+            # positions left, whether the slot's request ends once its
+            # block is shown; and the rows
+            # only it knows (a block opened by a join or after a commit),
+            # which override the device's at the next launch
             bl = self.block_len
             self._blk_tok = np.full((s, bl), self.mask_token, np.int32)
             self._blk_masked = np.zeros((s, bl), bool)
-            self._blk_pass = np.full((s, bl), -1, np.int32)
-            self._blk_step = np.zeros(s, np.int64)
+            self._blk_ovr = np.zeros(s, bool)
+            self._blk_step = np.zeros(s, np.int32)
+            self._blk_left = np.zeros(s, np.int32)
+            self._blk_ended = np.zeros(s, bool)
+            self._blk_dev = (jnp.asarray(self._blk_tok),
+                             jnp.asarray(self._blk_masked),
+                             jnp.asarray(np.full((s, bl), -1, np.int32)))
         #: program name -> the kernel-or-reference choices made while
         #: it was traced (``_jit_serving``); read it in ``health()``
         self.program_paths: Dict[str, str] = {}
@@ -1018,8 +1060,13 @@ class ServingEngine:
         if p is None:
             return
         self._pending = None
-        self._process_step(p, out if out is not None
-                           else self._finish_buf)
+        if out is None:
+            out = self._finish_buf
+        if self.block_len is not None:
+            # the block state stays on the device: nothing to hand back
+            self._consume_pass(p, out)
+            return
+        self._process_step(p, out)
         self._chain_dirty[:] = True
 
     def _process_step(self, p: _PendingStep, finished: List[Request],
@@ -2641,10 +2688,13 @@ class ServingEngine:
                                     else int(self._ep_mesh.shape[
                                         self._ep_axis]))}
         if self.block_len is not None:
+            passes = m.summary()["block_diffusion"] or {}
             out["block_diffusion"] = {
                 "block_len": self.block_len,
                 "denoising_steps": self.denoising_steps,
-                "mask_token": self.mask_token}
+                "mask_token": self.mask_token,
+                **{k: passes.get(k, 0) for k in (
+                    "passes_overlapped", "slot_passes_discarded")}}
         pool = self.pool
         out["pages"] = {
             "total": pool.num_pages, "free": pool.free_pages,
@@ -2877,47 +2927,76 @@ class ServingEngine:
         """``req`` joins the decode batch with ``cached`` positions (whole
         blocks) in its pages: its slot's block starts there, holding
         what is left of prompt + generated (under a block, and only
-        before the first generated block) and mask tokens after it."""
+        before the first generated block) and mask tokens after it.
+        Only the host knows these rows: they override the slot's rows
+        of the device's block state at the next launch."""
         s = req.slot
         rest = req.tokens[cached:]
         self.scheduler.to_decoding(req)
         self._comp_ver += 1
         self._t[s] = cached
-        self._blk_tok[s] = self.mask_token
-        self._blk_tok[s, :len(rest)] = rest
-        self._blk_masked[s] = np.arange(self.block_len) >= len(rest)
-        self._blk_pass[s] = -1
-        self._blk_step[s] = 0
+        self._fresh_block(s, rest)
         if req.generated:
             self.tracer.on_resume(req.rid)
 
+    def _fresh_block(self, slot: int, rest=()) -> None:
+        """Slot ``slot``'s block opens: ``rest`` first, masks after."""
+        bl = self.block_len
+        self._blk_tok[slot] = self.mask_token
+        self._blk_tok[slot, :len(rest)] = rest
+        self._blk_masked[slot] = np.arange(bl) >= len(rest)
+        self._blk_ovr[slot] = True
+        self._blk_step[slot] = 0
+        self._blk_left[slot] = bl - len(rest)
+        self._blk_ended[slot] = False
+
     def block_positions(self) -> Dict[int, tuple]:
-        """Per decoding slot of a block-diffusion engine: ``(first
-        position of its current block, masked positions left in it)``;
-        0 left means the slot's next pass is its commit pass."""
-        return {slot: (int(self._t[slot]),
-                       int(self._blk_masked[slot].sum()))
-                for slot in self.scheduler.running}
+        """Per slot that rides the next pass of a block-diffusion
+        engine: ``(first position of its current block, masked
+        positions left in it)``; 0 left means the slot's next pass is
+        its commit pass. (A slot whose request ends with the block in
+        flight rides no further pass and is left out.)"""
+        return {slot: (int(self._t[slot]), int(self._blk_left[slot]))
+                for slot in self.scheduler.running
+                if not self._blk_ended[slot]}
 
     def _block_fn(self, head: bool):
-        """The two block-pass programs: ``denoise`` (with the
-        vocabulary head: most probable token and its log-probability
-        at every position of every slot's block; slots that only
-        commit ride it) and ``commit`` (no head: run when every live
-        slot only commits). Both write the block's K/V in place."""
+        """The two block-pass programs. ``denoise`` (with the
+        vocabulary head) takes the block state of the device and, in
+        two arrays, what the host knows (``ctl [4, S]``: each slot's
+        ``t``, positions to fix, denoising step, and whether the host
+        overrides its rows; ``rows [2, S, B]``: the host's tokens and
+        masks for those slots), lays the overriding rows over the
+        state, runs the pass and makes its choice in the program
+        (``block_denoise_slots_paged``): the state after the fix is its
+        result; slots that only commit ride it with nothing to fix.
+        ``commit`` (no head) runs when every live slot only commits.
+        Both write the block's K/V in place."""
         fn = self._block_fns.get(head)
         if fn is None:
             module, page_len = self.module, self.page_len
-            pk, dispatched = self._paged_kernel, self._moe_dispatched
+            kw = dict(moe_dispatched=self._moe_dispatched,
+                      paged_kernel=self._paged_kernel)
 
-            def fn(params, state, cache, toks, t, tables):
+            def denoise(params, state, cache, toks, masked, fixed_pass,
+                        ctl, rows, tables):
+                t, n_fix, step, ovr = ctl
+                over = (ovr != 0)[:, None]
+                return block_denoise_slots_paged(
+                    module, params, state, cache,
+                    jnp.where(over, rows[0], toks),
+                    jnp.where(over, rows[1] != 0, masked),
+                    jnp.where(over, -1, fixed_pass),
+                    n_fix, step, t, tables, page_len, **kw)
+
+            def commit(params, state, cache, toks, t, tables):
                 return block_pass_slots_paged(
                     module, params, state, cache, toks, t, tables,
-                    page_len, head=head, moe_dispatched=dispatched,
-                    paged_kernel=pk)
+                    page_len, head=False, **kw)
 
             name = "denoise" if head else "commit"
-            fn = self._jit_serving(fn, 6, name)
+            fn = self._jit_serving(denoise, 9, name) if head \
+                else self._jit_serving(commit, 6, name)
             self._block_fns[head] = fn
             self._recompile.watch("serving." + name, fn)
         return fn
@@ -2926,87 +3005,171 @@ class ServingEngine:
         """One block-diffusion pass over the decode batch. A slot
         whose block still has masked positions DENOISES: the pass's
         most confident masked positions (``_fix_schedule``) are fixed
-        to their most probable tokens; when none is left the block is
-        shown to its client (appended to ``Request.generated`` with
-        the pass that fixed each token in ``Request.fixed_pass``). A
-        slot whose block is whole COMMITS: the pass's K/V writes are
-        the block's cache entries, and the next block opens. A request
-        that meets its budget or stop token when its block is shown
-        finishes without a commit pass: nothing will read that block.
-        Synchronous: the next pass's input depends on this one's
-        choice, so the fetch is in the iteration (like a speculative
-        verify)."""
-        bl = self.block_len
+        to their most probable tokens, in the program; when none is
+        left the block is shown to its client (appended to
+        ``Request.generated`` with the pass that fixed each token in
+        ``Request.fixed_pass``). A slot whose block is whole COMMITS:
+        the pass's K/V writes are the block's cache entries, and the
+        next block opens. A request that meets its budget or stop token
+        when its block is shown finishes without a commit pass: nothing
+        will read that block.
+
+        The choice is the program's and the schedule is static, so the
+        host steers by what it knows without a result (``_launch_pass``)
+        and, with ``overlap``, dispatches this pass BEFORE it reads the
+        last one (``_consume_pass``): the device goes from pass to pass
+        while the host fetches and books one pass behind, as the
+        one-token loop does. A budget's end is known ahead, so such a
+        stream rides no pass it does not need; a stop token is seen one
+        pass late, and the rows of the pass its slot then rode are
+        thrown away. Without ``overlap`` the pass is read as soon as it
+        is launched (the launch-and-wait reference)."""
+        running = self.scheduler.running
         with obs.span("serving.decode.pages"):
             look = np.zeros(self.num_slots, np.int64)
-            look[list(self.scheduler.running)] = bl - 1
+            look[[s for s in running if not self._blk_ended[s]]] = \
+                self.block_len - 1
             self._ensure_decode_pages(look)
-        running = self.scheduler.running
-        if not running:
-            return
+        # (funding the pages may have preempted a stream, and that
+        # reads the pass in flight first: re-read both)
+        prev = self._pending
         t0 = self.metrics.clock()
+        pend = self._launch_pass(prev is not None, t0)
+        if self.overlap:
+            self._pending = pend
+            if prev is not None:
+                self._consume_pass(prev, finished, t0)
+            if pend is not None and not any(
+                    s in running and running[s].rid == rid
+                    for s, rid in pend.slots):
+                # every stream the new pass carries ended with the one
+                # just read: nothing will come back for it, book it now
+                self._flush_pending(finished)
+        elif pend is not None:
+            self._consume_pass(pend, finished, t0)
+
+    def _launch_pass(self, overlapped: bool,
+                     t0: float) -> Optional[_PendingPass]:
+        """Dispatch one pass over the slots that need one, WITHOUT
+        waiting on it, and move the host's view of every such slot past
+        it: a denoising slot has ``_fix_schedule[step]`` positions
+        fewer to fix, and if that makes its block whole the block is
+        this pass's to show (and the request's budget says whether the
+        slot rides another); a committing slot's ``_t`` moves on a
+        block and a fresh block opens. Returns None where no slot needs
+        a pass (every stream waits for its last block to be read)."""
+        running = self.scheduler.running
+        live = np.fromiter((s for s in running if not self._blk_ended[s]),
+                           np.int64)
+        if not live.size:
+            return None
         with obs.span("serving.decode.tables"):
             tables = self.pool.device_tables()
-        slots = np.fromiter(running.keys(), np.int64, len(running))
-        denoising = self._blk_masked[slots].any(axis=1)
-        head = bool(denoising.any())
+        left = self._blk_left[live]
+        denoising = live[left > 0]
+        committing = live[left == 0]
+        head = bool(denoising.size)
         kind = "denoise" if head else "commit"
+        # idle slots, and those that only wait to be read, sit at the
+        # sentinel: they write nothing and nothing of theirs is read
+        t = np.full(self.num_slots, self.max_len, np.int32)
+        t[live] = self._t[live]
         with obs.span("serving.decode." + kind):
             with obs.span("serving.decode.dispatch"):
-                out = self._block_fn(head)(
-                    self._params, self._state, self.pool.cache,
-                    _snap(self._blk_tok), _snap(self._t), tables)
-            prefills, self._prefill_routed = self._prefill_routed, []
-            if head:
-                best, conf, self.pool.cache, routed = out
-                best, conf, routed, *prefills = self._fetch(
-                    best, conf, routed, *prefills)
-            else:
-                self.pool.cache, routed = out
-                routed, *prefills = self._fetch(routed, *prefills)
+                if head:
+                    n_fix = np.zeros(self.num_slots, np.int32)
+                    n_fix[denoising] = np.minimum(
+                        self._fix_schedule[self._blk_step[denoising]],
+                        self._blk_left[denoising])
+                    # the host's view crosses in two fresh arrays (the
+                    # program reads them after dispatch returns, and
+                    # the mirrors move on below)
+                    toks, masked, fixed_pass, self.pool.cache, routed = \
+                        self._block_fn(True)(
+                            self._params, self._state, self.pool.cache,
+                            *self._blk_dev,
+                            jnp.asarray(np.stack([
+                                t, n_fix, self._blk_step, self._blk_ovr])),
+                            jnp.asarray(np.stack([
+                                self._blk_tok, self._blk_masked])),
+                            tables)
+                    self._blk_dev = (toks, masked, fixed_pass)
+                    self._blk_ovr[:] = False
+                    blk = (toks, fixed_pass)
+                else:
+                    blk = None
+                    self.pool.cache, routed = self._block_fn(False)(
+                        self._params, self._state, self.pool.cache,
+                        self._blk_dev[0], jnp.asarray(t), tables)
         if "serving." + kind not in self._warmed:
             self._warmed.add("serving." + kind)
             self._recompile.mark_warm("serving." + kind)
+        prefills, self._prefill_routed = self._prefill_routed, []
+        shown = []
+        if head:
+            self._blk_left[denoising] -= n_fix[denoising]
+            self._blk_step[denoising] += 1
+            for slot in denoising[self._blk_left[denoising] == 0].tolist():
+                # whole after this pass: a client may see it, and a
+                # budget met at the block's end ends the stream with it
+                req = running[slot]
+                shown.append((slot, req.rid))
+                self._blk_ended[slot] = \
+                    self._t[slot] + self.block_len - len(req.prompt) \
+                    >= req.max_new_tokens
+        for slot in committing.tolist():
+            # committed by this pass: the block's K/V are cache now
+            self._t[slot] += self.block_len
+            self._fresh_block(slot)
+        return _PendingPass(
+            kind, blk, routed, prefills,
+            tuple((s, running[s].rid) for s in live.tolist()),
+            tuple(shown), int(denoising.size), int(committing.size),
+            overlapped, t0)
+
+    def _consume_pass(self, p: _PendingPass, finished: List[Request],
+                      t0: Optional[float] = None) -> None:
+        """Read one launched pass and book it: its routing counts (and
+        those of the prefills before it), the tokens of the blocks it
+        made whole onto their requests, first-token and commit
+        counters, and the requests that are done. A slot whose request
+        changed since the launch (finished by a stop token the pass
+        before showed, preempted, recycled) rode it for nothing: its
+        rows are discarded and counted. ``t0`` as in
+        ``_process_step``."""
+        running = self.scheduler.running
+
+        def stale(slot, rid):
+            req = running.get(slot)
+            return req is None or req.rid != rid
+
+        shown = [(slot, rid) for slot, rid in p.shown
+                 if not stale(slot, rid)]
+        if shown:
+            toks, fixed_pass, routed, *prefills = self._fetch(
+                *p.blk, p.routed, *p.prefills)
+        else:
+            routed, *prefills = self._fetch(p.routed, *p.prefills)
         with obs.span("serving.decode.consume"):
             # what the program says its expert layers did: every row of
             # the pass is routed, idle slots' too
             self.metrics.record_block_pass(
-                kind, int(denoising.sum()), int((~denoising).sum()),
-                *map(int, routed))
+                p.kind, p.denoising, p.committing, *map(int, routed),
+                overlapped=p.overlapped,
+                discarded=sum(stale(*sr) for sr in p.slots))
             for rows, touched in prefills:
                 self.metrics.record_block_prefill(int(rows), int(touched))
+            if t0 is None:
+                t0 = p.launch_t
             n_emitted = 0
             done_reqs = []
-            for slot, req in list(running.items()):
-                masked = self._blk_masked[slot]
-                if not masked.any():
-                    # committed: the block's K/V are cache now
-                    self._t[slot] += bl
-                    self._blk_tok[slot] = self.mask_token
-                    self._blk_masked[slot] = True
-                    self._blk_pass[slot] = -1
-                    self._blk_step[slot] = 0
-                    continue
-                step = int(self._blk_step[slot])
-                n_fix = min(int(self._fix_schedule[step]),
-                            int(masked.sum()))
-                # the n_fix most confident masked positions (ties: the
-                # earliest position, as a stable sort leaves them)
-                order = np.argsort(
-                    np.where(masked, -conf[slot], np.inf), kind="stable")
-                for pos in order[:n_fix]:
-                    self._blk_tok[slot, pos] = best[slot, pos]
-                    self._blk_pass[slot, pos] = step
-                    masked[pos] = False
-                self._blk_step[slot] = step + 1
-                if masked.any():
-                    continue
-                # whole: a client may see it
+            for slot, rid in shown:
+                req = running[slot]
                 first = not req.generated
                 appended = 0
-                for pos in np.nonzero(self._blk_pass[slot] >= 0)[0]:
-                    req.generated.append(int(self._blk_tok[slot, pos]))
-                    req.fixed_pass.append(int(self._blk_pass[slot, pos]))
+                for pos in np.nonzero(fixed_pass[slot] >= 0)[0]:
+                    req.generated.append(int(toks[slot, pos]))
+                    req.fixed_pass.append(int(fixed_pass[slot, pos]))
                     appended += 1
                     if req.done:
                         break           # budget / stop token mid-block
@@ -3016,16 +3179,16 @@ class ServingEngine:
                     self.metrics.record_first_token(req.rid)
                     self.tracer.on_first_token(req.rid)
                 if self.tracer.enabled:
-                    self._trace_decode[req.rid] = \
-                        self._trace_decode.get(req.rid, 0) + appended
+                    self._trace_decode[rid] = \
+                        self._trace_decode.get(rid, 0) + appended
                     if self._trace_decode_t0 is None:
                         self._trace_decode_t0 = t0
                 if req.done:
                     done_reqs.append(req)
             self._decode_buf.append(
-                (len(running), self._metrics.clock() - t0, n_emitted))
+                (len(p.slots), self._metrics.clock() - t0, n_emitted))
             if done_reqs:
-                self._flush_host_window()
+                self._flush_host_window()        # ticks precede terminals
                 for req in done_reqs:
                     self._finish(req, finished)
 

@@ -170,8 +170,15 @@ class ServingMetrics:
         # tokens a client was shown, and what the expert layers did in
         # those passes and (kind="prefill") in the prefills (rows
         # routed; experts that owned a row, summed over the expert
-        # layers that ran: the programs' own counts)
+        # layers that ran: the programs' own counts); and of the
+        # pipelined pass (``overlap``): passes dispatched while the one
+        # before was still unread, and rows a slot rode for a request
+        # that had ended or left by the time they were read
         self._bd_passes = self.registry.counter("serving.blockdiff_passes")
+        self._bd_overlapped = self.registry.counter(
+            "serving.blockdiff_passes_overlapped")
+        self._bd_discarded = self.registry.counter(
+            "serving.blockdiff_slot_passes_discarded")
         self._bd_slot_passes = self.registry.counter(
             "serving.blockdiff_slot_passes")
         self._bd_blocks = self.registry.counter(
@@ -352,14 +359,22 @@ class ServingMetrics:
         self._moe_conc.set(float(concentration))
 
     def record_block_pass(self, kind: str, denoising: int, committing: int,
-                          rows_routed: int, experts_touched: int) -> None:
+                          rows_routed: int, experts_touched: int, *,
+                          overlapped: bool = False,
+                          discarded: int = 0) -> None:
         """One block-diffusion pass: ``kind`` is the program that ran
         (``"denoise"`` or ``"commit"``), ``denoising`` / ``committing``
         the live slots that did either in it, ``rows_routed`` the rows
         its expert layers routed (live or not: every row of the pass
         is computed) and ``experts_touched`` the experts that owned at
-        least one of them, summed over the expert layers."""
+        least one of them, summed over the expert layers.
+        ``overlapped``: it was dispatched while the pass before it was
+        still unread; ``discarded``: how many of its slots' rows were
+        thrown away when it was read (the slot's request had ended or
+        left since the launch)."""
         self._bd_passes.inc(kind=kind)
+        self._bd_overlapped.inc(int(overlapped))
+        self._bd_discarded.inc(int(discarded))
         self._bd_slot_passes.inc(int(denoising), kind="denoise")
         self._bd_slot_passes.inc(int(committing), kind="commit")
         self._bd_rows.inc(int(rows_routed), kind="pass")
@@ -531,6 +546,8 @@ class ServingMetrics:
         if not sum(passes.values()):
             return None
         return {"passes": passes,
+                "passes_overlapped": int(self._bd_overlapped.value()),
+                "slot_passes_discarded": int(self._bd_discarded.value()),
                 "slot_passes": {
                     k: int(self._bd_slot_passes.value(kind=k))
                     for k in ("denoise", "commit")},

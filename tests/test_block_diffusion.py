@@ -22,7 +22,9 @@ from distkeras_tpu.models import Model, zoo  # noqa: E402
 from distkeras_tpu.models.attention import (MultiHeadAttention,  # noqa: E402
                                             TransformerBlock,
                                             TransformerMLP)
-from distkeras_tpu.models.decoding import generate  # noqa: E402
+from distkeras_tpu import obs  # noqa: E402
+from distkeras_tpu.models.decoding import (block_pass_slots_paged,  # noqa: E402
+                                           fix_most_confident, generate)
 from distkeras_tpu.models.moe import MoE  # noqa: E402
 from distkeras_tpu.ops import moe_kernels  # noqa: E402
 from distkeras_tpu.ops.attention import dot_product_attention  # noqa: E402
@@ -322,28 +324,17 @@ CONF_TOLERANCE = 5e-4
 
 
 def serve(model, requests, *, steps=4, num_slots=3, page_len=8, num_pages=None,
-          submit_at=None, decode_kernel="auto", between=None):
-    """Serve ``[(prompt, max_new_tokens)]``; returns the finished requests
-    by index and, per request id, the log-probabilities the engine fixed
-    its tokens at (read off the pass's fetch)."""
+          submit_at=None, decode_kernel="auto", between=None, overlap=True,
+          confidences=False):
+    """Serve ``[(prompt, max_new_tokens)]``; returns the engine, the finished
+    requests by index and (``confidences``) per request id the
+    log-probabilities the engine fixed its tokens at: the most confident
+    masked position of each of its denoising passes."""
     eng = ServingEngine(model, num_slots=num_slots, max_len=64,
                         page_len=page_len, num_pages=num_pages,
                         mask_token=MASK, denoising_steps=steps,
-                        decode_kernel=decode_kernel)
-    fixed_conf = {}
-    fetch = eng._fetch
-
-    def spy(*arrays):
-        out = fetch(*arrays)
-        if out[0].ndim == 2:               # a denoising pass: best, conf, ...
-            for slot, req in eng.scheduler.running.items():
-                masked = eng._blk_masked[slot]
-                if masked.any():
-                    fixed_conf.setdefault(req.rid, []).append(
-                        np.where(masked, out[1][slot], -np.inf).max())
-        return out
-
-    eng._fetch = spy
+                        decode_kernel=decode_kernel, overlap=overlap)
+    fixed_conf = _spy_confidences(eng) if confidences else None
     submit_at = submit_at or [0] * len(requests)
     rids, done, it = {}, {}, 0
     while len(done) < len(requests):
@@ -355,7 +346,44 @@ def serve(model, requests, *, steps=4, num_slots=3, page_len=8, num_pages=None,
             done[rids[r.rid]] = r
         it += 1
         assert it < 500
+    assert eng._pending is None            # nothing left in flight
     return eng, done, fixed_conf
+
+
+def _spy_confidences(eng):
+    """The choice is made inside ``serving_denoise`` and its confidences
+    never reach the host: recompute them beside every denoise program, from
+    the arguments it is called with (the plain pass with the head over the
+    same blocks, pool and tables), before the program takes the pool."""
+    module, page_len = eng.module, eng.page_len
+    conf_of = jax.jit(lambda params, state, cache, toks, t, tables:
+                      block_pass_slots_paged(module, params, state, cache,
+                                             toks, t, tables, page_len)[1])
+    fixed_conf, block_fn = {}, eng._block_fn
+
+    def spied(head):
+        fn = block_fn(head)
+        if not head:
+            return fn
+
+        def denoise(params, state, cache, toks, masked, fixed_pass, ctl,
+                    rows, tables):
+            t, n_fix, _, ovr = np.asarray(ctl)
+            over = (ovr != 0)[:, None]
+            now_masked = np.where(over, np.asarray(rows[1]) != 0, masked)
+            conf = np.asarray(conf_of(
+                params, state, cache, jnp.where(over, rows[0], toks),
+                jnp.asarray(t), tables))
+            for slot, req in eng.scheduler.running.items():
+                if n_fix[slot]:
+                    fixed_conf.setdefault(req.rid, []).append(
+                        np.where(now_masked[slot], conf[slot], -np.inf).max())
+            return fn(params, state, cache, toks, masked, fixed_pass, ctl,
+                      rows, tables)
+        return denoise
+
+    eng._block_fn = spied
+    return fixed_conf
 
 
 def check_against_reference(model, requests, done, steps=4, fixed_conf=None):
@@ -385,10 +413,14 @@ def _prompts(lengths, seed=0):
     ("output_not_a_multiple_of_the_block", [8, 12], [5, 7]),
     ("one_token", [9], [1]),
 ])
-def test_engine_follows_the_reference_trajectory(name, lengths, outputs):
+@pytest.mark.parametrize("overlap", [True, False],
+                         ids=["overlap", "wait"])
+def test_engine_follows_the_reference_trajectory(name, lengths, outputs,
+                                                 overlap):
     model = build()
     requests = list(zip(_prompts(lengths), outputs))
-    eng, done, conf = serve(model, requests)
+    eng, done, conf = serve(model, requests, overlap=overlap,
+                            confidences=True)
     check_against_reference(model, requests, done, fixed_conf=conf)
     assert all(len(done[i].generated) == n for i, n in enumerate(outputs))
     paths = eng.health()["programs"]
@@ -397,10 +429,13 @@ def test_engine_follows_the_reference_trajectory(name, lengths, outputs):
 
 
 @pytest.mark.parametrize("steps", [1, 2, 3])
-def test_engine_follows_the_reference_with_fewer_denoising_steps(steps):
+@pytest.mark.parametrize("overlap", [True, False],
+                         ids=["overlap", "wait"])
+def test_engine_follows_the_reference_with_fewer_denoising_steps(steps,
+                                                                 overlap):
     model = build()
     requests = list(zip(_prompts([10, 8]), [9, 8]))
-    eng, done, _ = serve(model, requests, steps=steps)
+    eng, done, _ = serve(model, requests, steps=steps, overlap=overlap)
     check_against_reference(model, requests, done, steps=steps)
     bd = eng.metrics.summary()["block_diffusion"]
     # a block of 4 takes `steps` denoising passes and, unless it is the
@@ -410,13 +445,16 @@ def test_engine_follows_the_reference_with_fewer_denoising_steps(steps):
     assert bd["slot_passes"]["commit"] == 3
 
 
-def test_slots_sit_at_different_passes_in_one_step():
+@pytest.mark.parametrize("overlap", [True, False],
+                         ids=["overlap", "wait"])
+def test_slots_sit_at_different_passes_in_one_step(overlap):
     """Requests that arrive two iterations apart denoise different steps of
     different blocks in the same batched pass, and commit beside slots
     that denoise."""
     model = build()
     requests = list(zip(_prompts([8, 12, 16]), [12, 12, 8]))
-    eng, done, conf = serve(model, requests, submit_at=[0, 2, 5])
+    eng, done, conf = serve(model, requests, submit_at=[0, 2, 5],
+                            overlap=overlap, confidences=True)
     check_against_reference(model, requests, done, fixed_conf=conf)
     bd = eng.metrics.summary()["block_diffusion"]
     assert bd["slot_passes"]["commit"] > bd["passes"]["commit"]
@@ -438,28 +476,34 @@ def test_programs_count_the_expert_layers_they_ran():
     assert 8 * 3 + 2 <= bd["experts_touched"] <= experts * (8 * 3 + 2)
 
 
-def test_prefix_cache_hit_at_a_block_boundary():
+@pytest.mark.parametrize("overlap", [True, False],
+                         ids=["overlap", "wait"])
+def test_prefix_cache_hit_at_a_block_boundary(overlap):
     """A second prompt that shares 18 tokens with a cached one reuses the
     16 of its first page (whole blocks) and matches the reference."""
     model = build()
     first = _prompts([26])[0]
     second = np.concatenate([first[:18], _prompts([7], seed=1)[0]])
     requests = [(first, 6), (second, 7)]
-    eng, done, _ = serve(model, requests, submit_at=[0, 12], page_len=8)
+    eng, done, _ = serve(model, requests, submit_at=[0, 12], page_len=8,
+                         overlap=overlap)
     check_against_reference(model, requests, done)
     hits = eng.metrics.summary()["prefix_cache"]
     assert hits["hits"] == 1
     assert done[1]._shared_len == 16 and done[1]._shared_len % 4 == 0
 
 
-def test_preemption_mid_block_resumes_on_the_reference_trajectory():
+@pytest.mark.parametrize("overlap", [True, False],
+                         ids=["overlap", "wait"])
+def test_preemption_mid_block_resumes_on_the_reference_trajectory(overlap):
     """A pool too small for both streams: the urgent arrival preempts the
     running one mid-block; it re-prefills its whole blocks, opens the
     block again and ends where the reference ends."""
     model = build()
     requests = list(zip(_prompts([16, 24]), [16, 6]))
     eng, done, _ = serve(model, requests, num_slots=2, page_len=4,
-                         num_pages=10, submit_at=[0, 6], between=1)
+                         num_pages=10, submit_at=[0, 6], between=1,
+                         overlap=overlap)
     check_against_reference(model, requests, done)
     assert done[0].n_preempted >= 1
 
@@ -478,16 +522,208 @@ def test_engine_serves_through_the_interpreted_kernels():
     assert "moe=grouped_kernel" in paths["prefill"]
 
 
-def test_stop_token_ends_a_stream_inside_a_block():
+@pytest.mark.parametrize("overlap", [True, False],
+                         ids=["overlap", "wait"])
+def test_stop_token_ends_a_stream_inside_a_block(overlap):
     model = build()
     prompt = _prompts([8])[0]
-    plain = serve(model, [(prompt, 12)])[1][0].generated
-    stop = plain[5]
+    whole = serve(model, [(prompt, 12)], overlap=overlap)[1][0]
+    stop = whole.generated[5]
     eng = ServingEngine(model, num_slots=1, max_len=64, page_len=8,
-                        mask_token=MASK)
+                        mask_token=MASK, overlap=overlap)
     eng.submit(prompt, 12, stop_token=stop)
     (req,) = [r for _ in range(40) for r in eng.step()]
-    assert req.generated == plain[:plain.index(stop) + 1]
+    n = whole.generated.index(stop) + 1
+    assert req.generated == whole.generated[:n]
+    assert req.fixed_pass == whole.fixed_pass[:n]
+    tokens, fixed_pass, _ = ref.generate(reference_tree(model.params), CFG,
+                                         prompt, 12, MASK, steps=4)
+    assert (req.generated, req.fixed_pass) == (tokens[:n], fixed_pass[:n])
+
+
+# --- the pipelined pass -------------------------------------------------------
+
+def _host_choice(toks, masked, fixed_pass, best, conf, n_fix, step):
+    """The rule as the host applied it: a stable sort of the masked
+    positions by falling confidence, the first ``n_fix`` fixed."""
+    toks, masked, fixed_pass = toks.copy(), masked.copy(), fixed_pass.copy()
+    for s in range(len(toks)):
+        order = np.argsort(np.where(masked[s], -conf[s], np.inf),
+                           kind="stable")
+        for pos in order[:n_fix[s]]:
+            toks[s, pos], fixed_pass[s, pos] = best[s, pos], step[s]
+            masked[s, pos] = False
+    return toks, masked, fixed_pass
+
+
+@pytest.mark.parametrize("name,conf,masked,n_fix,fixed", [
+    ("all_equal", [-1., -1., -1., -1.], [1, 1, 1, 1], 2, [0, 1]),
+    ("equal_behind_the_best", [-2., -1., -2., -2.], [1, 1, 1, 1], 3,
+     [0, 1, 2]),
+    ("equal_among_the_masked", [0., -3., -3., -3.], [0, 1, 0, 1], 1, [1]),
+    ("nothing_to_fix", [-1., -1., -1., -1.], [1, 1, 1, 1], 0, []),
+    ("certain_and_impossible", [-np.inf, 0., 0., -np.inf], [1, 1, 1, 1], 3,
+     [0, 1, 2]),
+])
+def test_equal_confidences_go_to_the_earliest_position(name, conf, masked,
+                                                       n_fix, fixed):
+    """The program's choice on planted ties is the stable sort's."""
+    args = (np.full((1, 4), MASK, np.int32), np.array([masked], bool),
+            np.full((1, 4), -1, np.int32), np.arange(10, 14)[None].astype(
+                np.int32), np.array([conf], np.float32),
+            np.array([n_fix], np.int32), np.array([2], np.int32))
+    got = [np.asarray(a) for a in
+           fix_most_confident(*map(jnp.asarray, args))]
+    for a, b in zip(got, _host_choice(*args)):
+        np.testing.assert_array_equal(a, b)
+    np.testing.assert_array_equal(np.nonzero(got[2][0] == 2)[0], fixed)
+    np.testing.assert_array_equal(got[0][0][fixed], 10 + np.array(fixed, int))
+
+
+@pytest.mark.parametrize("seed", [0, 1, 2])
+def test_the_programs_choice_is_the_stable_sorts(seed):
+    """Many slots at once, confidences drawn from a handful of values so
+    that most rows hold ties, some of them infinite or not a number."""
+    rng = np.random.default_rng(seed)
+    s, b = 64, 8
+    conf = rng.choice(np.array([-0.25, -0.5, -1., -2., -np.inf, np.nan],
+                               np.float32), (s, b))
+    masked = rng.random((s, b)) < 0.6
+    args = (rng.integers(0, 90, (s, b)).astype(np.int32), masked,
+            rng.integers(-1, 3, (s, b)).astype(np.int32),
+            rng.integers(100, 190, (s, b)).astype(np.int32), conf,
+            np.minimum(rng.integers(0, 5, s), masked.sum(1)).astype(np.int32),
+            rng.integers(0, 4, s).astype(np.int32))
+    got = fix_most_confident(*map(jnp.asarray, args))
+    for a, b_ in zip(got, _host_choice(*args)):
+        np.testing.assert_array_equal(np.asarray(a), b_)
+
+
+def _watch(eng):
+    """The order of the engine's dispatches and reads: ``("pass", n)`` when
+    the n-th block program is called, ``("read", n)`` when the n-th
+    ``_fetch`` starts."""
+    events, n = [], {"pass": 0, "read": 0}
+    block_fn, fetch = eng._block_fn, eng._fetch
+
+    def note(kind, fn):
+        def noted(*args):
+            events.append((kind, n[kind]))
+            n[kind] += 1
+            return fn(*args)
+        return noted
+
+    eng._block_fn = lambda head: note("pass", block_fn(head))
+    eng._fetch = note("read", fetch)
+    return events
+
+
+def test_the_next_pass_is_dispatched_before_the_last_one_is_read():
+    """A steady batch: pass N+1 goes to the device before pass N's results
+    are asked for, every pass but the first finds its predecessor unread,
+    and the synchronous engine reads each pass before the next."""
+    model = build()
+    requests = list(zip(_prompts([8, 13]), [12, 8]))
+    for overlap in (True, False):
+        eng = ServingEngine(model, num_slots=2, max_len=64, page_len=8,
+                            mask_token=MASK, overlap=overlap)
+        events = _watch(eng)
+        for prompt, n in requests:
+            eng.submit(prompt, n)
+        while eng.scheduler.pending:
+            eng.step()
+        bd = eng.metrics.summary()["block_diffusion"]
+        passes = sum(bd["passes"].values())
+        at = {e: i for i, e in enumerate(events)}
+        assert len(events) == 2 * passes and passes > 8
+        if overlap:
+            assert all(at["pass", n + 1] < at["read", n]
+                       for n in range(passes - 1))
+            assert bd["passes_overlapped"] == passes - 1
+        else:
+            assert all(at["read", n] < at["pass", n + 1]
+                       for n in range(passes - 1))
+            assert bd["passes_overlapped"] == 0
+        assert eng.health()["block_diffusion"]["passes_overlapped"] \
+            == bd["passes_overlapped"]
+
+
+def test_a_stop_token_costs_one_discarded_pass_and_no_stale_rows():
+    """Under ``overlap`` a stop token is seen one pass late: the stream ends
+    on the same token, its slot rode one more pass (counted, thrown away),
+    and the request that takes the slot over in between starts from its
+    own block, beside a stream that never stopped."""
+    model = build()
+    prompts = _prompts([8, 16, 12])
+    plain = serve(model, [(prompts[0], 12)], overlap=False)[1][0]
+    stop = plain.generated[5]                  # inside the second block
+    requests = [(prompts[0], 12), (prompts[1], 24), (prompts[2], 8)]
+    served, rides = {}, {}
+    for overlap in (True, False):
+        eng = ServingEngine(model, num_slots=2, max_len=64, page_len=8,
+                            mask_token=MASK, overlap=overlap)
+        rids = [eng.submit(p, n, **({"stop_token": stop} if i == 0 else {}))
+                for i, (p, n) in enumerate(requests)]
+        done = {r.rid: r for _ in range(80) for r in eng.step()}
+        assert sorted(done) == rids and eng._pending is None
+        served[overlap] = [done[r] for r in rids]
+        bd = eng.metrics.summary()["block_diffusion"]
+        assert bd["slot_passes_discarded"] == int(overlap)
+        rides[overlap] = sum(bd["slot_passes"].values())
+        assert eng.health()["block_diffusion"]["slot_passes_discarded"] \
+            == int(overlap)
+        # the third request waited for the stopped stream's slot
+        assert done[rids[2]].slot == done[rids[0]].slot
+    n = plain.generated.index(stop) + 1
+    for a, b in zip(served[True], served[False]):
+        assert (a.generated, a.fixed_pass) == (b.generated, b.fixed_pass)
+    assert served[True][0].generated == plain.generated[:n]
+    check_against_reference(model, requests[1:], dict(enumerate(
+        served[True][1:])))
+    # one pass more than the synchronous engine's, and no more
+    assert rides[True] == rides[False] + 1
+
+
+def test_a_budgets_end_is_known_ahead():
+    """A request that ends by its budget rides no pass after its last
+    block: nothing is discarded, and the slots ride as many passes a token
+    as in the synchronous engine."""
+    model = build()
+    requests = list(zip(_prompts([8, 10, 3]), [12, 9, 5]))
+    counts = {}
+    for overlap in (True, False):
+        eng, done, _ = serve(model, requests, num_slots=2, overlap=overlap)
+        bd = eng.metrics.summary()["block_diffusion"]
+        assert bd["slot_passes_discarded"] == 0
+        counts[overlap] = (bd["slot_passes"], bd["tokens_committed"],
+                           bd["blocks_committed"])
+        assert not eng.block_positions()
+    assert counts[True] == counts[False]
+    assert counts[True][1] == 12 + 9 + 5
+
+
+def test_the_programs_keep_their_paths_and_compile_once():
+    """``denoise`` (with the choice inside) and ``commit`` hold what they
+    held, and a second run of the same lengths compiles nothing."""
+    model = build()
+    # each alone in the batch: its commit pass has no slot that needs a head
+    requests = [(_prompts([8], seed=seed)[0], 8) for seed in (0, 1)]
+    done = {}
+    with moe_kernels.force_interpret():
+        eng = ServingEngine(model, num_slots=2, max_len=64, page_len=8,
+                            mask_token=MASK, decode_kernel="paged")
+        for i, request in enumerate(requests):
+            compiled = obs.compile_totals()["count"]
+            eng.submit(*request)
+            (done[i],) = [r for _ in range(40) for r in eng.step()]
+            if i:
+                assert obs.compile_totals()["count"] == compiled
+    check_against_reference(model, requests, done)
+    paths = eng.health()["programs"]
+    for name in ("denoise", "commit"):
+        assert paths[name] == ("kv_cache=donated, moe=grouped_kernel, "
+                               "paged_attention=kernel"), paths
+    assert eng.metrics.summary()["block_diffusion"]["passes"]["commit"] == 2
 
 
 @pytest.mark.parametrize("kwargs,match", [

@@ -398,13 +398,15 @@ def test_block_causal_flash_sdar_widths(one_chip, as_tpu, seq):
 @pytest.mark.parametrize("head", [True, False], ids=["denoise", "commit"])
 def test_block_pass_moves_no_pool_plane(one_chip, as_tpu, head):
     """One whole block-diffusion pass (one layer of the cell's widths, the
-    whole vocabulary) on a donated pool: both kernels in the program,
-    every cache leaf aliased, no copy the size of a pool plane. (Without
-    the head the deepest block stops at its K/V write: the commit program
-    of this one-layer model holds no kernel at all.)"""
+    whole vocabulary; the denoising pass with its choice of positions, as
+    the engine's program holds it) on a donated pool: both kernels in the
+    program, every cache leaf aliased, no copy the size of a pool plane.
+    (Without the head the deepest block stops at its K/V write: the commit
+    program of this one-layer model holds no kernel at all.)"""
     import re
     from distkeras_tpu.models import zoo
     from distkeras_tpu.models.decoding import (_resolve_head_dims,
+                                               block_denoise_slots_paged,
                                                block_pass_slots_paged,
                                                init_cache)
     module = zoo.transformer_lm(
@@ -425,13 +427,22 @@ def test_block_pass_moves_no_pool_plane(one_chip, as_tpu, head):
             lambda a: s(a.shape, dtype if dtype is not None and a.ndim >= 2
                         else a.dtype), tree)
 
-    def step(params, state, cache, toks, t, table):
+    def commit(params, state, cache, toks, t, table):
         return block_pass_slots_paged(module, params, state, cache, toks,
-                                      t, table, page_len, head=head)
+                                      t, table, page_len, head=False)
 
-    text = jax.jit(step, donate_argnums=2).lower(
+    def denoise(params, state, cache, toks, masked, fixed_pass, n_fix,
+                step, t, table):
+        return block_denoise_slots_paged(
+            module, params, state, cache, toks, masked, fixed_pass, n_fix,
+            step, t, table, page_len)
+
+    blk, per_slot = s((slots, 4), jnp.int32), s((slots,), jnp.int32)
+    state_args = (blk, s((slots, 4), jnp.bool_), blk, per_slot, per_slot) \
+        if head else (blk,)
+    text = jax.jit(denoise if head else commit, donate_argnums=2).lower(
         on_chip(params, jnp.bfloat16), on_chip(state), on_chip(cache),
-        s((slots, 4), jnp.int32), s((slots,), jnp.int32),
+        *state_args, per_slot,
         s((slots, 2048 // page_len), jnp.int32)).compile().as_text()
     leaves = jax.tree_util.tree_leaves(cache)
     assert text.split("\n", 1)[0].count("may-alias") == len(leaves)

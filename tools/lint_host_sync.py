@@ -88,6 +88,8 @@ SERVING_LOOP_FUNCS = frozenset({
     # call graph runs inside the iteration too
     "_spec_tree_step", "_tree_shape", "_adapt_tree", "_drop_swap",
     "_consume_spec",
+    # block diffusion: the pipelined pass
+    "_block_step", "_launch_pass", "_consume_pass", "_fresh_block",
 })
 
 #: how many ``# lint: allow-host-sync`` marks the serving loop may
