@@ -205,7 +205,10 @@ class MultiHeadAttention(Layer):
 
     ``qk_norm`` adds an RMSNorm (epsilon 1e-6, one learned scale of
     ``head_dim``) over every head's query and key before RoPE.
-    ``rope_base`` is RoPE's theta. ``block_len=B`` makes the causal
+    ``rope_base`` is RoPE's theta; ``rotary_dim`` rotates only the
+    first so many dimensions of each head, ``rope_yarn`` gives YaRN's
+    frequencies and attention factor (``ops.attention.yarn_inv_freq``).
+    ``block_len=B`` makes the causal
     mask BLOCK-causal (position ``i`` sees key ``j`` iff
     ``j // B <= i // B``): the attention of a block-diffusion language
     model, which ``ServingEngine`` decodes a block at a time. Not with
@@ -223,9 +226,19 @@ class MultiHeadAttention(Layer):
                  rope_scale: float = 1.0,
                  attn_window: Optional[int] = None,
                  qk_norm: bool = False, rope_base: float = 10000.0,
-                 block_len: Optional[int] = None):
+                 block_len: Optional[int] = None,
+                 rotary_dim: Optional[int] = None,
+                 rope_yarn: Optional[dict] = None):
         self.rope_scale = float(rope_scale)
         self.rope_base = float(rope_base)
+        #: RoPE over the first ``rotary_dim`` dimensions of each head
+        #: only (None: the whole head)
+        self.rotary_dim = None if rotary_dim is None else int(rotary_dim)
+        #: YaRN: ``{"factor", "original_max_position_embeddings",
+        #: "beta_fast", "beta_slow", "attention_factor"}`` (the last
+        #: three optional), as a published ``rope_parameters`` group
+        self.rope_yarn = None if rope_yarn is None else dict(rope_yarn)
+        self._rope_tables = {}           # rotary width -> (inv_freq, mscale)
         self.qk_norm = bool(qk_norm)
         self.block_len = None if block_len is None else int(block_len)
         if self.block_len is not None and (
@@ -287,9 +300,31 @@ class MultiHeadAttention(Layer):
         return params, {}, tuple(input_shape)
 
     def rope(self, x, positions, layout: str = "bshd"):
-        """RoPE with this layer's base and position scale."""
-        return apply_rope(x, positions, base=self.rope_base,
-                          layout=layout, scale=self.rope_scale)
+        """RoPE with this layer's base, position scale, rotary share
+        and (YaRN) frequency table."""
+        if self.rotary_dim is None and self.rope_yarn is None:
+            return apply_rope(x, positions, base=self.rope_base,
+                              layout=layout, scale=self.rope_scale)
+        rot = self.rotary_dim or x.shape[-1]
+        if rot not in self._rope_tables:
+            inv, mscale = None, 1.0
+            if self.rope_yarn is not None:
+                from distkeras_tpu.ops.attention import (
+                    yarn_attention_factor, yarn_inv_freq)
+                y = self.rope_yarn
+                inv = yarn_inv_freq(
+                    rot, self.rope_base, y["factor"],
+                    y["original_max_position_embeddings"],
+                    y.get("beta_fast") or 32.0, y.get("beta_slow") or 1.0,
+                    y.get("truncate", True))
+                mscale = y.get("attention_factor")
+                if mscale is None:
+                    mscale = yarn_attention_factor(y["factor"])
+            self._rope_tables[rot] = (inv, float(mscale))
+        inv, mscale = self._rope_tables[rot]
+        return apply_rope(x, positions, base=self.rope_base, layout=layout,
+                          scale=self.rope_scale, rotary_dim=rot,
+                          inv_freq=inv, mscale=mscale)
 
     def normed_qk(self, params, q, k):
         """The per-head RMSNorm of queries and keys (``qk_norm``); the
@@ -370,7 +405,8 @@ class MultiHeadAttention(Layer):
                 "rope_scale": self.rope_scale,
                 "attn_window": self.attn_window,
                 "qk_norm": self.qk_norm, "rope_base": self.rope_base,
-                "block_len": self.block_len}
+                "block_len": self.block_len,
+                "rotary_dim": self.rotary_dim, "rope_yarn": self.rope_yarn}
 
 
 @register_layer
@@ -453,8 +489,12 @@ class TransformerBlock(Layer):
                  qk_norm: bool = False, rope_base: float = 10000.0,
                  block_len: Optional[int] = None,
                  mlp_dim: Optional[int] = None, mlp_gated: bool = False,
-                 mlp_bias: bool = True):
+                 mlp_bias: bool = True,
+                 rotary_dim: Optional[int] = None,
+                 rope_yarn: Optional[dict] = None):
         self.num_heads = int(num_heads)
+        self.rotary_dim = rotary_dim
+        self.rope_yarn = rope_yarn
         self.qk_norm = bool(qk_norm)
         self.rope_base = float(rope_base)
         self.block_len = block_len
@@ -487,7 +527,8 @@ class TransformerBlock(Layer):
             dtype=dtype, attn_impl=attn_impl, seq_axis_name=seq_axis_name,
             ring_block_size=ring_block_size, num_kv_heads=num_kv_heads,
             rope_scale=rope_scale, attn_window=attn_window,
-            qk_norm=qk_norm, rope_base=rope_base, block_len=block_len)
+            qk_norm=qk_norm, rope_base=rope_base, block_len=block_len,
+            rotary_dim=rotary_dim, rope_yarn=rope_yarn)
         self.mlp = mlp_layer  # resolved in init once d_model is known
 
     def init(self, rng, input_shape):
@@ -560,7 +601,8 @@ class TransformerBlock(Layer):
                "attn_window": self.attn_window,
                "qk_norm": self.qk_norm, "rope_base": self.rope_base,
                "block_len": self.block_len, "mlp_dim": self.mlp_dim,
-               "mlp_gated": self.mlp_gated, "mlp_bias": self.mlp_bias}
+               "mlp_gated": self.mlp_gated, "mlp_bias": self.mlp_bias,
+               "rotary_dim": self.rotary_dim, "rope_yarn": self.rope_yarn}
         if self._mlp_override is not None:
             cfg["mlp_layer"] = layer_spec(self._mlp_override)
         return cfg

@@ -357,13 +357,15 @@ def _decode_block(block: TransformerBlock, p, s, kv, x, t):
     return x + m, kv
 
 
-def _prefill_block(block: TransformerBlock, p, s, kv, x, positions):
+def _prefill_block(block: TransformerBlock, p, s, kv, x, positions,
+                   routing=None):
     """Whole-prompt pass through one TransformerBlock: ONE causal
     attention over [B, P] (flash kernel on TPU) instead of P sequential
     decode steps, writing the block's K/V cache entries for every prompt
     position at once. Attention inside the prompt uses the exact
     (unquantized) K/V; an int8 cache quantizes what later DECODE steps
-    read — the standard serving contract."""
+    read — the standard serving contract. ``routing`` as in
+    :func:`_prefill_block_chunked`."""
     from distkeras_tpu.models.attention import _attention_compute
 
     attn = block.attn
@@ -386,7 +388,8 @@ def _prefill_block(block: TransformerBlock, p, s, kv, x, positions):
     x = x + y.astype(x.dtype)
     with jax.named_scope("mlp"):
         h_, _ = block.norm2.apply(p["norm2"], s["norm2"], x)
-        m, _ = block.mlp.apply(p["mlp"], s["mlp"], h_, training=False)
+        m = _apply_mlp_decode(block.mlp, p["mlp"], s["mlp"], h_,
+                              routing is not None, routing)
     return x + m, kv
 
 
@@ -653,7 +656,8 @@ def prefill_chunked(module: Sequential, params, state, cache, prompts,
     return last_logits, new_cache
 
 
-def prefill(module: Sequential, params, state, cache, prompts):
+def prefill(module: Sequential, params, state, cache, prompts,
+            routing=None):
     """Batched prompt ingestion (round 4): run the stack ONCE over the
     [B, P] prompt, filling every attention layer's cache at positions
     0..P-1, and return ``(last_logits [B, V], cache)``.
@@ -664,7 +668,7 @@ def prefill(module: Sequential, params, state, cache, prompts):
     8K-token prompt is ~250x fewer sequential device steps. The vocab
     head is applied to the LAST position only (the [B, P, V] logits
     tensor for a 32k vocab would be ~2 GB at P=8192 and is never
-    needed)."""
+    needed). ``routing`` as in :func:`prefill_chunk_step`."""
     b, p_len = prompts.shape
     x = prompts
     new_cache = list(cache)
@@ -675,7 +679,7 @@ def prefill(module: Sequential, params, state, cache, prompts):
         block = _decode_block_of(layer)
         if block is not None:
             x, new_cache[i] = _prefill_block(block, p, s, cache[i], x,
-                                             positions)
+                                             positions, routing)
         elif isinstance(layer, PositionalEmbedding):
             with jax.named_scope("embed"):
                 x = x + p["embeddings"][:p_len][None].astype(x.dtype)
@@ -781,7 +785,8 @@ def _moe_route_stats(routing, t, w_len: int, live_len: int):
         ent_sum = ent_sum + (ent * live[:, None]).sum()
         n_layers += 1
     n_tok = jnp.maximum(live.sum() * w_len * n_layers, 1.0)
-    return {"expert_load": load, "router_entropy": ent_sum / n_tok}
+    return {"expert_load": load, "router_entropy": ent_sum / n_tok,
+            "routed": routing_counts(routing)}
 
 
 def _cache_write_slots(kv, k, v, t):
@@ -877,7 +882,7 @@ def _attn_out(p, out, dt):
 
 
 def _slot_attn_readout(attn: MultiHeadAttention, p, q, kv, t, dt,
-                       tree=None, full_window: bool = False):
+                       tree=None, full_window: bool = False, kpos=None):
     """Masked per-slot attention of the projected decode queries against
     a logically contiguous ``[S, H, L, D]`` kv view — a contiguous cache
     or a page gather in logical-position order — plus the output projection.
@@ -893,7 +898,11 @@ def _slot_attn_readout(attn: MultiHeadAttention, p, q, kv, t, dt,
     that must not see it. ``tree`` (tree-speculation PR: ``{"depth":
     [S, W], "anc": [S, W, W]}``) generalizes the window to a token
     tree — see ``_window_valid_mask``; a chain-shaped tree produces the
-    exact mask above, bit for bit."""
+    exact mask above, bit for bit.
+
+    ``kpos`` ([S, L] int, a gathered RING of a window layer's pages):
+    the position each key of the view holds (negative: none), in place
+    of its index; chain windows of a sliding-window layer only."""
     scale = (attn.head_dim or q.shape[-1]) ** -0.5
     b = q.shape[0]
     w_len = q.shape[1]
@@ -904,8 +913,13 @@ def _slot_attn_readout(attn: MultiHeadAttention, p, q, kv, t, dt,
     qg = (q.astype(jnp.float32) * scale).reshape(
         b, w_len, hkv, g, dh)                        # [S, W, Hkv, G, D]
     s = _decode_scores(qg, kv)                       # [S, Hkv, G, W, L]
-    valid = _window_valid_mask(t, w_len, L, tree, attn.attn_window,
-                               full_window)
+    if kpos is None:
+        valid = _window_valid_mask(t, w_len, L, tree, attn.attn_window,
+                                   full_window)
+    else:
+        pos = (t[:, None] + jnp.arange(w_len))[:, :, None]   # [S, W, 1]
+        kp = kpos[:, None, :]                                # [S, 1, L]
+        valid = (kp >= 0) & (kp <= pos) & (kp > pos - attn.attn_window)
     s = jnp.where(valid[:, None, None, :, :], s, NEG_INF)
     w = jax.nn.softmax(s, axis=-1)
     out = _decode_mix(w, kv).astype(dt)              # [S, W, Hkv, G, D]
@@ -1034,21 +1048,26 @@ def _write_page_rows(plane, pp, off, vals):
     return flat.reshape(plane.shape)
 
 
-def _cache_write_pages(kv, k, v, t, table, page_len: int):
+def _cache_write_pages(kv, k, v, t, table, page_len: int, ring=None):
     """Write one [S, 1, H, D] k/v decode slab at per-slot positions
     ``t`` ([S] int) into the paged pool [N, H, page_len, D] through the
     slot page tables ``table`` ([S, P] int). Slot ``s`` writes physical
     page ``table[s, t[s] // page_len]`` at offset ``t[s] % page_len``;
     a ``t[s]`` past the logical capacity (the engine's free/prefilling
-    sentinel) or a sentinel table entry writes nothing (scatter drop)."""
+    sentinel) or a sentinel table entry writes nothing (scatter drop).
+    ``ring`` (an int: the logical pages a slot spans) says ``table`` is
+    a window group's RING, narrower than that: logical page ``p`` sits
+    in column ``p % table.shape[1]``."""
     kh = k[:, 0]                                         # [S, H, D]
     vh = v[:, 0]
     n_pages = kv["k"].shape[0]
-    n_logical = table.shape[1]
+    n_logical = table.shape[1] if ring is None else int(ring)
     lp = t // page_len                                   # [S] logical page
     off = t % page_len
-    pp = jnp.take_along_axis(
-        table, jnp.clip(lp, 0, n_logical - 1)[:, None], axis=1)[:, 0]
+    col = jnp.clip(lp, 0, n_logical - 1)
+    if ring is not None:
+        col = col % table.shape[1]
+    pp = jnp.take_along_axis(table, col[:, None], axis=1)[:, 0]
     # sentinel: out-of-range t (or an unallocated logical page whose
     # table entry is >= N already) routes the scatter out of bounds,
     # where mode="drop" discards it
@@ -1140,9 +1159,23 @@ def _use_paged_kernel(kv, page_len: int, paged_kernel) -> bool:
     return bool(paged_kernel) and page_aligned(page_len, quant)
 
 
+def _ring_key_positions(t, w_len: int, table, page_len: int):
+    """[S, R * page_len] positions the gathered view of a RING table
+    holds: column ``c`` is the newest logical page at or under the
+    window's top page congruent to ``c`` (negative where there is
+    none yet), as ``ops.paged_attention`` reads it."""
+    r = table.shape[1]
+    top = (t + (w_len - 1)) // page_len                  # [S]
+    cols = jnp.arange(r)[None, :]
+    lp = top[:, None] - (top[:, None] - cols) % r        # [S, R]
+    pos = lp[:, :, None] * page_len + jnp.arange(page_len)[None, None, :]
+    pos = jnp.where(lp[:, :, None] >= 0, pos, -1)
+    return pos.reshape(t.shape[0], r * page_len)
+
+
 def _paged_attn_readout(attn: MultiHeadAttention, p, q, kv, t, table,
                         page_len: int, dt, paged_kernel, tree=None,
-                        full_window: bool = False):
+                        full_window: bool = False, ring=None):
     """Readout for the paged decode/verify paths: the Pallas
     paged-attention kernel (K/V gathered HBM -> VMEM through the page
     table inside the kernel — no materialized [S, H, L, D] view) when
@@ -1150,12 +1183,21 @@ def _paged_attn_readout(attn: MultiHeadAttention, p, q, kv, t, table,
     off-TPU/interpret fallback and the kernel's oracle). ``tree``
     forwards the ancestor-mask window (tree-speculation PR) — the
     kernel takes the ``[S, W, W]`` mask as an operand; the gather path
-    threads it into the shared mask builder."""
+    threads it into the shared mask builder. ``ring``: ``table`` is
+    a window group's ring (``_cache_write_pages``); the kernel call is
+    then named ``paged_window_attention``."""
+    if ring is not None and (tree is not None or full_window
+                             or attn.attn_window is None):
+        raise ValueError("a ring page table serves chain windows of a "
+                         "sliding-window layer only")
     if not _use_paged_kernel(kv, page_len, paged_kernel):
         note_path("paged_attention", "gather_reference")
+        kpos = None if ring is None else _ring_key_positions(
+            t, q.shape[1], table, page_len)
         return _slot_attn_readout(attn, p, q,
                                   _gather_pages(kv, table), t, dt,
-                                  tree=tree, full_window=full_window)
+                                  tree=tree, full_window=full_window,
+                                  kpos=kpos)
     from distkeras_tpu.ops.paged_attention import paged_decode_attention
     note_path("paged_attention", "kernel")
     b, w_len, nh, dh = q.shape
@@ -1166,6 +1208,8 @@ def _paged_attn_readout(attn: MultiHeadAttention, p, q, kv, t, table,
     sc = {}
     if "k_scale" in kv:
         sc = {"k_scale": kv["k_scale"], "v_scale": kv["v_scale"]}
+    if ring is not None:
+        sc.update(ring=True, name="paged_window_attention")
     o = paged_decode_attention(
         qg, kv["k"], kv["v"], t, table, scale=scale,
         window=attn.attn_window,
@@ -1177,7 +1221,8 @@ def _paged_attn_readout(attn: MultiHeadAttention, p, q, kv, t, table,
 
 
 def _decode_attn_slots_paged(attn: MultiHeadAttention, p, kv, x, t,
-                             table, page_len: int, paged_kernel=None):
+                             table, page_len: int, paged_kernel=None,
+                             ring=None):
     """One-token attention against the PAGED pool at per-slot
     positions: scatter the new k/v through the page tables, then read
     back through the paged kernel (or the gathered per-slot view)."""
@@ -1187,20 +1232,21 @@ def _decode_attn_slots_paged(attn: MultiHeadAttention, p, kv, x, t,
     if attn.use_rope:
         q = attn.rope(q, t[:, None])
         k = attn.rope(k, t[:, None])
-    kv = _cache_write_pages(kv, k, v, t, table, page_len)
+    kv = _cache_write_pages(kv, k, v, t, table, page_len, ring)
     y = _paged_attn_readout(attn, p, q, kv, t, table, page_len, dt,
-                            paged_kernel)
+                            paged_kernel, ring=ring)
     return y.astype(x.dtype), kv
 
 
 def _decode_block_slots_paged(block: TransformerBlock, p, s, kv, x, t,
                               table, page_len: int,
                               moe_dispatched=True, routing=None,
-                              paged_kernel=None):
+                              paged_kernel=None, ring=None):
     with jax.named_scope("attn"):
         h, _ = block.norm1.apply(p["norm1"], s["norm1"], x)
         a, kv = _decode_attn_slots_paged(block.attn, p["attn"], kv, h, t,
-                                         table, page_len, paged_kernel)
+                                         table, page_len, paged_kernel,
+                                         ring)
     x = x + a
     with jax.named_scope("mlp"):
         h, _ = block.norm2.apply(p["norm2"], s["norm2"], x)
@@ -1212,12 +1258,19 @@ def _decode_block_slots_paged(block: TransformerBlock, p, s, kv, x, t,
 def decode_step_slots_paged(module: Sequential, params, state, cache,
                             tok, t, table, page_len: int,
                             *, moe_dispatched: bool = True,
-                            moe_stats=None, paged_kernel=None):
+                            moe_stats=None, paged_kernel=None,
+                            groups=None):
     """One token through the stack against a PAGED pooled cache: tok
     [S] int, t [S] int, table [S, P] int page tables; returns
     ([S, V] logits, cache). The paged mirror of ``decode_step_slots``
     — same garbage-logits contract for sentinel slots, same
     ``moe_dispatched``/``moe_stats`` MoE-decode contract.
+
+    ``groups`` (a pool with page groups by attention kind,
+    ``PagedKVPool.layer_groups``): per layer ``(g, ring)``; ``table``
+    is then the tuple of the groups' tables, layer ``i`` reads
+    ``table[g]``, and ``ring`` (the logical pages a slot spans, or
+    None) says that table is a window group's ring.
 
     ``paged_kernel`` selects the readout (decode-kernel PR): None =
     the Pallas page-table kernel on TPU and the ``_gather_pages``
@@ -1230,9 +1283,11 @@ def decode_step_slots_paged(module: Sequential, params, state, cache,
         p, s, kv = params[i], state[i], cache[i]
         block = _decode_block_of(layer)
         if block is not None:
+            tbl, ring = (table, None) if groups is None \
+                else (table[groups[i][0]], groups[i][1])
             x, new_cache[i] = _decode_block_slots_paged(
-                block, p, s, kv, x, t, table, page_len,
-                moe_dispatched, routing, paged_kernel)
+                block, p, s, kv, x, t, tbl, page_len,
+                moe_dispatched, routing, paged_kernel, ring)
         elif isinstance(layer, PositionalEmbedding):
             with jax.named_scope("embed"):
                 x = x + p["embeddings"][t][:, None, :].astype(x.dtype)

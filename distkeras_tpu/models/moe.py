@@ -125,8 +125,31 @@ class MoE(Layer):
                  dispatch: str = "dense",
                  capacity_factor: float = 1.25,
                  expert_unroll: bool = False,
-                 gated: bool = False, use_bias: bool = True):
+                 gated: bool = False, use_bias: bool = True,
+                 score: str = "softmax", norm_topk: bool = True,
+                 route_scale: float = 1.0,
+                 shared_dim: Optional[int] = None):
         self.num_experts = int(num_experts)
+        #: how a router logit becomes a gate: ``"softmax"`` over the k
+        #: chosen logits (renormalised by construction), or
+        #: ``"sigmoid"`` of each logit, divided by the sum over the k
+        #: chosen (``norm_topk``); either way times ``route_scale``
+        if score not in ("softmax", "sigmoid"):
+            raise ValueError(
+                f"score must be 'softmax' or 'sigmoid', got {score!r}")
+        self.score = score
+        self.norm_topk = bool(norm_topk)
+        self.route_scale = float(route_scale)
+        #: a shared expert of this width (the experts' own form: gated
+        #: or not, biased or not) that every token takes beside its
+        #: routed ones, unweighted; None: no shared expert
+        self.shared_dim = None if shared_dim is None else int(shared_dim)
+        self.shared = None
+        if self.shared_dim:
+            from distkeras_tpu.models.attention import TransformerMLP
+            self.shared = TransformerMLP(
+                self.shared_dim, activation=activation, dtype=dtype,
+                kernel_init=kernel_init, gated=gated, use_bias=use_bias)
         #: gated experts (SwiGLU with ``activation="silu"``):
         #: ``w2(act(x w1) * (x w3))``; ``use_bias=False`` drops b1/b2
         self.gated = bool(gated)
@@ -190,6 +213,9 @@ class MoE(Layer):
         if self.use_bias:
             params["b1"] = jnp.zeros((e, hid))
             params["b2"] = jnp.zeros((e, d))
+        if self.shared is not None:
+            params["shared"] = self.shared.init(
+                jax.random.fold_in(kg, 1), input_shape)[0]
         state = {}
         if self.aux_loss_weight:
             state[AUX_LOSS_KEY] = jnp.zeros((), jnp.float32)
@@ -209,14 +235,35 @@ class MoE(Layer):
         # is off the critical path, so there is no speed to buy here
         logits = jnp.einsum("bsd,de->bse", x.astype(jnp.float32),
                             gate.astype(jnp.float32))
-        full = jax.nn.softmax(logits, axis=-1)
-        topv, topi = lax.top_k(logits, self.top_k)
-        gates = jax.nn.softmax(topv, axis=-1)
+        if self.score == "sigmoid":
+            # the k largest scores are the k largest logits; ``full``
+            # (balance loss, entropy telemetry) is the scores as a
+            # distribution over all experts
+            scores = jax.nn.sigmoid(logits)
+            full = scores / jnp.sum(scores, axis=-1, keepdims=True)
+            topv, topi = lax.top_k(logits, self.top_k)
+            gates = jax.nn.sigmoid(topv)
+            if self.norm_topk:
+                gates = gates / jnp.sum(gates, axis=-1, keepdims=True)
+        else:
+            full = jax.nn.softmax(logits, axis=-1)
+            topv, topi = lax.top_k(logits, self.top_k)
+            gates = jax.nn.softmax(topv, axis=-1)
+        if self.route_scale != 1.0:
+            gates = gates * self.route_scale
         mask = None
         if self.top_k < self.num_experts:
             mask = jax.nn.one_hot(topi, self.num_experts,
                                   dtype=jnp.bool_).any(axis=-2)
         return full, topi, gates, mask
+
+    def _with_shared(self, params, x, out):
+        """``out`` plus the shared expert's output for ``x`` (every
+        token, unweighted), where the layer has one."""
+        if self.shared is None:
+            return out
+        y, _ = self.shared.apply(params["shared"], {}, x)
+        return out + y.astype(out.dtype)
 
     def _gate_probs(self, x, gate):
         """Routing weights [B, S, E] (softmax over top-k logits, 0
@@ -520,15 +567,17 @@ class MoE(Layer):
             out, full, _mask, topi = self._apply_dispatched(
                 params, x, fused=moe_kernels.fused_supported(),
                 capacity=b * s, return_routing=True)
+        out = self._with_shared(params, x, out.astype(x.dtype))
         if return_routing:
-            return out.astype(x.dtype), (topi, full)
-        return out.astype(x.dtype)
+            return out, (topi, full)
+        return out
 
     def apply(self, params, state, x, *, training=False, rng=None):
         dt = jnp.dtype(self.dtype)
 
         if self.dispatch == "grouped":
             out, full, mask, _topi = self._apply_grouped(params, x)
+            out = self._with_shared(params, x, out.astype(x.dtype))
             new_state = state
             if self.aux_loss_weight and training:
                 new_state = dict(state)
@@ -547,6 +596,7 @@ class MoE(Layer):
                 use_fused = moe_kernels.fused_supported()
             out, full, mask = self._apply_dispatched(params, x,
                                                      fused=use_fused)
+            out = self._with_shared(params, x, out.astype(x.dtype))
             new_state = state
             if self.aux_loss_weight and training:
                 new_state = dict(state)
@@ -582,6 +632,7 @@ class MoE(Layer):
             local = lax.dynamic_slice_in_dim(probs, idx * el, el, axis=-1)
             out = jnp.einsum("bse,besd->bsd", local.astype(dt), y)
             out = lax.psum(out, self.expert_axis_name)
+        out = self._with_shared(params, x, out.astype(x.dtype))
         new_state = state
         if self.aux_loss_weight and training:
             # router inputs/gate are replicated under expert sharding, so
@@ -601,7 +652,10 @@ class MoE(Layer):
                 "dispatch": self.dispatch,
                 "capacity_factor": self.capacity_factor,
                 "expert_unroll": self.expert_unroll,
-                "gated": self.gated, "use_bias": self.use_bias}
+                "gated": self.gated, "use_bias": self.use_bias,
+                "score": self.score, "norm_topk": self.norm_topk,
+                "route_scale": self.route_scale,
+                "shared_dim": self.shared_dim}
 
 
 def moe_all_to_all(moe: MoE, params, x, *, axis_name: str):

@@ -172,7 +172,15 @@ def transformer_lm(vocab_size: int, d_model: int = 512, num_heads: int = 8,
                    mlp_dim: Optional[int] = None,
                    mlp_activation: str = "gelu", mlp_gated: bool = False,
                    mlp_bias: bool = True,
-                   moe_top_k: int = 2) -> Sequential:
+                   moe_top_k: int = 2,
+                   layer_types: Optional[Sequence[str]] = None,
+                   attn_kinds: Optional[dict] = None,
+                   mlp_layer_types: Optional[Sequence[str]] = None,
+                   dense_mlp_dim: Optional[int] = None,
+                   moe_score: str = "softmax",
+                   moe_norm_topk: bool = True,
+                   moe_route_scale: float = 1.0,
+                   moe_shared_dim: Optional[int] = None) -> Sequential:
     """Decoder-only causal transformer LM — the long-context flagship.
 
     Absent from the reference (no attention models; SURVEY §5.7); this is
@@ -208,6 +216,22 @@ def transformer_lm(vocab_size: int, d_model: int = 512, num_heads: int = 8,
     ``num_experts`` take each token (gates renormalised over the k);
     ``moe_dispatch="grouped"`` is the drop-free serving dispatch for
     many small experts (``models/moe.py``).
+
+    Layers of several kinds in one stack: ``layer_types`` names each
+    layer's attention kind (its first ``num_layers`` entries are read)
+    and ``attn_kinds[kind]`` overrides, for the layers of that kind,
+    any of ``num_heads``, ``num_kv_heads``, ``head_dim``,
+    ``attn_window``, ``rope_base``, ``rope_scale``, ``rotary_dim``,
+    ``rope_yarn`` and ``qk_norm`` (what it leaves out is the argument
+    of the same name above). ``ServingEngine`` gives layers with a
+    window and layers without one page groups of their own
+    (docs/serving.md §Page groups). ``mlp_layer_types`` says per layer
+    ``"dense"`` (a plain MLP of width ``dense_mlp_dim``, else
+    ``mlp_dim``) or ``"sparse"`` (the experts) and takes the place of
+    ``moe_every``. ``moe_score`` / ``moe_norm_topk`` /
+    ``moe_route_scale`` / ``moe_shared_dim``: the router's score
+    function, its normalisation over the chosen experts, a scale on
+    the gates and a shared expert (``models.moe.MoE``).
     """
     from distkeras_tpu.models.attention import (
         LayerNorm, PositionalEmbedding, RMSNorm, TransformerBlock)
@@ -220,10 +244,44 @@ def transformer_lm(vocab_size: int, d_model: int = 512, num_heads: int = 8,
         # sequence parallelism (shard-local positions would be silently wrong)
         layers.append(PositionalEmbedding(max_len,
                                           seq_axis_name=seq_axis_name))
+    for name, kinds in (("layer_types", layer_types),
+                        ("mlp_layer_types", mlp_layer_types)):
+        if kinds is not None and len(kinds) < num_layers:
+            raise ValueError(f"{name} names {len(kinds)} layers of "
+                             f"{num_layers}")
+    if layer_types is None and attn_kinds:
+        raise ValueError("attn_kinds needs layer_types")
+    if mlp_layer_types is not None and set(mlp_layer_types) \
+            - {"dense", "sparse"}:
+        raise ValueError("mlp_layer_types entries are 'dense' or 'sparse'")
     for i in range(num_layers):
+        attn = dict(num_heads=num_heads, head_dim=head_dim,
+                    num_kv_heads=num_kv_heads, rope_scale=rope_scale,
+                    attn_window=attn_window, qk_norm=qk_norm,
+                    rope_base=rope_base)
+        if layer_types is not None:
+            kind = dict((attn_kinds or {}).get(layer_types[i], {}))
+            unknown = set(kind) - set(attn) - {"rotary_dim", "rope_yarn"}
+            if unknown:
+                raise ValueError(
+                    f"attn_kinds[{layer_types[i]!r}] has unknown keys "
+                    f"{sorted(unknown)}")
+            attn.update(kind)
+        if mlp_layer_types is None:
+            sparse = bool(moe_every and num_experts
+                          and (i + 1) % moe_every == 0)
+        else:
+            sparse = mlp_layer_types[i] == "sparse"
         mlp_layer = None
-        if moe_every and num_experts and (i + 1) % moe_every == 0:
+        layer_mlp_dim = mlp_dim
+        if sparse:
             from distkeras_tpu.models.moe import MoE
+            routing = {}
+            if moe_score != "softmax" or not moe_norm_topk \
+                    or moe_route_scale != 1.0 or moe_shared_dim:
+                routing = dict(score=moe_score, norm_topk=moe_norm_topk,
+                               route_scale=moe_route_scale,
+                               shared_dim=moe_shared_dim)
             mlp_layer = MoE(num_experts, mlp_dim or mlp_ratio * d_model,
                             top_k=moe_top_k, activation=mlp_activation,
                             dtype=dtype, expert_axis_name=moe_expert_axis,
@@ -231,16 +289,16 @@ def transformer_lm(vocab_size: int, d_model: int = 512, num_heads: int = 8,
                             dispatch=moe_dispatch,
                             capacity_factor=moe_capacity_factor,
                             expert_unroll=moe_expert_unroll,
-                            gated=mlp_gated, use_bias=mlp_bias)
+                            gated=mlp_gated, use_bias=mlp_bias, **routing)
+        elif mlp_layer_types is not None and dense_mlp_dim:
+            layer_mlp_dim = dense_mlp_dim
         block = TransformerBlock(
-            num_heads, mlp_ratio=mlp_ratio, head_dim=head_dim, causal=True,
+            mlp_ratio=mlp_ratio, causal=True,
             use_rope=use_rope, activation=mlp_activation,
             norm=norm, dtype=dtype, attn_impl=attn_impl,
             seq_axis_name=seq_axis_name, mlp_layer=mlp_layer,
-            num_kv_heads=num_kv_heads, rope_scale=rope_scale,
-            attn_window=attn_window, qk_norm=qk_norm, rope_base=rope_base,
-            block_len=block_len, mlp_dim=mlp_dim, mlp_gated=mlp_gated,
-            mlp_bias=mlp_bias)
+            block_len=block_len, mlp_dim=layer_mlp_dim,
+            mlp_gated=mlp_gated, mlp_bias=mlp_bias, **attn)
         if remat is not None:
             from distkeras_tpu.models.blocks import Remat
             block = Remat(block, policy=remat)

@@ -127,6 +127,14 @@ class _Metric:
     def _new_cell(self):
         raise NotImplementedError
 
+    def reserve_series(self, n: int) -> None:
+        """Make room for ``n`` label sets: for a label whose values the
+        caller bounds by construction (one series an expert of a model
+        with 256), where the registry's default cap is a guard against
+        per-request labels and not a limit that was meant."""
+        with self._lock:
+            self._max_series = max(self._max_series, int(n))
+
     def _cell(self, labels: Optional[Dict] = None):
         key = _label_key(labels) if labels else ()
         cell = self._series.get(key)

@@ -113,8 +113,51 @@ def rope_frequencies(head_dim: int, base: float = 10000.0) -> jnp.ndarray:
                                       dtype=jnp.float32) / head_dim))
 
 
+def yarn_inv_freq(rotary_dim: int, base: float, factor: float,
+                  original_max_len: int, beta_fast: float = 32.0,
+                  beta_slow: float = 1.0, truncate: bool = True):
+    """YaRN's inverse frequencies ``[rotary_dim // 2]`` (numpy float32), as
+    ``transformers`` computes them (``_compute_yarn_parameters``):
+    dimension ``i`` blends the plain frequency ``base^(-2i/dim)`` with
+    that frequency over ``factor`` by a linear ramp between the
+    correction dimensions of ``beta_fast`` and ``beta_slow`` rotations
+    over ``original_max_len`` positions — fast dimensions keep their
+    frequency, slow ones are interpolated."""
+    import math
+
+    import numpy as np
+    dim = int(rotary_dim)
+
+    def correction_dim(rotations):
+        return dim * math.log(original_max_len / (rotations * 2 * math.pi)) \
+            / (2 * math.log(base))
+
+    low, high = correction_dim(beta_fast), correction_dim(beta_slow)
+    if truncate:
+        low, high = math.floor(low), math.ceil(high)
+    low, high = max(low, 0), min(high, dim - 1)
+    if low == high:
+        high += 0.001
+    pos_freqs = base ** (np.arange(0, dim, 2, dtype=np.float64) / dim)
+    ramp = np.clip((np.arange(dim // 2, dtype=np.float64) - low)
+                   / (high - low), 0.0, 1.0)
+    keep = 1.0 - ramp                    # share of the plain frequency
+    inv = (1.0 / (factor * pos_freqs)) * (1.0 - keep) \
+        + (1.0 / pos_freqs) * keep
+    return inv.astype(np.float32)        # numpy: a constant, safe to keep
+
+
+def yarn_attention_factor(factor: float) -> float:
+    """The scale YaRN puts on cosine and sine where the configuration
+    states none: ``0.1 ln(factor) + 1`` (1 at ``factor <= 1``)."""
+    import math
+    return 1.0 if factor <= 1 else 0.1 * math.log(factor) + 1.0
+
+
 def apply_rope(x, positions=None, base: float = 10000.0,
-               layout: str = "bshd", scale: float = 1.0):
+               layout: str = "bshd", scale: float = 1.0,
+               rotary_dim: Optional[int] = None, inv_freq=None,
+               mscale: float = 1.0):
     """Rotary position embedding on a BSHD (default) or BHSD tensor.
 
     ``positions``: optional [S] or [B, S] int array of global token positions
@@ -125,6 +168,12 @@ def apply_rope(x, positions=None, base: float = 10000.0,
     positions are divided by ``scale`` so a model trained to length L
     serves length ``scale * L`` inside its trained rotary range — the
     standard cheap long-context extension.
+
+    ``rotary_dim`` rotates only the first ``rotary_dim`` dimensions of
+    each head (a partial rotary factor); the rest pass through.
+    ``inv_freq`` (``[rotary_dim // 2]``) replaces the frequencies
+    ``base`` would give (:func:`yarn_inv_freq`), and ``mscale``
+    multiplies cosine and sine (YaRN's attention factor).
     """
     if layout == "bhsd":
         b, h, s, d = x.shape
@@ -137,16 +186,26 @@ def apply_rope(x, positions=None, base: float = 10000.0,
         positions = positions / scale
     if positions.ndim == 1:
         positions = positions[None, :]  # [1, S] broadcasts over batch
-    freqs = rope_frequencies(d, base)                   # [D/2]
-    angles = positions[..., None] * freqs               # [B?, S, D/2]
+    rot = d if rotary_dim is None else int(rotary_dim)
+    if rot > d or rot % 2:
+        raise ValueError(f"rotary_dim {rot} must be even and at most the "
+                         f"head size {d}")
+    freqs = rope_frequencies(rot, base) if inv_freq is None \
+        else jnp.asarray(inv_freq, jnp.float32)         # [rot/2]
+    angles = positions[..., None] * freqs               # [B?, S, rot/2]
     if layout == "bhsd":
-        cos = jnp.cos(angles)[:, None, :, :]            # [B?, 1, S, D/2]
+        cos = jnp.cos(angles)[:, None, :, :]            # [B?, 1, S, rot/2]
         sin = jnp.sin(angles)[:, None, :, :]
     else:
-        cos = jnp.cos(angles)[:, :, None, :]            # [B?, S, 1, D/2]
+        cos = jnp.cos(angles)[:, :, None, :]            # [B?, S, 1, rot/2]
         sin = jnp.sin(angles)[:, :, None, :]
-    x1, x2 = x[..., ::2].astype(jnp.float32), x[..., 1::2].astype(jnp.float32)
+    if mscale != 1.0:
+        cos, sin = cos * mscale, sin * mscale
+    xr = x if rot == d else x[..., :rot]
+    x1, x2 = xr[..., ::2].astype(jnp.float32), xr[..., 1::2].astype(jnp.float32)
     r1 = x1 * cos - x2 * sin
     r2 = x2 * cos + x1 * sin
-    out = jnp.stack([r1, r2], axis=-1).reshape(x.shape)
-    return out.astype(x.dtype)
+    out = jnp.stack([r1, r2], axis=-1).reshape(xr.shape).astype(x.dtype)
+    if rot == d:
+        return out
+    return jnp.concatenate([out, x[..., rot:]], axis=-1)

@@ -126,7 +126,7 @@ def _unpack4(b, dt):
 def _kernel(t_ref, tb_ref, *refs, scale: float, page_len: int,
             g: int, w_len: int, hkv: int, window, quantized: bool,
             int4: bool, n_pages: int, tree: bool,
-            full_window: bool = False):
+            full_window: bool = False, ring: bool = False):
     if tree:
         anc_ref, refs = refs[0], refs[1:]
     else:
@@ -149,12 +149,25 @@ def _kernel(t_ref, tb_ref, *refs, scale: float, page_len: int,
         l_ref[:] = jnp.zeros_like(l_ref)
         acc_ref[:] = jnp.zeros_like(acc_ref)
 
-    start = pi * page_len
+    if ring:
+        # the table is a RING of ``npp`` columns: column ``pi`` holds
+        # the newest logical page at or under the window's top page
+        # that is congruent to ``pi`` (a window layer's page group:
+        # pages behind the window are given back, so the table is as
+        # wide as a window, not as the context)
+        top = (t + (w_len - 1)) // page_len
+        lp = top - lax.rem(top - pi + npp, npp)
+        start = lp * page_len
+    else:
+        lp = pi
+        start = pi * page_len
     # a page participates iff it holds any position some window query
     # admits: the union of the per-query ranges is (t - window, t+W-1]
     # (tree windows too: every node's column lies in [t, t+W-1])
     run = jnp.logical_and(start <= t + (w_len - 1),
                           tb_ref[si, pi] < n_pages)
+    if ring:
+        run = jnp.logical_and(run, lp >= 0)
     if window is not None:
         run = jnp.logical_and(run, start + page_len - 1 > t - window)
 
@@ -247,6 +260,8 @@ def paged_decode_attention(q, k_pages, v_pages, t, table, *,
                            window: Optional[int] = None,
                            k_scale=None, v_scale=None, anc=None,
                            full_window: bool = False,
+                           ring: bool = False,
+                           name: str = "paged_decode_attention",
                            interpret: Optional[bool] = None):
     """Window decode attention straight off the page pool.
 
@@ -271,11 +286,20 @@ def paged_decode_attention(q, k_pages, v_pages, t, table, *,
     ``full_window`` (block diffusion): every window query admits the
     committed prefix and ALL ``W`` window positions ``t .. t+W-1`` —
     attention is bidirectional inside the window (no ``window``/``anc``
-    with it)."""
+    with it).
+
+    ``ring`` (a window layer's page group): ``table`` is ``[S, R]``, a
+    ring in which column ``c`` holds the slot's newest logical page
+    ``p <= (t + W - 1) // page_len`` with ``p % R == c``; needs
+    ``window`` (what lies further back than ``R`` pages is never read)
+    and takes no tree. ``name`` is the kernel's name in a trace."""
     s, w_len, hkv, g, d = q.shape
     if full_window and (window is not None or anc is not None):
         raise ValueError("full_window takes neither a sliding window "
                          "nor a tree mask")
+    if ring and (window is None or anc is not None or full_window):
+        raise ValueError("a ring table needs a sliding window and takes "
+                         "neither a tree mask nor a full window")
     n_pages, _, payload_rows, _ = k_pages.shape
     n_logical = table.shape[1]
     quantized = k_scale is not None
@@ -354,7 +378,8 @@ def paged_decode_attention(q, k_pages, v_pages, t, table, *,
         _kernel, scale=float(scale), page_len=int(page_len), g=int(g),
         w_len=int(w_len), hkv=int(hkv), window=window,
         quantized=quantized, int4=int4, n_pages=int(n_pages),
-        tree=anc is not None, full_window=bool(full_window))
+        tree=anc is not None, full_window=bool(full_window),
+        ring=bool(ring))
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
         grid=(s, n_logical),
@@ -371,7 +396,7 @@ def paged_decode_attention(q, k_pages, v_pages, t, table, *,
         out_shape=jax.ShapeDtypeStruct((s, hkv, rows_p, d), jnp.float32),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
-        name="paged_decode_attention", interpret=interpret,
+        name=name, interpret=interpret,
     )(jnp.asarray(t, jnp.int32), jnp.asarray(table, jnp.int32),
       *operands)
     return out[:, :, :rows].reshape(s, hkv, w_len, g, d) \

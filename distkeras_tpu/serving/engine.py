@@ -162,9 +162,13 @@ class _PendingStep:
     slot recycled in the meantime discards its stale tokens."""
 
     __slots__ = ("nxt", "last", "keys", "moe", "slots", "covers",
-                 "count", "launch_t")
+                 "count", "launch_t", "prefills")
 
-    def __init__(self, nxt, last, keys, moe, slots, count, launch_t):
+    def __init__(self, nxt, last, keys, moe, slots, count, launch_t,
+                 prefills=()):
+        #: routing counts of the prefill programs dispatched before
+        #: this step (device arrays, read with the step's own)
+        self.prefills = prefills
         self.nxt = nxt
         self.last = last
         self.keys = keys
@@ -222,7 +226,12 @@ class ServingEngine:
     * ``num_pages`` — the HBM budget, in pages. Default
       ``num_slots * ceil(max_len / page_len)`` (every slot's worst
       case at once); size it DOWN to actual traffic and
-      let cost-aware admission + preemption absorb the tail.
+      let cost-aware admission + preemption absorb the tail. A model
+      whose layers are of several attention kinds (window and full
+      mixed) gets a page group per kind (docs/serving.md §Page groups):
+      this count is then the full layers' group, and a tuple ``(full,
+      window, ...)`` also states the window groups' (default: two
+      rings a slot less a page, a ring being one window's pages).
     * ``host_kv_pages`` — the HOST page pool (offload tier, docs/
       serving.md §Host KV offload). When > 0, preemption victims swap
       their pages out D2H (resume = H2D copy + table restore, token-
@@ -373,7 +382,7 @@ class ServingEngine:
                  max_queue: Optional[int] = None,
                  tracer=None, slo=None,
                  page_len: int = 16,
-                 num_pages: Optional[int] = None,
+                 num_pages=None,
                  host_kv_pages: int = 0,
                  decode_kernel: str = "auto",
                  prefix_cache: bool = True,
@@ -514,8 +523,14 @@ class ServingEngine:
             # the expert telemetry of the one-token step has no reader
             # here: a pass reports its own counts (rows, experts)
             self._moe_stats_on = False
-        #: routing counts of block-diffusion prefills not yet read
+        #: routing counts of prefill programs not yet read
         self._prefill_routed: list = []
+        #: the one-token programs report what their expert layers
+        #: routed (rows, experts touched) where every expert layer is
+        #: ``dispatch="grouped"``: its prefill and decode paths are one
+        self._count_routing = (
+            self._moe_dispatched and self.block_len is None
+            and all(m.dispatch == "grouped" for m in self._moe))
         self._moe_conc: Optional[float] = None   # routing-concentration EMA
         self._moe_iter = 0                       # stats-throttle counter
         self._setup_expert_parallel(ep_mesh)
@@ -548,6 +563,20 @@ class ServingEngine:
                                 hbm_budget=hbm_budget,
                                 reserve_bytes=reserve)
         self.page_len = self.pool.page_len
+        #: per layer ``(page group, ring)`` where the pool has a group
+        #: per attention kind (``PagedKVPool.layer_groups``), else None
+        self._groups = self.pool.layer_groups
+        if self.pool.aux:
+            if draft is not None or fuse_steps \
+                    or self.block_len is not None or ep_mesh is not None:
+                raise ValueError(
+                    "a model with window and full attention layers is "
+                    "served one token a step: no draft, fuse_steps, "
+                    "block diffusion or ep_mesh")
+            # a prefix hit resumes at a page boundary, where every
+            # group's pages begin (no copy-on-write donor)
+            prefix_granularity = int(np.lcm(int(prefix_granularity),
+                                            self.page_len))
         self.prefix = PrefixCache(self.pool) if prefix_cache else None
         if prefix_granularity < 1:
             raise ValueError(
@@ -1088,9 +1117,18 @@ class ServingEngine:
         if not any(running.get(s) is not None and running[s].rid == r
                    for s, r in p.slots):
             return      # every covered stream retired: drop wholesale
+        counts = ()
+        if self._count_routing and p.count == 1:
+            counts = (p.moe["routed"], *p.prefills)
         fetched = self._fetch(*((p.nxt,) if p.keys is None
-                                else (p.nxt, p.keys)))
+                                else (p.nxt, p.keys)), *counts)
         nxt = fetched[0]
+        if counts:
+            routed, *prefills = fetched[-len(counts):]
+            self.metrics.record_routing("decode", *map(int, routed))
+            for rows, touched in prefills:
+                self.metrics.record_routing("prefill", int(rows),
+                                            int(touched))
         if p.keys is not None:
             # chain-live slots take the program's post-split keys; a
             # slot the host overrode since launch (fresh admission)
@@ -1157,6 +1195,8 @@ class ServingEngine:
             if po > so or pr > sr:
                 m.record_offload(po - so, pr - sr, ob - sb)
                 self._off_seen = (po, pr, ob)
+            if self.pool.aux:
+                m.record_kv_groups(self._kv_groups())
             if self.prefix is not None:
                 now = (self.prefix.evictions,
                        self.prefix.evict_examined,
@@ -1191,6 +1231,30 @@ class ServingEngine:
                     [(rid, *pa)
                      for rid, pa in self._trace_spec.items()])
             self._trace_spec = {}
+
+    def _kv_groups(self) -> Dict[str, Dict]:
+        """Per page group (``"full"``, then each window group by its
+        name): pages in all, free, held by slots (``pages_live``),
+        held more than once (``pages_shared``), given back by slots
+        behind their window since the engine began
+        (``pages_released``; the full group gives none back) and by
+        the prefix cache under that group's pressure."""
+        pool = self.pool
+        out = {"full": {
+            "window": None, "pages_total": pool.num_pages,
+            "pages_free": pool.free_pages,
+            "pages_live": int((pool.tables < pool.num_pages).sum()),
+            "pages_shared": pool.shared_pages, "pages_released": 0}}
+        for g, grp in enumerate(pool.aux):
+            out[grp.name] = {
+                "window": grp.window, "pages_total": grp.num_pages,
+                "pages_free": grp.free_pages,
+                "pages_live": grp.live_pages,
+                "pages_shared": grp.shared_pages,
+                "pages_released": grp.pages_released,
+                "pages_evicted": (0 if self.prefix is None
+                                  else self.prefix.aux_evictions[g])}
+        return out
 
     def _inflight(self) -> Dict[int, int]:
         """slot -> tokens in flight for the slot's CURRENT request (0
@@ -1312,7 +1376,9 @@ class ServingEngine:
         for slot, _ in slots:
             self._t[slot] += count
             dirty[slot] = False          # chain live until overridden
-        return _PendingStep(nxt, last, keys, moe, slots, count, t0)
+        prefills, self._prefill_routed = self._prefill_routed, []
+        return _PendingStep(nxt, last, keys, moe, slots, count, t0,
+                            tuple(prefills))
 
     def _record_iteration(self, admitted: List[Request]) -> None:
         """Flight-recorder iteration entry, written BEFORE
@@ -1466,6 +1532,8 @@ class ServingEngine:
                 moe_stats=self.max_len if self._moe_stats_on else None)
             stats_on = self._moe_stats_on
             pk = self._paged_kernel
+            if self._groups is not None:
+                moe_kw["groups"] = self._groups
 
             def step(params, state, cache, tok, t, tables):
                 out = decode_step_slots_paged(
@@ -1881,6 +1949,19 @@ class ServingEngine:
                         module, params, state, cache, chunk, t0,
                         final=final, routing=routing)
                     return None, cache, routing_counts(routing or [])
+            elif self._count_routing:
+                # as below, with what the expert layers routed beside
+                # the logits and the cache
+                def f(params, state, cache, chunk):
+                    routing = []
+                    if t0 == 0 and final:
+                        logits, cache = prefill(module, params, state,
+                                                cache, chunk, routing)
+                    else:
+                        logits, cache = prefill_chunk_step(
+                            module, params, state, cache, chunk, t0,
+                            final=final, routing=routing)
+                    return logits, cache, routing_counts(routing)
             elif t0 == 0 and final:
                 def f(params, state, cache, chunk):
                     return prefill(module, params, state, cache, chunk)
@@ -1987,14 +2068,20 @@ class ServingEngine:
         # must land on an allocated page (block diffusion: the page
         # holds the whole first block, page_len % block_len == 0)
         n_logical = pool.pages_for(len(toks) + 1)
+        aux_load, reach = [{} for _ in pool.aux], 0
         if self.prefix is not None and len(toks):
-            full, shared_len, donor = self._match_prefix(toks)
+            if pool.aux:
+                full, shared_len, donor, aux_load, reach = \
+                    self.prefix.match_groups(toks)
+            else:
+                full, shared_len, donor = self._match_prefix(toks)
         else:
             full, shared_len, donor = [], 0, None
         for pid in full:
             pool.incref(pid)             # the slot's hold, owned early
         if donor is not None:
             pool.incref(donor)           # held until loaded to staging
+        self._hold_aux(aux_load)         # held until loaded to staging
         n_private = n_logical - len(full)
         # MoE-aware admission cost: under concentrated routing the
         # free-page budget must also show headroom pages (never
@@ -2008,15 +2095,31 @@ class ServingEngine:
             # later same-template request while the head stays queued)
             if self.prefix.evictable_pages() >= deficit:
                 self.prefix.reclaim(deficit)
-        if pool.free_pages < need:
+        # each window group's ring: the pages the window of the first
+        # decode write reaches (``WindowPages.span``)
+        rings = []
+        if pool.free_pages >= need:
+            for g, grp in enumerate(pool.aux):
+                got = self._aux_alloc(g, len(grp.span(len(toks))))
+                if got is None:
+                    break
+                rings.append(got)
+        if pool.free_pages < need or len(rings) < len(pool.aux):
             for pid in full:
                 pool.decref(pid)
             if donor is not None:
                 pool.decref(donor)
+            for grp, pages, ring in itertools.zip_longest(
+                    pool.aux, aux_load, rings, fillvalue=()):
+                for pid in (*pages.values(), *ring):
+                    grp.decref(pid)
             return None
         priv = [pool.alloc_page() for _ in range(n_private)]
-        return {"full": full, "priv": priv, "shared_len": shared_len,
+        plan = {"full": full, "priv": priv, "shared_len": shared_len,
                 "donor": donor}
+        if pool.aux:
+            plan.update(aux_load=aux_load, aux_ring=rings, reach=reach)
+        return plan
 
     def _match_prefix(self, toks):
         """``PrefixCache.match`` with the engine's partial-match
@@ -2043,9 +2146,18 @@ class ServingEngine:
         the budget."""
         pool = self.pool
         toks = self._context_of(req)
-        full, shared_len, donor = self._match_prefix(toks)
+        if pool.aux:
+            full, shared_len, donor, aux_load, reach = \
+                self.prefix.match_groups(toks)
+            req._reach = max(reach, getattr(req, "_reach", 0))
+        else:
+            full, shared_len, donor = self._match_prefix(toks)
         if shared_len <= getattr(req, "_shared_len", 0):
             return
+        if pool.aux:
+            self._drop_aux_holds(req)
+            self._hold_aux(aux_load)
+            req._aux_load = aux_load
         old_full = getattr(req, "_n_shared_full", 0)
         slot = req.slot
         for j in range(old_full, len(full)):
@@ -2097,6 +2209,118 @@ class ServingEngine:
         req._donor_ref = plan["donor"]
         req._load_pages = list(plan["full"]) + (
             [plan["donor"]] if plan["donor"] is not None else [])
+        if pool.aux:
+            n_ctx = len(self._context_of(req))
+            for grp, ring in zip(pool.aux, plan["aux_ring"]):
+                for lp, pid in zip(grp.span(n_ctx), ring):
+                    grp.assign(slot, lp, pid)
+            req._aux_load = plan["aux_load"]
+            req._reach = plan["reach"]
+
+    # --- window page groups (kv_pool.WindowPages) --------------------------
+
+    def _aux_alloc(self, g: int, n: int) -> Optional[List[int]]:
+        """``n`` pages of window group ``g``, giving back pages of it
+        that only the prefix cache holds where the free ones do not
+        reach; None (and nothing taken) where that does not either."""
+        grp = self.pool.aux[g]
+        short = n - grp.free_pages
+        if short > 0 and self.prefix is not None \
+                and self.prefix.aux_evictable(g) >= short:
+            for _ in range(short):
+                self.prefix.aux_evict_one(g)
+        if grp.free_pages < n:
+            return None
+        return [grp.alloc_page() for _ in range(n)]
+
+    def _hold_aux(self, aux_load) -> None:
+        """Take a hold on the window-group pages a prefix hit will load
+        (per group ``{logical page: page id}``), so that nothing gives
+        them back before the load."""
+        for grp, pages in zip(self.pool.aux, aux_load):
+            for pid in pages.values():
+                grp.incref(pid)
+
+    def _drop_aux_holds(self, req: Request) -> None:
+        """Release the holds a page plan took on the window-group pages
+        a prefix hit loads (taken so nothing gives them back first)."""
+        for grp, pages in zip(self.pool.aux,
+                              getattr(req, "_aux_load", None) or ()):
+            for pid in pages.values():
+                grp.decref(pid)
+        req._aux_load = None
+
+    def _aux_insert_plan(self, req: Request, p_len: int):
+        """What a finished prefill writes into the window groups, per
+        group: ``{logical page: page id}`` to write from staging (the
+        slot's ring, as far as the context fills it), the pages to
+        register with the prefix cache, and pages taken here for that
+        alone. Registered are the pages one window behind the boundary
+        at which this prompt stopped matching the trie, and only where
+        its own hit was cut short of it for lack of them
+        (``PrefixCache``'s class doc): the request that first finds
+        prompts parting there pays one prefill and leaves them."""
+        pl = self.page_len
+        cut = getattr(req, "_shared_len", 0) // pl
+        reach = getattr(req, "_reach", 0)
+        n_filled = self.pool.pages_for(p_len)
+        writes, rows, taken = [], [], []
+        for g, grp in enumerate(self.pool.aux):
+            held = grp.slot_pages(req.slot)
+            write = {lp: pid for lp, pid in held.items() if lp < n_filled}
+            row, mine = {}, []
+            if self.prefix is not None and cut < reach:
+                for lp in range(grp.first_needed(reach * pl), reach):
+                    pid = held.get(lp)
+                    if pid is None:
+                        got = self._aux_alloc(g, 1)
+                        if got is None:
+                            continue
+                        pid = got[0]
+                        mine.append(pid)
+                        write[lp] = pid
+                    row[lp] = pid
+            writes.append(write)
+            rows.append(row)
+            taken.append(mine)
+        return writes, rows, taken
+
+    def _ensure_window_pages(self) -> None:
+        """Before a decode step, per window group: every running slot
+        gives back the pages that lie wholly behind the window of the
+        position it writes next, and one whose write crosses into a new
+        logical page gets that page (free, else given back by the
+        prefix cache, else by preempting as ``_ensure_decode_pages``
+        does)."""
+        running = self.scheduler.running
+        slots = np.fromiter(running.keys(), np.int64, len(running))
+        t = np.minimum(self._t[slots].astype(np.int64),
+                       self.pool.pages_per_slot * self.page_len - 1)
+        for g, grp in enumerate(self.pool.aux):
+            held = grp.logical[slots]
+            first = np.maximum(t - grp.window + 1, 0) // grp.page_len
+            stale = ((held >= 0) & (held < first[:, None])).any(axis=1)
+            for slot, at in zip(slots[stale].tolist(), t[stale].tolist()):
+                grp.release_behind(slot, at)
+            missing = ~(grp.logical[slots]
+                        == (t // grp.page_len)[:, None]).any(axis=1)
+            if not missing.any():
+                continue
+            at = dict(zip(slots.tolist(), t.tolist()))
+            for req in sorted((running[s] for s in slots[missing].tolist()),
+                              key=lambda r: (r.priority, r.rid)):
+                while req.state is RequestState.DECODING:
+                    got = self._aux_alloc(g, 1)
+                    if got is not None:
+                        grp.assign(req.slot,
+                                   at[req.slot] // grp.page_len, got[0])
+                        break
+                    if not self._preempt_victim(beneficiary=req,
+                                                strict_priority=False):
+                        raise RuntimeError(
+                            f"page group {grp.name!r} exhausted: no free "
+                            "page, nothing the prefix cache can give "
+                            "back, no preemptable stream")
 
     def _preempt_victim(self, beneficiary: Request,
                         strict_priority: bool) -> bool:
@@ -2194,6 +2418,7 @@ class ServingEngine:
             # never consumed
             self.pool.decref(victim._donor_ref)
             victim._donor_ref = None
+        self._drop_aux_holds(victim)
         victim._shared_len = 0
         victim._n_shared_full = 0
         victim._load_pages = []
@@ -2228,6 +2453,10 @@ class ServingEngine:
         running = self.scheduler.running
         if not running:
             return
+        if pool.aux:
+            self._ensure_window_pages()
+            if not running:
+                return
         # steady-state fast path (zero-bubble PR): ONE vectorized scan
         # over the numpy table/position mirrors decides "no growth
         # needed" — the common case — without the per-slot int() loop
@@ -2581,6 +2810,7 @@ class ServingEngine:
         req._n_shared_full = 0
         req._load_pages = []
         req._donor_ref = None
+        req._aux_load = None
         # a swap record refers to the SOURCE engine's host pool
         # (transfer_out frees it; a router death-failover request may
         # still carry one from its dead engine) — restoring it here
@@ -2626,6 +2856,7 @@ class ServingEngine:
             # before its prefill turn consumed it
             self.pool.decref(req._donor_ref)
             req._donor_ref = None
+        self._drop_aux_holds(req)
         # preempted-and-swapped but terminated (deadline, cancel)
         # before the swap-in consumed the host copy / shared holds
         self._drop_swap(req)
@@ -2707,6 +2938,8 @@ class ServingEngine:
                 "free": pool.host_free_pages,
                 "offloaded": pool.pages_offloaded,
                 "restored": pool.pages_restored})}
+        if pool.aux:
+            out["kv_groups"] = self._kv_groups()
         out["prefix_cache"] = (
             None if self.prefix is None else {
                 "nodes": len(self.prefix),
@@ -2739,12 +2972,13 @@ class ServingEngine:
                           else bool(self._moe)
                           and moe_decode == "dispatched")
             module, page_len = self.module, self.page_len
+            kw = {} if self._groups is None else {"groups": self._groups}
 
             def f(params, state, cache, tok, t, tables):
                 return decode_step_slots_paged(
                     module, params, state, cache, tok, t, tables,
                     page_len, paged_kernel=pk,
-                    moe_dispatched=dispatched)[0]
+                    moe_dispatched=dispatched, **kw)[0]
 
             fn = self._logits_fns[decode_kernel, moe_decode] = \
                 self._jit_serving(
@@ -2825,7 +3059,8 @@ class ServingEngine:
                     # then skip straight to the first non-shared position —
                     # the shared tokens' prefill compute never runs
                     self._staging = self.pool.load_prefix(
-                        self._staging, req._load_pages, req._shared_len)
+                        self._staging, req._load_pages, req._shared_len,
+                        aux_pages=getattr(req, "_aux_load", None))
                     req.prefill_pos = req._shared_len
                     self.tracer.on_prefix_hit(req.rid, req._shared_len)
                 if getattr(req, "_donor_ref", None) is not None:
@@ -2834,6 +3069,7 @@ class ServingEngine:
                     # free it first) is no longer needed
                     self.pool.decref(req._donor_ref)
                     req._donor_ref = None
+                self._drop_aux_holds(req)    # loaded (or nothing to load)
             t0 = req.prefill_pos
             chunk = self.prefill_chunk
             if chunk is None:
@@ -2864,13 +3100,18 @@ class ServingEngine:
             # prefix pages that already hold identical data (the
             # copy-on-write donor's logical page IS written — into the
             # request's private copy)
+            writes, rows, taken = self._aux_insert_plan(req, p_len)
             self.pool.insert_pages(self._staging, req.slot,
                                    getattr(req, "_n_shared_full", 0),
-                                   p_len)
+                                   p_len, aux_pages=writes)
             if self.prefix is not None:
                 # full context pages are immutable from here (decode
                 # writes start at p_len): share them forward
-                self.prefix.register(toks, self.pool.tables[req.slot])
+                self.prefix.register(toks, self.pool.tables[req.slot],
+                                     aux_rows=rows)
+            for grp, pids in zip(self.pool.aux, taken):
+                for pid in pids:
+                    grp.decref(pid)          # the cache's, or free again
         s = req.slot
         if blockdiff:
             self._open_block(req, p_len)

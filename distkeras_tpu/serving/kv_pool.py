@@ -29,6 +29,18 @@ The pool composes with the int8 quantized cache (``dtype="int8"``):
 payload and per-token-per-head scale planes share the page tables and
 move together through every insert/load/gather program.
 
+PAGE GROUPS BY ATTENTION KIND: a model whose layers are not all of one
+kind (some attend to the whole context, some to a sliding window) gets
+one group of pages per kind. The layers without a window are the pool
+itself, as above. Each window kind is a ``WindowPages`` group
+(``pool.aux``): physical pages of its own (planes for its layers
+only), a per-slot table that is a RING as wide as a window and not as
+the context, and a slot gives a page back once every position in it
+lies a window behind the next one it writes (``release_behind``). A
+model of one kind builds no such group and is served exactly as
+before. docs/serving.md §Page groups has the contract, and which pages
+of a registered prefix a window group keeps.
+
 HOST KV OFFLOAD TIER (decode-kernel/offload PR, ROADMAP item 3b):
 ``PagedKVPool(host_pages=N)`` adds a host-memory page pool mirroring
 the device pool's per-layer planes. ``offload_pages`` copies physical
@@ -59,16 +71,16 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from distkeras_tpu.models.decoding import (init_cache, pack_int4,
-                                           unpack_int4)
+from distkeras_tpu.models.decoding import (_decode_block_of, init_cache,
+                                           pack_int4, unpack_int4)
 
 
 #: refcount slot for "no page": table entries >= num_pages are the
 #: unallocated sentinel (scatter drops, gather clamps into masked range)
 
 
-@partial(jax.jit, donate_argnums=0)
-def _write_pages(pool, staging, table):
+@partial(jax.jit, donate_argnums=0, static_argnames=("groups",))
+def _write_pages(pool, staging, table, groups=None):
     """Scatter staging pages into the pool: logical page ``p`` of the
     batch-1 staging cache lands on physical page ``table[p]``; sentinel
     entries (>= N) drop. One compiled program serves every insert —
@@ -78,8 +90,10 @@ def _write_pages(pool, staging, table):
     cache stays unpacked (one int8 byte per entry, the shared dequant
     contract), the POOL planes are where the 2x byte saving lives.
     ``pool`` is donated (the pages are written in place, the marker
-    leaf passes through aliased); ``staging`` lives on."""
-    def write(pl, st, packed):
+    leaf passes through aliased); ``staging`` lives on. ``groups`` (a
+    pool with page groups: per layer its group's index) makes ``table``
+    the tuple of one such vector per group."""
+    def write(pl, st, packed, table):
         page_len = 2 * pl.shape[2] if packed else pl.shape[2]
         if st.ndim == 4:
             _, h, s_max, d = st.shape
@@ -93,14 +107,15 @@ def _write_pages(pool, staging, table):
                       .transpose(1, 0, 2)
         return pl.at[table].set(pages.astype(pl.dtype), mode="drop")
     out = []
-    for pl_kv, st_kv in zip(pool, staging):
+    for i, (pl_kv, st_kv) in enumerate(zip(pool, staging)):
         if pl_kv is None:
             out.append(None)
             continue
         q4 = "q4" in pl_kv
+        tbl = table if groups is None else table[groups[i]]
         out.append({
             key: pl if key == "q4"
-            else write(pl, st_kv[key], q4 and key in ("k", "v"))
+            else write(pl, st_kv[key], q4 and key in ("k", "v"), tbl)
             for key, pl in pl_kv.items()})
     return out
 
@@ -124,15 +139,16 @@ def _scatter_rows(pool, ids, vals):
         lambda p, v: p.at[ids].set(v.astype(p.dtype)), pool, vals)
 
 
-@partial(jax.jit, donate_argnums=0)
-def _load_pages(staging, pool, table, valid):
+@partial(jax.jit, donate_argnums=0, static_argnames=("groups",))
+def _load_pages(staging, pool, table, valid, groups=None):
     """Gather pool pages into the batch-1 staging cache: logical page
     ``p`` becomes ``pool[table[p]]`` where ``valid[p]``, else keeps the
     staging content. The prefix-cache hit path: shared pages (and a
     copy-on-write donor) materialize as the staging prefix the
     remaining prefill chunks attend to. ``staging`` is donated, the
-    pool never."""
-    def load(st, pl, packed):
+    pool never. ``groups`` as in ``_write_pages``: ``table`` and
+    ``valid`` are then tuples, one vector a group."""
+    def load(st, pl, packed, table, valid):
         g = pl[table]                        # [P, H, page_len(/2), D?]
         if packed:
             g = unpack_int4(g)               # [P, H, page_len, D]
@@ -150,216 +166,37 @@ def _load_pages(staging, pool, table, valid):
         sel = jnp.where(valid[:, None, None], g.astype(cur.dtype), cur)
         return sel.transpose(1, 0, 2).reshape(1, h, s_max)
     out = []
-    for st_kv, pl_kv in zip(staging, pool):
+    for i, (st_kv, pl_kv) in enumerate(zip(staging, pool)):
         if st_kv is None:
             out.append(None)
             continue
         q4 = "q4" in pl_kv
+        tbl, ok = (table, valid) if groups is None \
+            else (table[groups[i]], valid[groups[i]])
         out.append({
             key: st if key == "q4"
-            else load(st, pl_kv[key], q4 and key in ("k", "v"))
+            else load(st, pl_kv[key], q4 and key in ("k", "v"), tbl, ok)
             for key, st in st_kv.items()})
     return out
 
 
-class PagedKVPool:
-    """Fixed pool of ``num_pages`` KV pages per layer + per-slot page
-    tables + host-side refcounted allocation.
+class _PageBook:
+    """Host-side accounts of one group's physical pages: a free list
+    and a holder count per page (``ref``), with a listener told when a
+    page's holders pass between 1 and 2."""
 
-    ``cache`` is the live device pytree ``decode_step_slots_paged``
-    consumes; ``tables`` is the host ``[S, P]`` int32 page-table array
-    (``device_tables()`` returns the cached device mirror, invalidated
-    by any mutation). A table entry of ``num_pages`` is the
-    unallocated sentinel.
-
-    ``cache`` is DONATED to every program that advances it
-    (``insert_pages``, ``restore_pages``, the engine's decode, verify
-    and fused programs): there is one pool on the device, written in
-    place, and the arrays of the old value are deleted, not merely
-    stale — a handle kept across such a call raises on its next read.
-    ``load_prefix`` and ``offload_pages`` only read the pool
-    (``load_prefix`` donates the STAGING cache it is handed); the
-    offload snapshot is a buffer of its own."""
-
-    def __init__(self, module, num_slots: int, max_len: int,
-                 page_len: int = 16, num_pages: Optional[int] = None,
-                 host_pages: int = 0, dtype=jnp.float32,
-                 hbm_budget: Optional[int] = None,
-                 reserve_bytes: int = 0):
-        if num_slots < 1:
-            raise ValueError(f"num_slots must be >= 1, got {num_slots}")
-        if max_len < 1:
-            raise ValueError(f"max_len must be >= 1, got {max_len}")
-        if page_len < 1:
-            raise ValueError(f"page_len must be >= 1, got {page_len}")
-        self._module = module
-        self.num_slots = int(num_slots)
-        self.max_len = int(max_len)
-        self.page_len = int(page_len)
-        self._int4 = isinstance(dtype, str) and dtype == "int4"
-        if self._int4 and self.page_len % 2:
-            raise ValueError(
-                f"int4 pages nibble-pack two positions per byte; "
-                f"page_len must be even, got {page_len}")
-        #: logical pages per slot: the page-table width (covers max_len)
-        self.pages_per_slot = -(-self.max_len // self.page_len)
-        #: bytes ONE physical page occupies across every layer's
-        #: planes — quantized payload (int4: packed, page_len // 2
-        #: bytes per head-dim row) AND the per-token scale planes.
-        #: Satellite fix: budget math that counts payload bytes only
-        #: overcommits quantized admission by the scale-plane share
-        #: (Dh=64 -> ~6% at int8, ~12% at int4 f32 scales).
-        self.page_bytes = self._page_bytes(module, self.page_len, dtype,
-                                           self.max_len)
-        if hbm_budget is not None:
-            # size the pool to a BYTE budget: pages = what fits after
-            # reserved bytes (weights etc.) — quantization translates
-            # directly into more resident pages, hence more admitted
-            # streams under the same budget
-            if num_pages is not None:
-                raise ValueError(
-                    "pass num_pages or hbm_budget, not both")
-            avail = int(hbm_budget) - int(reserve_bytes)
-            num_pages = avail // self.page_bytes
-            if num_pages < 1:
-                raise ValueError(
-                    f"hbm_budget {hbm_budget} - reserve {reserve_bytes}"
-                    f" does not fit one {self.page_bytes}-byte page")
-        if num_pages is None:
-            # every slot's worst case at once by default; real
-            # deployments size this to the HBM budget and rely on
-            # cost-aware admission + preemption
-            num_pages = self.num_slots * self.pages_per_slot
+    def _init_book(self, num_pages: int) -> None:
         self.num_pages = int(num_pages)
-        if self.num_pages < 1:
-            raise ValueError(
-                f"num_pages must be >= 1, got {self.num_pages}")
-        # a pool SMALLER than worst-case-per-request is legitimate —
-        # that is what cost-aware admission is for; the engine rejects
-        # individual requests whose own worst case exceeds the pool
-        self.dtype = dtype
-        # page pool: init_cache's batch axis is the PAGE axis; the
-        # position table is validated against max_len (check_len), not
-        # the page length
-        self.cache = init_cache(module, self.num_pages, self.page_len,
-                                dtype, check_len=self.max_len)
-        if self._int4:
-            # the POOL stores packed nibbles: the unpacked-payload
-            # planes init_cache built become [N, H, page_len//2, D]
-            # byte planes (zeros pack to zeros — no convert pass)
-            self.cache = [
-                kv if kv is None else {
-                    key: (jnp.zeros(a.shape[:2] + (a.shape[2] // 2,)
-                                    + a.shape[3:], jnp.int8)
-                          if key in ("k", "v") else a)
-                    for key, a in kv.items()}
-                for kv in self.cache]
-        self.tables = np.full((self.num_slots, self.pages_per_slot),
-                              self.num_pages, np.int32)
-        #: cached [pages_per_slot] logical-page index — reused by the
-        #: serving loop's per-iteration vector scans (pages_per_slot is
-        #: fixed at construction; rebuilding the arange every decode
-        #: iteration is avoidable hot-loop churn)
-        self.page_index = np.arange(self.pages_per_slot)
         self.ref = np.zeros(self.num_pages, np.int64)
         # pop() hands out page 0 first (deterministic placement for
         # tests/traces, same convention as the slot allocator)
         self._free = list(range(self.num_pages))[::-1]
-        self._tables_dev = None
-        # --- host offload tier (module doc): a host-memory mirror of
-        # the page planes, sized independently of the device pool —
-        # host RAM is an order of magnitude cheaper than HBM, so this
-        # is where preemption victims and cold prefix chains go
-        self.host_pages = int(host_pages)
-        if self.host_pages < 0:
-            raise ValueError(
-                f"host_pages must be >= 0, got {host_pages}")
-        self.host_cache = None
-        self._host_free: List[int] = []
-        if self.host_pages:
-            self.host_cache = [
-                None if kv is None else
-                {key: np.zeros((self.host_pages,) + tuple(a.shape[1:]),
-                               a.dtype)
-                 for key, a in kv.items()}
-                for kv in self.cache]
-            self._host_free = list(range(self.host_pages))[::-1]
-        #: offload odometers (cumulative since construction — the
-        #: engine publishes per-window deltas into ServingMetrics)
-        self.pages_offloaded = 0
-        self.pages_restored = 0
-        self.offload_bytes = 0
-        #: async swap-out (tree-speculation PR satellite): offload
-        #: batches whose D2H copies are enqueued but not yet fenced
-        #: into the host rows — each entry {"hids": [...], "dev":
-        #: gathered device pages}. The gather is a jitted snapshot, so
-        #: holding it is safe against later cache mutation; it pins
-        #: device memory until the fence, bounded by outstanding swaps.
-        self._pending_host: List[Dict] = []
-        #: lazy-fence odometer (tests pin laziness through it)
-        self.host_fences = 0
         #: called with a page id whenever that page's holder count
         #: passes between 1 and 2 (``incref``, ``decref``,
         #: ``release_slot``): ``PrefixCache`` sets it, because "only
         #: the cache holds this page" is a fact its eviction index
         #: keeps and only the pool sees change
         self._ref_listener = None
-
-    @staticmethod
-    def _page_bytes(module, page_len: int, dtype, max_len: int) -> int:
-        """Per-physical-page byte cost across all layers, from an
-        abstract (eval_shape — nothing allocated) one-page probe:
-        payload planes (int4: halved, two nibbles per byte) plus scale
-        planes. The structural ``"q4"`` marker is per-LAYER, not
-        per-page, and is excluded."""
-        probe = jax.eval_shape(
-            lambda: init_cache(module, 1, page_len, dtype,
-                               check_len=max_len))
-        int4 = isinstance(dtype, str) and dtype == "int4"
-        total = 0
-        for kv in probe:
-            if kv is None:
-                continue
-            for key, a in kv.items():
-                if key == "q4":
-                    continue
-                n = int(np.prod(a.shape)) * jnp.dtype(a.dtype).itemsize
-                if int4 and key in ("k", "v"):
-                    n //= 2
-                total += n
-        return total
-
-    # -- device views -------------------------------------------------------
-
-    def make_request_cache(self):
-        """The batch-1 prefill staging cache: ``pages_per_slot *
-        page_len`` positions (a page-multiple, so page loads/inserts
-        reshape exactly), position-validated at ``max_len`` — prefill
-        never writes past it."""
-        return init_cache(self._module, 1,
-                          self.pages_per_slot * self.page_len,
-                          self.dtype, check_len=self.max_len)
-
-    def device_tables(self):
-        """The [S, P] page tables on device (cached; any host-side
-        table mutation invalidates). Built from a SNAPSHOT of the host
-        array: the CPU client zero-copy aliases suitably aligned numpy
-        buffers into device memory, and the zero-bubble serving loop
-        keeps launched programs in flight while the host mutates
-        ``tables`` — without the copy an in-flight step could read a
-        page assignment made after its dispatch."""
-        if self._tables_dev is None:
-            self._tables_dev = jnp.asarray(self.tables.copy())
-        return self._tables_dev
-
-    def _dirty(self):
-        self._tables_dev = None
-
-    # -- allocation ---------------------------------------------------------
-
-    def pages_for(self, n_positions: int) -> int:
-        """Pages required to hold ``n_positions`` cache positions."""
-        return -(-int(n_positions) // self.page_len)
 
     @property
     def free_pages(self) -> int:
@@ -393,6 +230,376 @@ class PagedKVPool:
             self._free.append(pid)
         elif n == 1 and self._ref_listener is not None:
             self._ref_listener(pid)
+
+
+class WindowPages(_PageBook):
+    """The pages of the layers that attend to a sliding window of
+    ``window`` positions: one group of a ``PagedKVPool`` whose layers
+    are not all of one kind (module doc).
+
+    ``tables`` is ``[S, ring]``: a RING in which logical page ``p`` of a
+    slot sits in column ``p % ring``, ``ring = ceil((window - 1) /
+    page_len) + 1`` columns being what the positions ``(t - window,
+    t]`` can span. ``logical[slot, col]`` says which logical page a
+    column holds (-1: none). A slot holds only the pages its next
+    write's window reaches: ``release_behind(slot, t)`` gives back
+    every page that lies wholly at or under ``t - window``, ``t``
+    being the next position the slot writes."""
+
+    def __init__(self, pool: "PagedKVPool", name: str, layers, window: int,
+                 num_pages: Optional[int] = None):
+        self._pool = pool
+        self.name = name
+        self.layers = tuple(layers)
+        self.window = int(window)
+        self.page_len = pool.page_len
+        self.ring = -(-(self.window - 1) // self.page_len) + 1
+        if num_pages is None:
+            # every slot's ring at once, and as much again less a page
+            # for what the prefix cache keeps of registered prefixes
+            # (one window's pages before a point where prompts part)
+            num_pages = pool.num_slots * (2 * self.ring - 1)
+        self._init_book(num_pages)
+        if self.num_pages < self.ring:
+            raise ValueError(
+                f"page group {name!r} needs at least {self.ring} pages "
+                f"(one slot's window), got {self.num_pages}")
+        self.tables = np.full((pool.num_slots, self.ring), self.num_pages,
+                              np.int32)
+        self.logical = np.full((pool.num_slots, self.ring), -1, np.int64)
+        self._tables_dev = None
+        #: pages slots gave back behind their window, since construction
+        self.pages_released = 0
+
+    def device_table(self):
+        """The ring tables on the device (cached; a SNAPSHOT, as
+        ``PagedKVPool.device_tables``)."""
+        if self._tables_dev is None:
+            self._tables_dev = jnp.asarray(self.tables.copy())
+        return self._tables_dev
+
+    def first_needed(self, t: int) -> int:
+        """The lowest logical page that the window of a write at
+        position ``t`` reaches."""
+        return max(0, int(t) - self.window + 1) // self.page_len
+
+    def span(self, t: int) -> range:
+        """The logical pages a slot holds whose next write is ``t``."""
+        return range(self.first_needed(t), int(t) // self.page_len + 1)
+
+    def page_of(self, slot: int, lp: int) -> Optional[int]:
+        col = lp % self.ring
+        if self.logical[slot, col] != lp:
+            return None
+        return int(self.tables[slot, col])
+
+    def slot_pages(self, slot: int) -> Dict[int, int]:
+        """``{logical page: physical page}`` of what ``slot`` holds."""
+        held = self.logical[slot] >= 0
+        return dict(zip(self.logical[slot][held].tolist(),
+                        self.tables[slot][held].tolist()))
+
+    def assign(self, slot: int, lp: int, pid: int) -> None:
+        """Put ``pid`` (refcount already the caller's) at logical page
+        ``lp`` of ``slot``; the column has to be empty."""
+        col = lp % self.ring
+        if self.logical[slot, col] >= 0:
+            raise RuntimeError(
+                f"group {self.name!r}: slot {slot} still holds page "
+                f"{self.logical[slot, col]} where page {lp} goes (the "
+                "window behind it was not released)")
+        self.tables[slot, col] = pid
+        self.logical[slot, col] = lp
+        self._tables_dev = None
+
+    def _drop_columns(self, slot: int, cols) -> int:
+        for col in cols:
+            self.decref(int(self.tables[slot, col]))
+        self.tables[slot, cols] = self.num_pages
+        self.logical[slot, cols] = -1
+        if len(cols):
+            self._tables_dev = None
+        return len(cols)
+
+    def release_behind(self, slot: int, t: int) -> int:
+        """Give back the pages of ``slot`` that lie wholly behind the
+        window of a write at ``t``; returns how many."""
+        held = self.logical[slot]
+        cols = np.nonzero((held >= 0) & (held < self.first_needed(t)))[0]
+        n = self._drop_columns(slot, cols)
+        self.pages_released += n
+        return n
+
+    def release_slot(self, slot: int) -> int:
+        return self._drop_columns(slot, np.nonzero(
+            self.logical[slot] >= 0)[0])
+
+    @property
+    def live_pages(self) -> int:
+        """Pages some slot's ring holds."""
+        return int((self.logical >= 0).sum())
+
+
+class PagedKVPool(_PageBook):
+    """Fixed pool of ``num_pages`` KV pages per layer + per-slot page
+    tables + host-side refcounted allocation.
+
+    ``cache`` is the live device pytree ``decode_step_slots_paged``
+    consumes; ``tables`` is the host ``[S, P]`` int32 page-table array
+    (``device_tables()`` returns the cached device mirror, invalidated
+    by any mutation). A table entry of ``num_pages`` is the
+    unallocated sentinel.
+
+    ``cache`` is DONATED to every program that advances it
+    (``insert_pages``, ``restore_pages``, the engine's decode, verify
+    and fused programs): there is one pool on the device, written in
+    place, and the arrays of the old value are deleted, not merely
+    stale — a handle kept across such a call raises on its next read.
+    ``load_prefix`` and ``offload_pages`` only read the pool
+    (``load_prefix`` donates the STAGING cache it is handed); the
+    offload snapshot is a buffer of its own."""
+
+    def __init__(self, module, num_slots: int, max_len: int,
+                 page_len: int = 16, num_pages=None,
+                 host_pages: int = 0, dtype=jnp.float32,
+                 hbm_budget: Optional[int] = None,
+                 reserve_bytes: int = 0):
+        if num_slots < 1:
+            raise ValueError(f"num_slots must be >= 1, got {num_slots}")
+        if max_len < 1:
+            raise ValueError(f"max_len must be >= 1, got {max_len}")
+        if page_len < 1:
+            raise ValueError(f"page_len must be >= 1, got {page_len}")
+        self._module = module
+        self.num_slots = int(num_slots)
+        self.max_len = int(max_len)
+        self.page_len = int(page_len)
+        self._int4 = isinstance(dtype, str) and dtype == "int4"
+        if self._int4 and self.page_len % 2:
+            raise ValueError(
+                f"int4 pages nibble-pack two positions per byte; "
+                f"page_len must be even, got {page_len}")
+        #: logical pages per slot: the page-table width (covers max_len)
+        self.pages_per_slot = -(-self.max_len // self.page_len)
+        #: bytes ONE physical page occupies across every layer's
+        #: planes — quantized payload (int4: packed, page_len // 2
+        #: bytes per head-dim row) AND the per-token scale planes.
+        #: Satellite fix: budget math that counts payload bytes only
+        #: overcommits quantized admission by the scale-plane share
+        #: (Dh=64 -> ~6% at int8, ~12% at int4 f32 scales).
+        # page groups by attention kind (module doc): the layers' windows
+        # decide. One kind: this pool alone, as ever. Several: the
+        # layers without a window are this pool, each window a group
+        windows = {}
+        for i, layer in enumerate(module.layers):
+            blk = _decode_block_of(layer)
+            if blk is not None:
+                windows.setdefault(blk.attn.attn_window, []).append(i)
+        self.aux: List[WindowPages] = []
+        #: per layer ``(group index, ring)`` (None: no attention), or
+        #: None for a pool of one group: ``ring`` is the logical pages a
+        #: slot spans where the group's table is a ring, else None
+        self.layer_groups = None
+        aux_pages = ()
+        if len(windows) > 1:
+            if None not in windows:
+                raise ValueError(
+                    "layers with different windows and none without: "
+                    "page groups need a kind that holds the whole "
+                    f"context (windows {sorted(windows)})")
+            if host_pages or self._int4 or hbm_budget is not None:
+                raise ValueError(
+                    "page groups by attention kind are served without a "
+                    "host tier, int4 pages or hbm_budget")
+            if isinstance(num_pages, (tuple, list)):
+                num_pages, *aux_pages = num_pages
+        elif isinstance(num_pages, (tuple, list)):
+            raise ValueError("num_pages per group needs layers of more "
+                             "than one attention kind")
+        own = windows.get(None) if len(windows) > 1 else None
+        self.page_bytes = self._page_bytes(module, self.page_len, dtype,
+                                           self.max_len, own)
+        if hbm_budget is not None:
+            # size the pool to a BYTE budget: pages = what fits after
+            # reserved bytes (weights etc.) — quantization translates
+            # directly into more resident pages, hence more admitted
+            # streams under the same budget
+            if num_pages is not None:
+                raise ValueError(
+                    "pass num_pages or hbm_budget, not both")
+            avail = int(hbm_budget) - int(reserve_bytes)
+            num_pages = avail // self.page_bytes
+            if num_pages < 1:
+                raise ValueError(
+                    f"hbm_budget {hbm_budget} - reserve {reserve_bytes}"
+                    f" does not fit one {self.page_bytes}-byte page")
+        if num_pages is None:
+            # every slot's worst case at once by default; real
+            # deployments size this to the HBM budget and rely on
+            # cost-aware admission + preemption
+            num_pages = self.num_slots * self.pages_per_slot
+        self._init_book(num_pages)
+        if self.num_pages < 1:
+            raise ValueError(
+                f"num_pages must be >= 1, got {self.num_pages}")
+        # a pool SMALLER than worst-case-per-request is legitimate —
+        # that is what cost-aware admission is for; the engine rejects
+        # individual requests whose own worst case exceeds the pool
+        self.dtype = dtype
+        if own is None:
+            # page pool: init_cache's batch axis is the PAGE axis; the
+            # position table is validated against max_len (check_len),
+            # not the page length
+            self.cache = init_cache(module, self.num_pages, self.page_len,
+                                    dtype, check_len=self.max_len)
+        else:
+            kinds = sorted(w for w in windows if w is not None)
+            if len(aux_pages) > len(kinds):
+                raise ValueError(
+                    f"num_pages names {1 + len(aux_pages)} groups, the "
+                    f"model has {1 + len(kinds)}")
+            for j, w in enumerate(kinds):
+                self.aux.append(WindowPages(
+                    self, f"window{w}", windows[w], w,
+                    aux_pages[j] if j < len(aux_pages) else None))
+            pages_of, groups = {i: self.num_pages for i in own}, {}
+            for i in own:
+                groups[i] = (0, None)
+            for g, grp in enumerate(self.aux, start=1):
+                for i in grp.layers:
+                    pages_of[i] = grp.num_pages
+                    groups[i] = (g, self.pages_per_slot)
+            self.layer_groups = tuple(groups.get(i)
+                                      for i in range(len(module.layers)))
+            #: per layer its group's index: what the staging programs
+            #: (``_write_pages`` / ``_load_pages``) index their tables by
+            self._group_of_layer = tuple(
+                None if g is None else g[0] for g in self.layer_groups)
+            # every layer's planes at its own group's page count (a
+            # probe gives the shapes; nothing of the full width is ever
+            # allocated for a window layer)
+            probe = jax.eval_shape(
+                lambda: init_cache(module, 1, self.page_len, dtype,
+                                   check_len=self.max_len))
+            self.cache = [
+                None if kv is None else
+                {key: jnp.zeros((pages_of[i],) + a.shape[1:], a.dtype)
+                 for key, a in kv.items()}
+                for i, kv in enumerate(probe)]
+        if self._int4:
+            # the POOL stores packed nibbles: the unpacked-payload
+            # planes init_cache built become [N, H, page_len//2, D]
+            # byte planes (zeros pack to zeros — no convert pass)
+            self.cache = [
+                kv if kv is None else {
+                    key: (jnp.zeros(a.shape[:2] + (a.shape[2] // 2,)
+                                    + a.shape[3:], jnp.int8)
+                          if key in ("k", "v") else a)
+                    for key, a in kv.items()}
+                for kv in self.cache]
+        self.tables = np.full((self.num_slots, self.pages_per_slot),
+                              self.num_pages, np.int32)
+        #: cached [pages_per_slot] logical-page index — reused by the
+        #: serving loop's per-iteration vector scans (pages_per_slot is
+        #: fixed at construction; rebuilding the arange every decode
+        #: iteration is avoidable hot-loop churn)
+        self.page_index = np.arange(self.pages_per_slot)
+        self._tables_dev = None
+        # --- host offload tier (module doc): a host-memory mirror of
+        # the page planes, sized independently of the device pool —
+        # host RAM is an order of magnitude cheaper than HBM, so this
+        # is where preemption victims and cold prefix chains go
+        self.host_pages = int(host_pages)
+        if self.host_pages < 0:
+            raise ValueError(
+                f"host_pages must be >= 0, got {host_pages}")
+        self.host_cache = None
+        self._host_free: List[int] = []
+        if self.host_pages:
+            self.host_cache = [
+                None if kv is None else
+                {key: np.zeros((self.host_pages,) + tuple(a.shape[1:]),
+                               a.dtype)
+                 for key, a in kv.items()}
+                for kv in self.cache]
+            self._host_free = list(range(self.host_pages))[::-1]
+        #: offload odometers (cumulative since construction — the
+        #: engine publishes per-window deltas into ServingMetrics)
+        self.pages_offloaded = 0
+        self.pages_restored = 0
+        self.offload_bytes = 0
+        #: async swap-out (tree-speculation PR satellite): offload
+        #: batches whose D2H copies are enqueued but not yet fenced
+        #: into the host rows — each entry {"hids": [...], "dev":
+        #: gathered device pages}. The gather is a jitted snapshot, so
+        #: holding it is safe against later cache mutation; it pins
+        #: device memory until the fence, bounded by outstanding swaps.
+        self._pending_host: List[Dict] = []
+        #: lazy-fence odometer (tests pin laziness through it)
+        self.host_fences = 0
+
+    @staticmethod
+    def _page_bytes(module, page_len: int, dtype, max_len: int,
+                    layers=None) -> int:
+        """Per-physical-page byte cost across all layers (or the
+        ``layers`` of one page group), from an
+        abstract (eval_shape — nothing allocated) one-page probe:
+        payload planes (int4: halved, two nibbles per byte) plus scale
+        planes. The structural ``"q4"`` marker is per-LAYER, not
+        per-page, and is excluded."""
+        probe = jax.eval_shape(
+            lambda: init_cache(module, 1, page_len, dtype,
+                               check_len=max_len))
+        int4 = isinstance(dtype, str) and dtype == "int4"
+        total = 0
+        for i, kv in enumerate(probe):
+            if kv is None or (layers is not None and i not in layers):
+                continue
+            for key, a in kv.items():
+                if key == "q4":
+                    continue
+                n = int(np.prod(a.shape)) * jnp.dtype(a.dtype).itemsize
+                if int4 and key in ("k", "v"):
+                    n //= 2
+                total += n
+        return total
+
+    # -- device views -------------------------------------------------------
+
+    def make_request_cache(self):
+        """The batch-1 prefill staging cache: ``pages_per_slot *
+        page_len`` positions (a page-multiple, so page loads/inserts
+        reshape exactly), position-validated at ``max_len`` — prefill
+        never writes past it."""
+        return init_cache(self._module, 1,
+                          self.pages_per_slot * self.page_len,
+                          self.dtype, check_len=self.max_len)
+
+    def device_tables(self):
+        """The [S, P] page tables on device (cached; any host-side
+        table mutation invalidates). Built from a SNAPSHOT of the host
+        array: the CPU client zero-copy aliases suitably aligned numpy
+        buffers into device memory, and the zero-bubble serving loop
+        keeps launched programs in flight while the host mutates
+        ``tables`` — without the copy an in-flight step could read a
+        page assignment made after its dispatch."""
+        if self._tables_dev is None:
+            self._tables_dev = jnp.asarray(self.tables.copy())
+        if self.aux:
+            # one table a group, the window groups' rings behind this
+            # pool's own (``layer_groups`` says which layer reads which)
+            return (self._tables_dev,) + tuple(
+                g.device_table() for g in self.aux)
+        return self._tables_dev
+
+    def _dirty(self):
+        self._tables_dev = None
+
+    # -- allocation ---------------------------------------------------------
+
+    def pages_for(self, n_positions: int) -> int:
+        """Pages required to hold ``n_positions`` cache positions."""
+        return -(-int(n_positions) // self.page_len)
 
     def assign(self, slot: int, logical: int, pid: int) -> None:
         """Point ``tables[slot, logical]`` at ``pid`` (the caller has
@@ -430,6 +637,8 @@ class PagedKVPool:
         self._dirty()
         for pid in crossed:
             self._ref_listener(pid)
+        for grp in self.aux:
+            grp.release_slot(slot)
         return int(pages.size)
 
     # -- host offload tier --------------------------------------------------
@@ -549,24 +758,49 @@ class PagedKVPool:
 
     # -- staging transfers --------------------------------------------------
 
+    def _group_vectors(self, aux_pages):
+        """Per window group, ``{logical page: physical page}`` as the
+        ``[pages_per_slot]`` vector the staging programs index with,
+        and which entries are set."""
+        tvs, oks = [], []
+        for grp, pages in zip(self.aux, aux_pages):
+            tv = np.full(self.pages_per_slot, grp.num_pages, np.int32)
+            for lp, pid in pages.items():
+                tv[lp] = pid
+            tvs.append(jnp.asarray(tv))
+            oks.append(jnp.asarray(tv < grp.num_pages))
+        return tvs, oks
+
     def insert_pages(self, staging, slot: int, skip_pages: int,
-                     n_pos: int) -> None:
+                     n_pos: int, aux_pages=None) -> None:
         """Scatter the staging cache's logical pages
         ``[skip_pages, pages_for(n_pos))`` into the slot's physical
         pages — ONLY the pages the prompt actually fills and that are
         not already shared (the prefix-cache pages at the front hold
-        identical data and are skipped wholesale)."""
+        identical data and are skipped wholesale). ``aux_pages`` (a
+        pool with window groups): per group ``{logical page: physical
+        page}``, the staging pages its layers write and where."""
         n_needed = self.pages_for(n_pos)
         tv = np.full(self.pages_per_slot, self.num_pages, np.int32)
         tv[skip_pages:n_needed] = self.tables[slot, skip_pages:n_needed]
-        self.cache = _write_pages(self.cache, staging, jnp.asarray(tv))
+        if not self.aux:
+            self.cache = _write_pages(self.cache, staging, jnp.asarray(tv))
+            return
+        tvs, _ = self._group_vectors(aux_pages)
+        self.cache = _write_pages(self.cache, staging,
+                                  (jnp.asarray(tv), *tvs),
+                                  groups=self._group_of_layer)
 
-    def load_prefix(self, staging, page_ids: List[int], n_tokens: int):
+    def load_prefix(self, staging, page_ids: List[int], n_tokens: int,
+                    aux_pages=None):
         """Materialize a shared prefix into the staging cache: pages
         ``page_ids`` (full shared pages, plus the copy-on-write donor
         as the last entry for a partial match) become staging positions
         ``[0, n_tokens)`` (plus donor tail garbage the prefill chunks
-        overwrite). Returns the new staging pytree."""
+        overwrite). Returns the new staging pytree. ``aux_pages``: per
+        window group ``{logical page: physical page}``, the pages of
+        that group to load beside them (one window's before
+        ``n_tokens``)."""
         n_load = self.pages_for(n_tokens)
         if len(page_ids) < n_load:
             raise ValueError(
@@ -575,8 +809,13 @@ class PagedKVPool:
         tv = np.full(self.pages_per_slot, self.num_pages, np.int32)
         tv[:n_load] = page_ids[:n_load]
         valid = self.page_index < n_load
-        return _load_pages(staging, self.cache, jnp.asarray(tv),
-                           jnp.asarray(valid))
+        if not self.aux:
+            return _load_pages(staging, self.cache, jnp.asarray(tv),
+                               jnp.asarray(valid))
+        tvs, oks = self._group_vectors(aux_pages)
+        return _load_pages(staging, self.cache, (jnp.asarray(tv), *tvs),
+                           (jnp.asarray(valid), *oks),
+                           groups=self._group_of_layer)
 
 
 # --- prefix cache -----------------------------------------------------------
@@ -584,7 +823,7 @@ class PagedKVPool:
 
 class _Node:
     __slots__ = ("nid", "page", "parent", "key", "last_used", "host",
-                 "pinned", "blocked", "counted")
+                 "pinned", "blocked", "counted", "aux")
 
     def __init__(self, nid, page, parent, key, last_used):
         self.nid = nid
@@ -598,6 +837,8 @@ class _Node:
         self.blocked = 0                 # children with a pin at or below
         self.counted = 0                 # 0 not cache-only, 1 spill-only,
         #                                  2 droppable
+        self.aux = None                  # per window group: its page of
+        #                                  these positions, or None
 
 
 class _LRUHeap:
@@ -613,6 +854,9 @@ class _LRUHeap:
     def __init__(self):
         self._a: List[_Node] = []
         self._at: Dict[int, int] = {}    # nid -> index in _a
+
+    def __len__(self) -> int:
+        return len(self._a)
 
     def top(self) -> Optional[_Node]:
         return self._a[0] if self._a else None
@@ -746,7 +990,24 @@ class PrefixCache:
     ``evictable_pages`` two counters: neither grows with the trie.
     ``evictions``, ``evict_examined`` (heap tops read choosing
     victims plus nodes visited keeping the counts) and
-    ``evictable_queries`` are the odometers that show it."""
+    ``evictable_queries`` are the odometers that show it.
+
+    WINDOW GROUPS (a pool with ``aux`` groups). The trie stays keyed
+    and counted on the pool's own pages (the layers that hold the whole
+    context). A node MAY also hold a page of each window group
+    (``node.aux[g]``): the window layers' K/V of the same positions. A
+    prompt can resume prefill at a page boundary only if the window
+    layers' keys of the last ``window - 1`` positions before it are to
+    hand, so ``match_groups`` gives the whole chain a prompt shares, or
+    nothing where a group lacks a page of that reach. Which nodes get
+    such pages: those within one window's reach behind a boundary at
+    which some later prompt stopped matching (``register(...,
+    aux_rows=)``; the request that found the boundary cut for lack of
+    them wrote them): a hit ends where prompts part, and only there.
+    A window page only the cache holds is given back least recently
+    used first under that group's own pressure (``aux_evict_one``),
+    the node and its own page staying; a node that drops releases
+    them too."""
 
     def __init__(self, pool: PagedKVPool):
         self._pool = pool
@@ -787,6 +1048,18 @@ class PrefixCache:
         self.evict_examined = 0
         self.evictable_queries = 0
         pool._ref_listener = self._ref_crossed
+        # window groups (class doc): per group, page id -> node, the
+        # heap of nodes whose page of that group only the cache holds,
+        # and how many such pages were given back
+        self._aux_by_page: List[Dict[int, _Node]] = []
+        self._aux_lru: List[_LRUHeap] = []
+        self.aux_evictions: List[int] = []
+        for g, grp in enumerate(getattr(pool, "aux", ())):
+            self._aux_by_page.append({})
+            self._aux_lru.append(_LRUHeap())
+            self.aux_evictions.append(0)
+            grp._ref_listener = partial(self._aux_ref_crossed, g)
+        self._lrus += tuple(self._aux_lru)
 
     def __len__(self) -> int:
         return len(self._nodes)
@@ -794,6 +1067,63 @@ class PrefixCache:
     def resident(self, pid: int) -> bool:
         """Is device page ``pid`` held by a cache node right now?"""
         return int(pid) in self._by_page
+
+    # -- window groups (class doc) ------------------------------------------
+
+    def _aux_ref_crossed(self, g: int, pid: int) -> None:
+        node = self._aux_by_page[g].get(int(pid))
+        if node is not None:
+            self._aux_lru[g].keep(
+                node, self._pool.aux[g].ref[int(pid)] == 1)
+
+    def _aux_release(self, node: _Node, g: int) -> None:
+        pid = node.aux[g]
+        node.aux[g] = None
+        del self._aux_by_page[g][pid]
+        self._aux_lru[g].discard(node)
+        self._pool.aux[g].decref(pid)
+
+    def aux_evictable(self, g: int) -> int:
+        """Pages of window group ``g`` that only the cache holds."""
+        return len(self._aux_lru[g])
+
+    def aux_evict_one(self, g: int) -> bool:
+        """Give back the least recently used page of window group ``g``
+        that only the cache holds (its node stays, a boundary behind
+        which it lay can no longer be resumed at); False if none."""
+        node = self._aux_lru[g].top()
+        if node is None:
+            return False
+        self._aux_release(node, g)
+        self.aux_evictions[g] += 1
+        return True
+
+    def match_groups(self, tokens):
+        """``match`` for a pool with window groups, at page boundaries
+        only (no copy-on-write donor): ``(pages, shared_len, None,
+        aux_pages, reach)``. ``reach`` is how many pages the prompt
+        shares with the trie, the boundary at which it parts from
+        every prompt seen; the hit is that whole chain if every window
+        group still holds what its window reaches back to from there
+        (``aux_pages[g]``: ``{logical page: page id}`` of those pages of
+        group ``g``, which ``load_prefix`` loads beside ``pages``), and
+        nothing otherwise: a hit resumes where prompts part, which is
+        where such pages are left, or not at all (so the residual
+        prefill shapes stay those of whole hits and misses)."""
+        pool = self._pool
+        pl = pool.page_len
+        toks = np.ascontiguousarray(np.asarray(tokens, np.int32))
+        chain = self._walk(toks, next(self._tick))
+        reach = len(chain)
+        need = [range(grp.first_needed(reach * pl), reach)
+                for grp in pool.aux]
+        if reach and all(chain[lp].aux is not None
+                         and chain[lp].aux[g] is not None
+                         for g, lps in enumerate(need) for lp in lps):
+            return ([n.page for n in chain], reach * pl, None,
+                    [{lp: chain[lp].aux[g] for lp in lps}
+                     for g, lps in enumerate(need)], reach)
+        return [], 0, None, [{} for _ in pool.aux], reach
 
     # -- the eviction index (class doc) -------------------------------------
 
@@ -883,25 +1213,10 @@ class PrefixCache:
         toks = np.ascontiguousarray(np.asarray(tokens, np.int32))
         n = len(toks)
         tick = next(self._tick)
-        pages: List[int] = []
-        parent = 0
-        pos = 0
-        # full pages, capped so shared_len stays <= n - 1
-        while pos + pl < n:
-            key = toks[pos:pos + pl].tobytes()
-            node = self._children.get(parent, {}).get(key)
-            if node is None:
-                break
-            if node.page is None and not self._restore_node(node):
-                break                    # host-resident, no device page
-            self._touch(node, tick)
-            if parent == 0:
-                # affinity hit counter: this chain's root page served
-                # a match (the router's "hot prefix" signal)
-                self._hits[key] = self._hits.get(key, 0) + 1
-            pages.append(node.page)
-            parent = node.nid
-            pos += pl
+        chain = self._walk(toks, tick)
+        pages: List[int] = [node.page for node in chain]
+        parent = chain[-1].nid if chain else 0
+        pos = len(chain) * pl
         # best partial continuation among the chain's children (the
         # copy-on-write donor); also catches the "whole prompt cached"
         # case — the last page re-enters here with pl - 1 tokens
@@ -924,6 +1239,32 @@ class PrefixCache:
             return pages, pos + best, donor.page
         return pages, pos, None
 
+    def _walk(self, toks, tick: int) -> List[_Node]:
+        """The chain of full-page nodes ``toks`` shares with the trie,
+        each on the device and touched; capped so that the shared
+        length stays under ``len(toks)``."""
+        pl = self._pool.page_len
+        n = len(toks)
+        chain: List[_Node] = []
+        parent = 0
+        pos = 0
+        while pos + pl < n:
+            key = toks[pos:pos + pl].tobytes()
+            node = self._children.get(parent, {}).get(key)
+            if node is None:
+                break
+            if node.page is None and not self._restore_node(node):
+                break                    # host-resident, no device page
+            self._touch(node, tick)
+            if parent == 0:
+                # affinity hit counter: this chain's root page served
+                # a match (the router's "hot prefix" signal)
+                self._hits[key] = self._hits.get(key, 0) + 1
+            chain.append(node)
+            parent = node.nid
+            pos += pl
+        return chain
+
     def _restore_node(self, node: _Node) -> bool:
         """Bring a host-resident (spilled) node back onto a fresh
         device page — H2D copy, byte-identical, no prefill recompute.
@@ -941,13 +1282,16 @@ class PrefixCache:
         self._index(node)
         return True
 
-    def register(self, tokens, table_row) -> int:
+    def register(self, tokens, table_row, aux_rows=None) -> int:
         """Install every FULL prompt page of ``tokens`` (physical ids
         from ``table_row``) into the trie; pages already registered
         along the chain are left as-is (a privately recomputed
         duplicate stays private and dies with its request). Each new
         node increfs its page — the cache is a holder. Returns the
-        number of pages newly registered."""
+        number of pages newly registered. ``aux_rows`` (window groups,
+        class doc): per group ``{logical page: page id}``, pages of
+        that group holding the same positions; a node on the chain
+        that has none of that group takes it (and a hold on it)."""
         pool = self._pool
         pl = pool.page_len
         toks = np.ascontiguousarray(np.asarray(tokens, np.int32))
@@ -991,6 +1335,17 @@ class PrefixCache:
                     self._index(self._nodes[parent])
             self._touch(node, tick)
             parent = node.nid
+            for g, row in enumerate(aux_rows or ()):
+                pid = row.get(j)
+                if pid is None:
+                    continue
+                if node.aux is None:
+                    node.aux = [None] * len(aux_rows)
+                if node.aux[g] is None:
+                    node.aux[g] = int(pid)
+                    self._aux_by_page[g][int(pid)] = node
+                    self._pool.aux[g].incref(int(pid))
+                    self._aux_ref_crossed(g, int(pid))
         return added
 
     def _drop(self, node: _Node) -> None:
@@ -1010,6 +1365,9 @@ class PrefixCache:
             self._pool.decref(node.page)
         else:
             self._pool.free_host([node.host])
+        for g, pid in enumerate(node.aux or ()):
+            if pid is not None:
+                self._aux_release(node, g)
         # the index: the node leaves it, its parent may be a leaf now
         for lru in self._lrus:
             lru.discard(node)

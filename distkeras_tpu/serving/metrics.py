@@ -189,6 +189,20 @@ class ServingMetrics:
             "serving.blockdiff_rows_routed")
         self._bd_experts = self.registry.counter(
             "serving.blockdiff_experts_touched")
+        # what the expert layers of the one-token programs did (the
+        # programs' own counts, as the block-diffusion ones above):
+        # rows routed and experts that owned a row, summed over the
+        # expert layers, by kind of program ("decode" / "prefill")
+        self._routed_rows = self.registry.counter(
+            "serving.moe_rows_routed")
+        self._routed_experts = self.registry.counter(
+            "serving.moe_experts_touched")
+        # page groups by attention kind (a model with window and full
+        # layers): per group, gauges of its pages (free, held by slots,
+        # shared) and what slots gave back behind their window; None
+        # for a pool of one group
+        self._kv_group_pages = self.registry.gauge("serving.kv_group_pages")
+        self._kv_groups: Optional[Dict] = None
         #: exact (tokens, seconds) aggregation per decoding-slot count —
         #: bounded by the slot count, and authoritative for
         #: ``decode_tokens_per_sec`` (the labeled counters mirror it for
@@ -352,7 +366,9 @@ class ServingMetrics:
         routing, 1 = everything on one expert). One gauge series per
         expert id — the label set is bounded by E."""
         load = np.asarray(expert_load, np.float64)
-        self._moe_experts = max(self._moe_experts, len(load))
+        if len(load) > self._moe_experts:
+            self._moe_experts = len(load)
+            self._moe_load.reserve_series(len(load) + 1)
         for e, v in enumerate(load):
             self._moe_load.set(float(v), expert=str(e))
         self._moe_entropy.set(float(entropy))
@@ -386,6 +402,38 @@ class ServingMetrics:
         program did (as ``record_block_pass`` counts a pass's)."""
         self._bd_rows.inc(int(rows_routed), kind="prefill")
         self._bd_experts.inc(int(experts_touched), kind="prefill")
+
+    def record_routing(self, kind: str, rows_routed: int,
+                       experts_touched: int) -> None:
+        """What the expert layers of one one-token program did
+        (``kind``: ``"decode"`` or ``"prefill"``): the rows they routed
+        and the experts that owned at least one, summed over the
+        expert layers, as the program returned them."""
+        self._routed_rows.inc(int(rows_routed), kind=kind)
+        self._routed_experts.inc(int(experts_touched), kind=kind)
+
+    def record_kv_groups(self, groups: Dict[str, Dict]) -> None:
+        """The page groups' state at this flush (``ServingEngine
+        ._kv_groups``): kept whole for ``summary()["kv_groups"]``, the
+        page counts also as gauges by group and kind of count."""
+        self._kv_groups = {name: dict(g) for name, g in groups.items()}
+        for name, g in groups.items():
+            for key, v in g.items():
+                if key.startswith("pages_"):
+                    self._kv_group_pages.set(float(v), group=name,
+                                             count=key[len("pages_"):])
+
+    def _routing(self) -> Optional[Dict]:
+        rows = {k: int(self._routed_rows.value(kind=k))
+                for k in ("decode", "prefill")}
+        if not sum(rows.values()):
+            return None
+        touched = {k: int(self._routed_experts.value(kind=k))
+                   for k in ("decode", "prefill")}
+        return {"rows_routed": rows["decode"],
+                "experts_touched": touched["decode"],
+                "prefill_rows_routed": rows["prefill"],
+                "prefill_experts_touched": touched["prefill"]}
 
     def record_block_commit(self, n_tokens: int) -> None:
         """One whole block became visible to its client: ``n_tokens``
@@ -634,6 +682,12 @@ class ServingMetrics:
             # block diffusion (keys ADDED by the block-diffusion PR):
             # None until a pass ran
             "block_diffusion": self._block_diffusion(),
+            # keys ADDED with the page groups by attention kind: what
+            # the one-token programs' expert layers routed (None until
+            # a program reported), and the groups' pages (None for a
+            # pool of one group)
+            "routing": self._routing(),
+            "kv_groups": self._kv_groups,
             "acceptance_rate": self.acceptance_rate,
             "speculation": {
                 "proposed": self.spec_proposed,

@@ -604,7 +604,11 @@ def test_serving_summary_keys_are_backward_compatible():
         "offload",
         # passes, blocks and tokens of a block-diffusion engine ADDED
         # by the block-diffusion PR (None until a pass ran)
-        "block_diffusion"}
+        "block_diffusion",
+        # what the one-token programs' expert layers routed, and the
+        # page groups by attention kind, ADDED with the page groups
+        # (None until a program reported / for a pool of one group)
+        "routing", "kv_groups"}
 
 
 # --- integration: prefetch gauges -------------------------------------------

@@ -498,3 +498,125 @@ def test_block_diffusion_prefill_counts_what_it_runs(one_chip, as_tpu, t0,
     assert text.count('custom_call_target="tpu_custom_call"') \
         == (3 if t0 else 2)
 
+
+
+# --- window and full attention layers side by side (Laguna-XS.2's widths) ----
+
+@pytest.mark.parametrize("tokens", [32, 2048], ids=["step", "prefill_chunk"])
+def test_grouped_experts_laguna_widths(one_chip, as_tpu, tokens):
+    """Top-8 of 256 experts of width 512: a decode step of 32 slots (one
+    row an expert on average) and a prefill chunk of 2,048 tokens."""
+    from distkeras_tpu.ops.moe_kernels import (grouped_block_rows,
+                                               grouped_experts,
+                                               grouped_tiles)
+    s = _spec(one_chip)
+    e, d, f, a = 256, 2048, 512, tokens * 8
+    rows = grouped_block_rows(a, e)
+    tiles = grouped_tiles(a, e, rows)
+    assert rows == (16 if tokens == 32 else 64)
+    fn = lambda x, te, used, w1, w2, w3: grouped_experts(
+        x, te, used, w1, w2, w3, block_rows=rows, activation="silu")
+    n, _ = _compile(fn, s((tiles * rows, d), jnp.bfloat16),
+                    s((tiles,), jnp.int32), s((), jnp.int32),
+                    s((e, d, f), jnp.bfloat16), s((e, f, d), jnp.bfloat16),
+                    s((e, d, f), jnp.bfloat16))
+    assert n == 1
+
+
+@pytest.mark.parametrize("kind", ["full", "window"])
+def test_paged_attention_laguna_kinds(one_chip, as_tpu, kind):
+    """The paged kernel as each kind of layer calls it, 32 slots of 16,384
+    positions in pages of 128: 6 query heads a KV head over the whole
+    table, 8 over a ring of 5 columns under its own name."""
+    from distkeras_tpu.ops.paged_attention import paged_decode_attention
+    s = _spec(one_chip)
+    slots, hkv, d, page_len = 32, 8, 128, 128
+    if kind == "full":
+        g, pages, width, kw = 6, slots * 128, 128, {}
+    else:
+        g, pages, width = 8, slots * 9, 5
+        kw = dict(window=512, ring=True, name="paged_window_attention")
+    pool = s((pages, hkv, page_len, d), jnp.bfloat16)
+    fn = lambda q, k, v, t, tb: paged_decode_attention(q, k, v, t, tb, **kw)
+    n, text = _compile(fn, s((slots, 1, hkv, g, d), jnp.float32), pool, pool,
+                       s((slots,), jnp.int32), s((slots, width), jnp.int32))
+    assert n == 1
+    assert ("paged_window_attention" in text) == (kind == "window")
+
+
+@pytest.mark.parametrize("heads,window", [(48, None), (64, 512)],
+                         ids=["full", "window"])
+def test_flash_prefill_laguna_kinds(one_chip, as_tpu, heads, window):
+    from distkeras_tpu.ops.flash_attention import flash_attention
+    q = _spec(one_chip)((1, 2048, heads, 128), jnp.bfloat16)
+    fn = lambda q, k, v: flash_attention(q, k, v, causal=True, window=window)
+    n, _ = _compile(fn, q, q, q)
+    assert n == 1
+
+
+def test_decode_step_over_two_page_groups(one_chip, as_tpu):
+    """One decode step of the five layers at the cell's widths over a
+    donated pool with a page group a kind: five paged kernels (two over the
+    whole table, three over the ring, told apart by name), four grouped
+    expert kernels, every cache leaf aliased, no copy the size of a plane,
+    and the window layers' planes a window's worth."""
+    import re
+    from distkeras_tpu.models import zoo
+    from distkeras_tpu.models.decoding import (decode_step_slots_paged,
+                                               init_cache)
+    sliding = {"num_heads": 64, "attn_window": 512}
+    full = {"num_heads": 48, "rope_base": 5e5, "rotary_dim": 64,
+            "rope_yarn": {"factor": 64,
+                          "original_max_position_embeddings": 4096,
+                          "beta_fast": 64, "beta_slow": 1}}
+    module = zoo.transformer_lm(
+        100352, d_model=2048, num_heads=64, num_layers=5, max_len=16384,
+        num_kv_heads=8, head_dim=128, dtype="bfloat16",
+        layer_types=["f", "s", "s", "s", "f"],
+        attn_kinds={"f": full, "s": sliding},
+        mlp_layer_types=["dense"] + ["sparse"] * 4, dense_mlp_dim=8192,
+        mlp_dim=512, mlp_activation="silu", mlp_gated=True, mlp_bias=False,
+        num_experts=256, moe_top_k=8, moe_dispatch="grouped",
+        moe_score="sigmoid", moe_route_scale=2.5, moe_shared_dim=512)
+    params, state = jax.eval_shape(
+        lambda k: module.init(k, (16,))[:2], jax.random.PRNGKey(0))
+    slots, page_len, s = 32, 128, _spec(one_chip)
+    wide, ring = 16384 // page_len, 5
+    groups = (None, (0, None), (1, wide), (1, wide), (1, wide), (0, None),
+              None, None)
+    probe = jax.eval_shape(lambda: init_cache(module, 1, page_len,
+                                              jnp.bfloat16, check_len=16384))
+    cache = [None if kv is None else
+             {k: s(((slots * wide if groups[i][0] == 0 else slots * 9),)
+                   + a.shape[1:], a.dtype) for k, a in kv.items()}
+             for i, kv in enumerate(probe)]
+
+    def on_chip(tree, dtype=None):
+        return jax.tree_util.tree_map(
+            lambda a: s(a.shape, dtype if dtype is not None and a.ndim >= 2
+                        else a.dtype), tree)
+
+    def step(params, state, cache, tok, t, tables):
+        logits, cache, moe = decode_step_slots_paged(
+            module, params, state, cache, tok, t, tables, page_len,
+            moe_stats=16384, groups=groups)
+        return jnp.argmax(logits, -1), cache, moe["routed"]
+
+    per_slot = s((slots,), jnp.int32)
+    compiled = jax.jit(step, donate_argnums=2).lower(
+        on_chip(params, jnp.bfloat16), on_chip(state), cache, per_slot,
+        per_slot, (s((slots, wide), jnp.int32),
+                   s((slots, ring), jnp.int32))).compile()
+    text = compiled.as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 9
+    assert len(re.findall(r"paged_window_attention", text)) >= 3
+    leaves = jax.tree_util.tree_leaves(cache)
+    assert text.split("\n", 1)[0].count("may-alias") == len(leaves)
+    planes = {int(np.prod(a.shape)) for a in leaves}
+    moved = [line.strip()[:160] for line in text.splitlines()
+             if (dims := re.search(r"= \w+\[([\d,]+)\]\S* copy\(", line))
+             and int(np.prod(list(map(int, dims.group(1).split(",")))))
+             in planes]
+    assert not moved, moved[:2]
+    # the pool is 4.75 GB where one group for all five layers is 10.7
+    assert compiled.memory_analysis().alias_size_in_bytes < 4.8e9
