@@ -92,7 +92,7 @@ class _NullTape:
     def epoch_end(self, examples, steps=None):
         return {}
 
-    def watch(self, name, fn):
+    def watch(self, name, fn, donated=None):
         pass
 
     def mark_warm(self, name=None):
@@ -148,6 +148,8 @@ class TrainingTape:
         self._device_total = 0.0
         self._examples_total = 0
         self._epochs = 0
+        self._programs: Dict[str, str] = {}
+        self._carry_bytes = None
         # the prefix is a trainer CLASS name — a bounded, code-defined
         # set, not runtime data (lint_metric_names.py)
         self._hist = self.registry.histogram(  # lint: allow-dynamic-metric-name
@@ -193,7 +195,22 @@ class TrainingTape:
         return spans.span("train." + name)
 
     # -- recompile plumbing (delegates to the detector) -------------------
-    def watch(self, name, fn):
+    def watch(self, name, fn, donated=None):
+        """Track ``fn``'s recompiles under ``name``. ``donated`` is the
+        carry a program takes by donation and updates in place: the
+        snapshot then says ``programs[name] == "carry=donated"`` (the
+        serving engine's ``kv_cache=donated``, for a trainer) and gives
+        the carry's bytes (``carry_bytes``, gauge
+        ``<tape>.carry_bytes``). The tape keeps the size, not the
+        tree."""
+        if donated is not None:
+            import jax
+            self._programs[name] = "carry=donated"
+            self._carry_bytes = sum(
+                leaf.nbytes for leaf in jax.tree_util.tree_leaves(donated))
+            # bounded prefix: the tape/trainer class name (see _hist)
+            self.registry.gauge(  # lint: allow-dynamic-metric-name
+                f"{self.name}.carry_bytes").set(self._carry_bytes)
         try:
             self.detector.watch(name, fn)
         except TypeError:
@@ -293,7 +310,10 @@ class TrainingTape:
                "compile_s": compile_s,
                "goodput": (min(productive / wall, 1.0) if wall > 0
                            else None),
-               "recompiles": self.detector.counts()}
+               "recompiles": self.detector.counts(),
+               "programs": dict(self._programs)}
+        if self._carry_bytes is not None:
+            out["carry_bytes"] = self._carry_bytes
         if (self.flops_per_example and self.peak_flops and wall > 0
                 and self._examples_total):
             out["mfu"] = (self._examples_total / wall

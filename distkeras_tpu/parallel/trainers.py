@@ -553,10 +553,6 @@ class SingleTrainer(Trainer):
                                fused_vocab_head=self.fused_vocab_head)
         runner = make_epoch_runner(step)
         tape = self._make_tape()
-        # after the first epoch's legitimate compiles, any cache growth
-        # on the epoch program is a shape leak (warned via check() in
-        # tape.epoch_end)
-        tape.watch("SingleTrainer.epoch", runner)
 
         # SingleTrainer checkpoints the FULL carry (params + model state +
         # optimizer state + rng), so a resumed run is bitwise-identical to
@@ -567,15 +563,26 @@ class SingleTrainer(Trainer):
                  "opt": self.worker_optimizer.init(model.params),
                  "rng": jax.random.PRNGKey(self.seed)}
         tree, start_epoch = self._maybe_resume(manager, fresh)
-        # place the (numpy, when resumed) carry on device ONCE: the first
-        # epoch's runner signature then matches every later epoch's — a
-        # numpy carry on the first call plus a device carry on the next
-        # adds a second jit-cache entry and false-positives the recompile
-        # detector. The runner does not donate, so zero-copy placement is
-        # safe (unlike the SPMD/pipeline restore paths, which must copy).
-        tree = jax.tree_util.tree_map(jnp.asarray, tree)
-        carry = TrainCarry(params=tree["params"], state=tree["state"],
-                           opt_state=tree["opt"], rng=tree["rng"])
+        # The runner DONATES its carry, and the trainer owns neither
+        # carry it starts from: a fresh one holds the caller's
+        # ``model.params``, a resumed one np.load'd host memory that a
+        # zero-copy placement would alias (spmd.py, at its own copy, has
+        # what donating that does to the heap). ONE jitted copy puts
+        # every leaf into a device buffer XLA owns, before anything is
+        # donated: the caller's Model stays readable, and the first
+        # epoch's runner signature equals every later one's (a numpy
+        # carry first and a device carry next would add a second
+        # jit-cache entry and false-positive the recompile detector).
+        # From here on every epoch updates the carry in place; nothing
+        # may keep a carry, or a leaf of one, across a ``runner`` call.
+        carry = jax.jit(lambda c: jax.tree_util.tree_map(jnp.copy, c))(
+            TrainCarry(params=tree["params"], state=tree["state"],
+                       opt_state=tree["opt"], rng=tree["rng"]))
+        del fresh, tree   # the uncopied optimizer state goes now
+        # after the first epoch's legitimate compiles, any cache growth
+        # on the epoch program is a shape leak (warned via check() in
+        # tape.epoch_end)
+        tape.watch("SingleTrainer.epoch", runner, donated=carry)
 
         from distkeras_tpu.utils.prefetch import device_stager
         if sharded:

@@ -223,9 +223,17 @@ def make_train_step(module, loss_fn: Callable, optimizer: Optimizer,
 
 
 def make_epoch_runner(train_step: Callable) -> Callable:
-    """Jitted scan of ``train_step`` over ``[steps, batch, ...]`` data."""
+    """Jitted scan of ``train_step`` over ``[steps, batch, ...]`` data.
 
-    @jax.jit
+    The carry is DONATED: parameters, model state, optimizer state and
+    key are updated in the buffers they came in, the value passed in is
+    deleted, and the caller rebinds it from the result (a caller that
+    wants the old carry copies it first). It must therefore own every
+    leaf it passes (``SingleTrainer.train`` copies once, before the
+    first call). ``X``/``Y`` are not donated: the ``Prefetcher`` owns
+    them."""
+
+    @partial(jax.jit, donate_argnums=(0,))
     def train_epoch(carry: TrainCarry, X: jax.Array, Y: jax.Array):
         carry, losses = lax.scan(train_step, carry, (X, Y))
         return carry, losses

@@ -125,6 +125,44 @@ def test_flash_attention_under_dp4_mesh(topo, as_tpu):
     assert "all-gather" not in text
 
 
+def test_train_epoch_updates_its_carry_in_place(one_chip, as_tpu):
+    """``SingleTrainer``'s epoch program at the one-chip training cell's
+    shapes (Cerebras-GPT 256M widths, 8 steps of 2 x 2048 tokens, Adam; two
+    layers of its 14) on a DONATED carry: every leaf of parameters, moments
+    and key is aliased to the result, so the program allocates no second
+    carry, and the flash kernels are in it."""
+    from distkeras_tpu.models import zoo
+    from distkeras_tpu.ops.losses import get_loss
+    from distkeras_tpu.ops.optimizers import get_optimizer
+    from distkeras_tpu.parallel.worker import (TrainCarry, make_epoch_runner,
+                                               make_train_step)
+    module = zoo.transformer_lm(
+        50257, d_model=1088, num_heads=17, num_layers=2, mlp_ratio=4,
+        max_len=2048, use_rope=False, norm="layernorm", dtype="bfloat16")
+    params, state = jax.eval_shape(
+        lambda key: module.init(key, (2048,))[:2], jax.random.PRNGKey(0))
+    opt = get_optimizer("adam", learning_rate=1e-4)
+    step = make_train_step(
+        module, get_loss("sparse_categorical_crossentropy_from_logits"), opt)
+    s = _spec(one_chip)
+    carry = jax.tree_util.tree_map(
+        lambda a: s(a.shape, a.dtype),
+        TrainCarry(params, state, jax.eval_shape(opt.init, params),
+                   jax.ShapeDtypeStruct((2,), np.uint32)))
+    x = s((8, 2, 2048), jnp.int32)
+    compiled = make_epoch_runner(step).lower(carry, x, x).compile()
+    text = compiled.as_text()
+    leaves = jax.tree_util.tree_leaves(carry)
+    assert text.split("\n", 1)[0].count("may-alias") == len(leaves)
+    carry_bytes = sum(int(np.prod(a.shape)) * a.dtype.itemsize
+                      for a in leaves)
+    mem = compiled.memory_analysis()
+    # whole leaves alias (each padded to its tile), the losses do not
+    assert carry_bytes <= mem.alias_size_in_bytes <= 1.01 * carry_bytes
+    assert mem.output_size_in_bytes - mem.alias_size_in_bytes < 1 << 12
+    assert text.count('custom_call_target="tpu_custom_call"') == 3 * 2
+
+
 # --- decode attention ------------------------------------------------------
 
 @pytest.mark.parametrize("int8", [False, True], ids=["bf16", "int8"])

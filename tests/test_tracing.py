@@ -498,7 +498,8 @@ def step_program_texts(tiny_lm):
     decode = eng._decode_fn(True).lower(
         eng._params, eng._state, eng.pool.cache, eng._tok, eng._t,
         eng.pool.device_tables()).as_text(debug_info=True)
-    return {"train_epoch": train, "serving_decode_greedy": decode}
+    return {"train_epoch": train, "serving_decode_greedy": decode,
+            "train_epoch_carry_leaves": len(jax.tree_util.tree_leaves(carry))}
 
 
 @pytest.mark.parametrize("program,scope", [
@@ -515,3 +516,17 @@ def test_step_programs_carry_the_named_scopes(step_program_texts, program,
     assert f"module @jit_{program}" in text
     # ``.../attn/...``, or ``jvp(attn)/...`` where the step differentiates
     assert re.search(rf'[/("]{scope}[/)"]', text)
+
+
+def test_epoch_program_donates_its_carry_and_not_its_data(step_program_texts):
+    """``make_epoch_runner``: every leaf of the carry (parameters, Adam's
+    moments and count, the key) is aliased to the result it becomes; the two
+    data arguments are not, the ``Prefetcher`` owns them."""
+    text = step_program_texts["train_epoch"]
+    head = text[text.index("func.func public @main("):].split(") -> (", 1)[0]
+    args = re.split(r",\s*(?=%arg\d+:)", head)
+    donated = ["tf.aliasing_output" in a or "jax.buffer_donor" in a
+               for a in args]
+    assert len(args) == step_program_texts["train_epoch_carry_leaves"] + 2
+    assert all(donated[:-2]), args
+    assert not any(donated[-2:]), args[-2:]

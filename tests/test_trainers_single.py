@@ -268,3 +268,127 @@ def test_freeze_sublayer_inside_transformer_block():
             np.asarray(trained.params[i]["attn"][k]), attn_before[k])
     assert not np.allclose(np.asarray(trained.params[i]["mlp"]["w1"]),
                            mlp_before["w1"])
+
+
+# --- the donated carry -------------------------------------------------------
+# ``make_epoch_runner`` donates its carry: every epoch updates parameters,
+# optimizer state and key in place. The trainer copies the carry once before
+# the first epoch, so nothing the caller owns is ever donated.
+
+def _adam_trainer(model, num_epoch=3, **kw):
+    return SingleTrainer(
+        model, worker_optimizer="adam", learning_rate=0.01,
+        loss="sparse_categorical_crossentropy_from_logits",
+        batch_size=128, num_epoch=num_epoch, **kw)
+
+
+def _leaves(tree):
+    return [np.array(a) for a in jax.tree_util.tree_leaves(tree)]
+
+
+def _checkpointed_epoch(ckpt_dir, ds):
+    """Leave one epoch's checkpoint behind; returns the arguments that make
+    the next trainer start from it."""
+    _adam_trainer(mlp(), num_epoch=1, checkpoint_dir=ckpt_dir).train(ds)
+    return dict(checkpoint_dir=ckpt_dir, resume=True)
+
+
+@pytest.mark.parametrize("path", ["fresh", "resumed", "raises"])
+def test_donation_never_reaches_the_callers_model(tmp_path, path):
+    """Every leaf of the ``Model`` handed in is still readable, and bitwise
+    what it was, after ``train()`` returns (from a fresh and from a restored
+    carry) and after it raises mid-run."""
+    from distkeras_tpu.resilience import faults
+    ds = synthetic_classification(n=512)
+    model = mlp()
+    assert all(isinstance(a, jax.Array)
+               for a in jax.tree_util.tree_leaves(model.params))
+    before = _leaves((model.params, model.state))
+    kw = _checkpointed_epoch(str(tmp_path), ds) if path == "resumed" else {}
+    tr = _adam_trainer(model, **kw)
+    if path == "raises":
+        faults.inject("train.epoch", nth=2)
+        try:
+            with pytest.raises(faults.InjectedFault):
+                tr.train(ds)
+        finally:
+            faults.reset()
+        assert tr.master_model is model
+    else:
+        trained = tr.train(ds)
+        assert len(tr.get_history().epochs) == (2 if path == "resumed" else 3)
+        assert any((a != b).any()
+                   for a, b in zip(_leaves(trained.params), before))
+    leaves = jax.tree_util.tree_leaves((model.params, model.state))
+    assert not any(a.is_deleted() for a in leaves)
+    for a, b in zip(_leaves(leaves), before):
+        np.testing.assert_array_equal(a, b)
+
+
+@pytest.mark.parametrize("ckpt_async", [False, True], ids=["sync", "async"])
+def test_readers_between_epochs_see_the_new_carry_only(tmp_path, ckpt_async):
+    """A callback fetching the weights at every epoch end, validation and a
+    checkpoint every epoch neither disturb training (same losses as a bare
+    run) nor are disturbed by it: what each read at epoch e is still epoch
+    e's after epoch e+1 has updated the carry in place."""
+    from distkeras_tpu.utils.callbacks import LambdaCallback
+    from distkeras_tpu.utils.checkpoint import CheckpointManager
+    ds = synthetic_classification(n=512)
+    bare = _adam_trainer(mlp())
+    bare.train(ds)
+
+    seen = []
+    tr = _adam_trainer(
+        mlp(), checkpoint_dir=str(tmp_path), checkpoint_every=1,
+        checkpoint_async=ckpt_async,
+        validation_data=(ds["features"][:64], ds["label"][:64]),
+        callbacks=[LambdaCallback(on_epoch_end=lambda e, logs: seen.append(
+            (tr.get_weights(), logs["val_loss"])))])
+    trained = tr.train(ds)
+    np.testing.assert_array_equal(tr.get_history().losses(),
+                                  bare.get_history().losses())
+    assert len(seen) == 3
+    mgr = CheckpointManager(str(tmp_path))
+    # keep_last bounds what is still on disk: compare every epoch that is
+    steps = mgr.all_steps()
+    assert steps[-1] == 2 and len(steps) >= 2
+    template = {"params": trained.params, "state": trained.state}
+    for e in steps:
+        (params, _), _ = seen[e]
+        stored = mgr.restore(dict(template), step=e)["params"]
+        for a, b in zip(_leaves(stored), _leaves(params)):
+            np.testing.assert_array_equal(a, b)
+    # epoch e's weights are not epoch e+1's, and the last are the result
+    assert any((a != b).any() for a, b in zip(_leaves(seen[0][0][0]),
+                                              _leaves(seen[1][0][0])))
+    for a, b in zip(_leaves(seen[-1][0][0]), _leaves(trained.params)):
+        np.testing.assert_array_equal(a, b)
+    assert len({v for _, v in seen}) == 3       # validation saw each epoch
+
+
+@pytest.mark.parametrize("resumed", [False, True], ids=["fresh", "resumed"])
+def test_epoch_program_compiles_once_and_tape_says_donated(tmp_path, resumed):
+    """The one copy before the first epoch gives epoch 1 the signature of
+    every later epoch: one cache entry, the recompile detector silent from
+    the first epoch on. The tape says what the program does with its carry
+    and how large that is."""
+    import warnings
+
+    from distkeras_tpu import obs
+    ds = synthetic_classification(n=512)
+    kw = _checkpointed_epoch(str(tmp_path), ds) if resumed else {}
+    model = mlp()
+    tr = _adam_trainer(model, **kw)
+    with warnings.catch_warnings():
+        warnings.simplefilter("error", obs.RecompileWarning)
+        tr.train(ds)
+    snap = tr.tape.snapshot()
+    assert snap["recompiles"] == {"SingleTrainer.epoch": 1}
+    assert tr.tape.check_recompiles() == {}
+    assert snap["programs"] == {"SingleTrainer.epoch": "carry=donated"}
+    # parameters and Adam's two moments, its step count and the key
+    param_bytes = sum(a.nbytes
+                      for a in jax.tree_util.tree_leaves(model.params))
+    assert snap["carry_bytes"] == 3 * param_bytes + 4 + 8
+    assert tr.tape.registry.gauge("SingleTrainer.carry_bytes").value() \
+        == snap["carry_bytes"]
