@@ -410,6 +410,166 @@ class MultiHeadAttention(Layer):
 
 
 @register_layer
+class LatentAttention(Layer):
+    """Multi-head LATENT attention (MLA) over [B, S, d_model]: queries
+    through a low-rank pair with a norm between, and ONE shared latent
+    of ``kv_lora_rank`` values plus ONE shared rope key of
+    ``qk_rope_head_dim`` a token, from which every head's key and
+    value are up-projected::
+
+        cq        = q_scale  * n_q(x Wqa)                 [q_lora_rank]
+        [qn | qr] = cq Wqb              per head          [H, dn | dr]
+        [ckv| kr] = x Wkva              ONE per token     [kv_lora_rank | dr]
+        c         = kv_scale * n_kv(ckv)
+        [kn | v]  = c Wkvb              per head          [H, dn | dv]
+        score     = (qn.kn + RoPE(qr).RoPE(kr)) / sqrt(dn + dr), causal
+
+    What a serving cache keeps of a token is ``(c, RoPE(kr))``:
+    ``latent_dim = kv_lora_rank + dr`` values and no head axis
+    (``models.decoding`` / ``serving.kv_pool``: a latent page plane).
+    Prefill attends the per-head keys and values rebuilt from the
+    latent (query/key width ``dn + dr``, value width ``dv``); decode
+    takes ``Wkvb``'s key half into the query and its value half into
+    the output (:meth:`absorb_q` / :meth:`unabsorb_v`) and attends the
+    latent itself, every head over the one shared key whose first
+    ``kv_lora_rank`` values are also the value. Same function, other
+    association. ``q_scale`` / ``kv_scale`` multiply the normed
+    low-rank query and the normed latent (so ``kn`` and ``v``, not
+    ``kr``). No bias anywhere; RoPE rotates pairs ``(2i, 2i+1)`` as
+    ``ops.attention.apply_rope``.
+    """
+
+    accepts_segment_ids = False
+    #: what the serving paths read of any attention layer
+    attn_window = None
+    block_len = None
+    use_rope = True
+    kv_heads = 1
+
+    def __init__(self, num_heads: int, q_lora_rank: int, kv_lora_rank: int,
+                 qk_nope_head_dim: int, qk_rope_head_dim: int,
+                 v_head_dim: int, rope_base: float = 10000.0,
+                 q_scale: float = 1.0, kv_scale: float = 1.0,
+                 norm_eps: float = 1e-6, dtype: str = "float32",
+                 attn_impl: str = "auto",
+                 kernel_init: str = "glorot_uniform"):
+        self.num_heads = int(num_heads)
+        self.q_lora_rank = int(q_lora_rank)
+        self.kv_lora_rank = int(kv_lora_rank)
+        self.qk_nope_head_dim = int(qk_nope_head_dim)
+        self.qk_rope_head_dim = int(qk_rope_head_dim)
+        self.v_head_dim = int(v_head_dim)
+        self.rope_base = float(rope_base)
+        self.q_scale = float(q_scale)
+        self.kv_scale = float(kv_scale)
+        self.norm_eps = float(norm_eps)
+        self.dtype = dtype
+        if attn_impl not in ("auto", "xla", "flash"):
+            raise ValueError(
+                f"latent attention runs attn_impl 'auto', 'xla' or "
+                f"'flash', got {attn_impl!r}")
+        self.attn_impl = attn_impl
+        self.kernel_init = kernel_init
+        #: query/key width of a head: the softmax scale is its root
+        self.head_dim = self.qk_nope_head_dim + self.qk_rope_head_dim
+        #: values a token leaves in a serving cache
+        self.latent_dim = self.kv_lora_rank + self.qk_rope_head_dim
+        self.scale = self.head_dim ** -0.5
+
+    def init(self, rng, input_shape):
+        d = input_shape[-1]
+        h, dn, dr, dv = (self.num_heads, self.qk_nope_head_dim,
+                         self.qk_rope_head_dim, self.v_head_dim)
+        qr, kr = self.q_lora_rank, self.kv_lora_rank
+        ks = jax.random.split(rng, 5)
+        w2d = lambda k, m, n: init_weights(self.kernel_init, k, (m, n))
+        params = {
+            "wqa": w2d(ks[0], d, qr), "q_norm": jnp.ones((qr,)),
+            "wqb": w2d(ks[1], qr, h * (dn + dr)).reshape(qr, h, dn + dr),
+            "wkva": w2d(ks[2], d, kr + dr), "kv_norm": jnp.ones((kr,)),
+            "wkvb": w2d(ks[3], kr, h * (dn + dv)).reshape(kr, h, dn + dv),
+            "wo": w2d(ks[4], h * dv, d).reshape(h, dv, d),
+        }
+        return params, {}, tuple(input_shape)
+
+    def _norm(self, scale, x, mult: float):
+        y, _ = RMSNorm(self.norm_eps).apply({"scale": scale}, {}, x)
+        return y if mult == 1.0 else (y.astype(jnp.float32)
+                                      * mult).astype(y.dtype)
+
+    def project(self, params, xc, positions=None):
+        """``xc`` [B, S, d] in the compute dtype, at ``positions`` ([S]
+        or [B, S]; None: 0..S-1). Returns ``(qn [B, S, H, dn], qr
+        [B, S, H, dr] after RoPE, entry [B, S, latent_dim])``: the
+        queries, and what the cache keeps of each token (the scaled
+        normed latent, then the roped shared key)."""
+        dt = xc.dtype
+        dn = self.qk_nope_head_dim
+        cq = self._norm(params["q_norm"], xc @ params["wqa"].astype(dt),
+                        self.q_scale)
+        q = jnp.einsum("bsr,rhe->bshe", cq, params["wqb"].astype(dt))
+        ckv = xc @ params["wkva"].astype(dt)
+        c = self._norm(params["kv_norm"], ckv[..., :self.kv_lora_rank],
+                       self.kv_scale)
+        kr = apply_rope(ckv[..., None, self.kv_lora_rank:], positions,
+                        base=self.rope_base)[..., 0, :]
+        qr = apply_rope(q[..., dn:], positions, base=self.rope_base)
+        return q[..., :dn], qr, jnp.concatenate([c, kr], axis=-1)
+
+    def expand_kv(self, params, entry, dt):
+        """Per-head keys and values of cached tokens ``entry``
+        [B, T, latent_dim], head-major: ``(k [B, H, T, dn + dr],
+        v [B, H, T, dv])``; the shared rope key repeats under every
+        head."""
+        dn, r = self.qk_nope_head_dim, self.kv_lora_rank
+        kv = jnp.einsum("btc,che->bhte", entry[..., :r].astype(dt),
+                        params["wkvb"].astype(dt))
+        kr = jnp.broadcast_to(
+            entry[:, None, :, r:].astype(dt),
+            kv.shape[:3] + (self.qk_rope_head_dim,))
+        return jnp.concatenate([kv[..., :dn], kr], axis=-1), kv[..., dn:]
+
+    def absorb_q(self, params, qn, qr):
+        """The decode form's queries against the latent itself:
+        ``Wkvb``'s key half taken into ``qn``; [..., H, latent_dim]."""
+        wk = params["wkvb"][..., :self.qk_nope_head_dim].astype(qn.dtype)
+        return jnp.concatenate(
+            [jnp.einsum("bshn,chn->bshc", qn, wk), qr], axis=-1)
+
+    def unabsorb_v(self, params, o_lat, dt):
+        """Attention output over the latent ``o_lat`` [B, S, H,
+        kv_lora_rank] through ``Wkvb``'s value half and ``Wo``:
+        [B, S, d]."""
+        wv = params["wkvb"][..., self.qk_nope_head_dim:].astype(dt)
+        v = jnp.einsum("bshc,chv->bshv", o_lat.astype(dt), wv)
+        return jnp.einsum("bshv,hvd->bsd", v, params["wo"].astype(dt))
+
+    def apply(self, params, state, x, *, training=False, rng=None):
+        dt = jnp.dtype(self.dtype)
+        qn, qr, entry = self.project(params, x.astype(dt))
+        k, v = self.expand_kv(params, entry, dt)
+        q = jnp.concatenate([qn, qr], axis=-1)
+        out = _attention_compute(
+            q, k.transpose(0, 2, 1, 3), v.transpose(0, 2, 1, 3),
+            causal=True, impl=self.attn_impl)
+        y = jnp.einsum("bshv,hvd->bsd", out.astype(dt),
+                       params["wo"].astype(dt))
+        return y.astype(x.dtype), state
+
+    def get_config(self):
+        return {"num_heads": self.num_heads,
+                "q_lora_rank": self.q_lora_rank,
+                "kv_lora_rank": self.kv_lora_rank,
+                "qk_nope_head_dim": self.qk_nope_head_dim,
+                "qk_rope_head_dim": self.qk_rope_head_dim,
+                "v_head_dim": self.v_head_dim,
+                "rope_base": self.rope_base, "q_scale": self.q_scale,
+                "kv_scale": self.kv_scale, "norm_eps": self.norm_eps,
+                "dtype": self.dtype, "attn_impl": self.attn_impl,
+                "kernel_init": self.kernel_init}
+
+
+@register_layer
 class TransformerMLP(Layer):
     """Position-wise MLP with the standard column→row TP-splittable pair.
 
@@ -469,7 +629,19 @@ class TransformerBlock(Layer):
     """Pre-norm residual block: x + attn(norm(x)); x + mlp(norm(x)).
 
     ``mlp`` may be a ``TransformerMLP`` or a ``models.moe.MoE`` (expert
-    parallelism); both expose the same Layer protocol.
+    parallelism); both expose the same Layer protocol. ``attn_layer``
+    takes the attention's place likewise (a ``LatentAttention``);
+    ``norm_eps`` states the norms' epsilon.
+
+    A SHORTCUT-CONNECTED expert layer spans two blocks. The first
+    (``shortcut_layer=``) computes it from its own post-attention norm,
+    beside its MLP, and hands the result on beside the residual
+    stream: it returns the pair ``(x, m)``. The second
+    (``shortcut_add=True``) takes that pair and adds ``m`` after its
+    own MLP::
+
+        a = h + attn_0(n1(h));  u = n2(a);  m = experts(u);  h = a + mlp_0(u)
+        a = h + attn_1(n1(h));  u = n2(a);  h = a + mlp_1(u) + m
     """
 
     accepts_segment_ids = True
@@ -491,10 +663,23 @@ class TransformerBlock(Layer):
                  mlp_dim: Optional[int] = None, mlp_gated: bool = False,
                  mlp_bias: bool = True,
                  rotary_dim: Optional[int] = None,
-                 rope_yarn: Optional[dict] = None):
+                 rope_yarn: Optional[dict] = None,
+                 attn_layer: Optional[Layer] = None,
+                 shortcut_layer: Optional[Layer] = None,
+                 shortcut_add: bool = False,
+                 norm_eps: Optional[float] = None):
         self.num_heads = int(num_heads)
         self.rotary_dim = rotary_dim
         self.rope_yarn = rope_yarn
+        self.norm_eps = None if norm_eps is None else float(norm_eps)
+        #: the expert layer this block computes beside its MLP and hands
+        #: on (class doc), and whether it takes one handed on
+        self.shortcut = shortcut_layer
+        self.shortcut_add = bool(shortcut_add)
+        if self.shortcut is not None and self.shortcut_add:
+            raise ValueError("a block hands a shortcut layer's output on "
+                             "or adds one, not both")
+        self._attn_override = attn_layer
         self.qk_norm = bool(qk_norm)
         self.rope_base = float(rope_base)
         self.block_len = block_len
@@ -519,10 +704,11 @@ class TransformerBlock(Layer):
         self._mlp_override = mlp_layer
 
         norm_cls = RMSNorm if norm == "rmsnorm" else LayerNorm
-        self.norm1 = norm_cls()
-        self.norm2 = norm_cls()
+        norm_kw = {} if norm_eps is None else {"epsilon": norm_eps}
+        self.norm1 = norm_cls(**norm_kw)
+        self.norm2 = norm_cls(**norm_kw)
         self._dropout = Dropout(self.dropout_rate)
-        self.attn = MultiHeadAttention(
+        self.attn = attn_layer or MultiHeadAttention(
             num_heads, head_dim=head_dim, causal=causal, use_rope=use_rope,
             dtype=dtype, attn_impl=attn_impl, seq_axis_name=seq_axis_name,
             ring_block_size=ring_block_size, num_kv_heads=num_kv_heads,
@@ -548,17 +734,25 @@ class TransformerBlock(Layer):
                                ("norm2", self.norm2, ks[2]),
                                ("mlp", self.mlp, ks[3])):
             p[name], s[name], _ = layer.init(k, tuple(input_shape))
+        if self.shortcut is not None:
+            p["shortcut"], s["shortcut"], _ = self.shortcut.init(
+                jax.random.fold_in(ks[3], 1), tuple(input_shape))
         return p, s, tuple(input_shape)
 
     def apply(self, params, state, x, *, training=False, rng=None,
               segment_ids=None):
         new_state = dict(state)
+        carry = None
+        if self.shortcut_add:
+            x, carry = x
+        seg_kw = {"segment_ids": segment_ids} if getattr(
+            self.attn, "accepts_segment_ids", False) else {}
         with jax.named_scope("attn"):
             h, new_state["norm1"] = self.norm1.apply(
                 params["norm1"], state["norm1"], x, training=training)
             a, new_state["attn"] = self.attn.apply(
                 params["attn"], state["attn"], h, training=training,
-                segment_ids=segment_ids)
+                **seg_kw)
 
         def drop(y, key):  # both residual branches share the Dropout layer
             return self._dropout.apply({}, {}, y, training=training,
@@ -579,13 +773,24 @@ class TransformerBlock(Layer):
             m, new_state["mlp"] = self.mlp.apply(
                 params["mlp"], state["mlp"], h, training=training,
                 rng=k_mlp)
+            if self.shortcut is not None:
+                handed, new_state["shortcut"] = self.shortcut.apply(
+                    params["shortcut"], state["shortcut"], h,
+                    training=training, rng=k_mlp)
         if use_dropout:
             m = drop(m, k_drop2)
+        if self.shortcut is not None:
+            return (x + m, handed), new_state
+        if carry is not None:
+            return x + m + carry, new_state
         return x + m, new_state
 
     def sub_layers(self):
-        return {"norm1": self.norm1, "attn": self.attn,
+        subs = {"norm1": self.norm1, "attn": self.attn,
                 "norm2": self.norm2, "mlp": self.mlp}
+        if self.shortcut is not None:
+            subs["shortcut"] = self.shortcut
+        return subs
 
     def get_config(self):
         cfg = {"num_heads": self.num_heads, "mlp_ratio": self.mlp_ratio,
@@ -603,14 +808,22 @@ class TransformerBlock(Layer):
                "block_len": self.block_len, "mlp_dim": self.mlp_dim,
                "mlp_gated": self.mlp_gated, "mlp_bias": self.mlp_bias,
                "rotary_dim": self.rotary_dim, "rope_yarn": self.rope_yarn}
-        if self._mlp_override is not None:
-            cfg["mlp_layer"] = layer_spec(self._mlp_override)
+        if self.norm_eps is not None:
+            cfg["norm_eps"] = self.norm_eps
+        if self.shortcut_add:
+            cfg["shortcut_add"] = True
+        for key, layer in (("mlp_layer", self._mlp_override),
+                           ("attn_layer", self._attn_override),
+                           ("shortcut_layer", self.shortcut)):
+            if layer is not None:
+                cfg[key] = layer_spec(layer)
         return cfg
 
     @classmethod
     def from_config(cls, config):
         config = dict(config)
-        spec = config.pop("mlp_layer", None)
-        if spec is not None:
-            config["mlp_layer"] = layer_from_spec(spec)
+        for key in ("mlp_layer", "attn_layer", "shortcut_layer"):
+            spec = config.pop(key, None)
+            if spec is not None:
+                config[key] = layer_from_spec(spec)
         return cls(**config)

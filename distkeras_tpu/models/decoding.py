@@ -42,7 +42,8 @@ import numpy as np
 from jax import lax
 
 from distkeras_tpu.compat import backend_is_tpu, note_path
-from distkeras_tpu.models.attention import (MultiHeadAttention,
+from distkeras_tpu.models.attention import (LatentAttention,
+                                            MultiHeadAttention,
                                             PositionalEmbedding,
                                             TransformerBlock)
 from distkeras_tpu.models.core import Model, Sequential, scoped
@@ -120,7 +121,21 @@ def init_cache(module: Sequential, batch: int, max_len: int,
                 f"PositionalEmbedding(max_len={layer.max_len}) is too small "
                 f"for a {need}-position decode cache")
         block = _decode_block_of(layer)
-        if block is not None:
+        if block is not None and isinstance(block.attn, LatentAttention):
+            # latent attention keeps ONE latent and one shared rope key
+            # a token: a plane with no head axis and no separate V,
+            # positions LAST (see the latent section below)
+            if int8:
+                raise ValueError(
+                    "latent attention keeps its cache in a float type: "
+                    "no int8 / int4 latent pages")
+            cache.append({"c": jnp.zeros(
+                (batch, block.attn.latent_dim, max_len), dtype)})
+        elif block is not None:
+            if block.shortcut is not None or block.shortcut_add:
+                raise ValueError(
+                    "a shortcut-connected expert layer is decoded round "
+                    "latent attention blocks only")
             attn = block.attn
             # GQA: the cache stores only the kv heads — the whole point
             # of grouped queries at serving time
@@ -284,6 +299,18 @@ def _resolve_head_dims(module: Sequential, params) -> None:
             block.attn.head_dim = int(p["attn"]["wq"].shape[-1])
 
 
+def _is_latent(block: TransformerBlock) -> bool:
+    return isinstance(block.attn, LatentAttention)
+
+
+def _refuse_latent(block: TransformerBlock, what: str) -> None:
+    if _is_latent(block):
+        raise NotImplementedError(
+            f"latent attention is not decoded by {what}: it is served "
+            "by ServingEngine (whole and chunked prefill, the paged "
+            "one-token step)")
+
+
 def _decode_attn(attn: MultiHeadAttention, p, kv, x, t):
     """One-token attention against the cache. x: [B, 1, d]; t: step.
 
@@ -347,6 +374,7 @@ def _decode_attn(attn: MultiHeadAttention, p, kv, x, t):
 
 
 def _decode_block(block: TransformerBlock, p, s, kv, x, t):
+    _refuse_latent(block, "generate()'s scalar step")
     with jax.named_scope("attn"):
         h, _ = block.norm1.apply(p["norm1"], s["norm1"], x)
         a, kv = _decode_attn(block.attn, p["attn"], kv, h, t)
@@ -368,6 +396,9 @@ def _prefill_block(block: TransformerBlock, p, s, kv, x, positions,
     :func:`_prefill_block_chunked`."""
     from distkeras_tpu.models.attention import _attention_compute
 
+    if _is_latent(block):
+        return _latent_prefill_block(block, p, s, kv, x, positions, 0,
+                                     routing)
     attn = block.attn
     dt = jnp.dtype(attn.dtype)
     with jax.named_scope("attn"):
@@ -509,6 +540,9 @@ def _prefill_block_chunked(block: TransformerBlock, p, s, kv, x, positions,
     ``routing`` (a list): expert layers take the drop-free dispatched
     path of the decode steps and append their routing to it
     (``_apply_mlp_decode``); None leaves the layer its own ``apply``."""
+    if _is_latent(block):
+        return _latent_prefill_block(block, p, s, kv, x, positions, t0,
+                                     routing, kv_only)
     attn = block.attn
     dt = jnp.dtype(attn.dtype)
     with jax.named_scope("attn"):
@@ -586,6 +620,144 @@ def _prefill_block_chunked(block: TransformerBlock, p, s, kv, x, positions,
         m = _apply_mlp_decode(block.mlp, p["mlp"], s["mlp"], h_,
                               routing is not None, routing)
     return x + m, kv
+
+# --- latent attention (MLA) -------------------------------------------------
+#
+# A ``LatentAttention`` block's cache entry is ``{"c": [B, latent_dim, L]}``
+# (the page pool: ``[N, latent_dim, page_len]``): per token the scaled
+# normed latent and behind it the roped shared key, no head axis and no
+# separate V. The POSITIONS are the last axis: ``latent_dim`` (576 = 4.5
+# lane tiles) is no multiple of the chip's 128 lanes, a page length is,
+# and the TPU's own default layout of ``[N, page_len, 576]`` puts the
+# positions in the lanes whatever the shape says, at the price of a
+# relayout of the whole plane round every kernel that wants it the other
+# way. Declared as stored, a page is the ``K^T`` block that ``Q K^T``
+# wants and nothing is padded. PREFILL (whole, or a chunk over a cached prefix) attends
+# per-head keys and values REBUILT from the latent: flash at query/key
+# width ``dn + dr`` and value width ``dv``. DECODE takes ``Wkvb`` into the
+# query and the output and attends the latent itself: every head's row
+# over ONE shared key of ``latent_dim`` whose first ``kv_lora_rank`` values
+# are also the value (``ops.paged_attention.paged_latent_attention``). A
+# block of a shortcut-connected expert layer (``TransformerBlock``'s class
+# doc) hands ``(x, m)`` on or takes it, here as in ``apply``.
+
+
+def _block_tail(block: TransformerBlock, p, s, x, y, carry,
+                moe_dispatched, routing):
+    """What follows a block's attention output ``y``: the residual, the
+    MLP on the post-attention norm, and the shortcut-connected expert
+    layer: computed from that same norm and handed on (``(x, m)``), or
+    the one handed in (``carry``) added after the MLP."""
+    x = x + y.astype(x.dtype)
+    with jax.named_scope("mlp"):
+        u, _ = block.norm2.apply(p["norm2"], s["norm2"], x)
+        m = _apply_mlp_decode(block.mlp, p["mlp"], s["mlp"], u,
+                              moe_dispatched, routing)
+        handed = None
+        if block.shortcut is not None:
+            handed = _apply_mlp_decode(
+                block.shortcut, p["shortcut"], s["shortcut"], u,
+                moe_dispatched, routing)
+    x = x + m
+    if carry is not None:
+        x = x + carry
+    return x if handed is None else (x, handed)
+
+
+def _latent_prefill_block(block: TransformerBlock, p, s, kv, x, positions,
+                          t0: int, routing=None, kv_only: bool = False):
+    """One chunk ``[t0, t0 + Q)`` of one latent-attention block (the
+    whole prompt: ``t0 = 0``): write the chunk's latents at their
+    positions, attend causally inside the chunk over keys and values
+    rebuilt from them, and (``t0 > 0``) over those rebuilt from the
+    cached prefix ``[0, t0)``, merged through the log-sum-exps as
+    ``_prefill_block_chunked`` merges."""
+    attn = block.attn
+    dt = jnp.dtype(attn.dtype)
+    x_in, carry = x, None
+    if block.shortcut_add:
+        x, carry = x
+    with jax.named_scope("attn"):
+        h_, _ = block.norm1.apply(p["norm1"], s["norm1"], x)
+        qn, qr, entry = attn.project(p["attn"], h_.astype(dt), positions)
+        kv = {"c": lax.dynamic_update_slice_in_dim(
+            kv["c"], entry.transpose(0, 2, 1).astype(kv["c"].dtype), t0,
+            axis=2)}
+        if kv_only:
+            return x_in, kv
+        q = jnp.concatenate([qn, qr], axis=-1).transpose(0, 2, 1, 3)
+        k, v = attn.expand_kv(p["attn"], entry, dt)
+        out, lse = _attn_lse(q, k, v, causal=True, scale=attn.scale,
+                             layout="bhsd")
+        if t0 > 0:
+            kp, vp = attn.expand_kv(
+                p["attn"], kv["c"][:, :, :t0].transpose(0, 2, 1), dt)
+            o_pre, lse_pre = _attn_lse(q, kp, vp, causal=False,
+                                       scale=attn.scale, layout="bhsd")
+            out = _merge_attention(o_pre, lse_pre, out, lse)
+        y = jnp.einsum("bhsv,hvd->bsd", out.astype(dt),
+                       p["attn"]["wo"].astype(dt))
+    return _block_tail(block, p, s, x, y, carry, routing is not None,
+                       routing), kv
+
+
+def _write_latent_rows(plane, pp, off, vals):
+    """``plane.at[pp, :, off].set(vals, mode="drop")`` for a latent page
+    plane [N, C, page_len] and per-slot vectors [S, C]: a token is a
+    COLUMN of its page, so each slot's page is read, the column blended
+    in and the page written back whole (S pages of traffic, in place; the
+    one-position write of an int4 page does the same). A ``pp`` of N or
+    more drops; live slots never share the page they write."""
+    n = plane.shape[0]
+    pages = plane[jnp.clip(pp, 0, n - 1)]                # [S, C, page_len]
+    col = jnp.arange(plane.shape[2])[None, None, :] == off[:, None, None]
+    pages = jnp.where(col, vals.astype(plane.dtype)[:, :, None], pages)
+    return plane.at[pp].set(pages, mode="drop")
+
+
+def _latent_decode_block(block: TransformerBlock, p, s, kv, x, t, table,
+                         page_len: int, moe_dispatched=True, routing=None,
+                         paged_kernel=None):
+    """One token a slot through a latent-attention block against the
+    PAGED latent plane, in the absorbed form: the token's latent is
+    written through the page table, the queries take ``Wkvb``'s key
+    half, attend the latent pages (the Pallas kernel, or the gathered
+    view: its oracle and the off-TPU path) and the output takes
+    ``Wkvb``'s value half and ``Wo``."""
+    attn = block.attn
+    dt = jnp.dtype(attn.dtype)
+    carry = None
+    if block.shortcut_add:
+        x, carry = x
+    with jax.named_scope("attn"):
+        h_, _ = block.norm1.apply(p["norm1"], s["norm1"], x)
+        qn, qr, entry = attn.project(p["attn"], h_.astype(dt), t[:, None])
+        plane = kv["c"]
+        n_pages, n_logical = plane.shape[0], table.shape[1]
+        lp = t // page_len
+        pp = jnp.take_along_axis(
+            table, jnp.clip(lp, 0, n_logical - 1)[:, None], axis=1)[:, 0]
+        # a position past the slot's pages (the free-slot sentinel) or an
+        # unallocated page: the write falls off the end and drops
+        pp = jnp.where((lp >= 0) & (lp < n_logical), pp, n_pages)
+        plane = _write_latent_rows(plane, pp, t % page_len, entry[:, 0])
+        q = attn.absorb_q(p["attn"], qn, qr)         # [S, 1, H, latent_dim]
+        from distkeras_tpu.ops import paged_attention as pa
+        if (backend_is_tpu() if paged_kernel is None else paged_kernel) \
+                and pa.page_aligned(page_len):
+            note_path("paged_attention", "kernel")
+            o = pa.paged_latent_attention(
+                q, plane, t, table, v_dim=attn.kv_lora_rank,
+                scale=attn.scale,
+                interpret=None if backend_is_tpu() else True)
+        else:
+            note_path("paged_attention", "gather_reference")
+            o = pa.paged_latent_attention_reference(
+                q, plane, t, table, v_dim=attn.kv_lora_rank,
+                scale=attn.scale)
+        y = attn.unabsorb_v(p["attn"], o, dt)
+    return _block_tail(block, p, s, x, y, carry, moe_dispatched,
+                       routing), {"c": plane}
 
 
 def prefill_chunk_step(module: Sequential, params, state, cache, chunk,
@@ -754,7 +926,9 @@ def _apply_mlp_decode(mlp, p, s, x, moe_dispatched, routing):
         if routing is None:
             return mlp.decode_apply(p, x)
         out, r = mlp.decode_apply(p, x, return_routing=True)
-        routing.append((mlp.num_experts, r))
+        share = mlp.routing_share()
+        routing.append((mlp.num_experts, r) if share is None
+                       else (mlp.router_dim, r, share))
         return out
     out, _ = mlp.apply(p, s, x, training=False)
     return out
@@ -775,7 +949,7 @@ def _moe_route_stats(routing, t, w_len: int, live_len: int):
     load = jnp.zeros((e0,), jnp.float32)
     ent_sum = jnp.zeros((), jnp.float32)
     n_layers = 0
-    for e, (topi, full) in routing:
+    for e, (topi, full), *_share in routing:
         if e != e0:
             continue
         oh = jax.nn.one_hot(topi, e0, dtype=jnp.float32).sum(-2)
@@ -946,6 +1120,7 @@ def _decode_attn_slots(attn: MultiHeadAttention, p, kv, x, t):
 def _decode_block_slots(block: TransformerBlock, p, s, kv, x, t,
                         moe_dispatched=True, routing=None):
     """Contiguous-cache reference; the engine does not call this."""
+    _refuse_latent(block, "the contiguous-cache reference step")
     with jax.named_scope("attn"):
         h, _ = block.norm1.apply(p["norm1"], s["norm1"], x)
         a, kv = _decode_attn_slots(block.attn, p["attn"], kv, h, t)
@@ -1242,6 +1417,9 @@ def _decode_block_slots_paged(block: TransformerBlock, p, s, kv, x, t,
                               table, page_len: int,
                               moe_dispatched=True, routing=None,
                               paged_kernel=None, ring=None):
+    if _is_latent(block):
+        return _latent_decode_block(block, p, s, kv, x, t, table, page_len,
+                                    moe_dispatched, routing, paged_kernel)
     with jax.named_scope("attn"):
         h, _ = block.norm1.apply(p["norm1"], s["norm1"], x)
         a, kv = _decode_attn_slots_paged(block.attn, p["attn"], kv, h, t,
@@ -1342,6 +1520,7 @@ def _decode_block_slots_window(block: TransformerBlock, p, s, kv, x, t,
     path at its contiguous final positions. ``kv_only``: the block
     stops once the window's K/V are written (the deepest block of a
     pass that needs no logits)."""
+    _refuse_latent(block, "a verify or block-diffusion window")
     attn = block.attn
     with jax.named_scope("attn"):
         h, _ = block.norm1.apply(p["norm1"], s["norm1"], x)
@@ -1503,13 +1682,39 @@ def routing_counts(routing):
     they collected (``(num_experts, (topi, full))`` each): the rows
     they routed, and the experts that owned at least one row, both
     summed over the layers. What the program ran, said by the program:
-    a layer that did not run collected nothing."""
+    a layer that did not run collected nothing.
+
+    Where a layer holds a SHARE of its experts (a third entry ``(lo, n,
+    num_experts)``: ``MoE.routing_share``) the vector is ``int32[5]``:
+    the rows routed, the HELD experts that owned a row (what the layer
+    read), then the rows by where they went: to experts held here, to
+    experts that are not here (left out), to identity experts."""
+    if any(len(r) > 2 for r in routing):
+        return _share_counts(routing)
     rows = sum(topi.size for _e, (topi, _full) in routing)
     touched = jnp.zeros((), jnp.int32)
     for e, (topi, _full) in routing:
         owned = jnp.zeros((e,), jnp.int32).at[topi.reshape(-1)].add(1)
         touched = touched + jnp.sum(owned > 0, dtype=jnp.int32)
     return jnp.stack([jnp.asarray(rows, jnp.int32), touched])
+
+
+def _share_counts(routing):
+    rows = sum(r[1][0].size for r in routing)
+    counts = jnp.zeros((4,), jnp.int32)      # touched, held, absent, zero
+    for e, (topi, _full), *share in routing:
+        lo, n, routed = share[0] if share else (0, e, e)
+        ids = topi.reshape(-1)
+        held = (ids >= lo) & (ids < lo + n)
+        zero = ids >= routed
+        owned = jnp.zeros((n,), jnp.int32).at[
+            jnp.where(held, ids - lo, n)].add(1, mode="drop")
+        counts = counts + jnp.stack([
+            jnp.sum(owned > 0, dtype=jnp.int32),
+            jnp.sum(held, dtype=jnp.int32),
+            jnp.sum(~held & ~zero, dtype=jnp.int32),
+            jnp.sum(zero, dtype=jnp.int32)])
+    return jnp.concatenate([jnp.asarray([rows], jnp.int32), counts])
 
 
 def block_pass_slots_paged(module: Sequential, params, state, cache,
@@ -2094,6 +2299,10 @@ def generate(model: Model, prompts, max_new_tokens: int,
     if not isinstance(module, Sequential):
         raise TypeError("generate() expects a Sequential LM "
                         f"(got {type(module).__name__})")
+    for layer in module.layers:
+        blk = _decode_block_of(layer)
+        if blk is not None:
+            _refuse_latent(blk, "generate()")
     if block_len_of(module) is not None:
         raise ValueError(
             "generate() decodes one token a step; a block-causal "

@@ -78,8 +78,14 @@ from distkeras_tpu.models.core import (AUX_LOSS_KEY, Layer,
 from distkeras_tpu.models.layers import get_activation, init_weights
 
 
-def _dispatch_plan(experts, gates, num_experts: int, capacity: int):
+def _dispatch_plan(experts, gates, num_experts: int, capacity: int,
+                   valid=None):
     """Static-shape dispatch bookkeeping.
+
+    ``valid`` ([N, K] bool; a layer that holds a share of the experts):
+    assignments to experts that are not here. Their ``experts`` entry is
+    ``num_experts`` (one past the last), they take no place in any
+    expert's buffer and come back dropped.
 
     experts/gates: [N, K] top-k expert ids / combine weights per token.
     Returns (dest, token, weight, keep) flat [N*K] slot arrays in
@@ -100,8 +106,14 @@ def _dispatch_plan(experts, gates, num_experts: int, capacity: int):
     # (slots stay in choice-major order, which IS the priority order).
     onehot = jax.nn.one_hot(slot_e, num_experts, dtype=jnp.int32)
     ranks = jnp.cumsum(onehot, axis=0) - onehot         # [K*N, E] exclusive
-    pos = jnp.take_along_axis(ranks, slot_e[:, None], axis=1)[:, 0]
-    keep = pos < capacity
+    if valid is None:
+        pos = jnp.take_along_axis(ranks, slot_e[:, None], axis=1)[:, 0]
+        keep = pos < capacity
+    else:
+        pos = jnp.take_along_axis(
+            ranks, jnp.minimum(slot_e, num_experts - 1)[:, None],
+            axis=1)[:, 0]
+        keep = jnp.logical_and(pos < capacity, valid.T.reshape(-1))
     # dropped slots get UNIQUE out-of-range sentinels (E*C + slot index),
     # not one shared overflow value: the consumers scatter with
     # unique_indices=True, a promise a shared sentinel would break
@@ -128,12 +140,49 @@ class MoE(Layer):
                  gated: bool = False, use_bias: bool = True,
                  score: str = "softmax", norm_topk: bool = True,
                  route_scale: float = 1.0,
-                 shared_dim: Optional[int] = None):
+                 shared_dim: Optional[int] = None,
+                 zero_experts: int = 0,
+                 experts_held: Optional[tuple] = None,
+                 select_bias: bool = False):
         self.num_experts = int(num_experts)
+        #: ZERO-COMPUTE experts: router outputs ``num_experts ..
+        #: num_experts + zero_experts - 1`` are identity experts, a token
+        #: that picks one gets ``gate * x`` from it, no weights, no work
+        self.zero_experts = int(zero_experts)
+        #: ``(lo, n)``: this layer HOLDS experts ``lo .. lo + n - 1`` of
+        #: the ``num_experts`` the router chooses among (one chip's share
+        #: of an expert-parallel deployment, on one chip: no axis, no
+        #: exchange). The stacked weights are ``[n, ...]``; what the
+        #: absent experts would add is left out, the identity experts
+        #: are all computed. None: all of them
+        self.experts_held = None
+        if experts_held is not None:
+            lo, n = (int(v) for v in experts_held)
+            if lo < 0 or n < 1 or lo + n > self.num_experts:
+                raise ValueError(
+                    f"experts_held {experts_held} is not a range of the "
+                    f"{self.num_experts} experts")
+            self.experts_held = (lo, n)
+        #: a per-output bias added to the scores to CHOOSE the top k; the
+        #: gates are the scores without it (``params["select_bias"]``)
+        self.select_bias = bool(select_bias)
+        #: outputs of the router, and experts whose weights are here
+        self.router_dim = self.num_experts + self.zero_experts
+        self._held_lo, self.num_held = self.experts_held \
+            or (0, self.num_experts)
+        self._partial = bool(self.zero_experts or self.experts_held)
+        if self._partial and (expert_axis_name or aux_loss_weight
+                              or dispatch == "fused"):
+            raise ValueError(
+                "zero_experts / experts_held serve on one chip: no "
+                "expert_axis_name, no aux_loss_weight, not "
+                "dispatch='fused'")
         #: how a router logit becomes a gate: ``"softmax"`` over the k
-        #: chosen logits (renormalised by construction), or
-        #: ``"sigmoid"`` of each logit, divided by the sum over the k
-        #: chosen (``norm_topk``); either way times ``route_scale``
+        #: chosen logits (renormalised by construction; with
+        #: ``norm_topk=False`` or a ``select_bias`` the softmax is over
+        #: ALL outputs and the k chosen keep their probabilities as they
+        #: are), or ``"sigmoid"`` of each logit, divided by the sum over
+        #: the k chosen (``norm_topk``); either way times ``route_scale``
         if score not in ("softmax", "sigmoid"):
             raise ValueError(
                 f"score must be 'softmax' or 'sigmoid', got {score!r}")
@@ -173,7 +222,9 @@ class MoE(Layer):
         if dispatch == "grouped" and (use_bias or expert_axis_name):
             raise ValueError(
                 "dispatch='grouped' runs bias-free experts on one chip "
-                "(use_bias=False, no expert_axis_name)")
+                "(use_bias=False, no expert_axis_name); one chip's share "
+                "of an expert-parallel layer is experts_held=(lo, n), "
+                "which needs no axis")
         self.dispatch = dispatch
         # expert capacity = ceil(top_k * tokens / E) * capacity_factor:
         # at 1.0 a perfectly balanced router drops nothing; the default
@@ -197,15 +248,18 @@ class MoE(Layer):
 
     def init(self, rng, input_shape):
         d = input_shape[-1]
-        e, hid = self.num_experts, self.hidden_dim
+        e, hid = self.num_held, self.hidden_dim
         kg, k1, k2 = jax.random.split(rng, 3)
         # per-expert init: split so experts start decorrelated
         w1 = jnp.stack([init_weights(self.kernel_init, k, (d, hid))
                         for k in jax.random.split(k1, e)])
         w2 = jnp.stack([init_weights(self.kernel_init, k, (hid, d))
                         for k in jax.random.split(k2, e)])
-        params = {"gate": init_weights(self.kernel_init, kg, (d, e)),
+        params = {"gate": init_weights(self.kernel_init, kg,
+                                       (d, self.router_dim)),
                   "w1": w1, "w2": w2}
+        if self.select_bias:
+            params["select_bias"] = jnp.zeros((self.router_dim,))
         if self.gated:
             params["w3"] = jnp.stack(
                 [init_weights(self.kernel_init, k, (d, hid))
@@ -221,7 +275,7 @@ class MoE(Layer):
             state[AUX_LOSS_KEY] = jnp.zeros((), jnp.float32)
         return params, state, tuple(input_shape)
 
-    def _route(self, x, gate):
+    def _route(self, x, gate, select_bias=None):
         """Shared router: ``(full, topi, gates, mask)`` — full softmax
         [B, S, E], top-k expert ids + their renormalized weights [B, S, K]
         (softmax over the k logits == the masked-softmax restriction, so
@@ -245,17 +299,50 @@ class MoE(Layer):
             gates = jax.nn.sigmoid(topv)
             if self.norm_topk:
                 gates = gates / jnp.sum(gates, axis=-1, keepdims=True)
-        else:
+        elif self.norm_topk and select_bias is None:
             full = jax.nn.softmax(logits, axis=-1)
             topv, topi = lax.top_k(logits, self.top_k)
             gates = jax.nn.softmax(topv, axis=-1)
+        else:
+            # softmax over ALL outputs; the bias chooses and does not
+            # weight; the chosen keep their probabilities
+            full = jax.nn.softmax(logits, axis=-1)
+            choose = full if select_bias is None \
+                else full + select_bias.astype(jnp.float32)
+            _, topi = lax.top_k(choose, self.top_k)
+            gates = jnp.take_along_axis(full, topi, axis=-1)
+            if self.norm_topk:
+                gates = gates / jnp.sum(gates, axis=-1, keepdims=True)
         if self.route_scale != 1.0:
             gates = gates * self.route_scale
         mask = None
-        if self.top_k < self.num_experts:
-            mask = jax.nn.one_hot(topi, self.num_experts,
+        if self.top_k < self.router_dim:
+            mask = jax.nn.one_hot(topi, self.router_dim,
                                   dtype=jnp.bool_).any(axis=-2)
         return full, topi, gates, mask
+
+    def _route_params(self, params, x):
+        return self._route(x, params["gate"], params.get("select_bias"))
+
+    def _local(self, topi, gates):
+        """A partial layer's view of the routing: ``(ids, held,
+        zero_w)``: each assignment's index among the experts HELD
+        (``num_held``, one past the last, where its expert is not
+        here), whether it is held, and per token the sum of the gates
+        of the identity experts it chose."""
+        local = topi - self._held_lo
+        held = jnp.logical_and(local >= 0, local < self.num_held)
+        zero_w = jnp.sum(jnp.where(topi >= self.num_experts, gates, 0.0),
+                         axis=-1)
+        return jnp.where(held, local, self.num_held), held, zero_w
+
+    def routing_share(self):
+        """``(lo, n, num_experts)`` of a partial layer (what
+        ``models.decoding.routing_counts`` splits the rows by), or
+        None."""
+        if not self._partial:
+            return None
+        return (self._held_lo, self.num_held, self.num_experts)
 
     def _with_shared(self, params, x, out):
         """``out`` plus the shared expert's output for ``x`` (every
@@ -402,15 +489,22 @@ class MoE(Layer):
         dt = jnp.dtype(self.dtype)
         b, s, d = x.shape
         n = b * s
-        e, k = self.num_experts, self.top_k
+        e, k = self.num_held, self.top_k
         c = self._capacity(n) if capacity is None else int(capacity)
-        full, topi, gates, mask = self._route(x, params["gate"])
+        full, topi, gates, mask = self._route_params(params, x)
         # the fused kernels are written for biased single-activation
         # experts; gated or bias-free ones take the XLA tokens floor
-        fused = fused and self.use_bias and not self.gated
+        fused = fused and self.use_bias and not self.gated \
+            and not self._partial
 
-        dest, _st, sg, keep = _dispatch_plan(
-            topi.reshape(n, k), gates.reshape(n, k), e, c)
+        if self._partial:
+            ids, held, zero_w = self._local(topi, gates)
+            dest, _st, sg, keep = _dispatch_plan(
+                ids.reshape(n, k), gates.reshape(n, k), e, c,
+                valid=held.reshape(n, k))
+        else:
+            dest, _st, sg, keep = _dispatch_plan(
+                topi.reshape(n, k), gates.reshape(n, k), e, c)
         xt = x.reshape(n, d).astype(dt)
 
         note_path("moe", "fused_kernel" if fused else "tokens_xla")
@@ -487,6 +581,9 @@ class MoE(Layer):
         safe = jnp.where(keep[:, None], ye_flat[dest], jnp.zeros((), dt))
         contrib = safe * sg[:, None].astype(dt)
         out = contrib.reshape(k, n, d).sum(axis=0)
+        if self.zero_experts:
+            out = (out.astype(jnp.float32) + zero_w.reshape(n, 1)
+                   * xt.astype(jnp.float32)).astype(dt)
         if return_routing:
             return out.reshape(b, s, d), full, mask, topi
         return out.reshape(b, s, d), full, mask
@@ -500,15 +597,30 @@ class MoE(Layer):
         from distkeras_tpu.ops import moe_kernels
         dt = jnp.dtype(self.dtype)
         b, s, d = x.shape
-        n, e, k = b * s, self.num_experts, self.top_k
-        full, topi, gates, mask = self._route(x, params["gate"])
-        rows = moe_kernels.grouped_block_rows(n * k, e)
-        dest, tile_expert, used, _counts = moe_kernels.grouped_layout(
-            topi.reshape(n * k).astype(jnp.int32), e, rows)
+        n, e, k = b * s, self.num_held, self.top_k
+        full, topi, gates, mask = self._route_params(params, x)
+        # a tile is as tall as the mean group: of the router's outputs,
+        # where the layer holds a share of them
+        rows = moe_kernels.grouped_block_rows(n * k, self.router_dim)
         m = moe_kernels.grouped_tiles(n * k, e, rows) * rows
-        # row -> token (padding rows read token 0: finite, never summed)
-        row_token = jnp.zeros((m,), jnp.int32).at[dest].set(
-            jnp.arange(n * k, dtype=jnp.int32) // k, unique_indices=True)
+        if self._partial:
+            # rows routed to experts that are not here have no place in
+            # the layout: nothing gathers them, no tile computes them
+            ids, held, zero_w = self._local(topi, gates)
+            dest, tile_expert, used, _counts = moe_kernels.grouped_layout(
+                ids.reshape(n * k).astype(jnp.int32), e, rows,
+                valid=held.reshape(n * k))
+            row_token = jnp.zeros((m,), jnp.int32).at[dest].set(
+                jnp.arange(n * k, dtype=jnp.int32) // k,
+                unique_indices=True, mode="drop")
+        else:
+            dest, tile_expert, used, _counts = moe_kernels.grouped_layout(
+                topi.reshape(n * k).astype(jnp.int32), e, rows)
+            # row -> token (padding rows read token 0: finite, never
+            # summed)
+            row_token = jnp.zeros((m,), jnp.int32).at[dest].set(
+                jnp.arange(n * k, dtype=jnp.int32) // k,
+                unique_indices=True)
         x_rows = x.reshape(n, d).astype(dt)[row_token]
         kw = dict(block_rows=rows, activation=self.activation)
         w = [params[name].astype(dt) for name in
@@ -522,7 +634,12 @@ class MoE(Layer):
             y_rows = moe_kernels.grouped_experts_reference(
                 x_rows, tile_expert, used, *w, **kw)
         y = y_rows[dest].reshape(n, k, d).astype(jnp.float32)
+        if self._partial:
+            y = jnp.where(held.reshape(n, k, 1), y, 0.0)
         out = jnp.sum(y * gates.reshape(n, k, 1), axis=1)
+        if self.zero_experts:
+            out = out + zero_w.reshape(n, 1) \
+                * x.reshape(n, d).astype(jnp.float32)
         return out.reshape(b, s, d).astype(dt), full, mask, topi
 
     def decode_apply(self, params, x, *, return_routing=False):
@@ -605,7 +722,18 @@ class MoE(Layer):
             return out.astype(x.dtype), new_state
 
         note_path("moe", "dense_xla")
-        probs, full, mask = self._gate_probs(x, params["gate"])  # f32
+        zero_w = None
+        if self._partial or self.select_bias:
+            # gates over the experts HELD (zero outside the token's
+            # choice), and the identity experts' share of the token
+            full, topi, gates, mask = self._route_params(params, x)
+            ids, _held, zero_w = self._local(topi, gates)
+            probs = jnp.einsum(
+                "bske,bsk->bse",
+                jax.nn.one_hot(ids, self.num_held, dtype=gates.dtype),
+                gates)
+        else:
+            probs, full, mask = self._gate_probs(x, params["gate"])  # f32
 
         xc = x.astype(dt)
         # local experts: [El, ...] slice when sharded over the expert axis
@@ -632,6 +760,8 @@ class MoE(Layer):
             local = lax.dynamic_slice_in_dim(probs, idx * el, el, axis=-1)
             out = jnp.einsum("bse,besd->bsd", local.astype(dt), y)
             out = lax.psum(out, self.expert_axis_name)
+        if zero_w is not None:
+            out = out + (zero_w[..., None] * x).astype(out.dtype)
         out = self._with_shared(params, x, out.astype(x.dtype))
         new_state = state
         if self.aux_loss_weight and training:
@@ -655,7 +785,10 @@ class MoE(Layer):
                 "gated": self.gated, "use_bias": self.use_bias,
                 "score": self.score, "norm_topk": self.norm_topk,
                 "route_scale": self.route_scale,
-                "shared_dim": self.shared_dim}
+                "shared_dim": self.shared_dim,
+                "zero_experts": self.zero_experts,
+                "experts_held": self.experts_held,
+                "select_bias": self.select_bias}
 
 
 def moe_all_to_all(moe: MoE, params, x, *, axis_name: str):

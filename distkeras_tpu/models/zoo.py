@@ -180,7 +180,11 @@ def transformer_lm(vocab_size: int, d_model: int = 512, num_heads: int = 8,
                    moe_score: str = "softmax",
                    moe_norm_topk: bool = True,
                    moe_route_scale: float = 1.0,
-                   moe_shared_dim: Optional[int] = None) -> Sequential:
+                   moe_shared_dim: Optional[int] = None,
+                   moe_zero_experts: int = 0,
+                   moe_experts_held: Optional[Sequence[int]] = None,
+                   moe_select_bias: bool = False,
+                   norm_eps: Optional[float] = None) -> Sequential:
     """Decoder-only causal transformer LM — the long-context flagship.
 
     Absent from the reference (no attention models; SURVEY §5.7); this is
@@ -231,7 +235,28 @@ def transformer_lm(vocab_size: int, d_model: int = 512, num_heads: int = 8,
     ``moe_every``. ``moe_score`` / ``moe_norm_topk`` /
     ``moe_route_scale`` / ``moe_shared_dim``: the router's score
     function, its normalisation over the chosen experts, a scale on
-    the gates and a shared expert (``models.moe.MoE``).
+    the gates and a shared expert (``models.moe.MoE``);
+    ``moe_zero_experts`` identity experts behind the router's last
+    outputs, ``moe_select_bias`` a bias that chooses and does not
+    weight, and ``moe_experts_held=(lo, n)`` ONE CHIP'S SHARE of an
+    expert-parallel layer: the router keeps all its outputs, the layer
+    holds and computes experts ``lo .. lo + n - 1`` (and every identity
+    expert) and leaves the others' part out.
+
+    LATENT attention: an ``attn_kinds[kind]`` with a ``"latent"`` entry
+    (``{"q_lora_rank", "kv_lora_rank", "qk_nope_head_dim",
+    "qk_rope_head_dim", "v_head_dim", "q_scale", "kv_scale"}``) makes
+    the layers of that kind ``models.attention.LatentAttention``
+    (``num_heads`` and ``rope_base`` as for the other kinds);
+    ``ServingEngine`` keeps one latent a token for them
+    (docs/serving.md §Latent pages). ``mlp_layer_types[i] ==
+    "shortcut"`` makes entry ``i`` of ``num_layers`` a DOUBLE layer:
+    two attention blocks and two dense MLPs (width ``dense_mlp_dim``)
+    round a shortcut-connected expert layer, computed from the first
+    block's post-attention norm and added after the second block's MLP
+    (``TransformerBlock``'s class doc); it is two blocks of the
+    ``Sequential`` and two entries of a serving cache. ``norm_eps``
+    states every norm's epsilon.
     """
     from distkeras_tpu.models.attention import (
         LayerNorm, PositionalEmbedding, RMSNorm, TransformerBlock)
@@ -252,8 +277,9 @@ def transformer_lm(vocab_size: int, d_model: int = 512, num_heads: int = 8,
     if layer_types is None and attn_kinds:
         raise ValueError("attn_kinds needs layer_types")
     if mlp_layer_types is not None and set(mlp_layer_types) \
-            - {"dense", "sparse"}:
-        raise ValueError("mlp_layer_types entries are 'dense' or 'sparse'")
+            - {"dense", "sparse", "shortcut"}:
+        raise ValueError("mlp_layer_types entries are 'dense', 'sparse' "
+                         "or 'shortcut'")
     for i in range(num_layers):
         attn = dict(num_heads=num_heads, head_dim=head_dim,
                     num_kv_heads=num_kv_heads, rope_scale=rope_scale,
@@ -261,17 +287,24 @@ def transformer_lm(vocab_size: int, d_model: int = 512, num_heads: int = 8,
                     rope_base=rope_base)
         if layer_types is not None:
             kind = dict((attn_kinds or {}).get(layer_types[i], {}))
-            unknown = set(kind) - set(attn) - {"rotary_dim", "rope_yarn"}
+            unknown = set(kind) - set(attn) - {"rotary_dim", "rope_yarn",
+                                               "latent"}
             if unknown:
                 raise ValueError(
                     f"attn_kinds[{layer_types[i]!r}] has unknown keys "
                     f"{sorted(unknown)}")
             attn.update(kind)
+        latent = attn.pop("latent", None)
+        if latent is not None and block_len is not None:
+            raise ValueError("block_len (block-causal attention) is not "
+                             "built for latent attention layers")
         if mlp_layer_types is None:
             sparse = bool(moe_every and num_experts
                           and (i + 1) % moe_every == 0)
         else:
-            sparse = mlp_layer_types[i] == "sparse"
+            sparse = mlp_layer_types[i] in ("sparse", "shortcut")
+        shortcut = mlp_layer_types is not None \
+            and mlp_layer_types[i] == "shortcut"
         mlp_layer = None
         layer_mlp_dim = mlp_dim
         if sparse:
@@ -282,6 +315,12 @@ def transformer_lm(vocab_size: int, d_model: int = 512, num_heads: int = 8,
                 routing = dict(score=moe_score, norm_topk=moe_norm_topk,
                                route_scale=moe_route_scale,
                                shared_dim=moe_shared_dim)
+            if moe_zero_experts or moe_experts_held or moe_select_bias:
+                routing.update(
+                    zero_experts=moe_zero_experts,
+                    experts_held=None if moe_experts_held is None
+                    else tuple(moe_experts_held),
+                    select_bias=moe_select_bias)
             mlp_layer = MoE(num_experts, mlp_dim or mlp_ratio * d_model,
                             top_k=moe_top_k, activation=mlp_activation,
                             dtype=dtype, expert_axis_name=moe_expert_axis,
@@ -292,18 +331,48 @@ def transformer_lm(vocab_size: int, d_model: int = 512, num_heads: int = 8,
                             gated=mlp_gated, use_bias=mlp_bias, **routing)
         elif mlp_layer_types is not None and dense_mlp_dim:
             layer_mlp_dim = dense_mlp_dim
-        block = TransformerBlock(
-            mlp_ratio=mlp_ratio, causal=True,
-            use_rope=use_rope, activation=mlp_activation,
-            norm=norm, dtype=dtype, attn_impl=attn_impl,
-            seq_axis_name=seq_axis_name, mlp_layer=mlp_layer,
-            block_len=block_len, mlp_dim=layer_mlp_dim,
-            mlp_gated=mlp_gated, mlp_bias=mlp_bias, **attn)
-        if remat is not None:
-            from distkeras_tpu.models.blocks import Remat
-            block = Remat(block, policy=remat)
-        layers.append(block)
-    layers.append(RMSNorm() if norm == "rmsnorm" else LayerNorm())
+        extra = {} if norm_eps is None else {"norm_eps": norm_eps}
+
+        def attention():
+            if latent is None:
+                return {}
+            from distkeras_tpu.models.attention import LatentAttention
+            return {"attn_layer": LatentAttention(
+                attn["num_heads"], rope_base=attn["rope_base"],
+                norm_eps=1e-6 if norm_eps is None else norm_eps,
+                dtype=dtype,
+                attn_impl=attn_impl if attn_impl in ("xla", "flash")
+                else "auto", **latent)}
+
+        def block_of(**kw):
+            block = TransformerBlock(
+                mlp_ratio=mlp_ratio, causal=True,
+                use_rope=use_rope, activation=mlp_activation,
+                norm=norm, dtype=dtype, attn_impl=attn_impl,
+                seq_axis_name=seq_axis_name,
+                block_len=block_len,
+                mlp_gated=mlp_gated, mlp_bias=mlp_bias, **attn, **kw)
+            if remat is not None:
+                from distkeras_tpu.models.blocks import Remat
+                block = Remat(block, policy=remat)
+            return block
+
+        if shortcut:
+            # the double layer: two blocks, the expert layer handed from
+            # the first to the second beside the residual stream
+            width = dense_mlp_dim or mlp_dim
+            layers.append(block_of(mlp_dim=width, shortcut_layer=mlp_layer,
+                                   **attention(), **extra))
+            layers.append(block_of(mlp_dim=width, shortcut_add=True,
+                                   **attention(), **extra))
+            continue
+        layers.append(block_of(mlp_layer=mlp_layer, mlp_dim=layer_mlp_dim,
+                               **attention(), **extra))
+    if norm_eps is not None:
+        layers.append(RMSNorm(norm_eps) if norm == "rmsnorm"
+                      else LayerNorm(norm_eps))
+    else:
+        layers.append(RMSNorm() if norm == "rmsnorm" else LayerNorm())
     layers.append(Dense(vocab_size, use_bias=False, dtype=dtype))
     return Sequential(layers)
 

@@ -276,6 +276,9 @@ def _flash_forward(q, k, v, scale: float, causal: bool, block_q: int,
         b, sq, h, d = q.shape
         sk = k.shape[1]
         seq_axis = 1
+    # the values' width may differ from the queries' and keys' (latent
+    # attention: 192 and 128); the output is as wide as the values
+    dv = v.shape[-1]
     # clamp to the (8-rounded) sequence length: Mosaic requires the block's
     # second-to-last dim % 8 == 0, so a raw min(block, seq) would fail to
     # lower for seq in (block, 8k) that isn't a multiple of 8 — the padder
@@ -304,12 +307,12 @@ def _flash_forward(q, k, v, scale: float, causal: bool, block_q: int,
         # layout the layer uses when it targets this kernel
         qf = qp.reshape(b * h, sq_p, d)
         kf = kp.reshape(b * h, sk_p, d)
-        vf = vp.reshape(b * h, sk_p, d)
+        vf = vp.reshape(b * h, sk_p, dv)
     else:
         # BSHD -> (B*H, S, D): one grid row per (batch, head)
         qf = qp.transpose(0, 2, 1, 3).reshape(b * h, sq_p, d)
         kf = kp.transpose(0, 2, 1, 3).reshape(b * h, sk_p, d)
-        vf = vp.transpose(0, 2, 1, 3).reshape(b * h, sk_p, d)
+        vf = vp.transpose(0, 2, 1, 3).reshape(b * h, sk_p, dv)
 
     nk = sk_p // block_k
     nkw = _window_kblocks(block_q, block_k, nk, window,
@@ -330,7 +333,7 @@ def _flash_forward(q, k, v, scale: float, causal: bool, block_q: int,
     in_specs = [
         pl.BlockSpec((1, block_q, d), lambda bh, qi, ki: (bh, qi, 0)),
         pl.BlockSpec((1, block_k, d), k_map),
-        pl.BlockSpec((1, block_k, d), k_map),
+        pl.BlockSpec((1, block_k, dv), k_map),
     ]
     operands = [qf, kf, vf]
     if segment_ids is not None:
@@ -350,25 +353,25 @@ def _flash_forward(q, k, v, scale: float, causal: bool, block_q: int,
         grid=grid,
         in_specs=in_specs,
         out_specs=[
-            pl.BlockSpec((1, block_q, d), lambda bh, qi, ki: (bh, qi, 0)),
+            pl.BlockSpec((1, block_q, dv), lambda bh, qi, ki: (bh, qi, 0)),
             pl.BlockSpec((1, block_q, 1), lambda bh, qi, ki: (bh, qi, 0)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((b * h, sq_p, d), q.dtype),
+            jax.ShapeDtypeStruct((b * h, sq_p, dv), q.dtype),
             jax.ShapeDtypeStruct((b * h, sq_p, 1), jnp.float32),
         ],
         scratch_shapes=[
             pltpu.VMEM((block_q, 1), jnp.float32),
             pltpu.VMEM((block_q, 1), jnp.float32),
-            pltpu.VMEM((block_q, d), jnp.float32),
+            pltpu.VMEM((block_q, dv), jnp.float32),
         ],
         compiler_params=_COMPILER_PARAMS,
         name="flash_fwd", interpret=interpret,
     )(*operands)
     if bhsd:
-        out = out.reshape(b, h, sq_p, d)[:, :, :sq]
+        out = out.reshape(b, h, sq_p, dv)[:, :, :sq]
     else:
-        out = out.reshape(b, h, sq_p, d).transpose(0, 2, 1, 3)[:, :sq]
+        out = out.reshape(b, h, sq_p, dv).transpose(0, 2, 1, 3)[:, :sq]
     lse = lse.reshape(b, h, sq_p)[:, :, :sq]
     return out, lse
 
@@ -925,6 +928,15 @@ def flash_attention(q, k, v, *, causal: bool = False,
     if block_len is not None:
         return _flash_forward(q, k, v, scale, True, block_q, block_k,
                               interpret, bhsd, block_len=block_len)[0]
+    if v.shape[-1] != q.shape[-1]:
+        # values of another width than queries and keys (latent
+        # attention's prefill): FORWARD ONLY, past the custom VJP, whose
+        # backward kernels take one width
+        if segment_ids is not None:
+            raise ValueError("values of another width than the keys take "
+                             "no segment ids")
+        return _flash_forward(q, k, v, scale, causal, block_q, block_k,
+                              interpret, bhsd, window)[0]
     kernel = functools.partial(
         _flash, scale=scale, causal=causal, block_q=block_q,
         block_k=block_k, interpret=interpret, bwd=bwd, bhsd=bhsd,

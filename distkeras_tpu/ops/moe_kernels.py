@@ -566,7 +566,8 @@ def grouped_tiles(assignments: int, num_experts: int,
     return int(assignments) // int(block_rows) + int(num_experts)
 
 
-def grouped_layout(flat_experts, num_experts: int, block_rows: int):
+def grouped_layout(flat_experts, num_experts: int, block_rows: int,
+                   valid=None):
     """Where each routed row goes. ``flat_experts`` [A] int32 is the
     expert of every assignment (token-major: assignment ``a`` belongs to
     token ``a // k``). Returns ``(dest [A], tile_expert [T], used,
@@ -575,16 +576,27 @@ def grouped_layout(flat_experts, num_experts: int, block_rows: int):
     repeat the last live expert, so they fetch nothing new); the number
     of live tiles; assignments per expert. No sort: the position inside
     an expert's group is an exclusive running count (the
-    ``models.moe._dispatch_plan`` construction)."""
+    ``models.moe._dispatch_plan`` construction).
+
+    ``valid`` ([A] bool; a layer that holds ``num_experts`` of the
+    experts its router chooses among): assignments whose expert is
+    here. The others carry the id ``num_experts``, take no row in the
+    layout and get a ``dest`` past its end (each its own: a scatter
+    through ``dest`` drops them, a gather has to mask them)."""
     a = flat_experts.shape[0]
     tiles = grouped_tiles(a, num_experts, block_rows)
     onehot = jax.nn.one_hot(flat_experts, num_experts, dtype=jnp.int32)
     ranks = jnp.cumsum(onehot, axis=0) - onehot
+    if valid is not None:
+        flat_experts = jnp.minimum(flat_experts, num_experts - 1)
     pos = jnp.take_along_axis(ranks, flat_experts[:, None], axis=1)[:, 0]
     counts = onehot.sum(axis=0)
     padded = -(-counts // block_rows) * block_rows
     ends = jnp.cumsum(padded)
     dest = (ends - padded)[flat_experts] + pos
+    if valid is not None:
+        dest = jnp.where(valid, dest, tiles * block_rows
+                         + jnp.arange(a, dtype=dest.dtype))
     used = ends[-1] // block_rows
     last_live = jnp.max(jnp.where(counts > 0,
                                   jnp.arange(num_experts), 0))
@@ -621,9 +633,114 @@ def _grouped_kernel(te_ref, used_ref, *refs, act_name: str, gated: bool):
         o_ref[...] = jnp.zeros_like(o_ref)
 
 
+#: the most VMEM a grouped tile's double-buffered expert blocks may take
+#: before the expert's hidden width is walked in blocks
+GROUPED_WEIGHT_VMEM = 48 << 20
+
+
+def grouped_hidden_block(d: int, f: int, gated: bool, dtype) -> int:
+    """The block of an expert's hidden width ``f`` that one program of
+    :func:`grouped_experts` holds: all of it where the expert's matrices
+    fit VMEM double-buffered (``GROUPED_WEIGHT_VMEM``), else the largest
+    multiple of 128 dividing ``f`` that does."""
+    per_column = (3 if gated else 2) * _nbytes((d,), dtype)
+    if 2 * per_column * f <= GROUPED_WEIGHT_VMEM or f % 128:
+        return f
+    fits = [b for b in range(128, f, 128)
+            if f % b == 0 and 2 * per_column * b <= GROUPED_WEIGHT_VMEM]
+    return max(fits) if fits else 128
+
+
+def _grouped_kernel_blocks(te_ref, used_ref, *refs, act_name: str,
+                           gated: bool):
+    """As ``_grouped_kernel`` with the expert's hidden width walked in
+    blocks along a second, innermost grid axis: each step adds its
+    block's share of the down-projection to a float32 accumulator, the
+    last one writes the tile."""
+    if gated:
+        x_ref, w1_ref, w3_ref, w2_ref, o_ref, acc_ref = refs
+    else:
+        x_ref, w1_ref, w2_ref, o_ref, acc_ref = refs
+        w3_ref = None
+    fi, nf = pl.program_id(1), pl.num_programs(1)
+    live = pl.program_id(0) < used_ref[0]
+
+    @pl.when(fi == 0)
+    def _init():
+        acc_ref[...] = jnp.zeros_like(acc_ref)
+
+    @pl.when(live)
+    def _compute():
+        x = x_ref[...]
+        h = get_activation(act_name)(
+            jnp.dot(x, w1_ref[0], preferred_element_type=jnp.float32))
+        if gated:
+            h = h * jnp.dot(x, w3_ref[0],
+                            preferred_element_type=jnp.float32)
+        acc_ref[...] += jnp.dot(h.astype(x.dtype), w2_ref[0],
+                                preferred_element_type=jnp.float32)
+
+    @pl.when(fi == nf - 1)
+    def _write():
+        o_ref[...] = acc_ref[...].astype(o_ref.dtype)
+
+
+def _grouped_experts_blocks(x_rows, tile_expert, used, w1, w2, w3, *,
+                            block_rows: int, block_f: int, activation: str,
+                            interpret: bool):
+    m, d = x_rows.shape
+    f = w1.shape[2]
+    tiles, nf = m // block_rows, f // block_f
+    gated = w3 is not None
+
+    def x_map(t, fi, te, used):
+        return (jnp.minimum(t, jnp.maximum(used[0] - 1, 0)), 0)
+
+    def f_of(t, fi, used):
+        # a tile past the live ones stays on the last block read: it
+        # fetches nothing new
+        return jnp.where(t < used[0], fi, nf - 1)
+
+    def up_map(t, fi, te, used):
+        return (te[t], 0, f_of(t, fi, used))
+
+    def down_map(t, fi, te, used):
+        return (te[t], f_of(t, fi, used), 0)
+
+    in_specs = [pl.BlockSpec((block_rows, d), x_map),
+                pl.BlockSpec((1, d, block_f), up_map)]
+    operands = [x_rows, w1]
+    if gated:
+        in_specs.append(pl.BlockSpec((1, d, block_f), up_map))
+        operands.append(w3)
+    in_specs.append(pl.BlockSpec((1, block_f, d), down_map))
+    operands.append(w2)
+    wdt, xdt = w1.dtype, x_rows.dtype
+    pipelined = ((3 if gated else 2) * _nbytes((d, block_f), wdt)
+                 + 2 * _nbytes((block_rows, d), xdt))
+    resident = (3 * _nbytes((block_rows, block_f), jnp.float32)
+                + 2 * _nbytes((block_rows, d), jnp.float32))
+    need = 2 * pipelined + resident + (4 << 20)
+    return pl.pallas_call(
+        functools.partial(_grouped_kernel_blocks, act_name=activation,
+                          gated=gated),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2, grid=(tiles, nf), in_specs=in_specs,
+            out_specs=pl.BlockSpec((block_rows, d),
+                                   lambda t, fi, te, used: (t, 0)),
+            scratch_shapes=[pltpu.VMEM((block_rows, d), jnp.float32)]),
+        out_shape=jax.ShapeDtypeStruct((m, d), xdt),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary", "arbitrary"),
+            vmem_limit_bytes=max(16 << 20, need)),
+        name="moe_grouped_experts", interpret=interpret,
+    )(tile_expert, jnp.reshape(used, (1,)).astype(jnp.int32), *operands)
+
+
 def grouped_experts(x_rows, tile_expert, used, w1, w2, w3=None, *,
                     block_rows: int, activation: str,
-                    interpret: Optional[bool] = None):
+                    interpret: Optional[bool] = None,
+                    block_f: Optional[int] = None):
     """``act(x w1[e]) (* x w3[e]) w2[e]`` for rows already laid out by
     :func:`grouped_layout`: ``x_rows`` [T * block_rows, d], stacked
     expert weights ``w1``/``w3`` [E, d, f], ``w2`` [E, f, d] (no
@@ -631,13 +748,24 @@ def grouped_experts(x_rows, tile_expert, used, w1, w2, w3=None, *,
     scalar-prefetched ``tile_expert``; tiles at or past ``used`` write
     zeros and read nothing new. A whole expert (three matrices) sits
     in VMEM, double-buffered: the scoped limit is stated from the
-    shapes (``_compiler_params``)."""
+    shapes (``_compiler_params``). An expert too large for that
+    (``grouped_hidden_block``; ``block_f`` overrides it) has its hidden
+    width walked in blocks by the same tile's further programs."""
     m, d = x_rows.shape
     e, _, f = w1.shape
     tiles = m // block_rows
     gated = w3 is not None
     if interpret is None:
         interpret = _FORCE_INTERPRET or not backend_is_tpu()
+    if block_f is None:
+        block_f = grouped_hidden_block(d, f, gated, w1.dtype)
+    if block_f != f:
+        if f % block_f:
+            raise ValueError(f"block_f {block_f} does not divide the "
+                             f"experts' width {f}")
+        return _grouped_experts_blocks(
+            x_rows, tile_expert, used, w1, w2, w3, block_rows=block_rows,
+            block_f=block_f, activation=activation, interpret=interpret)
 
     def x_map(t, te, used):
         return (jnp.minimum(t, jnp.maximum(used[0] - 1, 0)), 0)

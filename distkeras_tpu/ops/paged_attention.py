@@ -401,3 +401,144 @@ def paged_decode_attention(q, k_pages, v_pages, t, table, *,
       *operands)
     return out[:, :, :rows].reshape(s, hkv, w_len, g, d) \
         .transpose(0, 2, 1, 3, 4)
+
+
+# --- latent pages (MLA decode, absorbed form) --------------------------------
+#
+# A latent-attention layer caches ONE vector a token and no head axis:
+# ``[N, C, page_len]``, the latent's ``v_dim`` values and behind them the
+# shared rope key, the positions in the lanes (``models.decoding``: the
+# latent section says why). In the absorbed form every query head attends that one
+# shared key, and the value is the key's first ``v_dim`` columns: the
+# kernel reads ONE plane (storing V apart would double the bytes the layer
+# exists to save), the ``W * H`` rows of a slot are one matmul M dimension.
+
+
+def _latent_kernel(t_ref, tb_ref, q_ref, c_ref, o_ref, m_ref, l_ref,
+                   acc_ref, *, scale: float, page_len: int, heads: int,
+                   w_len: int, v_dim: int, n_pages: int):
+    si = pl.program_id(0)
+    pi = pl.program_id(1)
+    npp = pl.num_programs(1)
+    t = t_ref[si]
+    rows = q_ref.shape[1]                      # W*H, padded to % 8
+
+    @pl.when(pi == 0)
+    def _init():
+        m_ref[:] = jnp.full_like(m_ref, NEG_INF)
+        l_ref[:] = jnp.zeros_like(l_ref)
+        acc_ref[:] = jnp.zeros_like(acc_ref)
+
+    start = pi * page_len
+    run = jnp.logical_and(start <= t + (w_len - 1),
+                          tb_ref[si, pi] < n_pages)
+
+    @pl.when(run)
+    def _compute():
+        # row r is window query r // H (rows are window-major): it
+        # admits positions <= t + r // H, the chain window's mask
+        j_idx = lax.broadcasted_iota(jnp.int32, (rows, page_len), 0) // heads
+        pos = start + lax.broadcasted_iota(jnp.int32, (rows, page_len), 1)
+        q = q_ref[0]                           # [rows, C]
+        cblk = c_ref[0]                        # [C, page_len]: K^T
+        s = lax.dot_general(q, cblk, (((1,), (0,)), ((), ())),
+                            preferred_element_type=jnp.float32) * scale
+        s = jnp.where(pos <= t + j_idx, s, NEG_INF)
+        m_prev = m_ref[:]
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+        alpha = jnp.exp(m_prev - m_new)
+        p = jnp.exp(s - m_new)
+        m_ref[:] = m_new
+        l_ref[:] = l_ref[:] * alpha + jnp.sum(p, axis=-1, keepdims=True)
+        acc_ref[:] = acc_ref[:] * alpha + lax.dot_general(
+            p.astype(cblk.dtype), cblk[:v_dim], (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32)
+
+    @pl.when(pi == npp - 1)
+    def _finalize():
+        l = l_ref[:]
+        l_safe = jnp.where(l == 0.0, 1.0, l)
+        o_ref[0] = (acc_ref[:] / l_safe).astype(o_ref.dtype)
+
+
+def paged_latent_attention(q, c_pages, t, table, *, v_dim: int,
+                           scale: float,
+                           name: str = "paged_latent_attention",
+                           interpret: Optional[bool] = None):
+    """Absorbed-form latent attention straight off the latent page plane.
+
+    q: ``[S, W, H, C]`` (the queries with ``Wkvb``'s key half taken in,
+    then the roped rope part; W = 1 for plain decode); c_pages:
+    ``[N, C, page_len]``, ONE plane: down a column a token's latent
+    (``v_dim`` values) and its roped shared key behind it; t: ``[S]`` int32 window start
+    positions; table: ``[S, P]`` int32 page tables (entries >= N: the
+    unallocated sentinel, skipped). Window query ``j`` of slot ``s``
+    admits positions ``<= t[s] + j``. Returns ``[S, W, H, v_dim]``
+    float32: each row's softmax over the shared keys, times their first
+    ``v_dim`` columns. ``scale`` is the layer's (the root of the
+    NON-absorbed query/key width, not of ``C``)."""
+    s, w_len, heads, c = q.shape
+    n_pages, c2, page_len = c_pages.shape
+    if c2 != c or not 0 < v_dim <= c:
+        raise ValueError(f"queries of width {c}, plane of width {c2}, "
+                         f"values of width {v_dim}")
+    if not page_aligned(page_len):
+        raise ValueError(
+            f"page_len {page_len} is not kernel-tileable (% 8); use "
+            "paged_latent_attention_reference instead")
+    if interpret is None:
+        interpret = not backend_is_tpu()
+    rows = w_len * heads
+    qr = q.astype(c_pages.dtype).reshape(s, rows, c)
+    pad = (-rows) % 8
+    if pad:
+        qr = jnp.pad(qr, ((0, 0), (0, pad), (0, 0)))
+    rows_p = rows + pad
+
+    def q_map(si, pi, *_):
+        return (si, 0, 0)
+
+    def c_map(si, pi, t_ref, tb_ref):
+        return (jnp.minimum(tb_ref[si, pi], n_pages - 1), 0, 0)
+
+    kernel = functools.partial(
+        _latent_kernel, scale=float(scale), page_len=int(page_len),
+        heads=int(heads), w_len=int(w_len), v_dim=int(v_dim),
+        n_pages=int(n_pages))
+    out = pl.pallas_call(
+        kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2, grid=(s, table.shape[1]),
+            in_specs=[pl.BlockSpec((1, rows_p, c), q_map),
+                      pl.BlockSpec((1, c, page_len), c_map)],
+            out_specs=pl.BlockSpec((1, rows_p, v_dim), q_map),
+            scratch_shapes=[pltpu.VMEM((rows_p, 1), jnp.float32),
+                            pltpu.VMEM((rows_p, 1), jnp.float32),
+                            pltpu.VMEM((rows_p, v_dim), jnp.float32)]),
+        out_shape=jax.ShapeDtypeStruct((s, rows_p, v_dim), jnp.float32),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary")),
+        name=name, interpret=interpret,
+    )(jnp.asarray(t, jnp.int32), jnp.asarray(table, jnp.int32), qr,
+      c_pages)
+    return out[:, :rows].reshape(s, w_len, heads, v_dim)
+
+
+def paged_latent_attention_reference(q, c_pages, t, table, *, v_dim: int,
+                                     scale: float):
+    """The same function by a gather of each slot's pages into a
+    ``[S, C, P * page_len]`` view and one masked softmax: the kernel's
+    oracle and the path off a TPU. Sentinel table entries clamp to the
+    last page: garbage the ``<= t + j`` mask never admits."""
+    s, w_len, _heads, c = q.shape
+    view = c_pages[jnp.minimum(table, c_pages.shape[0] - 1)]
+    view = view.transpose(0, 2, 1, 3).reshape(s, c, -1)  # [S, C, L]
+    sc = jnp.einsum("swhc,scl->shwl", q.astype(view.dtype), view,
+                    preferred_element_type=jnp.float32) * scale
+    pos = (t[:, None] + jnp.arange(w_len))[:, None, :, None]
+    sc = jnp.where(jnp.arange(view.shape[2])[None, None, None, :] <= pos,
+                   sc, NEG_INF)
+    w = jax.nn.softmax(sc, axis=-1)
+    return jnp.einsum("shwl,svl->swhv", w.astype(view.dtype),
+                      view[:, :v_dim],
+                      preferred_element_type=jnp.float32)

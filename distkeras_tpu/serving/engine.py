@@ -476,10 +476,13 @@ class ServingEngine:
                 f"moe_decode must be 'dispatched' or 'dense', "
                 f"got {moe_decode!r}")
         self.moe_decode = moe_decode
-        #: the model's MoE MLPs (inside TransformerBlocks), in layer order
-        self._moe = [blk.mlp for blk in
+        #: the model's MoE MLPs (inside TransformerBlocks: in the MLP's
+        #: place, or a shortcut-connected one beside it), in layer order
+        self._moe = [m for blk in
                      (_decode_block_of(layer) for layer in module.layers)
-                     if blk is not None and isinstance(blk.mlp, MoE)]
+                     if blk is not None
+                     for m in (blk.mlp, blk.shortcut)
+                     if isinstance(m, MoE)]
         self._moe_dispatched = bool(self._moe) and \
             moe_decode == "dispatched"
         # expert telemetry rides only on the dispatched path (the dense
@@ -563,6 +566,15 @@ class ServingEngine:
                                 hbm_budget=hbm_budget,
                                 reserve_bytes=reserve)
         self.page_len = self.pool.page_len
+        if self.pool.latent and (
+                draft is not None or fuse_steps or ep_mesh is not None
+                or weight_quant is not None or self.block_len is not None):
+            # (host_kv_pages, hbm_budget and int8 / int4 pages: the pool
+            # has refused them already)
+            raise ValueError(
+                "latent attention is served one token a step from float "
+                "latent pages: no draft, fuse_steps, ep_mesh, "
+                "weight_quant or block diffusion")
         #: per layer ``(page group, ring)`` where the pool has a group
         #: per attention kind (``PagedKVPool.layer_groups``), else None
         self._groups = self.pool.layer_groups
@@ -1126,9 +1138,8 @@ class ServingEngine:
         if counts:
             routed, *prefills = fetched[-len(counts):]
             self.metrics.record_routing("decode", *map(int, routed))
-            for rows, touched in prefills:
-                self.metrics.record_routing("prefill", int(rows),
-                                            int(touched))
+            for counts in prefills:
+                self.metrics.record_routing("prefill", *map(int, counts))
         if p.keys is not None:
             # chain-live slots take the program's post-split keys; a
             # slot the host overrode since launch (fresh admission)
@@ -1195,7 +1206,7 @@ class ServingEngine:
             if po > so or pr > sr:
                 m.record_offload(po - so, pr - sr, ob - sb)
                 self._off_seen = (po, pr, ob)
-            if self.pool.aux:
+            if self.pool.aux or self.pool.latent:
                 m.record_kv_groups(self._kv_groups())
             if self.prefix is not None:
                 now = (self.prefix.evictions,
@@ -1233,14 +1244,15 @@ class ServingEngine:
             self._trace_spec = {}
 
     def _kv_groups(self) -> Dict[str, Dict]:
-        """Per page group (``"full"``, then each window group by its
+        """Per page group (``"full"``, or ``"latent"`` where the pool's
+        planes hold one latent a token; then each window group by its
         name): pages in all, free, held by slots (``pages_live``),
         held more than once (``pages_shared``), given back by slots
         behind their window since the engine began
         (``pages_released``; the full group gives none back) and by
         the prefix cache under that group's pressure."""
         pool = self.pool
-        out = {"full": {
+        out = {"latent" if pool.latent else "full": {
             "window": None, "pages_total": pool.num_pages,
             "pages_free": pool.free_pages,
             "pages_live": int((pool.tables < pool.num_pages).sum()),
@@ -2938,7 +2950,7 @@ class ServingEngine:
                 "free": pool.host_free_pages,
                 "offloaded": pool.pages_offloaded,
                 "restored": pool.pages_restored})}
-        if pool.aux:
+        if pool.aux or pool.latent:
             out["kv_groups"] = self._kv_groups()
         out["prefix_cache"] = (
             None if self.prefix is None else {

@@ -29,6 +29,14 @@ The pool composes with the int8 quantized cache (``dtype="int8"``):
 payload and per-token-per-head scale planes share the page tables and
 move together through every insert/load/gather program.
 
+LATENT PAGES: a model of latent-attention layers
+(``models.attention.LatentAttention``) keeps ONE vector a token and
+layer, the latent and behind it the roped shared key: its planes are
+``[num_pages, latent_dim, page_len]``, no head axis and no separate V,
+the positions last (``pool.latent``; ``models.decoding`` says why). Tables, refcounts, the prefix cache, inserts and
+loads are the pool's as ever; there is no host tier, no quantized page
+and no byte budget for them yet.
+
 PAGE GROUPS BY ATTENTION KIND: a model whose layers are not all of one
 kind (some attend to the whole context, some to a sliding window) gets
 one group of pages per kind. The layers without a window are the pool
@@ -71,6 +79,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from distkeras_tpu.models.attention import LatentAttention
 from distkeras_tpu.models.decoding import (_decode_block_of, init_cache,
                                            pack_int4, unpack_int4)
 
@@ -93,7 +102,13 @@ def _write_pages(pool, staging, table, groups=None):
     leaf passes through aliased); ``staging`` lives on. ``groups`` (a
     pool with page groups: per layer its group's index) makes ``table``
     the tuple of one such vector per group."""
-    def write(pl, st, packed, table):
+    def write(pl, st, packed, table, latent=False):
+        if latent:
+            # a latent plane [N, C, page_len] has no head axis: staging
+            # [1, C, s_max] is its pages side by side
+            c, page_len = pl.shape[1:]
+            pages = st.reshape(c, -1, page_len).transpose(1, 0, 2)
+            return pl.at[table].set(pages.astype(pl.dtype), mode="drop")
         page_len = 2 * pl.shape[2] if packed else pl.shape[2]
         if st.ndim == 4:
             _, h, s_max, d = st.shape
@@ -115,7 +130,8 @@ def _write_pages(pool, staging, table, groups=None):
         tbl = table if groups is None else table[groups[i]]
         out.append({
             key: pl if key == "q4"
-            else write(pl, st_kv[key], q4 and key in ("k", "v"), tbl)
+            else write(pl, st_kv[key], q4 and key in ("k", "v"), tbl,
+                       key == "c")
             for key, pl in pl_kv.items()})
     return out
 
@@ -148,7 +164,13 @@ def _load_pages(staging, pool, table, valid, groups=None):
     remaining prefill chunks attend to. ``staging`` is donated, the
     pool never. ``groups`` as in ``_write_pages``: ``table`` and
     ``valid`` are then tuples, one vector a group."""
-    def load(st, pl, packed, table, valid):
+    def load(st, pl, packed, table, valid, latent=False):
+        if latent:
+            c, page_len = pl.shape[1:]
+            cur = st.reshape(c, -1, page_len).transpose(1, 0, 2)
+            sel = jnp.where(valid[:, None, None],
+                            pl[table].astype(cur.dtype), cur)
+            return sel.transpose(1, 0, 2).reshape(st.shape)
         g = pl[table]                        # [P, H, page_len(/2), D?]
         if packed:
             g = unpack_int4(g)               # [P, H, page_len, D]
@@ -175,7 +197,8 @@ def _load_pages(staging, pool, table, valid, groups=None):
             else (table[groups[i]], valid[groups[i]])
         out.append({
             key: st if key == "q4"
-            else load(st, pl_kv[key], q4 and key in ("k", "v"), tbl, ok)
+            else load(st, pl_kv[key], q4 and key in ("k", "v"), tbl, ok,
+                      key == "c")
             for key, st in st_kv.items()})
     return out
 
@@ -390,11 +413,24 @@ class PagedKVPool(_PageBook):
         # page groups by attention kind (module doc): the layers' windows
         # decide. One kind: this pool alone, as ever. Several: the
         # layers without a window are this pool, each window a group
-        windows = {}
+        windows, kinds = {}, set()
         for i, layer in enumerate(module.layers):
             blk = _decode_block_of(layer)
             if blk is not None:
                 windows.setdefault(blk.attn.attn_window, []).append(i)
+                kinds.add(isinstance(blk.attn, LatentAttention))
+        #: the pool's layers are LATENT attention: the planes are
+        #: ``[N, latent_dim, page_len]``, one vector a token (module doc)
+        if len(kinds) > 1:
+            raise ValueError(
+                "latent attention layers and layers that keep K and V a "
+                "head side by side are not served from one pool")
+        self.latent = kinds == {True}
+        if self.latent and (host_pages or hbm_budget is not None
+                            or isinstance(dtype, str)):
+            raise ValueError(
+                "latent pages are served without host_kv_pages, "
+                "hbm_budget or int8 / int4 pages")
         self.aux: List[WindowPages] = []
         #: per layer ``(group index, ring)`` (None: no attention), or
         #: None for a pool of one group: ``ring`` is the logical pages a

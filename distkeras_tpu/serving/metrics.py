@@ -197,6 +197,12 @@ class ServingMetrics:
             "serving.moe_rows_routed")
         self._routed_experts = self.registry.counter(
             "serving.moe_experts_touched")
+        # the programs that reported, and a held share's rows by where
+        # they went (held / absent / zero)
+        self._routed_programs = self.registry.counter(
+            "serving.moe_programs_counted")
+        self._routed_split = self.registry.counter(
+            "serving.moe_rows_by_share")
         # page groups by attention kind (a model with window and full
         # layers): per group, gauges of its pages (free, held by slots,
         # shared) and what slots gave back behind their window; None
@@ -404,13 +410,23 @@ class ServingMetrics:
         self._bd_experts.inc(int(experts_touched), kind="prefill")
 
     def record_routing(self, kind: str, rows_routed: int,
-                       experts_touched: int) -> None:
+                       experts_touched: int, rows_held: int = 0,
+                       rows_absent: int = 0, rows_zero: int = 0) -> None:
         """What the expert layers of one one-token program did
         (``kind``: ``"decode"`` or ``"prefill"``): the rows they routed
         and the experts that owned at least one, summed over the
-        expert layers, as the program returned them."""
+        expert layers, as the program returned them. Layers that hold a
+        share of their experts (``MoE(experts_held=)``) split the rows
+        besides: to experts held here, to experts that are not here
+        (their part is left out), to identity experts; a layer that
+        holds all its experts reports none of the three."""
         self._routed_rows.inc(int(rows_routed), kind=kind)
         self._routed_experts.inc(int(experts_touched), kind=kind)
+        self._routed_programs.inc(1, kind=kind)
+        for where, n in (("held", rows_held), ("absent", rows_absent),
+                         ("zero", rows_zero)):
+            if n:
+                self._routed_split.inc(int(n), kind=kind, where=where)
 
     def record_kv_groups(self, groups: Dict[str, Dict]) -> None:
         """The page groups' state at this flush (``ServingEngine
@@ -430,10 +446,17 @@ class ServingMetrics:
             return None
         touched = {k: int(self._routed_experts.value(kind=k))
                    for k in ("decode", "prefill")}
-        return {"rows_routed": rows["decode"],
-                "experts_touched": touched["decode"],
-                "prefill_rows_routed": rows["prefill"],
-                "prefill_experts_touched": touched["prefill"]}
+        out = {"rows_routed": rows["decode"],
+               "experts_touched": touched["decode"],
+               "prefill_rows_routed": rows["prefill"],
+               "prefill_experts_touched": touched["prefill"],
+               "decode_programs": int(
+                   self._routed_programs.value(kind="decode"))}
+        for where in ("held", "absent", "zero"):
+            for kind, prefix in (("decode", ""), ("prefill", "prefill_")):
+                out[f"{prefix}rows_{where}"] = int(
+                    self._routed_split.value(kind=kind, where=where))
+        return out
 
     def record_block_commit(self, n_tokens: int) -> None:
         """One whole block became visible to its client: ``n_tokens``

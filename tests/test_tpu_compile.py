@@ -658,3 +658,156 @@ def test_decode_step_over_two_page_groups(one_chip, as_tpu):
     assert not moved, moved[:2]
     # the pool is 4.75 GB where one group for all five layers is 10.7
     assert compiled.memory_analysis().alias_size_in_bytes < 4.8e9
+
+
+# --- latent attention and a held share of experts (LongCat-Flash's widths) ---
+
+def test_paged_latent_attention_longcat_widths(one_chip, as_tpu):
+    """The latent kernel as a decode step calls it: 32 slots of 16,384
+    positions in pages of 128, 64 query heads over ONE plane of 576
+    values a token (down a column: the positions are the lanes) whose
+    first 512 are the value."""
+    from distkeras_tpu.ops.paged_attention import paged_latent_attention
+    s = _spec(one_chip)
+    slots, heads, c, page_len = 32, 64, 576, 128
+    fn = lambda q, pages, t, tb: paged_latent_attention(
+        q, pages, t, tb, v_dim=512, scale=192 ** -0.5)
+    n, text = _compile(fn, s((slots, 1, heads, c), jnp.bfloat16),
+                       s((3072, c, page_len), jnp.bfloat16),
+                       s((slots,), jnp.int32), s((slots, 128), jnp.int32))
+    assert n == 1 and "paged_latent_attention" in text
+
+
+@pytest.mark.parametrize("keys,causal", [(2048, True), (12288, False)],
+                         ids=["chunk", "prefix"])
+def test_flash_prefill_two_widths(one_chip, as_tpu, keys, causal):
+    """Flash forward with queries and keys of 192 and values of 128: a
+    prefill chunk of 2,048 on itself, and over a cached prefix of 12,288
+    rebuilt from the latent (head-major, with its log-sum-exp)."""
+    from distkeras_tpu.models.decoding import _attn_lse
+    s = _spec(one_chip)
+    fn = lambda q, k, v: _attn_lse(q, k, v, causal=causal,
+                                   scale=192 ** -0.5, layout="bhsd")
+    n, _ = _compile(fn, s((1, 64, 2048, 192), jnp.bfloat16),
+                    s((1, 64, keys, 192), jnp.bfloat16),
+                    s((1, 64, keys, 128), jnp.bfloat16))
+    assert n == 1
+
+
+@pytest.mark.parametrize("tokens", [32, 2048], ids=["step", "prefill_chunk"])
+def test_grouped_experts_held_share(one_chip, as_tpu, tokens):
+    """Top-12 of 768 router outputs with 16 experts of width 2048 held:
+    the layout's tiles as tall as the router's mean group, one tile an
+    expert past the assignments' worst case."""
+    from distkeras_tpu.ops.moe_kernels import (grouped_block_rows,
+                                               grouped_experts,
+                                               grouped_tiles)
+    s = _spec(one_chip)
+    e, d, f, a = 16, 6144, 2048, tokens * 12
+    rows = grouped_block_rows(a, 768)
+    tiles = grouped_tiles(a, e, rows)
+    assert rows == (16 if tokens == 32 else 32)
+    fn = lambda x, te, used, w1, w2, w3: grouped_experts(
+        x, te, used, w1, w2, w3, block_rows=rows, activation="silu")
+    n, _ = _compile(fn, s((tiles * rows, d), jnp.bfloat16),
+                    s((tiles,), jnp.int32), s((), jnp.int32),
+                    s((e, d, f), jnp.bfloat16), s((e, f, d), jnp.bfloat16),
+                    s((e, d, f), jnp.bfloat16))
+    assert n == 1
+
+
+def _longcat_module(layers):
+    from distkeras_tpu.models import zoo
+    latent = dict(q_lora_rank=1536, kv_lora_rank=512, qk_nope_head_dim=128,
+                  qk_rope_head_dim=64, v_head_dim=128, q_scale=2.0,
+                  kv_scale=12 ** 0.5)
+    return zoo.transformer_lm(
+        16384, d_model=6144, num_heads=64, num_layers=layers, max_len=16384,
+        dtype="bfloat16", norm_eps=1e-5, layer_types=["mla"] * layers,
+        attn_kinds={"mla": {"rope_base": 1e7, "latent": latent}},
+        mlp_layer_types=["shortcut"] * layers, dense_mlp_dim=12288,
+        mlp_dim=2048, mlp_activation="silu", mlp_gated=True, mlp_bias=False,
+        num_experts=512, moe_top_k=12, moe_dispatch="grouped",
+        moe_norm_topk=False, moe_route_scale=6.0, moe_zero_experts=256,
+        moe_experts_held=(0, 16), moe_select_bias=True)
+
+
+def _on_chip(tree, s, dtype=None):
+    return jax.tree_util.tree_map(
+        lambda a: s(a.shape, dtype if dtype is not None and a.ndim >= 2
+                    else a.dtype), tree)
+
+
+def test_decode_step_over_latent_pages(one_chip, as_tpu):
+    """One decode step of two double layers at the cell's widths over a
+    donated pool of latent pages: four latent kernels and two grouped
+    expert kernels, every plane aliased and none copied, the planes one
+    vector of 576 a token with no head axis."""
+    import re
+    from distkeras_tpu.models.decoding import (decode_step_slots_paged,
+                                               init_cache)
+    module = _longcat_module(2)
+    params, state = jax.eval_shape(
+        lambda k: module.init(k, (16,))[:2], jax.random.PRNGKey(0))
+    slots, page_len, pages, s = 32, 128, 3072, _spec(one_chip)
+    probe = jax.eval_shape(lambda: init_cache(module, pages, page_len,
+                                              jnp.bfloat16, check_len=16384))
+    cache = [None if kv is None else
+             {k: s(a.shape, a.dtype) for k, a in kv.items()} for kv in probe]
+    assert {a.shape for kv in cache if kv for a in kv.values()} \
+        == {(pages, 576, page_len)}
+
+    def step(params, state, cache, tok, t, tables):
+        logits, cache, moe = decode_step_slots_paged(
+            module, params, state, cache, tok, t, tables, page_len,
+            moe_stats=16384)
+        return jnp.argmax(logits, -1), cache, moe["routed"]
+
+    per_slot = s((slots,), jnp.int32)
+    text = jax.jit(step, donate_argnums=2).lower(
+        _on_chip(params, s, jnp.bfloat16), _on_chip(state, s), cache,
+        per_slot, per_slot, s((slots, 128), jnp.int32)).compile().as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 6
+    assert len(re.findall(r"paged_latent_attention", text)) >= 4
+    leaves = jax.tree_util.tree_leaves(cache)
+    assert text.split("\n", 1)[0].count("may-alias") == len(leaves)
+    plane = pages * page_len * 576
+    moved = [line.strip()[:160] for line in text.splitlines()
+             if (dims := re.search(r"= \w+\[([\d,]+)\]\S* copy\(", line))
+             and int(np.prod(list(map(int, dims.group(1).split(","))))) == plane]
+    assert not moved, moved[:2]
+
+
+@pytest.mark.parametrize("t0,final", [(0, False), (12288, True)],
+                         ids=["cold_chunk", "question_over_prefix"])
+def test_prefill_chunk_over_latent_prefix(one_chip, as_tpu, t0, final):
+    """A prefill chunk of 2,048 through one double layer at the cell's
+    widths against the batch-1 staging cache: the first of a cold document
+    (one flash pass a block) and a question over a cached prefix of 12,288
+    latents (two a block, merged), with what the expert layer routed."""
+    from distkeras_tpu.models.decoding import (init_cache,
+                                               prefill_chunk_step,
+                                               routing_counts)
+    module = _longcat_module(1)
+    params, state = jax.eval_shape(
+        lambda k: module.init(k, (16,))[:2], jax.random.PRNGKey(0))
+    s = _spec(one_chip)
+    cache = _on_chip(jax.eval_shape(
+        lambda: init_cache(module, 1, 16384, jnp.bfloat16)), s)
+
+    def chunk(params, state, cache, toks):
+        routing = []
+        logits, cache = prefill_chunk_step(module, params, state, cache,
+                                           toks, t0, final=final,
+                                           routing=routing)
+        return logits, cache, routing_counts(routing)
+
+    text = jax.jit(chunk, donate_argnums=2).lower(
+        _on_chip(params, s, jnp.bfloat16), _on_chip(state, s), cache,
+        s((1, 2048), jnp.int32)).compile().as_text()
+    # flash: one a block, two over a prefix. The last block of a chunk that
+    # is not the final one only writes its latents, and nothing reads what
+    # the expert layer before it hands on: its kernel is not in the program
+    flash = (2 if t0 else 1) * (2 if final else 1)
+    assert text.count('custom_call_target="tpu_custom_call"') \
+        == flash + (1 if final else 0)
