@@ -76,7 +76,7 @@ from jax.experimental import pallas as pl
 
 from jax.experimental.pallas import tpu as pltpu
 
-from distkeras_tpu.compat import backend_is_tpu
+from distkeras_tpu.compat import backend_is_tpu, note_path
 from distkeras_tpu.ops.attention import NEG_INF
 
 
@@ -411,36 +411,82 @@ def paged_decode_attention(q, k_pages, v_pages, t, table, *,
 # latent section says why). In the absorbed form every query head attends that one
 # shared key, and the value is the key's first ``v_dim`` columns: the
 # kernel reads ONE plane (storing V apart would double the bytes the layer
-# exists to save), the ``W * H`` rows of a slot are one matmul M dimension.
+# exists to save), the ``W * H`` rows of a slot are one matmul M dimension,
+# and with the positions in the lanes G pages side by side ARE the ``K^T``
+# of ``G * page_len`` positions: a program takes a block of pages, not one.
 
 
-def _latent_kernel(t_ref, tb_ref, q_ref, c_ref, o_ref, m_ref, l_ref,
-                   acc_ref, *, scale: float, page_len: int, heads: int,
-                   w_len: int, v_dim: int, n_pages: int):
+#: positions a program of the latent kernel walks: a grid step costs as much
+#: as a page of 128 positions does in bytes, so a program takes as many
+#: consecutive pages of its slot as make about this many
+_LATENT_POSITIONS = 1024
+#: what a program's buffers may take of the compiler's scoped VMEM (16 MiB
+#: on a v5e): half, the rest is the compiler's own temporaries
+_LATENT_VMEM = 8 * 2 ** 20
+
+
+def _latent_vmem_bytes(g: int, page_len: int, c: int, rows: int, v_dim: int,
+                       itemsize: int) -> int:
+    """VMEM of one program over ``g`` pages: every page double-buffered
+    (a page narrower than the 128 lanes is padded to them), the queries
+    and the output double-buffered, the float32 state, and the block's
+    scores and probabilities in float32 with the probabilities' cast."""
+    lanes = pl.cdiv(page_len, 128) * 128
+    span = pl.cdiv(g * page_len, 128) * 128
+    return (2 * g * c * lanes * itemsize + 2 * rows * c * itemsize
+            + 3 * rows * v_dim * 4 + rows * span * (8 + itemsize))
+
+
+def latent_pages_per_program(page_len: int, n_logical: int, c: int,
+                             rows: int, v_dim: int, dtype) -> int:
+    """G, the consecutive logical pages of a slot that one program of
+    :func:`paged_latent_attention` reads: as many as make about 1,024
+    positions (8 pages of 128), no more than the table is wide, and no more
+    than fit ``_LATENT_VMEM`` at these widths. From what the kernel is
+    handed alone: nothing chooses it from outside."""
+    itemsize = jnp.dtype(dtype).itemsize
+    g = max(1, min(_LATENT_POSITIONS // page_len, n_logical))
+    while g > 1 and _latent_vmem_bytes(g, page_len, c, rows, v_dim,
+                                       itemsize) > _LATENT_VMEM:
+        g -= 1
+    return g
+
+
+def _latent_kernel(t_ref, tb_ref, q_ref, *refs, scale: float, page_len: int,
+                   heads: int, w_len: int, v_dim: int, n_pages: int, g: int):
+    c_refs = refs[:g]
+    o_ref, m_ref, l_ref, acc_ref = refs[g:]
     si = pl.program_id(0)
-    pi = pl.program_id(1)
-    npp = pl.num_programs(1)
+    bi = pl.program_id(1)
+    nb = pl.num_programs(1)
     t = t_ref[si]
     rows = q_ref.shape[1]                      # W*H, padded to % 8
+    span = g * page_len
 
-    @pl.when(pi == 0)
+    @pl.when(bi == 0)
     def _init():
         m_ref[:] = jnp.full_like(m_ref, NEG_INF)
         l_ref[:] = jnp.zeros_like(l_ref)
         acc_ref[:] = jnp.zeros_like(acc_ref)
 
-    start = pi * page_len
+    # a block takes part iff its first page does: it holds a position some
+    # window query admits, and the table holds it (a slot's pages are
+    # allocated from the front, so no page behind a sentinel is live)
+    start = bi * span
     run = jnp.logical_and(start <= t + (w_len - 1),
-                          tb_ref[si, pi] < n_pages)
+                          tb_ref[si, bi * g] < n_pages)
 
     @pl.when(run)
     def _compute():
         # row r is window query r // H (rows are window-major): it
-        # admits positions <= t + r // H, the chain window's mask
-        j_idx = lax.broadcasted_iota(jnp.int32, (rows, page_len), 0) // heads
-        pos = start + lax.broadcasted_iota(jnp.int32, (rows, page_len), 1)
+        # admits positions <= t + r // H, the chain window's mask, which
+        # also masks the block's dead pages: they lie past the slot's
+        # depth, and their buffers hold whatever was read last
+        j_idx = lax.broadcasted_iota(jnp.int32, (rows, span), 0) // heads
+        pos = start + lax.broadcasted_iota(jnp.int32, (rows, span), 1)
         q = q_ref[0]                           # [rows, C]
-        cblk = c_ref[0]                        # [C, page_len]: K^T
+        # the block's pages side by side, positions in the lanes: K^T
+        cblk = jnp.concatenate([r[0] for r in c_refs], axis=1)
         s = lax.dot_general(q, cblk, (((1,), (0,)), ((), ())),
                             preferred_element_type=jnp.float32) * scale
         s = jnp.where(pos <= t + j_idx, s, NEG_INF)
@@ -454,7 +500,7 @@ def _latent_kernel(t_ref, tb_ref, q_ref, c_ref, o_ref, m_ref, l_ref,
             p.astype(cblk.dtype), cblk[:v_dim], (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32)
 
-    @pl.when(pi == npp - 1)
+    @pl.when(bi == nb - 1)
     def _finalize():
         l = l_ref[:]
         l_safe = jnp.where(l == 0.0, 1.0, l)
@@ -476,7 +522,18 @@ def paged_latent_attention(q, c_pages, t, table, *, v_dim: int,
     admits positions ``<= t[s] + j``. Returns ``[S, W, H, v_dim]``
     float32: each row's softmax over the shared keys, times their first
     ``v_dim`` columns. ``scale`` is the layer's (the root of the
-    NON-absorbed query/key width, not of ``C``)."""
+    NON-absorbed query/key width, not of ``C``).
+
+    The grid is ``(S, ceil(P / G))``: one program reads a BLOCK of G
+    consecutive logical pages of its slot (:func:`latent_pages_per_program`),
+    each through the page table by an index map of its own, and takes the
+    block's ``G * page_len`` positions through one score product, one
+    softmax update and one value product. A block whose first page is dead
+    (past the slot's depth, or a sentinel) is skipped; a dead page inside a
+    live block is not read either (its index repeats the one its buffer
+    holds) and its positions are masked. A slot's pages are taken to be
+    allocated from the front: a sentinel below its depth is no more
+    defined here than in the reference, which reads the last page there."""
     s, w_len, heads, c = q.shape
     n_pages, c2, page_len = c_pages.shape
     if c2 != c or not 0 < v_dim <= c:
@@ -494,23 +551,39 @@ def paged_latent_attention(q, c_pages, t, table, *, v_dim: int,
     if pad:
         qr = jnp.pad(qr, ((0, 0), (0, pad), (0, 0)))
     rows_p = rows + pad
+    g = latent_pages_per_program(page_len, table.shape[1], c, rows_p, v_dim,
+                                 c_pages.dtype)
+    note_path("latent_pages_per_program", str(g))
+    n_blocks = pl.cdiv(table.shape[1], g)
+    table = jnp.asarray(table, jnp.int32)
+    if n_blocks * g != table.shape[1]:
+        # whole blocks: the columns past the table are sentinels
+        table = jnp.pad(table, ((0, 0), (0, n_blocks * g - table.shape[1])),
+                        constant_values=n_pages)
 
-    def q_map(si, pi, *_):
+    def q_map(si, bi, *_):
         return (si, 0, 0)
 
-    def c_map(si, pi, t_ref, tb_ref):
-        return (jnp.minimum(tb_ref[si, pi], n_pages - 1), 0, 0)
+    def c_map(j):
+        def index(si, bi, t_ref, tb_ref):
+            # THE page-table indirection, page j of block bi. Past the
+            # slot's top page the column stays in the last block that has
+            # a live page j: the index repeats and nothing is fetched
+            top = (t_ref[si] + (w_len - 1)) // page_len
+            blk = jnp.minimum(bi, lax.div(jnp.maximum(top - j, 0), g))
+            return (jnp.minimum(tb_ref[si, blk * g + j], n_pages - 1), 0, 0)
+        return index
 
     kernel = functools.partial(
         _latent_kernel, scale=float(scale), page_len=int(page_len),
         heads=int(heads), w_len=int(w_len), v_dim=int(v_dim),
-        n_pages=int(n_pages))
+        n_pages=int(n_pages), g=g)
     out = pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=2, grid=(s, table.shape[1]),
-            in_specs=[pl.BlockSpec((1, rows_p, c), q_map),
-                      pl.BlockSpec((1, c, page_len), c_map)],
+            num_scalar_prefetch=2, grid=(s, n_blocks),
+            in_specs=[pl.BlockSpec((1, rows_p, c), q_map)]
+            + [pl.BlockSpec((1, c, page_len), c_map(j)) for j in range(g)],
             out_specs=pl.BlockSpec((1, rows_p, v_dim), q_map),
             scratch_shapes=[pltpu.VMEM((rows_p, 1), jnp.float32),
                             pltpu.VMEM((rows_p, 1), jnp.float32),
@@ -519,8 +592,7 @@ def paged_latent_attention(q, c_pages, t, table, *, v_dim: int,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         name=name, interpret=interpret,
-    )(jnp.asarray(t, jnp.int32), jnp.asarray(table, jnp.int32), qr,
-      c_pages)
+    )(jnp.asarray(t, jnp.int32), table, qr, *([c_pages] * g))
     return out[:, :rows].reshape(s, w_len, heads, v_dim)
 
 

@@ -161,6 +161,9 @@ def test_prefill_then_decode_equals_the_reference(model, ref_weights, kernel):
     path = "kernel" if kernel == "paged" else "gather_reference"
     health = eng.health()
     assert f"paged_attention={path}" in health["programs"]["decode_greedy"]
+    # 256 positions in pages of 8: the kernel reads a slot's row in one program
+    assert ("latent_pages_per_program=32"
+            in health["programs"]["decode_greedy"]) == (kernel == "paged")
     assert list(health["kv_groups"]) == ["latent"]
     planes = {kv["c"].shape for kv in eng.pool.cache if kv is not None}
     assert planes == {(eng.pool.num_pages, S["latent"], 8)}
@@ -389,21 +392,95 @@ def test_grouped_experts_walks_a_wide_expert_in_blocks():
 
 # --- kernels ---------------------------------------------------------------------
 
+_SENT = 48          # the table's sentinel: as many pages as the plane has
+
+#: name -> (page_len, table width P, t a slot, pages a slot holds from the
+#: front; the rest of its row is the sentinel). At ``page_len`` 128 a program
+#: reads G = 8 pages, so P = 11 is a block and three pages of a second one;
+#: at 8 the whole row is one program (G = P).
+_KERNEL_CASES = {
+    # the first test of this kernel: scrambled pages, slots at three depths
+    "one_program_a_slot": (8, 4, [5, 17, 28], [2, 3, 4]),
+    "table_wider_than_a_block": (8, 11, [5, 60, 85], [1, 8, 11]),
+    # P = 11 is no multiple of G = 8: the second block's last five columns
+    # are the wrapper's own sentinels
+    "table_not_whole_blocks": (128, 11, [200, 1100, 1400], [2, 9, 11]),
+    # t in the first page of the second block: its other pages are dead
+    "first_page_of_a_block": (128, 11, [1024 + 5, 1024, 3], [9, 9, 1]),
+    # t on a page's last position and on the next page's first, inside a
+    # block and across two
+    "page_edges": (128, 11, [383, 384, 1023, 1024], [4, 4, 9, 9]),
+    # allocated pages BEHIND a sentinel in a live block (a row the pool
+    # never writes): past the slot's depth, never read
+    "sentinel_in_a_live_block": (128, 11, [300, 700], [-3, -6]),
+    # a free slot as the engine parks it (t at max_len, the row all
+    # sentinels) between two live ones: zeros, no page read
+    "slot_with_no_live_page": (128, 11, [130, 11 * 128, 1300], [2, 0, 11]),
+}
+
+
 @pytest.mark.parametrize("w_len", [1, 3])
-def test_paged_latent_attention_equals_its_gather_reference(w_len):
-    """Scrambled page order, a sentinel page, slots at different depths."""
+@pytest.mark.parametrize("case", sorted(_KERNEL_CASES))
+def test_paged_latent_attention_equals_its_gather_reference(case, w_len):
+    """The kernel in interpreter mode against the gather reference, over
+    the shapes a block of G pages a program brings."""
+    page_len, width, depths, held = _KERNEL_CASES[case]
     k = jax.random.PRNGKey(0)
-    s, h, c, v, n, pl = 3, 4, 24, 16, 10, 8
+    h, c, v = 4, 24, 16
+    s = len(depths)
     q = jax.random.normal(k, (s, w_len, h, c))
-    pages = jax.random.normal(jax.random.fold_in(k, 1), (n, c, pl))
-    t = jnp.array([5, 17, 28])
-    table = jnp.array([[3, 1, 10, 10], [0, 2, 4, 10], [9, 8, 7, 6]])
-    got = paged_latent_attention(q, pages, t, table, v_dim=v, scale=0.3,
-                                 interpret=True)
-    want = paged_latent_attention_reference(q, pages, t, table, v_dim=v,
-                                            scale=0.3)
+    pages = jax.random.normal(jax.random.fold_in(k, 1), (_SENT, c, page_len))
+    order = np.random.default_rng(3).permutation(_SENT)
+    table = np.full((s, width), _SENT, np.int32)
+    for i, n in enumerate(held):
+        row = order[i * width:i * width + width]
+        if n < 0:       # -n pages from the front, a sentinel, pages behind
+            table[i] = row
+            table[i, -n] = _SENT
+        else:
+            table[i, :n] = row[:n]
+    t = jnp.array(depths, jnp.int32)
+    # every window query's position lies in a page the slot holds
+    assert all(n <= 0 or (d + w_len - 1) // page_len < n
+               for d, n in zip(depths, held))
+    got = paged_latent_attention(q, pages, t, jnp.asarray(table), v_dim=v,
+                                 scale=0.3, interpret=True)
+    want = paged_latent_attention_reference(q, pages, t, jnp.asarray(table),
+                                            v_dim=v, scale=0.3)
     assert got.shape == (s, w_len, h, v)
-    assert float(jnp.abs(got - want).max()) < 1e-5
+    live = np.array([n != 0 for n in held])
+    assert float(jnp.abs(got - want)[live].max()) < 1e-5
+    assert not np.asarray(got)[~live].any()
+
+
+def test_latent_pages_per_program_follows_what_the_kernel_is_handed():
+    """G is about 1,024 positions a program, no more than the table is wide
+    and no more than fits the VMEM budget at these widths: the cell's shape
+    reads 8 pages a program, the rehearsal's one program a slot."""
+    from distkeras_tpu.compat import record_paths
+    from distkeras_tpu.ops.paged_attention import latent_pages_per_program
+    bf16 = jnp.bfloat16
+    # the cell: 64 heads, C 576, pages of 128, a table of 128 columns
+    assert latent_pages_per_program(128, 128, 576, 64, 512, bf16) == 8
+    # its CPU rehearsal (4 heads, C 16, float32, pages of 8, 32 columns)
+    # and this file's tiny shapes: the whole row, one program a slot
+    assert latent_pages_per_program(8, 32, 16, 8, 8, jnp.float32) == 32
+    assert latent_pages_per_program(8, 4, 24, 8, 16, jnp.float32) == 4
+    # pages of 1,024 and more: one a program
+    assert latent_pages_per_program(2048, 8, 576, 64, 512, bf16) == 1
+    # narrow pages are padded to the 128 lanes in VMEM: the budget, not
+    # the positions, bounds them, as it does a wide float32 plane's pages
+    assert latent_pages_per_program(16, 1024, 576, 64, 512, bf16) == 25
+    assert latent_pages_per_program(128, 128, 2048, 64, 512,
+                                    jnp.float32) == 3
+    # a verify window of 3 x 64 rows still reads 8 pages of 128
+    assert latent_pages_per_program(128, 128, 576, 192, 512, bf16) == 8
+    with record_paths() as paths:
+        paged_latent_attention(
+            jnp.zeros((2, 1, 4, 24)), jnp.zeros((6, 24, 128)),
+            jnp.array([0, 130]), jnp.array([[0, 6, 6], [1, 2, 6]]),
+            v_dim=16, scale=1.0, interpret=True)
+    assert paths == {"latent_pages_per_program=3"}
 
 
 @pytest.mark.parametrize("causal", [True, False])

@@ -662,12 +662,37 @@ def test_decode_step_over_two_page_groups(one_chip, as_tpu):
 
 # --- latent attention and a held share of experts (LongCat-Flash's widths) ---
 
+def _kernel_grid_and_vmem(text, name):
+    """From a compiled program's text, the grid of the Mosaic kernel
+    ``name`` (the ``iteration_bounds`` of the module the custom call
+    carries) and the scoped VMEM the compiler gave it, in bytes."""
+    import base64
+    import re
+    from jax._src.interpreters import mlir as jax_mlir
+    from jax._src.lib.mlir import ir
+    call, = [line for line in text.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in line
+             and f" %{name}" in line.split(" = ", 1)[0]]
+    body = base64.b64decode(re.search(r'"body":"([^"]+)"', call).group(1))
+    ctx = jax_mlir.make_ir_context()
+    ctx.allow_unregistered_dialects = True
+    with ctx:
+        module = str(ir.Module.parse(body))
+    grid = re.search(r"iteration_bounds = array<i64: ([\d, ]+)>", module)
+    vmem = re.search(r'"used_scoped_memory_configs":\[\{"memory_space":"1",'
+                     r'"offset":"0","size":"(\d+)"\}\]', call)
+    return tuple(map(int, grid.group(1).split(","))), int(vmem.group(1))
+
+
 def test_paged_latent_attention_longcat_widths(one_chip, as_tpu):
     """The latent kernel as a decode step calls it: 32 slots of 16,384
     positions in pages of 128, 64 query heads over ONE plane of 576
     values a token (down a column: the positions are the lanes) whose
-    first 512 are the value."""
-    from distkeras_tpu.ops.paged_attention import paged_latent_attention
+    first 512 are the value. A program reads G = 8 pages: an eighth of
+    the (slot, page) pairs in the grid, inside the compiler's scoped
+    VMEM (16 MiB on a v5e)."""
+    from distkeras_tpu.ops.paged_attention import (latent_pages_per_program,
+                                                   paged_latent_attention)
     s = _spec(one_chip)
     slots, heads, c, page_len = 32, 64, 576, 128
     fn = lambda q, pages, t, tb: paged_latent_attention(
@@ -676,6 +701,10 @@ def test_paged_latent_attention_longcat_widths(one_chip, as_tpu):
                        s((3072, c, page_len), jnp.bfloat16),
                        s((slots,), jnp.int32), s((slots, 128), jnp.int32))
     assert n == 1 and "paged_latent_attention" in text
+    g = latent_pages_per_program(page_len, 128, c, heads, 512, jnp.bfloat16)
+    grid, vmem = _kernel_grid_and_vmem(text, "paged_latent_attention")
+    assert g == 8 and int(np.prod(grid)) <= slots * 128 // g
+    assert g * c * page_len * 2 * 2 < vmem < 16 * 2 ** 20
 
 
 @pytest.mark.parametrize("keys,causal", [(2048, True), (12288, False)],
