@@ -3,7 +3,9 @@
 One subsystem answering, from a single snapshot: where did the step
 time go (spans + the training tape's data/host/device breakdown), did
 we recompile (``collectors.RecompileDetector`` + process-global compile
-totals), are we data-stalled (``Prefetcher`` queue-depth/stall gauges),
+totals, ``compile_totals()``, and the compile log by program,
+``compile_log()``), are we data-stalled (``Prefetcher`` queue-depth/
+stall gauges),
 and what is the serving fleet doing (``ServingMetrics`` re-expressed on
 the registry). Exporters: JSONL event log, Prometheus text, and the
 in-process ``telemetry_snapshot()``.
@@ -42,7 +44,7 @@ from distkeras_tpu.obs.spans import (  # noqa: F401
     current_path, reset_spans, span, span_records, span_summary)
 from distkeras_tpu.obs import collectors, exporters  # noqa: F401
 from distkeras_tpu.obs.collectors import (  # noqa: F401
-    RecompileDetector, RecompileWarning, compile_totals,
+    RecompileDetector, RecompileWarning, compile_log, compile_totals,
     memory_watermark)
 from distkeras_tpu.obs.exporters import SCHEMA_VERSION  # noqa: F401
 from distkeras_tpu.obs.tape import (  # noqa: F401
@@ -54,6 +56,11 @@ from distkeras_tpu.obs.recorder import (  # noqa: F401
     NULL_RECORDER, FlightRecorder, get_recorder, resolve_recorder)
 from distkeras_tpu.obs.timeseries import Ring, TimeSeries  # noqa: F401
 from distkeras_tpu.obs.slo import Objective, SLOEngine  # noqa: F401
+
+# from here on every compile is in compile_totals() and compile_log():
+# the programs that make a model's weights compile before any engine,
+# tape or detector exists
+collectors.install_compile_listener()
 
 _enabled = [os.environ.get("DKT_TELEMETRY", "1") not in ("0", "false")]
 _registry_lock = threading.Lock()

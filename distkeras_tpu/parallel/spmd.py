@@ -259,120 +259,127 @@ class SPMDTrainer(Trainer):
         from distkeras_tpu.data.sharded import ShardedDataset
         model = self.master_model
         sharded = isinstance(dataset, ShardedDataset)
-        if not sharded:
-            X, y = self._training_arrays(dataset)
-        param_sh, repl, data_sh = self._placements(model)
+        tape = self._make_tape()
+        # train.setup: everything before the first train.dispatch (as
+        # in SingleTrainer.train)
+        with tape.span("setup"):
+            if not sharded:
+                X, y = self._training_arrays(dataset)
+            param_sh, repl, data_sh = self._placements(model)
 
-        # full-carry checkpoint (params + model state + optimizer moments +
-        # rng) so a resumed run is bitwise-identical to an uninterrupted
-        # one — same contract as SingleTrainer
-        manager = self._checkpoint_manager()
-        if self.sharded_checkpoints:
-            restored, start_epoch = self._restore_sharded(
-                manager, model, param_sh, repl)
-        else:
-            restored, start_epoch = self._restore_full_carry(manager, model)
+            # full-carry checkpoint (params + model state + optimizer moments +
+            # rng) so a resumed run is bitwise-identical to an uninterrupted
+            # one — same contract as SingleTrainer
+            manager = self._checkpoint_manager()
+            if self.sharded_checkpoints:
+                restored, start_epoch = self._restore_sharded(
+                    manager, model, param_sh, repl)
+            else:
+                restored, start_epoch = self._restore_full_carry(
+                    manager, model)
 
-        if restored is None:
-            # fresh start: shard params first, then init the optimizer
-            # UNDER jit so the moments are created already sharded/lazy —
-            # never materialized whole on one device
-            params = jax.tree_util.tree_map(jax.device_put, model.params,
-                                            param_sh)
-            state = jax.device_put(model.state, repl)
-            opt_state = jax.jit(
-                self.worker_optimizer.init,
-                out_shardings=self._opt_shardings(model.params, param_sh,
-                                                  repl))(params)
-            rng = jax.device_put(jax.random.PRNGKey(self.seed), repl)
-        elif self.sharded_checkpoints:
-            # already device-resident with the right shardings; fill any
-            # missing slots (params-only legacy checkpoints)
-            params = restored["params"]
-            state = restored["state"]
-            opt_state = restored.get("opt")
-            if opt_state is None:
+            if restored is None:
+                # fresh start: shard params first, then init the optimizer
+                # UNDER jit so the moments are created already sharded/lazy —
+                # never materialized whole on one device
+                params = jax.tree_util.tree_map(jax.device_put, model.params,
+                                                param_sh)
+                state = jax.device_put(model.state, repl)
                 opt_state = jax.jit(
                     self.worker_optimizer.init,
-                    out_shardings=self._opt_shardings(
-                        model.params, param_sh, repl))(params)
-            rng = restored.get("rng")
-            if rng is None:
+                    out_shardings=self._opt_shardings(model.params, param_sh,
+                                                      repl))(params)
                 rng = jax.device_put(jax.random.PRNGKey(self.seed), repl)
-        else:
-            params = jax.tree_util.tree_map(jax.device_put,
-                                            restored["params"], param_sh)
-            state = jax.device_put(restored["state"], repl)
-            opt_state = self._place_opt(restored["opt"], model.params,
-                                        param_sh)
-            rng = jax.device_put(jnp.asarray(restored["rng"]), repl)
-        carry = TrainCarry(params, state, opt_state, rng)
-        self._record_placement("params", params)
+            elif self.sharded_checkpoints:
+                # already device-resident with the right shardings; fill any
+                # missing slots (params-only legacy checkpoints)
+                params = restored["params"]
+                state = restored["state"]
+                opt_state = restored.get("opt")
+                if opt_state is None:
+                    opt_state = jax.jit(
+                        self.worker_optimizer.init,
+                        out_shardings=self._opt_shardings(
+                            model.params, param_sh, repl))(params)
+                rng = restored.get("rng")
+                if rng is None:
+                    rng = jax.device_put(jax.random.PRNGKey(self.seed), repl)
+            else:
+                params = jax.tree_util.tree_map(jax.device_put,
+                                                restored["params"], param_sh)
+                state = jax.device_put(restored["state"], repl)
+                opt_state = self._place_opt(restored["opt"], model.params,
+                                            param_sh)
+                rng = jax.device_put(jnp.asarray(restored["rng"]), repl)
+            carry = TrainCarry(params, state, opt_state, rng)
+            self._record_placement("params", params)
 
-        step = make_train_step(model.module, self.loss, self.worker_optimizer,
-                               self._metric_fns(), self.grad_accum_steps,
-                               param_mask=self._param_mask(model),
-                               state_mask=self._state_mask(model),
-                               fused_vocab_head=self.fused_vocab_head)
+            step = make_train_step(
+                model.module, self.loss, self.worker_optimizer,
+                self._metric_fns(), self.grad_accum_steps,
+                param_mask=self._param_mask(model),
+                state_mask=self._state_mask(model),
+                fused_vocab_head=self.fused_vocab_head)
 
-        # pin the carry's layout across epochs: GSPMD is otherwise free to
-        # re-shard unconstrained outputs (e.g. row-shard a replicated
-        # param's adam moment), which would drift the layout away from
-        # what _opt_shardings promised the checkpoint format
-        rmap = lambda tree: jax.tree_util.tree_map(lambda _: repl, tree)
-        carry_sh = TrainCarry(
-            param_sh, rmap(model.state),
-            self._opt_shardings(model.params, param_sh, repl), repl)
+            # pin the carry's layout across epochs: GSPMD is otherwise free to
+            # re-shard unconstrained outputs (e.g. row-shard a replicated
+            # param's adam moment), which would drift the layout away from
+            # what _opt_shardings promised the checkpoint format
+            rmap = lambda tree: jax.tree_util.tree_map(lambda _: repl, tree)
+            carry_sh = TrainCarry(
+                param_sh, rmap(model.state),
+                self._opt_shardings(model.params, param_sh, repl), repl)
 
-        if restored is not None:
-            # A restored carry can hold leaves whose device buffers ALIAS
-            # host numpy memory: a sharded device_put of a host array
-            # zero-copy-aliases the numpy buffer on this CPU client (each
-            # shard's device pointer is a slice of the host allocation —
-            # verified), and both restore paths device_put np.load'd
-            # trees. run_epoch donates the carry, so XLA would reuse/free
-            # buffers it does not own — intermittent heap corruption
-            # (`free(): corrupted unsorted chunks` aborts on the resume
-            # path; ~3-in-4 before this copy, 0 after). A non-donated
-            # jitted copy rematerializes every leaf into XLA-owned
-            # buffers once, before anything is donated.
-            carry = jax.jit(
-                lambda c: jax.tree_util.tree_map(jnp.copy, c),
-                out_shardings=carry_sh)(carry)
+            if restored is not None:
+                # A restored carry can hold leaves whose device buffers ALIAS
+                # host numpy memory: a sharded device_put of a host array
+                # zero-copy-aliases the numpy buffer on this CPU client (each
+                # shard's device pointer is a slice of the host allocation —
+                # verified), and both restore paths device_put np.load'd
+                # trees. run_epoch donates the carry, so XLA would reuse/free
+                # buffers it does not own — intermittent heap corruption
+                # (`free(): corrupted unsorted chunks` aborts on the resume
+                # path; ~3-in-4 before this copy, 0 after). A non-donated
+                # jitted copy rematerializes every leaf into XLA-owned
+                # buffers once, before anything is donated.
+                carry = jax.jit(
+                    lambda c: jax.tree_util.tree_map(jnp.copy, c),
+                    out_shardings=carry_sh)(carry)
 
-        @partial(jax.jit, donate_argnums=(0,), out_shardings=(carry_sh, None))
-        def run_epoch(carry, Xs, Ys):
-            with self._trace_scope():
-                return jax.lax.scan(step, carry, (Xs, Ys))
+            @partial(jax.jit, donate_argnums=(0,),
+                     out_shardings=(carry_sh, None))
+            def run_epoch(carry, Xs, Ys):
+                with self._trace_scope():
+                    return jax.lax.scan(step, carry, (Xs, Ys))
 
-        tape = self._make_tape()
-        tape.watch("SPMDTrainer.epoch", run_epoch)
+            tape.watch("SPMDTrainer.epoch", run_epoch)
 
-        from distkeras_tpu.utils.prefetch import Prefetcher, device_stager
-        validator = self._make_validator(model.module)
-        cbs = self._cb_list(
-            lambda: host_fetch((carry.params, carry.state)))
+            from distkeras_tpu.utils.prefetch import Prefetcher, device_stager
+            validator = self._make_validator(model.module)
+            cbs = self._cb_list(
+                lambda: host_fetch((carry.params, carry.state)))
 
-        # loader-thread staging with the TRAINER'S data sharding: the
-        # epoch loop consumes batches already resident (or streaming)
-        # across the data axes — no inline device_put on the training
-        # thread (docs/overlap.md)
-        stage = device_stager(data_sh)
-        if sharded:
-            # out-of-core (data.sharded.ShardedDataset): compiled scan per
-            # shard; ONE flat prefetch stream spans epoch boundaries so the
-            # loader thread never idles (Trainer._sharded_stream)
-            stream = self._sharded_stream(dataset, start_epoch, place=stage)
-        else:
-            # in-memory: ONE chunk per epoch; the Prefetcher overlaps the
-            # next epoch's shuffle+stack+H2D with this epoch's device
-            # scan. depth=1: a chunk is the whole stacked epoch, and
-            # one-ahead is full overlap — deeper only multiplies the
-            # dataset's device-memory footprint
-            stream = (((e, 0, True), chunk) for e, chunk in Prefetcher(
-                lambda e: stack_batches(X, y, self.batch_size,
-                                        self._epoch_perm(e, len(X))),
-                range(start_epoch, self.num_epoch), depth=1, place=stage))
+            # loader-thread staging with the TRAINER'S data sharding: the
+            # epoch loop consumes batches already resident (or streaming)
+            # across the data axes — no inline device_put on the training
+            # thread (docs/overlap.md)
+            stage = device_stager(data_sh)
+            if sharded:
+                # out-of-core (data.sharded.ShardedDataset): compiled scan per
+                # shard; ONE flat prefetch stream spans epoch boundaries so the
+                # loader thread never idles (Trainer._sharded_stream)
+                stream = self._sharded_stream(dataset, start_epoch,
+                                              place=stage)
+            else:
+                # in-memory: ONE chunk per epoch; the Prefetcher overlaps the
+                # next epoch's shuffle+stack+H2D with this epoch's device
+                # scan. depth=1: a chunk is the whole stacked epoch, and
+                # one-ahead is full overlap — deeper only multiplies the
+                # dataset's device-memory footprint
+                stream = (((e, 0, True), chunk) for e, chunk in Prefetcher(
+                    lambda e: stack_batches(X, y, self.batch_size,
+                                            self._epoch_perm(e, len(X))),
+                    range(start_epoch, self.num_epoch), depth=1, place=stage))
 
         self.record_training_start()
         tape.train_begin()

@@ -544,72 +544,78 @@ class SingleTrainer(Trainer):
         from distkeras_tpu.utils.prefetch import Prefetcher
         model = self.master_model
         sharded = isinstance(dataset, ShardedDataset)
-        if not sharded:
-            X, y = self._training_arrays(dataset)
-        step = make_train_step(model.module, self.loss, self.worker_optimizer,
-                               self._metric_fns(), self.grad_accum_steps,
-                               param_mask=self._param_mask(model),
-                               state_mask=self._state_mask(model),
-                               fused_vocab_head=self.fused_vocab_head)
-        runner = make_epoch_runner(step)
         tape = self._make_tape()
+        # train.setup: everything before the first train.dispatch
+        # (staging, the epoch runner, the starting carry and its copy);
+        # the first dispatch compiles or loads the epoch program, and
+        # obs.compile_log() says in which span
+        with tape.span("setup"):
+            if not sharded:
+                X, y = self._training_arrays(dataset)
+            step = make_train_step(
+                model.module, self.loss, self.worker_optimizer,
+                self._metric_fns(), self.grad_accum_steps,
+                param_mask=self._param_mask(model),
+                state_mask=self._state_mask(model),
+                fused_vocab_head=self.fused_vocab_head)
+            runner = make_epoch_runner(step)
 
-        # SingleTrainer checkpoints the FULL carry (params + model state +
-        # optimizer state + rng), so a resumed run is bitwise-identical to
-        # an uninterrupted one. (Distributed trainers checkpoint the center
-        # only — the documented PS-retry semantic.)
-        manager = self._checkpoint_manager()
-        fresh = {"params": model.params, "state": model.state,
-                 "opt": self.worker_optimizer.init(model.params),
-                 "rng": jax.random.PRNGKey(self.seed)}
-        tree, start_epoch = self._maybe_resume(manager, fresh)
-        # The runner DONATES its carry, and the trainer owns neither
-        # carry it starts from: a fresh one holds the caller's
-        # ``model.params``, a resumed one np.load'd host memory that a
-        # zero-copy placement would alias (spmd.py, at its own copy, has
-        # what donating that does to the heap). ONE jitted copy puts
-        # every leaf into a device buffer XLA owns, before anything is
-        # donated: the caller's Model stays readable, and the first
-        # epoch's runner signature equals every later one's (a numpy
-        # carry first and a device carry next would add a second
-        # jit-cache entry and false-positive the recompile detector).
-        # From here on every epoch updates the carry in place; nothing
-        # may keep a carry, or a leaf of one, across a ``runner`` call.
-        carry = jax.jit(lambda c: jax.tree_util.tree_map(jnp.copy, c))(
-            TrainCarry(params=tree["params"], state=tree["state"],
-                       opt_state=tree["opt"], rng=tree["rng"]))
-        del fresh, tree   # the uncopied optimizer state goes now
-        # after the first epoch's legitimate compiles, any cache growth
-        # on the epoch program is a shape leak (warned via check() in
-        # tape.epoch_end)
-        tape.watch("SingleTrainer.epoch", runner, donated=carry)
+            # SingleTrainer checkpoints the FULL carry (params + model state +
+            # optimizer state + rng), so a resumed run is bitwise-identical to
+            # an uninterrupted one. (Distributed trainers checkpoint the center
+            # only — the documented PS-retry semantic.)
+            manager = self._checkpoint_manager()
+            fresh = {"params": model.params, "state": model.state,
+                     "opt": self.worker_optimizer.init(model.params),
+                     "rng": jax.random.PRNGKey(self.seed)}
+            tree, start_epoch = self._maybe_resume(manager, fresh)
+            # The runner DONATES its carry, and the trainer owns neither
+            # carry it starts from: a fresh one holds the caller's
+            # ``model.params``, a resumed one np.load'd host memory that a
+            # zero-copy placement would alias (spmd.py, at its own copy, has
+            # what donating that does to the heap). ONE jitted copy puts
+            # every leaf into a device buffer XLA owns, before anything is
+            # donated: the caller's Model stays readable, and the first
+            # epoch's runner signature equals every later one's (a numpy
+            # carry first and a device carry next would add a second
+            # jit-cache entry and false-positive the recompile detector).
+            # From here on every epoch updates the carry in place; nothing
+            # may keep a carry, or a leaf of one, across a ``runner`` call.
+            carry = jax.jit(lambda c: jax.tree_util.tree_map(jnp.copy, c))(
+                TrainCarry(params=tree["params"], state=tree["state"],
+                           opt_state=tree["opt"], rng=tree["rng"]))
+            del fresh, tree   # the uncopied optimizer state goes now
+            # after the first epoch's legitimate compiles, any cache growth
+            # on the epoch program is a shape leak (warned via check() in
+            # tape.epoch_end)
+            tape.watch("SingleTrainer.epoch", runner, donated=carry)
 
-        from distkeras_tpu.utils.prefetch import device_stager
-        if sharded:
-            # out-of-core: compiled scan per shard; ONE flat prefetch
-            # stream spans epoch boundaries so the loader never idles
-            # (Trainer._sharded_stream; reference analogue: Spark workers
-            # iterate HDFS partition rows — workers.py :: Worker.train);
-            # the loader thread also stages each chunk onto device
-            stream = self._sharded_stream(dataset, start_epoch,
-                                          place=device_stager())
-        else:
-            # in-memory: ONE chunk per epoch; epoch e+1's shuffle gather,
-            # stacking AND device staging run while the device trains
-            # epoch e. depth=1 here — a chunk is the WHOLE stacked
-            # epoch, and one-ahead already gives full overlap; deeper
-            # buffering would only multiply dataset copies in device
-            # memory (docs/overlap.md)
-            stream = (((e, 0, True), chunk) for e, chunk in Prefetcher(
-                lambda e: stack_batches(X, y, self.batch_size,
-                                        self._epoch_perm(e, len(X))),
-                range(start_epoch, self.num_epoch), depth=1,
-                place=device_stager()))
+            from distkeras_tpu.utils.prefetch import device_stager
+            if sharded:
+                # out-of-core: compiled scan per shard; ONE flat prefetch
+                # stream spans epoch boundaries so the loader never idles
+                # (Trainer._sharded_stream; reference analogue: Spark workers
+                # iterate HDFS partition rows — workers.py :: Worker.train);
+                # the loader thread also stages each chunk onto device
+                stream = self._sharded_stream(dataset, start_epoch,
+                                              place=device_stager())
+            else:
+                # in-memory: ONE chunk per epoch; epoch e+1's shuffle gather,
+                # stacking AND device staging run while the device trains
+                # epoch e. depth=1 here — a chunk is the WHOLE stacked
+                # epoch, and one-ahead already gives full overlap; deeper
+                # buffering would only multiply dataset copies in device
+                # memory (docs/overlap.md)
+                stream = (((e, 0, True), chunk) for e, chunk in Prefetcher(
+                    lambda e: stack_batches(X, y, self.batch_size,
+                                            self._epoch_perm(e, len(X))),
+                    range(start_epoch, self.num_epoch), depth=1,
+                    place=device_stager()))
 
-        validator = self._make_validator(model.module)
-        cbs = self._cb_list(  # callback API: an explicit user-facing fetch
-            lambda: jax.device_get(  # lint: allow-host-sync
-                (carry.params, carry.state)))
+            validator = self._make_validator(model.module)
+            cbs = self._cb_list(  # callback API: an explicit user-facing fetch
+                lambda: jax.device_get(  # lint: allow-host-sync
+                    (carry.params, carry.state)))
         self.record_training_start()
         tape.train_begin()
         try:

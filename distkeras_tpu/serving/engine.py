@@ -372,6 +372,7 @@ class ServingEngine:
     ``_moe_admit_extra``).
     """
 
+    @obs.span("serving.init")
     def __init__(self, model: Model, *, num_slots: int = 4,
                  max_len: int = 256,
                  prefill_chunk: Optional[int] = None,
@@ -452,22 +453,24 @@ class ServingEngine:
         self._wq_keep_attn = False
         self._wq_dequant_dt = (compute_dt if compute_dt is not None
                                else jnp.float32)
-        if weight_quant is not None:
-            from distkeras_tpu.ops import quant_matmul as _qm
-            qtree = _qm.quantize_params_tree(
-                model.params, bits=4 if weight_quant == "int4" else 8)
-            self.weight_quant_error = _qm.tree_quant_errors(
-                model.params, qtree)
-            self._params = qtree
-            # shape misalignments degrade per-leaf to the XLA
-            # reference inside quant_matmul, so the keep-attn decision
-            # only needs the backend gate (TPU, or a test forcing
-            # interpreter mode at construction+trace time)
-            self._wq_keep_attn = _qm.kernel_enabled()
-        else:
-            self._params = (model.params if weights_dtype is None
-                            else _serving_params(model.params,
-                                                 weights_dtype))
+        with obs.span("serving.init.weights"):
+            if weight_quant is not None:
+                from distkeras_tpu.ops import quant_matmul as _qm
+                qtree = _qm.quantize_params_tree(
+                    model.params,
+                    bits=4 if weight_quant == "int4" else 8)
+                self.weight_quant_error = _qm.tree_quant_errors(
+                    model.params, qtree)
+                self._params = qtree
+                # shape misalignments degrade per-leaf to the XLA
+                # reference inside quant_matmul, so the keep-attn
+                # decision only needs the backend gate (TPU, or a test
+                # forcing interpreter mode at construction+trace time)
+                self._wq_keep_attn = _qm.kernel_enabled()
+            else:
+                self._params = (model.params if weights_dtype is None
+                                else _serving_params(model.params,
+                                                     weights_dtype))
         self._state = model.state
 
         # --- MoE serving (MoE-serving PR) -------------------------------
@@ -536,7 +539,8 @@ class ServingEngine:
             and all(m.dispatch == "grouped" for m in self._moe))
         self._moe_conc: Optional[float] = None   # routing-concentration EMA
         self._moe_iter = 0                       # stats-throttle counter
-        self._setup_expert_parallel(ep_mesh)
+        with obs.span("serving.init.weights"):
+            self._setup_expert_parallel(ep_mesh)
 
         # paged-attention decode kernel (decode-kernel PR): "auto" =
         # the Pallas page-table kernel on TPU, the _gather_pages
@@ -558,13 +562,14 @@ class ServingEngine:
         reserve = (sum(np.asarray(l).nbytes for l in
                        jax.tree_util.tree_leaves(self._params))
                    if hbm_budget is not None else 0)
-        self.pool = PagedKVPool(module, self.num_slots, self.max_len,
-                                page_len=page_len,
-                                num_pages=num_pages,
-                                host_pages=host_kv_pages,
-                                dtype=cache_dtype,
-                                hbm_budget=hbm_budget,
-                                reserve_bytes=reserve)
+        with obs.span("serving.init.pool"):
+            self.pool = PagedKVPool(module, self.num_slots, self.max_len,
+                                    page_len=page_len,
+                                    num_pages=num_pages,
+                                    host_pages=host_kv_pages,
+                                    dtype=cache_dtype,
+                                    hbm_budget=hbm_budget,
+                                    reserve_bytes=reserve)
         self.page_len = self.pool.page_len
         if self.pool.latent and (
                 draft is not None or fuse_steps or ep_mesh is not None
@@ -605,7 +610,8 @@ class ServingEngine:
         # is safe — insert copies only the pages/rows the prompt filled,
         # and the occupant's decode writes position t before the mask
         # ever admits it
-        self._staging = self.pool.make_request_cache()
+        with obs.span("serving.init.pool"):
+            self._staging = self.pool.make_request_cache()
         #: host-offload odometer snapshot (pool counts cumulatively;
         #: _flush_host_window publishes per-window deltas)
         self._off_seen = (0, 0, 0)
@@ -832,7 +838,8 @@ class ServingEngine:
         self._tree_fns = {}                  # greedy_only -> jit tree fn
         self._spec_tree_buf: List = []       # (tree_width, path_len)
         if draft is not None:
-            draft.bind(self)
+            with obs.span("serving.init.pool"):      # the draft's own pool
+                draft.bind(self)
 
         # telemetry: the CURRENT metrics window joins the unified
         # obs.telemetry_snapshot() under "serving" (weakref-bound, so a
@@ -2920,6 +2927,10 @@ class ServingEngine:
                          "preempted": m.requests_preempted},
             "telemetry": obs.telemetry_snapshot(),
             "programs": dict(self.program_paths),
+            # what the watched programs that grew past their warm
+            # size cost (program, seconds, cache, the span it fell
+            # in); the process's totals: ["telemetry"]["compile"]
+            "compiles": {"after_warm": self._recompile.after_warm()},
         }
         if self._moe:
             out["moe"] = {

@@ -256,6 +256,176 @@ def test_compile_totals_increase_on_fresh_compile():
     assert after["seconds"] > before["seconds"]
 
 
+# --- the compile log --------------------------------------------------------
+
+def _logged(program):
+    return [e for e in obs.compile_log() if e["program"] == program]
+
+
+@pytest.fixture
+def fresh_compile_log(monkeypatch):
+    """Totals at zero and an empty log for one test (the process's own
+    come back after it): what an earlier test compiled, or dropped at
+    the bound, is not in the sums."""
+    import collections
+    from distkeras_tpu.obs import collectors
+
+    def fresh(maxlen=collectors.MAX_LOG):
+        monkeypatch.setattr(collectors, "_log",
+                            collections.deque(maxlen=maxlen))
+        monkeypatch.setattr(collectors, "_totals",
+                            dict.fromkeys(collectors._totals, 0))
+    fresh()
+    return fresh
+
+
+def test_compile_log_names_the_program_its_stages_and_its_span():
+    @jax.jit
+    def logged_fresh_fn(x):
+        return jnp.tanh(x) @ x
+
+    x = jnp.ones((5, 5))                 # made outside the span
+    with obs.span("outer"):
+        with obs.span("inner"):
+            logged_fresh_fn(x)
+    (entry,) = _logged("logged_fresh_fn")
+    assert set(entry) == {"program", "backend_s", "trace_s", "lower_s",
+                          "cache", "t_end", "span"}
+    assert entry["backend_s"] > 0
+    assert entry["trace_s"] > 0 and entry["lower_s"] > 0
+    assert entry["span"] == ("outer", "inner")
+    assert entry["cache"] in (None, "hit", "miss")
+    from distkeras_tpu.utils.profiling import now
+    assert entry["t_end"] <= now()
+    # a second call at the same shapes: the function's own cache answers
+    logged_fresh_fn(x)
+    assert len(_logged("logged_fresh_fn")) == 1
+    # a new shape is a new entry, outside any span this time
+    logged_fresh_fn(jnp.ones((6, 6)))
+    assert [e["span"] for e in _logged("logged_fresh_fn")] \
+        == [("outer", "inner"), ()]
+
+
+def test_compile_log_agrees_with_compile_totals(fresh_compile_log):
+    jax.jit(lambda x: x * 2.5 - 1)(jnp.ones(13))
+    jax.jit(lambda x: x * 2.5 + 1).lower(jnp.ones(13))
+    totals, log = obs.compile_totals(), obs.compile_log()
+    assert not totals["overflow"]
+    assert len(log) == totals["count"] >= 1
+    assert sum(e["backend_s"] for e in log) \
+        == pytest.approx(totals["seconds"])
+    assert all(e[k] >= 0 for e in log
+               for k in ("backend_s", "trace_s", "lower_s"))
+    # entries hold the outermost functions' stages only: never more than
+    # the totals, which also have what led to no compile
+    for k in ("trace_s", "lower_s"):
+        assert sum(e[k] for e in log) <= totals[k] + 1e-9
+    assert totals["hits"] == sum(e["cache"] == "hit" for e in log)
+    assert totals["misses"] == sum(e["cache"] == "miss" for e in log)
+    assert obs.telemetry_snapshot()["compile"]["count"] >= totals["count"]
+
+
+def test_lower_alone_adds_to_the_totals_and_to_no_entry():
+    def lowered_only_fn(x):
+        return jnp.cos(x) + 1
+
+    before, n = obs.compile_totals(), len(obs.compile_log())
+    x = jax.ShapeDtypeStruct((7,), jnp.float32)
+    jax.jit(lowered_only_fn).lower(x)
+    jax.eval_shape(lowered_only_fn, x)
+    after = obs.compile_totals()
+    assert after["lower_s"] > before["lower_s"]
+    assert after["trace_s"] > before["trace_s"]
+    assert after["count"] == before["count"]
+    assert len(obs.compile_log()) == n
+    assert not _logged("lowered_only_fn")
+
+
+def test_compile_log_records_with_telemetry_disabled_but_no_span():
+    @jax.jit
+    def logged_while_disabled_fn(x):
+        return x * 3 + 2
+
+    x = jnp.ones(9)
+    obs.disable()
+    try:
+        with obs.span("not_recorded"):
+            logged_while_disabled_fn(x)
+    finally:
+        obs.enable()
+    (entry,) = _logged("logged_while_disabled_fn")
+    assert entry["span"] == ()
+
+
+def test_compile_log_is_bounded_and_counts_its_overflow(fresh_compile_log):
+    from distkeras_tpu.obs import collectors
+    assert collectors._log.maxlen == collectors.MAX_LOG == 4096
+    fresh_compile_log(maxlen=3)
+    f = jax.jit(lambda x: x - 0.25)
+    for n in range(2, 7):                # five shapes, five programs
+        f(jnp.ones(n))
+    totals, log = obs.compile_totals(), obs.compile_log()
+    assert totals["count"] >= 5
+    assert len(log) == 3 and log[-1]["program"] == "<lambda>"
+    assert totals["overflow"] == totals["count"] - 3
+
+
+def test_compile_log_reads_a_persistent_cache_miss_then_hit(tmp_path):
+    from jax.experimental.compilation_cache import compilation_cache as cc
+    if not getattr(jax.devices()[0].client,
+                   "supports_executable_serialization", True):
+        pytest.skip("this backend cannot serialize an executable: it has "
+                    "no persistent compile cache")
+    names = ("jax_compilation_cache_dir",
+             "jax_persistent_cache_min_compile_time_secs",
+             "jax_persistent_cache_min_entry_size_bytes")
+    saved = {n: getattr(jax.config, n) for n in names}
+
+    @jax.jit
+    def cached_across_runs_fn(x):
+        return jnp.sinh(x) * 1.75
+
+    try:
+        cc.reset_cache()
+        for n, v in zip(names, (str(tmp_path), 0, -1)):
+            jax.config.update(n, v)
+        cached_across_runs_fn(jnp.ones(17))
+        if not any(tmp_path.iterdir()):
+            pytest.skip("this backend wrote no persistent cache entry")
+        jax.clear_caches()               # as a new process would find it
+        cached_across_runs_fn(jnp.ones(17))
+    finally:
+        cc.reset_cache()
+        for n, v in saved.items():
+            jax.config.update(n, v)
+    first, second = _logged("cached_across_runs_fn")
+    assert first["cache"] == "miss" and second["cache"] == "hit"
+
+
+def test_recompile_warning_says_what_the_compile_cost_and_where():
+    det = obs.RecompileDetector(MetricsRegistry())
+
+    @jax.jit
+    def leaky_hot_fn(x):
+        return x * 2
+
+    det.watch("hot", leaky_hot_fn)
+    a, b = jnp.ones(3), jnp.ones(8)
+    leaky_hot_fn(a)
+    det.mark_warm()
+    assert det.after_warm() == []
+    with pytest.warns(obs.RecompileWarning,
+                      match=r"leaky_hot_fn \d+\.\d+ s .* in step/dispatch"):
+        with obs.span("step"):
+            with obs.span("dispatch"):
+                leaky_hot_fn(b)          # shape leak
+        det.check()
+    (cost,) = det.after_warm()
+    assert cost["program"] == "leaky_hot_fn"
+    assert cost["span"] == ("step", "dispatch")
+    assert cost["seconds"] > 0 and cost["cache"] in (None, "hit", "miss")
+
+
 # --- exporters --------------------------------------------------------------
 
 def _populated_registry():

@@ -339,7 +339,7 @@ def test_engine_step_opens_the_documented_spans(tiny_lm):
     obs.reset_spans()
     eng = _paged_engine_30_steps(tiny_lm)
     tree = obs.span_summary()
-    assert set(tree) == {"serving.step"}
+    assert set(tree) == {"serving.init", "serving.step"}
     step = tree["serving.step"]
     assert step["count"] == eng._iters == 30
     kids = step["children"]
@@ -360,6 +360,51 @@ def test_engine_step_opens_the_documented_spans(tiny_lm):
     assert decode["children"]["serving.decode.dispatch"]["count"] == n
     assert decode["children"]["serving.decode.fetch"]["count"] == n - 1
     assert decode["children"]["serving.decode.consume"]["count"] == n - 1
+
+
+def test_engine_constructor_opens_serving_init_with_its_children(tiny_lm):
+    obs.reset_spans()
+    ServingEngine(tiny_lm, num_slots=2, max_len=32, page_len=4)
+    tree = obs.span_summary()
+    assert set(tree) == {"serving.init"}
+    init = tree["serving.init"]
+    assert init["count"] == 1
+    kids = init["children"]
+    assert set(kids) == {"serving.init.weights", "serving.init.pool"}
+    # the pool, then the staging cache
+    assert kids["serving.init.pool"]["count"] == 2
+    assert sum(c["total_s"] for c in kids.values()) <= init["total_s"]
+    # a constructor that raises closes its spans all the same
+    with pytest.raises(ValueError, match="decode_kernel"):
+        ServingEngine(tiny_lm, max_len=32, decode_kernel="nope")
+    assert obs.current_path() == ()
+    assert obs.span_summary()["serving.init"]["count"] == 2
+
+
+def test_health_lists_a_program_compiled_after_warm_with_its_span(tiny_lm):
+    import jax
+    import jax.numpy as jnp
+    eng = _paged_engine_30_steps(tiny_lm)
+    health = eng.health()
+    assert health["compiles"] == {"after_warm": []}
+    assert health["telemetry"]["compile"]["count"] \
+        == obs.compile_totals()["count"] > 0
+    # a dtype drift in the weights: the warm decode step retraces
+    eng._params = jax.tree_util.tree_map(
+        lambda a: a.astype(jnp.bfloat16), eng._params)
+    eng.submit(PATTERN[:6], 4)
+    with pytest.warns(obs.RecompileWarning,
+                      match=r"serving_decode_greedy \d+\.\d+ s .* in "
+                      "serving.step/serving.decode/"):
+        for _ in range(8):
+            eng.step()
+        eng._recompile.check()
+    after = eng.health()["compiles"]["after_warm"]
+    assert [c["program"] for c in after] == ["serving_decode_greedy"]
+    assert after[0]["span"][:2] == ("serving.step", "serving.decode")
+    assert after[0]["span"][-1] == "serving.decode.dispatch"
+    assert after[0]["seconds"] > 0
+    assert set(after[0]) == {"program", "seconds", "cache", "span"}
 
 
 def test_engine_step_opens_no_span_with_obs_disabled(tiny_lm):
@@ -408,8 +453,14 @@ def test_trainer_epoch_loop_opens_the_train_spans(tmp_path):
     obs.reset_spans()
     tr = _train_two_epochs(checkpoint_dir=str(tmp_path))
     tree = obs.span_summary()
-    assert set(tree) == {"train.data_wait", "train.dispatch", "train.fetch",
-                         "train.epoch_end"}
+    assert set(tree) == {"train.setup", "train.data_wait", "train.dispatch",
+                         "train.fetch", "train.epoch_end"}
+    # everything before the first dispatch, once a train() call
+    assert tree["train.setup"]["count"] == 1
+    assert tree["train.setup"]["children"] == {}
+    # the epoch program compiled in the first dispatch, not in set-up
+    assert [e["span"] for e in obs.compile_log()
+            if e["program"] == "train_epoch"][-1] == ("train.dispatch",)
     # one epoch program an epoch; the stream is asked once more at its end
     assert tree["train.dispatch"]["count"] == 2
     assert tree["train.fetch"]["count"] == 2
@@ -433,6 +484,50 @@ def test_trainer_opens_no_span_without_telemetry(how):
         obs.disable()
         try:
             _train_two_epochs()
+        finally:
+            obs.enable()
+    assert obs.span_summary() == {}
+
+
+def _spmd_train_two_epochs(**kw):
+    from distkeras_tpu.data.dataset import Dataset
+    from distkeras_tpu.parallel.mesh import make_mesh_2d
+    from distkeras_tpu.parallel.spmd import SPMDTrainer
+    rs = np.random.RandomState(0)
+    X = rs.rand(128, 8).astype(np.float32)
+    y = (X.sum(axis=1) > 4).astype(np.int32)
+    model = Model.build(zoo.mlp((8,), num_classes=2), (8,), seed=0)
+    tr = SPMDTrainer(
+        model, mesh=make_mesh_2d({"workers": 2, "tp": 1}),
+        worker_optimizer="sgd", learning_rate=0.1,
+        loss="sparse_categorical_crossentropy_from_logits",
+        batch_size=32, num_epoch=2, **kw)
+    tr.train(Dataset({"features": X, "label": y}))
+    return tr
+
+
+def test_spmd_trainer_opens_train_setup_once():
+    obs.reset_spans()
+    tr = _spmd_train_two_epochs()
+    tree = obs.span_summary()
+    assert set(tree) == {"train.setup", "train.data_wait", "train.dispatch",
+                         "train.fetch", "train.epoch_end"}
+    assert tree["train.setup"]["count"] == 1
+    assert tree["train.dispatch"]["count"] == 2
+    # the SPMD epoch program compiled in the first dispatch too
+    assert [e["span"] for e in obs.compile_log()
+            if e["program"] == "run_epoch"][-1] == ("train.dispatch",)
+
+
+@pytest.mark.parametrize("how", ["telemetry_false", "obs_disabled"])
+def test_spmd_trainer_opens_no_span_without_telemetry(how):
+    obs.reset_spans()
+    if how == "telemetry_false":
+        _spmd_train_two_epochs(telemetry=False)
+    else:
+        obs.disable()
+        try:
+            _spmd_train_two_epochs()
         finally:
             obs.enable()
     assert obs.span_summary() == {}
