@@ -448,9 +448,9 @@ def _attn_lse(q, k, v, *, causal: bool, scale: float, layout: str,
     from distkeras_tpu.ops.flash_attention import _flash_forward
     if backend_is_tpu():
         note_path("flash_attention", "kernel")
-        # mirror flash_attention's adaptive default (round 5): the
-        # square 1024 tile wins at exactly d_head 128, causal unwindowed
-        bq = 1024 if (q.shape[-1] == 128 and causal
+        # mirror flash_attention's adaptive default: the square 1024
+        # tile wins up to d_head 128, causal unwindowed
+        bq = 1024 if (q.shape[-1] <= 128 and causal
                       and window is None) else 512
         bk = 1024 if window is None else 512
         return _flash_forward(q, k, v, scale, causal, bq, bk, False,

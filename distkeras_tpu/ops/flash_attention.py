@@ -3,23 +3,37 @@
 Absent from the reference (no attention models; SURVEY §5.7) — this is the
 TPU build's hot-op kernel for the long-context path. The forward pass never
 materializes the ``[S, S]`` score matrix: the grid is
-``(batch*heads, q_blocks, k_blocks)`` with the K axis innermost ("arbitrary"
-= sequential on TPU), so exactly one ``[block_k, D]`` tile of K and V is
-resident in VMEM at a time while the online-softmax carry (running max
-``m``, normalizer ``l``, accumulator ``acc``) persists in VMEM scratch
-across the K sweep. Causal q/k tiles above the diagonal skip their compute
-via ``pl.when``. Sequence lengths that don't divide the block sizes are
-zero-padded and the pad keys masked off.
+``(batch*heads, live tiles)``, the second axis sequential ("arbitrary"),
+each step one ``[block_q, block_k]`` tile whose q and k blocks a
+scalar-prefetched table names (:func:`_grid_table`); a q block's k tiles
+come in order while the online-softmax carry (running max ``m``,
+normalizer ``l``, accumulator ``acc``) persists in VMEM scratch. The
+table holds only the tiles the mask keeps anything of: a causal tile above
+the diagonal, or one older than every query's sliding window, costs no
+grid step and no DMA. Sequence lengths that don't divide the block sizes
+are zero-padded and the pad keys masked off.
 
-The backward pass is in-kernel too (two Pallas kernels: dq sweeps K blocks
-innermost; dk/dv sweeps Q blocks innermost, both recomputing probabilities
+What each tile computes (PR 38): a tile no mask edge crosses runs one
+unmasked body. For a plain causal call (no window, segment ids or
+``block_len``) a tile the diagonal crosses is done in pieces
+(:func:`_diag_plan`, :func:`_diag_rows`, :func:`_diag_cols`): runs of at
+most ``SUB_BLOCK_FWD`` query rows forward (``SUB_BLOCK_BWD`` rows in the
+dq pass, keys in the dk/dv pass), each an unmasked product over the keys
+every row of the run sees and a masked one over the sub-block the
+diagonal crosses; keys above the diagonal are not multiplied. At the
+train cell's S 2048 (1024 tiles) a (batch, head) computes 2.62M scores
+and masks 1.05M forward, 2.36M and 0.52M in each backward pass, where
+the whole-tile kernels (512/1024) computed 3.15M and masked 2.10M in
+each. Windowed, packed and block-causal calls, and a
+grid of one tile, mask each tile the mask crosses whole, as before.
+``compat.note_path`` records ``flash_causal=live<steps>of<dense
+grid>,sub<fwd>/<bwd>`` for every plain causal call.
+
+The backward pass is in-kernel too (two Pallas kernels: dq sweeps a q
+block's k tiles; dk/dv a k block's q tiles, both recomputing probabilities
 from the saved log-sum-exp with f32 VMEM accumulators) — the probability
 tile never touches HBM. A blockwise XLA-scan backward is retained for
-interpreter/CPU runs and as a cross-check oracle (``bwd="xla"``). The
-round-5 record (``BENCH_r05.json``: an earlier backend and JAX, not
-re-measured) put the 218M LM — B8 H16 S2048 D64 causal bf16, kernel
-backward + BHSD layer path + tuned blocks — at 2.15x the fused-XLA
-attention path end to end.
+interpreter/CPU runs and as a cross-check oracle (``bwd="xla"``).
 
 Under a mesh XLA cannot partition the kernel; :func:`partitioned` is
 the trace-time scope ``SPMDTrainer`` opens so that it runs inside a
@@ -51,44 +65,132 @@ from distkeras_tpu.compat import backend_is_tpu, note_path, shard_map
 from distkeras_tpu.ops.attention import (NEG_INF, causal_mask,
                                          dot_product_attention)
 
-# Round-4 sweep on a v5e (causal bf16, fwd+bwd, BHSD; an earlier backend
-# and JAX, not re-measured): 512/1024 beat 512/512 by ~10-15% at both
-# S=2048 (B8 H16) and S=8192 (B2 H8). Score tile at 512x1024 f32 is 2 MB
-# of VMEM, safe through D=256.
+# Tiles of every call but a causal one up to d_head 128 (which takes
+# 1024/1024, see flash_attention): 512/1024 beat 512/512 by ~10-15% in
+# the round-4 sweep on a v5e (causal bf16, fwd+bwd, BHSD, at S=2048 B8
+# H16 and S=8192 B2 H8) — an earlier backend and JAX, and the whole-tile
+# kernels before PR 38; not re-measured for those calls. Score tile at
+# 512x1024 f32 is 2 MB of VMEM, safe through D=256.
 DEFAULT_BLOCK_Q = 512
 DEFAULT_BLOCK_K = 1024
 
-#: every kernel here: (batch*head, outer block) parallel, inner sweep
-#: sequential (it carries the VMEM accumulators)
+#: the most query rows of a diagonal tile's sub-blocks in the forward
+#: pass, and the most query rows (dq) or keys (dk/dv) in the backward
+#: passes: measured on a v5e at the train cell's 1024 tiles (PERF.md §6
+#: PR 38), below 512 a forward piece costs more than the work it skips,
+#: and above 256 a backward one skips too little
+SUB_BLOCK_FWD = 512
+SUB_BLOCK_BWD = 256
+
+#: a plain causal call gets one static sub-block schedule per distinct
+#: offset of its diagonal tiles; past this many it masks them whole
+_MAX_DIAG_OFFSETS = 8
+
+#: every kernel here: (batch*head) parallel, the live-tile steps
+#: sequential (they carry the VMEM accumulators)
 _COMPILER_PARAMS = pltpu.CompilerParams(
-    dimension_semantics=("parallel", "parallel", "arbitrary"))
+    dimension_semantics=("parallel", "arbitrary"))
 
 
-def _window_kblocks(block_q: int, block_k: int, nk: int,
-                    window, nq: int) -> int:
-    """Number of k-grid steps per q block under a sliding window: the
-    reachable key span per q block is ``block_q + window - 1`` positions,
-    so the k-axis grid shrinks from ``nk`` to O(window/block_k) — skipped
-    tiles then never pay their K/V DMA (they are not in the grid at all),
-    instead of being ``pl.when``-skipped compute with full-cost DMA.
-    Computed as the EXACT trace-time maximum over q blocks (one fewer
-    step than the closed form when window/block_q align to block_k)."""
-    if window is None:
-        return nk
-    best = 1
-    for qi in range(nq):
-        last = min(nk - 1, (qi * block_q + block_q - 1) // block_k)
-        first = max(0, (qi * block_q - window + 1) // block_k)
-        best = max(best, last - first + 1)
-    return min(nk, best)
+def _tile_live(qi: int, kb: int, block_q: int, block_k: int, causal: bool,
+               window) -> bool:
+    """Does tile (q block ``qi``, k block ``kb``) hold a score the mask
+    keeps? Causal: not wholly above the diagonal; sliding window: not
+    wholly older than every query's window start."""
+    q0, k0 = qi * block_q, kb * block_k
+    if causal and k0 > q0 + block_q - 1:
+        return False
+    return window is None or k0 + block_k - 1 > q0 - window
 
 
-def _k_base(qi, block_q: int, block_k: int, nkw: int):
-    """First k block visited for q block ``qi`` (window remap): the last
-    ``nkw`` blocks ending at the causal diagonal block, clamped at 0.
-    Shared by the BlockSpec index maps and the kernels' position math."""
-    end = (qi * block_q + block_q - 1) // block_k
-    return jnp.maximum(0, end - (nkw - 1))
+def _grid_table(nq: int, nk: int, block_q: int, block_k: int, causal: bool,
+                window, kmajor: bool = False) -> np.ndarray:
+    """One kernel's grid as the live tiles it visits, in order: a flat
+    int32 table of (q block, k block, edge) a step, scalar-prefetched
+    into SMEM and read by the block index maps and the kernel. A tile
+    the mask wholly discards is not in it, so it costs neither a grid
+    step nor a DMA. The outer block (q for the forward and dq passes, k
+    for dk/dv) is swept with its live inner blocks ascending; ``edge``
+    bit 1 marks the outer block's first step (init), bit 2 its last
+    (finalize). An outer block with no live tile keeps one step that
+    computes nothing, so its output is still written."""
+    n_out, n_in = (nk, nq) if kmajor else (nq, nk)
+    rows = []
+    for o in range(n_out):
+        tiles = [(i, o) if kmajor else (o, i) for i in range(n_in)]
+        live = [t for t in tiles
+                if _tile_live(*t, block_q, block_k, causal, window)]
+        live = live or tiles[:1]
+        for j, (qi, kb) in enumerate(live):
+            rows += [qi, kb, (j == 0) + 2 * (j == len(live) - 1)]
+    return np.asarray(rows, np.int32)
+
+
+def _diag_plan(tab: np.ndarray, nq: int, nk: int, block_q: int,
+               block_k: int, plain_causal: bool):
+    """How a plain causal call (no window, segment ids or ``block_len``;
+    queries no longer than keys, so the causal mask also hides every
+    zero-padded key from every real query) computes the tiles the
+    diagonal crosses: ``(offsets, sub_fwd, sub_bwd)`` — a static
+    schedule (:func:`_diag_rows`, :func:`_diag_cols`) for each distinct
+    offset (query start minus key start) such a tile has in ``tab``,
+    with sub-blocks of at most ``SUB_BLOCK_FWD`` / ``SUB_BLOCK_BWD``
+    rows and at most half the smaller tile — or None: the whole tile is
+    masked, as it is where the grid is ONE tile (measured: a lone
+    tile's pieces have no other tile's work to hide behind). Notes
+    ``flash_causal=live<steps>of<dense grid>,sub<fwd>/<bwd>`` (or
+    ``,whole``) for every plain causal call."""
+    if not plain_causal:
+        return None
+    steps = tab.reshape(-1, 3)
+    offs = sorted({o for o in (int(qi) * block_q - int(kb) * block_k
+                               for qi, kb, _ in steps)
+                   if -block_q < o < block_k - 1})
+    # sub-blocks are a multiple of 8 rows (Mosaic's sublane tiling)
+    half = max(8, min(block_q, block_k) // 16 * 8)
+    subs = (min(SUB_BLOCK_FWD, half), min(SUB_BLOCK_BWD, half))
+    ok = (nq * nk > 1 and len(offs) <= _MAX_DIAG_OFFSETS
+          and block_q % 8 == 0 and block_k % 8 == 0)
+    note_path("flash_causal", f"live{len(steps)}of{nq * nk},"
+              + ("sub%d/%d" % subs if ok else "whole"))
+    return (tuple(offs),) + subs if ok else None
+
+
+def _diag_rows(off: int, block_q: int, block_k: int, sub: int):
+    """Forward / dq schedule of a diagonal tile whose queries start
+    ``off`` positions after its keys: ``(r0, r1, c_a, c_b)`` per run of
+    ``sub`` query rows that sees any key — keys ``[0, c_a)`` are seen by
+    every row (no mask), ``[c_a, c_b)`` is the sub-block the diagonal
+    crosses (masked), keys from ``c_b`` on by no row (skipped)."""
+    out = []
+    for r0 in range(0, block_q, sub):
+        r1 = min(block_q, r0 + sub)
+        c_b = min(block_k, off + r1)
+        if c_b > 0:
+            out.append((r0, r1, min(c_b, max(0, off + r0)), c_b))
+    return out
+
+
+def _diag_cols(off: int, block_q: int, block_k: int, sub: int):
+    """dk/dv schedule of the same tile: ``(c0, c1, r_a, r_b)`` per run of
+    ``sub`` keys that any query sees — query rows ``[r_a, r_b)`` are the
+    sub-block the diagonal crosses (masked), rows from ``r_b`` on see
+    every key of the run (no mask), rows before ``r_a`` none."""
+    out = []
+    for c0 in range(0, block_k, sub):
+        c1 = min(block_k, c0 + sub)
+        r_a = max(0, c0 - off)
+        if r_a < block_q:
+            out.append((c0, c1, r_a, min(block_q, max(r_a, c1 - off))))
+    return out
+
+
+def _diag_mask(s, d: int):
+    """Causal mask of a sub-block whose first query sits ``d`` positions
+    after its first key: entry (i, j) is kept iff ``d + i >= j``."""
+    i = lax.broadcasted_iota(jnp.int32, s.shape, 0)
+    j = lax.broadcasted_iota(jnp.int32, s.shape, 1)
+    return jnp.where(i + d >= j, s, NEG_INF)
 
 
 def _needs_mask(qi, kb, block_q: int, block_k: int, causal: bool,
@@ -116,10 +218,33 @@ def _needs_mask(qi, kb, block_q: int, block_k: int, causal: bool,
     return need
 
 
-def _mask_dispatch(run, need, masked_fn, clear_fn):
-    """Emit the masked and/or clear tile bodies under ``pl.when`` guards
-    per ``_needs_mask``'s verdict (Python bool = one static body; traced
-    bool = both bodies, selected per tile at run time)."""
+def _tile_dispatch(qi, kb, block_q: int, block_k: int, causal: bool,
+                   window, k_len: int, has_seg: bool, diag,
+                   masked_fn, clear_fn, diag_fn):
+    """Emit a kernel's tile bodies under ``pl.when`` guards. With a
+    :func:`_diag_plan`, a tile wholly below the diagonal runs the clear
+    body and one the diagonal crosses the ``diag_fn(offset)`` schedule of
+    its offset. Otherwise per ``_needs_mask``'s verdict (Python bool =
+    one static body; traced bool = both bodies, selected per tile at run
+    time), and only on tiles the mask keeps anything of (the one step
+    :func:`_grid_table` keeps for an outer block with none runs
+    neither)."""
+    if diag is not None:
+        off = qi * block_q - kb * block_k
+        pl.when(off >= block_k - 1)(clear_fn)
+        for o in diag[0]:
+            pl.when(off == o)(functools.partial(diag_fn, o))
+        return
+    # causal: tiles strictly above the diagonal contribute nothing;
+    # sliding window: tiles entirely OLDER than any query's window start
+    # contribute nothing either
+    run = (kb * block_k <= qi * block_q + block_q - 1) if causal \
+        else (kb >= 0)
+    if window is not None:
+        run = jnp.logical_and(
+            run, kb * block_k + block_k - 1 > qi * block_q - window)
+    need = _needs_mask(qi, kb, block_q, block_k, causal, window, k_len,
+                       has_seg)
     if need is True:
         pl.when(run)(masked_fn)
     elif need is False:
@@ -129,29 +254,61 @@ def _mask_dispatch(run, need, masked_fn, clear_fn):
         pl.when(jnp.logical_and(run, jnp.logical_not(need)))(clear_fn)
 
 
-def _fwd_kernel(*refs, scale: float, causal: bool, k_len: int,
-                window=None, nkw=None, has_seg: bool = False,
-                block_len=None):
-    """One (batch*head, q_block, k_block) program.
+def _tile_mask(s, qi, kb, causal: bool, k_len: int, window=None,
+               qseg_ref=None, kseg_ref=None, block_len=None):
+    """The whole-tile mask of the (qi, kb) score tile ``s``: causal (or
+    block-causal), window, zero-padded keys and packed segments."""
+    block_q, block_k = s.shape
+    q_pos = qi * block_q + lax.broadcasted_iota(jnp.int32, s.shape, 0)
+    k_pos = kb * block_k + lax.broadcasted_iota(jnp.int32, s.shape, 1)
+    if causal and block_len is not None:
+        q_end = (q_pos // block_len) * block_len + (block_len - 1)
+        s = jnp.where(q_end >= k_pos, s, NEG_INF)
+    elif causal:
+        s = jnp.where(q_pos >= k_pos, s, NEG_INF)
+    if window is not None:
+        s = jnp.where(k_pos > q_pos - window, s, NEG_INF)
+    # mask zero-padded keys past the true sequence end
+    if k_len % block_k:
+        s = jnp.where(k_pos < k_len, s, NEG_INF)
+    if qseg_ref is not None:
+        same = qseg_ref[0, :, 0][:, None] == kseg_ref[0, :, 0][None, :]
+        s = jnp.where(same, s, NEG_INF)
+    return s
+
+
+def _step(tab_ref):
+    """(q block, k block, edge) of this grid step, from the table."""
+    i = 3 * pl.program_id(1)
+    return tab_ref[i], tab_ref[i + 1], tab_ref[i + 2]
+
+
+def _fwd_kernel(tab_ref, *refs, scale: float, causal: bool, k_len: int,
+                window=None, has_seg: bool = False, block_len=None,
+                diag=None):
+    """One (batch*head, live tile) program; the tile is read from the
+    :func:`_grid_table` in SMEM.
 
     Block shapes: q_ref [1, bq, D]; k_ref/v_ref [1, bk, D];
     o_ref [1, bq, D]; lse_ref [1, bq, 1] (the trailing singleton keeps the
     block's last-two dims Mosaic-tileable: (bq, 1) with bq % 8 == 0 and 1
     equal to the full array dim — a [1, bq] block fails TPU lowering).
-    Scratch m/l [bq, 1], acc [bq, D] persist across the (sequential,
-    innermost) k grid axis. Under a sliding window the k grid axis is
-    REMAPPED: grid step ``ki`` addresses actual k block
-    ``_k_base(qi) + ki`` (see ``_window_kblocks``). With ``has_seg``
-    two extra [1, blk, 1] int32 refs carry packed segment ids; scores
-    with unequal ids are masked (packed-sequence support).
+    Scratch m/l [bq, 1], acc [bq, D] persist across the q block's
+    (sequential, ascending) k tiles. With ``has_seg`` two extra
+    [1, blk, 1] int32 refs carry packed segment ids; scores with unequal
+    ids are masked (packed-sequence support).
 
     ``block_len`` (static; ``_flash_forward`` holds it to a divisor of
     both tile sizes) turns the causal mask into the BLOCK-causal one: a
     query sees every key up to the end of its own block of
     ``block_len`` positions. Tiles are whole blocks, so which tiles
     run and which need a mask is the causal rule unchanged; only the
-    mask's comparison differs, and with ``block_len`` None the traced
-    kernel is the causal kernel as it was.
+    mask's comparison differs.
+
+    ``diag`` (a :func:`_diag_plan`): a tile the diagonal crosses is done
+    by runs of ``sub`` query rows, each one online-softmax update over
+    the keys it wholly sees and the one sub-block the diagonal crosses,
+    the only part that builds a mask (:func:`_diag_rows`).
     """
     if has_seg:
         (q_ref, k_ref, v_ref, qseg_ref, kseg_ref,
@@ -159,83 +316,72 @@ def _fwd_kernel(*refs, scale: float, causal: bool, k_len: int,
     else:
         q_ref, k_ref, v_ref, o_ref, lse_ref, m_ref, l_ref, acc_ref = refs
         qseg_ref = kseg_ref = None
-    qi, ki = pl.program_id(1), pl.program_id(2)
-    nk = pl.num_programs(2)
+    qi, kb, edge = _step(tab_ref)
     block_q, block_k = q_ref.shape[1], k_ref.shape[1]
-    kb = ki if nkw is None else _k_base(qi, block_q, block_k, nkw) + ki
 
-    @pl.when(ki == 0)
+    @pl.when(edge % 2 == 1)
     def _init():
         m_ref[:] = jnp.full_like(m_ref, NEG_INF)
         l_ref[:] = jnp.zeros_like(l_ref)
         acc_ref[:] = jnp.zeros_like(acc_ref)
 
-    # causal: tiles strictly above the diagonal contribute nothing;
-    # sliding window: tiles entirely OLDER than any query's window start
-    # contribute nothing either
-    run = (kb * block_k <= qi * block_q + block_q - 1) if causal \
-        else (kb >= 0)
-    if window is not None:
-        run = jnp.logical_and(
-            run, kb * block_k + block_k - 1 > qi * block_q - window)
-
-    def _scores():
+    def _scores(r0, r1, c0, c1):
         # matmul inputs stay in the STORED dtype (bf16 for bf16 models)
         # with f32 accumulation — the MXU's native mode. Upcasting inputs
         # to f32 forces multi-pass f32 matmuls (~3-6x slower); round 4
         # measured the f32-input kernel at ~22% MXU on v5e. Scale is
         # applied to the f32 scores, not the bf16 q, so no precision is
         # lost relative to the old `q.astype(f32) * scale` form.
-        return lax.dot_general(q_ref[0], k_ref[0], (((1,), (1,)), ((), ())),
+        return lax.dot_general(q_ref[0, r0:r1], k_ref[0, c0:c1],
+                               (((1,), (1,)), ((), ())),
                                preferred_element_type=jnp.float32) * scale
 
-    def _mask(s):
-        q_pos = (qi * block_q +
-                 lax.broadcasted_iota(jnp.int32, s.shape, 0))
-        k_pos = (kb * block_k +
-                 lax.broadcasted_iota(jnp.int32, s.shape, 1))
-        if causal and block_len is not None:
-            q_end = (q_pos // block_len) * block_len + (block_len - 1)
-            s = jnp.where(q_end >= k_pos, s, NEG_INF)
-        elif causal:
-            s = jnp.where(q_pos >= k_pos, s, NEG_INF)
-        if window is not None:
-            s = jnp.where(k_pos > q_pos - window, s, NEG_INF)
-        # mask zero-padded keys past the true sequence end
-        if k_len % block_k:
-            s = jnp.where(k_pos < k_len, s, NEG_INF)
-        if qseg_ref is not None:
-            same = qseg_ref[0, :, 0][:, None] == kseg_ref[0, :, 0][None, :]
-            s = jnp.where(same, s, NEG_INF)
-        return s
-
-    def _merge(s):
-        m_prev, l_prev, acc_prev = m_ref[:], l_ref[:], acc_ref[:]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+    def _merge(r0, r1, pieces):
+        # online-softmax update of rows [r0, r1) by score pieces
+        # (s, c0, c1) over keys [c0, c1) of the tile
+        m_prev, l_new, acc_new = m_ref[r0:r1], l_ref[r0:r1], acc_ref[r0:r1]
+        m_new = m_prev
+        for s, _, _ in pieces:
+            m_new = jnp.maximum(m_new, jnp.max(s, axis=-1, keepdims=True))
         alpha = jnp.exp(m_prev - m_new)
-        p = jnp.exp(s - m_new)
-        m_ref[:] = m_new
-        l_ref[:] = l_prev * alpha + jnp.sum(p, axis=-1, keepdims=True)
-        # p is cast to the value dtype for the PV matmul (f32 accumulate);
-        # p in [0, 1] so bf16's relative precision bounds the elementwise
-        # error at ~2^-8 of each probability — the flash-on-TPU standard
-        acc_ref[:] = acc_prev * alpha + lax.dot_general(
-            p.astype(v_ref.dtype), v_ref[0], (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
+        l_new, acc_new = l_new * alpha, acc_new * alpha
+        for s, c0, c1 in pieces:
+            p = jnp.exp(s - m_new)
+            l_new = l_new + jnp.sum(p, axis=-1, keepdims=True)
+            # p is cast to the value dtype for the PV matmul (f32
+            # accumulate); p in [0, 1] so bf16's relative precision bounds
+            # the elementwise error at ~2^-8 of each probability — the
+            # flash-on-TPU standard
+            acc_new = acc_new + lax.dot_general(
+                p.astype(v_ref.dtype), v_ref[0, c0:c1],
+                (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32)
+        m_ref[r0:r1], l_ref[r0:r1], acc_ref[r0:r1] = m_new, l_new, acc_new
 
-    # tile-static mask specialization (round 4): the kernels are
-    # VPU-bound, not MXU-bound (measured — the bf16-input change moved
-    # nothing), so interior tiles skip the whole iota/compare/select
-    # chain. A tile needs masking only if the causal diagonal, the
-    # window's trailing edge, or the key padding actually intersects it
-    # — a predicate of the program ids.
-    need = _needs_mask(qi, kb, block_q, block_k, causal, window, k_len,
-                       has_seg)
-    _mask_dispatch(run, need,
-                   lambda: _merge(_mask(_scores())),
-                   lambda: _merge(_scores()))
+    def _diag_tile(off):
+        for r0, r1, c_a, c_b in _diag_rows(off, block_q, block_k, diag[1]):
+            pieces = [(_scores(r0, r1, 0, c_a), 0, c_a)] if c_a else []
+            if c_b > c_a:
+                pieces.append((_diag_mask(_scores(r0, r1, c_a, c_b),
+                                          off + r0 - c_a), c_a, c_b))
+            _merge(r0, r1, pieces)
 
-    @pl.when(ki == nk - 1)
+    # tile-static mask specialization (round 4): a tile no mask edge
+    # crosses skips the whole iota/compare/select chain, and a diagonal
+    # tile builds its mask on the sub-blocks the diagonal crosses alone.
+    # At d_head 64 every product runs at half the MXU's width; there
+    # (PR 38, PERF.md §6) the two backward passes take 1.15-1.25x their
+    # products' time and this one 1.6x, bound by neither
+    _tile_dispatch(
+        qi, kb, block_q, block_k, causal, window, k_len,
+        qseg_ref is not None, diag,
+        lambda: _merge(0, block_q, [(_tile_mask(
+            _scores(0, block_q, 0, block_k), qi, kb, causal, k_len, window,
+            qseg_ref, kseg_ref, block_len), 0, block_k)]),
+        lambda: _merge(0, block_q,
+                       [(_scores(0, block_q, 0, block_k), 0, block_k)]),
+        _diag_tile)
+
+    @pl.when(edge >= 2)
     def _finalize():
         l = l_ref[:]
         l_safe = jnp.where(l == 0.0, 1.0, l)
@@ -263,6 +409,66 @@ def _seg_blocks(segment_ids, sq_p: int, sk_p: int):
     segq = jnp.pad(seg, ((0, 0), (0, sq_p - s)), constant_values=-1)
     segk = jnp.pad(seg, ((0, 0), (0, sk_p - s)), constant_values=-1)
     return segq[..., None], segk[..., None]
+
+
+def _table_specs(h: int, block_q: int, block_k: int, d: int, dv: int,
+                 has_seg: bool):
+    """BlockSpecs of q, k, v (and the q/k segment ids) addressed through
+    the grid table: the q and k blocks of step ``i`` are ``tab[3i]`` and
+    ``tab[3i + 1]``; segment ids are per BATCH, so their maps divide the
+    b*h grid row back down to the batch row."""
+    q_map = lambda bh, i, tab: (bh, tab[3 * i], 0)
+    k_map = lambda bh, i, tab: (bh, tab[3 * i + 1], 0)
+    specs = [pl.BlockSpec((1, block_q, d), q_map),
+             pl.BlockSpec((1, block_k, d), k_map),
+             pl.BlockSpec((1, block_k, dv), k_map)]
+    if has_seg:
+        specs += [
+            pl.BlockSpec((1, block_q, 1),
+                         lambda bh, i, tab: (bh // h, tab[3 * i], 0)),
+            pl.BlockSpec((1, block_k, 1),
+                         lambda bh, i, tab: (bh // h, tab[3 * i + 1], 0)),
+        ]
+    return specs, q_map
+
+
+#: the kernels' ``pallas_call`` s are jitted INLINE: a layer's call is
+#: traced once for all layers of one shape (the kernel bodies are long
+#: Python since PR 38) and still lowered where it stands, so the step
+#: holds one named kernel a layer
+_inline_jit = functools.partial(jax.jit, inline=True)
+
+
+@functools.partial(_inline_jit, static_argnames=(
+    "scale", "causal", "k_len", "window", "block_len", "diag", "block_q",
+    "block_k", "h", "interpret"))
+def _fwd_call(tab, qf, kf, vf, *segs, scale, causal, k_len, window,
+              block_len, diag, block_q, block_k, h, interpret):
+    bh, sq_p, d = qf.shape
+    dv = vf.shape[-1]
+    in_specs, q_map = _table_specs(h, block_q, block_k, d, dv, bool(segs))
+    return pl.pallas_call(
+        functools.partial(_fwd_kernel, scale=scale, causal=causal,
+                          k_len=k_len, window=window, has_seg=bool(segs),
+                          block_len=block_len, diag=diag),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(bh, tab.shape[0] // 3),
+            in_specs=in_specs,
+            out_specs=[pl.BlockSpec((1, block_q, dv), q_map),
+                       pl.BlockSpec((1, block_q, 1), q_map)],
+            scratch_shapes=[
+                pltpu.VMEM((block_q, 1), jnp.float32),
+                pltpu.VMEM((block_q, 1), jnp.float32),
+                pltpu.VMEM((block_q, dv), jnp.float32),
+            ]),
+        out_shape=[
+            jax.ShapeDtypeStruct((bh, sq_p, dv), qf.dtype),
+            jax.ShapeDtypeStruct((bh, sq_p, 1), jnp.float32),
+        ],
+        compiler_params=_COMPILER_PARAMS,
+        name="flash_fwd", interpret=interpret,
+    )(tab, qf, kf, vf, *segs)
 
 
 def _flash_forward(q, k, v, scale: float, causal: bool, block_q: int,
@@ -314,60 +520,18 @@ def _flash_forward(q, k, v, scale: float, causal: bool, block_q: int,
         kf = kp.transpose(0, 2, 1, 3).reshape(b * h, sk_p, d)
         vf = vp.transpose(0, 2, 1, 3).reshape(b * h, sk_p, dv)
 
-    nk = sk_p // block_k
-    nkw = _window_kblocks(block_q, block_k, nk, window,
-                          sq_p // block_q)
-    remap = nkw < nk
-    grid = (b * h, sq_p // block_q, nkw)
-    kernel = functools.partial(_fwd_kernel, scale=scale, causal=causal,
-                               k_len=sk, window=window,
-                               nkw=nkw if remap else None,
-                               has_seg=segment_ids is not None,
-                               block_len=block_len)
-
-    def k_map(bh, qi, ki):
-        if remap:
-            return (bh, _k_base(qi, block_q, block_k, nkw) + ki, 0)
-        return (bh, ki, 0)
-
-    in_specs = [
-        pl.BlockSpec((1, block_q, d), lambda bh, qi, ki: (bh, qi, 0)),
-        pl.BlockSpec((1, block_k, d), k_map),
-        pl.BlockSpec((1, block_k, dv), k_map),
-    ]
-    operands = [qf, kf, vf]
-    if segment_ids is not None:
-        segq, segk = _seg_blocks(segment_ids, sq_p, sk_p)
-        # segment ids are per-BATCH: block index maps divide the b*h grid
-        # row back down to the batch row
-        in_specs += [
-            pl.BlockSpec((1, block_q, 1),
-                         lambda bh, qi, ki: (bh // h, qi, 0)),
-            pl.BlockSpec((1, block_k, 1),
-                         lambda bh, qi, ki: (bh // h,) + k_map(bh, qi,
-                                                               ki)[1:]),
-        ]
-        operands += [segq, segk]
-    out, lse = pl.pallas_call(
-        kernel,
-        grid=grid,
-        in_specs=in_specs,
-        out_specs=[
-            pl.BlockSpec((1, block_q, dv), lambda bh, qi, ki: (bh, qi, 0)),
-            pl.BlockSpec((1, block_q, 1), lambda bh, qi, ki: (bh, qi, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((b * h, sq_p, dv), q.dtype),
-            jax.ShapeDtypeStruct((b * h, sq_p, 1), jnp.float32),
-        ],
-        scratch_shapes=[
-            pltpu.VMEM((block_q, 1), jnp.float32),
-            pltpu.VMEM((block_q, 1), jnp.float32),
-            pltpu.VMEM((block_q, dv), jnp.float32),
-        ],
-        compiler_params=_COMPILER_PARAMS,
-        name="flash_fwd", interpret=interpret,
-    )(*operands)
+    has_seg = segment_ids is not None
+    nq, nk = sq_p // block_q, sk_p // block_k
+    tab = _grid_table(nq, nk, block_q, block_k, causal, window)
+    plan = _diag_plan(tab, nq, nk, block_q, block_k,
+                      causal and window is None and not has_seg
+                      and block_len is None and sq <= sk)
+    segs = _seg_blocks(segment_ids, sq_p, sk_p) if has_seg else ()
+    out, lse = _fwd_call(
+        jnp.asarray(tab), qf, kf, vf, *segs, scale=scale, causal=causal,
+        k_len=sk, window=window, block_len=block_len,
+        diag=plan and plan[:2], block_q=block_q, block_k=block_k, h=h,
+        interpret=interpret)
     if bhsd:
         out = out.reshape(b, h, sq_p, dv)[:, :, :sq]
     else:
@@ -376,11 +540,12 @@ def _flash_forward(q, k, v, scale: float, causal: bool, block_q: int,
     return out, lse
 
 
-def _bwd_dq_kernel(*refs, scale: float, causal: bool, k_len: int,
-                   window=None, nkw=None, has_seg: bool = False):
-    """dq pass: one (batch*head, q_block, k_block) program, K innermost.
-    ``dq_acc`` [bq, D] f32 persists across the K sweep. Window remap as
-    in ``_fwd_kernel``; ``has_seg`` adds packed-segment masking."""
+def _bwd_dq_kernel(tab_ref, *refs, scale: float, causal: bool, k_len: int,
+                   window=None, has_seg: bool = False, diag=None):
+    """dq pass: one (batch*head, live tile) program, a q block's k tiles
+    in sequence. ``dq_acc`` [bq, D] f32 persists across them. The tile
+    dispatch, segment masking and the diagonal tiles' runs of query rows
+    are ``_fwd_kernel``'s."""
     if has_seg:
         (q_ref, k_ref, v_ref, g_ref, lse_ref, delta_ref, qseg_ref,
          kseg_ref, dq_ref, dq_acc) = refs
@@ -388,90 +553,62 @@ def _bwd_dq_kernel(*refs, scale: float, causal: bool, k_len: int,
         (q_ref, k_ref, v_ref, g_ref, lse_ref, delta_ref, dq_ref,
          dq_acc) = refs
         qseg_ref = kseg_ref = None
-    qi, ki = pl.program_id(1), pl.program_id(2)
-    nk = pl.num_programs(2)
+    qi, kb, edge = _step(tab_ref)
     block_q, block_k = q_ref.shape[1], k_ref.shape[1]
-    kb = ki if nkw is None else _k_base(qi, block_q, block_k, nkw) + ki
 
-    @pl.when(ki == 0)
+    @pl.when(edge % 2 == 1)
     def _init():
         dq_acc[:] = jnp.zeros_like(dq_acc)
 
-    run = (kb * block_k <= qi * block_q + block_q - 1) if causal \
-        else (kb >= 0)
-    if window is not None:
-        run = jnp.logical_and(
-            run, kb * block_k + block_k - 1
-            > qi * block_q - window)
-
-    def _mask(s):
-        q_pos = qi * block_q + lax.broadcasted_iota(jnp.int32, s.shape, 0)
-        k_pos = kb * block_k + lax.broadcasted_iota(jnp.int32, s.shape, 1)
-        if causal:
-            s = jnp.where(q_pos >= k_pos, s, NEG_INF)
-        if window is not None:
-            s = jnp.where(k_pos > q_pos - window, s, NEG_INF)
-        if k_len % block_k:
-            s = jnp.where(k_pos < k_len, s, NEG_INF)
-        if qseg_ref is not None:
-            same = qseg_ref[0, :, 0][:, None] == kseg_ref[0, :, 0][None, :]
-            s = jnp.where(same, s, NEG_INF)
-        return s
-
-    def _compute(mask):
+    def _compute(r0, r1, pieces):
         # bf16 matmul inputs + f32 accumulation throughout (see
-        # _fwd_kernel); scale folds into the f32 score/grad tensors
-        s = lax.dot_general(q_ref[0], k_ref[0], (((1,), (1,)), ((), ())),
-                            preferred_element_type=jnp.float32) * scale
-        if mask:
-            s = _mask(s)
-        p = jnp.exp(s - lse_ref[0])                        # [bq, bk]
-        dp = lax.dot_general(g_ref[0], v_ref[0], (((1,), (1,)), ((), ())),
-                             preferred_element_type=jnp.float32)
-        ds = (p * (dp - delta_ref[0])).astype(k_ref.dtype)
-        dq_acc[:] += lax.dot_general(
-            ds, k_ref[0], (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32) * scale
+        # _fwd_kernel); scale folds into the f32 score/grad tensors.
+        # ``pieces``: (c0, c1, mask) over keys [c0, c1) of the tile
+        acc = None
+        for c0, c1, mask in pieces:
+            s = lax.dot_general(q_ref[0, r0:r1], k_ref[0, c0:c1],
+                                (((1,), (1,)), ((), ())),
+                                preferred_element_type=jnp.float32) * scale
+            s = mask(s)
+            p = jnp.exp(s - lse_ref[0, r0:r1])                 # [bq, bk]
+            dp = lax.dot_general(g_ref[0, r0:r1], v_ref[0, c0:c1],
+                                 (((1,), (1,)), ((), ())),
+                                 preferred_element_type=jnp.float32)
+            ds = (p * (dp - delta_ref[0, r0:r1])).astype(k_ref.dtype)
+            part = lax.dot_general(ds, k_ref[0, c0:c1],
+                                   (((1,), (0,)), ((), ())),
+                                   preferred_element_type=jnp.float32)
+            acc = part if acc is None else acc + part
+        dq_acc[r0:r1] += acc * scale
 
-    need = _needs_mask(qi, kb, block_q, block_k, causal, window, k_len,
-                       qseg_ref is not None)
-    _mask_dispatch(run, need,
-                   lambda: _compute(True), lambda: _compute(False))
+    def _diag_tile(off):
+        for r0, r1, c_a, c_b in _diag_rows(off, block_q, block_k, diag[1]):
+            pieces = [(0, c_a, lambda s: s)] if c_a else []
+            if c_b > c_a:
+                pieces.append((c_a, c_b, functools.partial(
+                    _diag_mask, d=off + r0 - c_a)))
+            _compute(r0, r1, pieces)
 
-    @pl.when(ki == nk - 1)
+    _tile_dispatch(
+        qi, kb, block_q, block_k, causal, window, k_len,
+        qseg_ref is not None, diag,
+        lambda: _compute(0, block_q, [(0, block_k, lambda s: _tile_mask(
+            s, qi, kb, causal, k_len, window, qseg_ref, kseg_ref))]),
+        lambda: _compute(0, block_q, [(0, block_k, lambda s: s)]),
+        _diag_tile)
+
+    @pl.when(edge >= 2)
     def _finalize():
         dq_ref[0] = dq_acc[:].astype(dq_ref.dtype)
 
 
-def _window_qblocks(block_q: int, block_k: int, nq: int,
-                    window, nk: int) -> int:
-    """Mirror of ``_window_kblocks`` for the dk/dv pass: the reachable
-    query span per k block is ``block_k + window - 1`` positions. Exact
-    trace-time maximum over k blocks."""
-    if window is None:
-        return nq
-    best = 1
-    for ki in range(nk):
-        first = min(nq - 1, (ki * block_k) // block_q)
-        last = min(nq - 1,
-                   (ki * block_k + block_k - 1 + window - 1) // block_q)
-        best = max(best, last - first + 1)
-    return min(nq, best)
-
-
-def _q_base(ki, block_q: int, block_k: int, nq: int, nqw: int):
-    """First q block visited for k block ``ki`` (window remap). Clamped
-    from ABOVE to ``nq - nqw`` so every program stays in range without
-    any q block appearing twice in one sweep (a double-visit would
-    double-count its dk/dv contribution)."""
-    return jnp.minimum((ki * block_k) // block_q, nq - nqw)
-
-
-def _bwd_dkv_kernel(*refs, scale: float, causal: bool, k_len: int,
-                    window=None, nq=None, nqw=None, has_seg: bool = False):
-    """dk/dv pass: one (batch*head, k_block, q_block) program, Q innermost.
-    ``dk_acc``/``dv_acc`` [bk, D] f32 persist across the Q sweep. Window
-    remap: grid step ``qi`` addresses actual q block ``_q_base(ki) + qi``."""
+def _bwd_dkv_kernel(tab_ref, *refs, scale: float, causal: bool, k_len: int,
+                    window=None, has_seg: bool = False, diag=None):
+    """dk/dv pass: one (batch*head, live tile) program, a k block's q
+    tiles in sequence. ``dk_acc``/``dv_acc`` [bk, D] f32 persist across
+    them. A diagonal tile is done by runs of ``sub`` keys, each over the
+    query rows that see all of it and the one sub-block the diagonal
+    crosses (:func:`_diag_cols`)."""
     if has_seg:
         (q_ref, k_ref, v_ref, g_ref, lse_ref, delta_ref, qseg_ref,
          kseg_ref, dk_ref, dv_ref, dk_acc, dv_acc) = refs
@@ -479,67 +616,98 @@ def _bwd_dkv_kernel(*refs, scale: float, causal: bool, k_len: int,
         (q_ref, k_ref, v_ref, g_ref, lse_ref, delta_ref, dk_ref, dv_ref,
          dk_acc, dv_acc) = refs
         qseg_ref = kseg_ref = None
-    ki, qi = pl.program_id(1), pl.program_id(2)
+    qb, ki, edge = _step(tab_ref)
     block_k, block_q = k_ref.shape[1], q_ref.shape[1]
-    qb = qi if nqw is None else _q_base(ki, block_q, block_k, nq, nqw) + qi
 
-    @pl.when(qi == 0)
+    @pl.when(edge % 2 == 1)
     def _init():
         dk_acc[:] = jnp.zeros_like(dk_acc)
         dv_acc[:] = jnp.zeros_like(dv_acc)
 
-    # causal: q tiles entirely above the diagonal see none of this k
-    # block; sliding window: q tiles entirely NEWER than every key's
-    # window reach see none of it either
-    run = (qb * block_q + block_q - 1 >= ki * block_k) if causal \
-        else (qb >= 0)
-    if window is not None:
-        run = jnp.logical_and(
-            run, qb * block_q
-            < ki * block_k + block_k - 1 + window)
-
-    def _mask(s):
-        q_pos = qb * block_q + lax.broadcasted_iota(jnp.int32, s.shape, 0)
-        k_pos = ki * block_k + lax.broadcasted_iota(jnp.int32, s.shape, 1)
-        if causal:
-            s = jnp.where(q_pos >= k_pos, s, NEG_INF)
-        if window is not None:
-            s = jnp.where(k_pos > q_pos - window, s, NEG_INF)
-        if k_len % block_k:
-            s = jnp.where(k_pos < k_len, s, NEG_INF)
-        if qseg_ref is not None:
-            same = qseg_ref[0, :, 0][:, None] == kseg_ref[0, :, 0][None, :]
-            s = jnp.where(same, s, NEG_INF)
-        return s
-
-    def _compute(mask):
+    def _compute(c0, c1, pieces):
         # bf16 matmul inputs + f32 accumulation (see _fwd_kernel); the
         # dk contribution applies scale to the f32 accumulator instead of
-        # pre-scaling q (dot(ds, q*scale) == scale * dot(ds, q))
-        s = lax.dot_general(q_ref[0], k_ref[0], (((1,), (1,)), ((), ())),
-                            preferred_element_type=jnp.float32) * scale
-        if mask:
-            s = _mask(s)
-        p = jnp.exp(s - lse_ref[0])                        # [bq, bk]
-        dv_acc[:] += lax.dot_general(
-            p.astype(g_ref.dtype), g_ref[0], (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        dp = lax.dot_general(g_ref[0], v_ref[0], (((1,), (1,)), ((), ())),
-                             preferred_element_type=jnp.float32)
-        ds = (p * (dp - delta_ref[0])).astype(q_ref.dtype)
-        dk_acc[:] += lax.dot_general(
-            ds, q_ref[0], (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32) * scale
+        # pre-scaling q (dot(ds, q*scale) == scale * dot(ds, q)).
+        # ``pieces``: (r0, r1, mask) over query rows [r0, r1) of the tile
+        dv = dk = None
+        for r0, r1, mask in pieces:
+            s = lax.dot_general(q_ref[0, r0:r1], k_ref[0, c0:c1],
+                                (((1,), (1,)), ((), ())),
+                                preferred_element_type=jnp.float32) * scale
+            s = mask(s)
+            p = jnp.exp(s - lse_ref[0, r0:r1])                 # [bq, bk]
+            dv_part = lax.dot_general(
+                p.astype(g_ref.dtype), g_ref[0, r0:r1],
+                (((0,), (0,)), ((), ())), preferred_element_type=jnp.float32)
+            dp = lax.dot_general(g_ref[0, r0:r1], v_ref[0, c0:c1],
+                                 (((1,), (1,)), ((), ())),
+                                 preferred_element_type=jnp.float32)
+            ds = (p * (dp - delta_ref[0, r0:r1])).astype(q_ref.dtype)
+            dk_part = lax.dot_general(
+                ds, q_ref[0, r0:r1], (((0,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32)
+            dv = dv_part if dv is None else dv + dv_part
+            dk = dk_part if dk is None else dk + dk_part
+        dv_acc[c0:c1] += dv
+        dk_acc[c0:c1] += dk * scale
 
-    need = _needs_mask(qb, ki, block_q, block_k, causal, window, k_len,
-                       qseg_ref is not None)
-    _mask_dispatch(run, need,
-                   lambda: _compute(True), lambda: _compute(False))
+    def _diag_tile(off):
+        for c0, c1, r_a, r_b in _diag_cols(off, block_q, block_k, diag[1]):
+            pieces = [(r_a, r_b, functools.partial(
+                _diag_mask, d=off + r_a - c0))] if r_b > r_a else []
+            if r_b < block_q:
+                pieces.append((r_b, block_q, lambda s: s))
+            _compute(c0, c1, pieces)
 
-    @pl.when(qi == pl.num_programs(2) - 1)
+    _tile_dispatch(
+        qb, ki, block_q, block_k, causal, window, k_len,
+        qseg_ref is not None, diag,
+        lambda: _compute(0, block_k, [(0, block_q, lambda s: _tile_mask(
+            s, qb, ki, causal, k_len, window, qseg_ref, kseg_ref))]),
+        lambda: _compute(0, block_k, [(0, block_q, lambda s: s)]),
+        _diag_tile)
+
+    @pl.when(edge >= 2)
     def _finalize():
         dk_ref[0] = dk_acc[:].astype(dk_ref.dtype)
         dv_ref[0] = dv_acc[:].astype(dv_ref.dtype)
+
+
+@functools.partial(_inline_jit, static_argnames=(
+    "kmajor", "scale", "causal", "k_len", "window", "diag", "block_q",
+    "block_k", "h", "interpret"))
+def _bwd_call(tab, *operands, kmajor, scale, causal, k_len, window, diag,
+              block_q, block_k, h, interpret):
+    """One backward pass over ``(q, k, v, dO, lse, delta[, segment
+    ids])``: dq (``kmajor`` False) or dk and dv (True)."""
+    qf, kf = operands[:2]
+    bh, d = qf.shape[0], qf.shape[-1]
+    specs, q_map = _table_specs(h, block_q, block_k, d, d,
+                                len(operands) > 6)
+    q_spec, k_spec = specs[:2]
+    row_q = pl.BlockSpec((1, block_q, 1), q_map)
+    if kmajor:
+        kernel, out_spec, name = _bwd_dkv_kernel, k_spec, "flash_bwd_dkv"
+        out_shape = [jax.ShapeDtypeStruct(x.shape, x.dtype)
+                     for x in operands[1:3]]
+        scratch = [pltpu.VMEM((block_k, d), jnp.float32)] * 2
+    else:
+        kernel, out_spec, name = _bwd_dq_kernel, q_spec, "flash_bwd_dq"
+        out_shape = [jax.ShapeDtypeStruct(qf.shape, qf.dtype)]
+        scratch = [pltpu.VMEM((block_q, d), jnp.float32)]
+    return pl.pallas_call(
+        functools.partial(kernel, scale=scale, causal=causal, k_len=k_len,
+                          window=window, has_seg=len(operands) > 6,
+                          diag=diag),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1, grid=(bh, tab.shape[0] // 3),
+            in_specs=[q_spec, k_spec, k_spec, q_spec, row_q, row_q]
+            + specs[3:],
+            out_specs=[out_spec] * len(out_shape), scratch_shapes=scratch),
+        out_shape=out_shape,
+        compiler_params=_COMPILER_PARAMS,
+        name=name, interpret=interpret,
+    )(tab, *operands)
 
 
 def _flash_backward_pallas(res, g, scale: float, causal: bool,
@@ -583,81 +751,22 @@ def _flash_backward_pallas(res, g, scale: float, causal: bool,
             b * h, x.shape[1], d)
     qf, kf, vf, gf = to_flat(qp), to_flat(kp), to_flat(vp), to_flat(gp)
 
+    has_seg = segment_ids is not None
     nq, nk = sq_p // block_q, sk_p // block_k
-    nkw = _window_kblocks(block_q, block_k, nk, window, nq)
-    nqw = _window_qblocks(block_q, block_k, nq, window, nk)
-    q_spec = pl.BlockSpec((1, block_q, d), lambda bh, qi, ki: (bh, qi, 0))
-
-    def k_map(bh, qi, ki):
-        if nkw < nk:
-            return (bh, _k_base(qi, block_q, block_k, nkw) + ki, 0)
-        return (bh, ki, 0)
-
-    k_spec = pl.BlockSpec((1, block_k, d), k_map)
-    row_q = pl.BlockSpec((1, block_q, 1), lambda bh, qi, ki: (bh, qi, 0))
-    in_specs = [q_spec, k_spec, k_spec, q_spec, row_q, row_q]
-    operands = [qf, kf, vf, gf, lsef, deltaf]
-    if segment_ids is not None:
-        segq, segk = _seg_blocks(segment_ids, sq_p, sk_p)
-        in_specs += [
-            pl.BlockSpec((1, block_q, 1),
-                         lambda bh, qi, ki: (bh // h, qi, 0)),
-            pl.BlockSpec((1, block_k, 1),
-                         lambda bh, qi, ki: (bh // h,) + k_map(bh, qi,
-                                                               ki)[1:]),
-        ]
-        operands += [segq, segk]
-    dq = pl.pallas_call(
-        functools.partial(_bwd_dq_kernel, scale=scale, causal=causal,
-                          k_len=sk, window=window,
-                          nkw=nkw if nkw < nk else None,
-                          has_seg=segment_ids is not None),
-        grid=(b * h, nq, nkw),
-        in_specs=in_specs,
-        out_specs=[q_spec],
-        out_shape=[jax.ShapeDtypeStruct((b * h, sq_p, d), q.dtype)],
-        scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)],
-        compiler_params=_COMPILER_PARAMS,
-        name="flash_bwd_dq", interpret=interpret,
-    )(*operands)[0]
-
-    # second pass: k blocks parallel, q innermost (window-remapped)
-    def q_map2(bh, ki, qi):
-        if nqw < nq:
-            return (bh, _q_base(ki, block_q, block_k, nq, nqw) + qi, 0)
-        return (bh, qi, 0)
-
-    q_spec2 = pl.BlockSpec((1, block_q, d), q_map2)
-    k_spec2 = pl.BlockSpec((1, block_k, d), lambda bh, ki, qi: (bh, ki, 0))
-    row_q2 = pl.BlockSpec((1, block_q, 1), q_map2)
-    in_specs2 = [q_spec2, k_spec2, k_spec2, q_spec2, row_q2, row_q2]
-    operands2 = [qf, kf, vf, gf, lsef, deltaf]
-    if segment_ids is not None:
-        segq, segk = _seg_blocks(segment_ids, sq_p, sk_p)
-        in_specs2 += [
-            pl.BlockSpec((1, block_q, 1),
-                         lambda bh, ki, qi: (bh // h,) + q_map2(bh, ki,
-                                                                qi)[1:]),
-            pl.BlockSpec((1, block_k, 1),
-                         lambda bh, ki, qi: (bh // h, ki, 0)),
-        ]
-        operands2 += [segq, segk]
-    dk, dv = pl.pallas_call(
-        functools.partial(_bwd_dkv_kernel, scale=scale, causal=causal,
-                          k_len=sk, window=window,
-                          nq=nq if nqw < nq else None,
-                          nqw=nqw if nqw < nq else None,
-                          has_seg=segment_ids is not None),
-        grid=(b * h, nk, nqw),
-        in_specs=in_specs2,
-        out_specs=[k_spec2, k_spec2],
-        out_shape=[jax.ShapeDtypeStruct((b * h, sk_p, d), k.dtype),
-                   jax.ShapeDtypeStruct((b * h, sk_p, d), v.dtype)],
-        scratch_shapes=[pltpu.VMEM((block_k, d), jnp.float32),
-                        pltpu.VMEM((block_k, d), jnp.float32)],
-        compiler_params=_COMPILER_PARAMS,
-        name="flash_bwd_dkv", interpret=interpret,
-    )(*operands2)
+    tab = _grid_table(nq, nk, block_q, block_k, causal, window)
+    plan = _diag_plan(tab, nq, nk, block_q, block_k,
+                      causal and window is None and not has_seg and sq <= sk)
+    segs = _seg_blocks(segment_ids, sq_p, sk_p) if has_seg else ()
+    operands = (qf, kf, vf, gf, lsef, deltaf) + tuple(segs)
+    kw = dict(scale=scale, causal=causal, k_len=sk, window=window,
+              diag=plan and (plan[0], plan[2]), block_q=block_q,
+              block_k=block_k, h=h, interpret=interpret)
+    # first pass: a q block's k tiles in sequence; second: a k block's
+    # q tiles
+    dq = _bwd_call(jnp.asarray(tab), *operands, kmajor=False, **kw)[0]
+    dk, dv = _bwd_call(jnp.asarray(_grid_table(nq, nk, block_q, block_k,
+                                               causal, window, True)),
+                       *operands, kmajor=True, **kw)
 
     if bhsd:
         unflat = lambda x, s: x.reshape(b, h, x.shape[1], d)[:, :, :s]
@@ -841,14 +950,19 @@ def flash_attention(q, k, v, *, causal: bool = False,
     since interpreted kernels are slow on CPU; also the cross-check
     oracle for the kernel backward's numerics).
 
-    ``block_q``/``block_k`` default adaptively: 512/1024 for full
-    attention, except 1024/1024 at exactly d_head 128 causal (both
-    measured optima — module header and the round-5 D=128 sweep),
-    512/512 under a sliding ``window`` at every d_head — the remapped
-    k-grid covers ``~window + block_q + block_k`` keys per q block, so
-    the smaller blocks tighten coverage (measured: W=1024 S=8192
-    fwd+bwd 1.80x full-causal at 512/512 vs 1.44x at 1024/1024 on
-    v5e).
+    ``block_q``/``block_k`` default adaptively: 1024/1024 for causal
+    attention without a window or segment ids up to d_head 128, 512/1024
+    for the rest (wider heads: VMEM; non-causal: not swept), 512/512
+    under a sliding ``window`` at every d_head — its grid covers
+    ``~window + block_q + block_k`` keys per q block, so the smaller
+    blocks tighten coverage (measured: W=1024 S=8192 fwd+bwd 1.80x
+    full-causal at 512/512 vs 1.44x at 1024/1024 on v5e, before PR 38).
+    Tiles above the diagonal (or outside the window) are not in the
+    grid, and a plain causal call does the tiles the diagonal crosses
+    in pieces (module docstring); measured on a v5e at B2 H17 S2048 D64
+    (PR 38): fwd + dq + dk/dv 1.25 ms a call at 1024/1024, 1.42 at
+    512/1024, 1.75 at 512/512; the whole-tile kernels took 1.68 at
+    512/1024.
 
     ``segment_ids``: [B, S] int — packed-sequence masking (attention
     restricted to equal ids) through every path: forward, both Pallas
@@ -864,20 +978,19 @@ def flash_attention(q, k, v, *, causal: bool = False,
     if layout not in ("bshd", "bhsd"):
         raise ValueError(f"layout must be 'bshd' or 'bhsd', got {layout!r}")
     if block_q is None:
-        # d_head == 128 prefers the square 1024 tile for FULL causal
-        # attention: measured fwd+bwd at B4 H16 S2048 D128 (the lm_big
-        # shape, round 5) — 1024/1024 4.58 ms vs the d64-tuned 512/1024
-        # default's 6.05 (24% faster; 512/512 5.10, 2048-sized tiles
-        # fail to compile). Deliberately NARROW: exactly d_head 128 and
-        # causal — D=256 would double the measured VMEM footprint into
-        # the range that failed to compile at D=128, and non-causal
-        # shapes were not swept; both keep the 512/1024 default
-        # (documented safe through D=256). WINDOWED attention keeps
-        # 512/512 at every d_head — its remapped k-grid covers
-        # ~window + block_q + block_k keys per q block, and the bigger
-        # q tile widens exactly the overscan 512/512 was measured to
-        # avoid.
-        block_q = 1024 if (q.shape[-1] == 128 and causal
+        # causal attention without a window or packing takes the square
+        # 1024 tile up to d_head 128: measured fwd+bwd on a v5e with
+        # the diagonal tiles done in pieces (PR 38) — at B2 H17 S2048
+        # D64 1.25 ms a call against 1.42 at 512/1024 (the parent's
+        # kernels: 1.62 vs 1.68), and at D128 (round 5, whole masked
+        # tiles) 4.58 ms vs 6.05. 2048-sized tiles fail to compile, and
+        # D=256 would double the VMEM footprint into that range, so
+        # wider heads and non-causal calls keep 512/1024 (documented
+        # safe through D=256). WINDOWED attention keeps 512/512 at every
+        # d_head — its grid covers ~window + block_q + block_k keys per
+        # q block, and the bigger q tile widens exactly the overscan
+        # 512/512 was measured to avoid.
+        block_q = 1024 if (q.shape[-1] <= 128 and causal
                            and window is None
                            and segment_ids is None) else DEFAULT_BLOCK_Q
     if block_k is None:

@@ -51,13 +51,28 @@ def test_causal_mask_offsets():
     assert not bool(m2.any())
 
 
+#: (sequence, block_q, block_k, the plain causal call's counter): tiles
+#: above the diagonal leave the grid, diagonal tiles split into sub-blocks
+#: (half the smaller tile, a multiple of 8), and a length no tile divides
+#: pads its keys
+FLASH_TILES = [(32, 8, 8, "live10of16,sub8/8"),
+               (48, 16, 16, "live6of9,sub8/8"),
+               (100, 32, 64, "live6of8,sub16/16"),
+               (100, 64, 32, "live6of8,sub16/16")]
+
+
+@pytest.mark.parametrize("s,block_q,block_k,counter", FLASH_TILES)
 @pytest.mark.parametrize("causal", [False, True])
-def test_flash_matches_sdpa(causal):
-    q, k, v = _rand_qkv(jax.random.PRNGKey(1), b=1, s=32, h=2, d=8)
-    out = flash_attention(q, k, v, causal=causal, block_q=8, block_k=8,
-                          interpret=True)
+def test_flash_matches_sdpa(causal, s, block_q, block_k, counter):
+    from distkeras_tpu.compat import record_paths
+    q, k, v = _rand_qkv(jax.random.PRNGKey(1), b=1, s=s, h=2, d=8)
+    with record_paths() as paths:
+        out = flash_attention(q, k, v, causal=causal, block_q=block_q,
+                              block_k=block_k, interpret=True)
     ref = dot_product_attention(q, k, v, causal=causal)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=1e-5)
+    noted = {p for p in paths if p.startswith("flash_causal=")}
+    assert noted == ({f"flash_causal={counter}"} if causal else set())
 
 
 @pytest.mark.parametrize("causal", [False, True])
@@ -492,12 +507,16 @@ def test_positional_embedding_undersized_table_raises(devices):
         jax.jit(fn)(params, x)
 
 
+@pytest.mark.parametrize("s,block_q,block_k",
+                         [(44, 16, 16), (100, 32, 64), (100, 64, 32)])
 @pytest.mark.parametrize("causal", [True, False])
-def test_flash_pallas_backward_matches_oracles(causal):
+def test_flash_pallas_backward_matches_oracles(causal, s, block_q, block_k):
     """The in-kernel backward (TPU default) must match both the XLA-scan
     backward and the reference SDPA gradients — including a sequence that
-    doesn't divide the block sizes (pad-row handling in both passes)."""
-    q, k, v = _rand_qkv(jax.random.PRNGKey(5), b=2, s=44, h=2, d=8)
+    doesn't divide the block sizes (pad-row handling in both passes), and
+    tiles where the causal ones leave the grid and split the diagonal
+    tiles into sub-blocks in both passes (``FLASH_TILES``)."""
+    q, k, v = _rand_qkv(jax.random.PRNGKey(5), b=2, s=s, h=2, d=8)
     co = jax.random.normal(jax.random.PRNGKey(9), q.shape)
 
     def grads(fn):
@@ -508,16 +527,62 @@ def test_flash_pallas_backward_matches_oracles(causal):
                                                       causal=causal))
     pal = grads(lambda a, b, c: flash_attention(
         a, b, c, causal=causal, interpret=True, bwd="pallas",
-        block_q=16, block_k=16))
+        block_q=block_q, block_k=block_k))
     xla = grads(lambda a, b, c: flash_attention(
         a, b, c, causal=causal, interpret=True, bwd="xla",
-        block_q=16, block_k=16))
+        block_q=block_q, block_k=block_k))
     for p, x, r in zip(pal, xla, ref):
         np.testing.assert_allclose(p, r, atol=2e-5)
         np.testing.assert_allclose(p, x, atol=2e-5)
 
     with pytest.raises(ValueError, match="bwd must be"):
         flash_attention(q, k, v, interpret=True, bwd="fused")
+
+
+@pytest.mark.parametrize("kw", [
+    dict(causal=True, window=7),
+    dict(causal=True, window=24, block_q=8, block_k=16),
+    dict(causal=True, segment_ids=True),
+    dict(causal=False, segment_ids=True, block_k=8),
+    dict(causal=False, block_k=32),
+    dict(causal=True, block_len=4),
+], ids=["window", "window-remap", "segments", "segments-noncausal",
+        "noncausal", "block_len"])
+def test_flash_off_the_plain_causal_path_is_unchanged(kw, monkeypatch):
+    """Windowed, packed, block-causal and non-causal calls keep the
+    whole-tile bodies: leaving the tiles the mask wholly discards out of
+    the grid changes no bit of what they compute, forward or backward
+    (the dense grid, every tile visited in order, is what the kernels
+    ran before PR 38), and none of them notes the plain causal
+    counter."""
+    from distkeras_tpu.compat import record_paths
+    from distkeras_tpu.ops import flash_attention as fa
+    kw = dict(kw)
+    s = 48 if "block_len" in kw else 44
+    q, k, v = _rand_qkv(jax.random.PRNGKey(3), b=2, s=s, h=2, d=8)
+    co = jax.random.normal(jax.random.PRNGKey(4), q.shape)
+    if kw.pop("segment_ids", False):
+        kw["segment_ids"] = jnp.asarray(np.repeat(np.arange(4), 11)[None]
+                                        .repeat(2, 0))
+    kw = dict(dict(block_q=16, block_k=16, interpret=True, bwd="pallas"),
+              **kw)
+
+    def run():
+        f = lambda a, b, c: flash_attention(a, b, c, **kw)
+        if "block_len" in kw:           # forward only
+            return [f(q, k, v)]
+        return [f(q, k, v)] + list(jax.grad(
+            lambda *a: jnp.sum(f(*a) * co), argnums=(0, 1, 2))(q, k, v))
+
+    with record_paths() as paths:
+        live = run()
+    assert not any(p.startswith("flash_causal=") for p in paths)
+    table = fa._grid_table
+    monkeypatch.setattr(fa, "_grid_table", lambda nq, nk, bq, bk, causal,
+                        window, kmajor=False: table(nq, nk, bq, bk, False,
+                                                    None, kmajor))
+    for a, b in zip(live, run()):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
 
 
 @pytest.mark.parametrize("causal", [True, False])
@@ -647,18 +712,18 @@ def test_sliding_window_matches_banded_reference(window):
 @pytest.mark.parametrize("window,block_q,block_k",
                          [(8, 16, 8), (24, 8, 16), (3, 8, 8)])
 def test_sliding_window_grid_remap_exact(window, block_q, block_k):
-    """W << S exercises the shrunken, REMAPPED k/q grids (round 3): the
-    k-axis grid covers only each q block's window reach, so correctness
-    here proves the index-map clamping never drops or double-counts a
-    block (fwd, dq, and the mirrored dk/dv sweeps)."""
+    """W << S exercises the shrunken grids: each q block's k sweep (and
+    each k block's q sweep) holds only the tiles within the window's
+    reach, so correctness here proves the grid table never drops or
+    double-counts a tile (fwd, dq, and the mirrored dk/dv sweeps)."""
     from distkeras_tpu.ops.attention import NEG_INF
-    from distkeras_tpu.ops.flash_attention import (_window_kblocks,
-                                                   _window_qblocks)
+    from distkeras_tpu.ops.flash_attention import _grid_table
 
     B, S, H, D = 1, 128, 2, 8
-    nk = S // block_k
-    assert _window_kblocks(block_q, block_k, nk, window,
-                           S // block_q) < nk  # remap on
+    nq, nk = S // block_q, S // block_k
+    for kmajor, outer, n_in in ((False, 0, nk), (True, 1, nq)):
+        tab = _grid_table(nq, nk, block_q, block_k, True, window, kmajor)
+        assert np.bincount(tab.reshape(-1, 3)[:, outer]).max() < n_in
     q, k, v = _rand_qkv(jax.random.PRNGKey(17), b=B, s=S, h=H, d=D)
     co = jax.random.normal(jax.random.PRNGKey(18), q.shape)
 

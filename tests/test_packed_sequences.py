@@ -363,8 +363,9 @@ def test_segments_compose_with_sliding_window(bwd):
 
     kw = dict(causal=True, window=window, segment_ids=seg, interpret=True,
               block_q=16, block_k=8)
-    from distkeras_tpu.ops.flash_attention import _window_kblocks
-    assert _window_kblocks(16, 8, S // 8, window, S // 16) < S // 8
+    from distkeras_tpu.ops.flash_attention import _grid_table
+    tab = _grid_table(S // 16, S // 8, 16, 8, True, window)
+    assert np.bincount(tab.reshape(-1, 3)[:, 0]).max() < S // 8
     out = flash_attention(q, k, v, **kw)
     np.testing.assert_allclose(out, oracle(q, k, v), atol=1e-5)
     gr = jax.grad(lambda *a: jnp.sum(oracle(*a) * co),

@@ -125,6 +125,43 @@ def test_flash_attention_under_dp4_mesh(topo, as_tpu):
     assert "all-gather" not in text
 
 
+def test_train_step_flash_kernels_at_the_cell_shape(one_chip, as_tpu):
+    """The one-chip training cell's step (Cerebras-GPT 256M, all 14
+    layers, 2 x 2048 tokens, Adam) lowered for the chip: each layer holds
+    one call of each flash kernel (the harness fails a run on another
+    count), and the plain causal counter reads the live grid at the
+    default 1024 tiles — 3 of the 2 x 2 tiles a (batch, head), the one
+    above the diagonal gone, the two on it done in pieces of 512 rows
+    forward and 256 backward."""
+    import re
+    from distkeras_tpu.compat import record_paths
+    from distkeras_tpu.models import zoo
+    from distkeras_tpu.ops.losses import get_loss
+    from distkeras_tpu.ops.optimizers import get_optimizer
+    from distkeras_tpu.parallel.worker import TrainCarry, make_train_step
+    module = zoo.transformer_lm(
+        50257, d_model=1088, num_heads=17, num_layers=14, mlp_ratio=4,
+        max_len=2048, use_rope=False, norm="layernorm", dtype="bfloat16")
+    params, state = jax.eval_shape(
+        lambda key: module.init(key, (2048,))[:2], jax.random.PRNGKey(0))
+    opt = get_optimizer("adam", learning_rate=1e-4)
+    step = make_train_step(
+        module, get_loss("sparse_categorical_crossentropy_from_logits"), opt)
+    s = _spec(one_chip)
+    carry = jax.tree_util.tree_map(
+        lambda a: s(a.shape, a.dtype),
+        TrainCarry(params, state, jax.eval_shape(opt.init, params),
+                   jax.ShapeDtypeStruct((2,), np.uint32)))
+    x = s((2, 2048), jnp.int32)
+    with record_paths() as paths:
+        text = jax.jit(step).lower(carry, (x, x)).as_text()
+    names = re.findall(r'kernel_name = "([^"]+)"', text)
+    assert {n: names.count(n) for n in set(names)} == {
+        "flash_fwd": 14, "flash_bwd_dq": 14, "flash_bwd_dkv": 14}
+    assert {p for p in paths if p.startswith("flash")} == {
+        "flash_attention=kernel", "flash_causal=live3of4,sub512/256"}
+
+
 def test_train_epoch_updates_its_carry_in_place(one_chip, as_tpu):
     """``SingleTrainer``'s epoch program at the one-chip training cell's
     shapes (Cerebras-GPT 256M widths, 8 steps of 2 x 2048 tokens, Adam; two
