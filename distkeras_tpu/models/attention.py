@@ -131,13 +131,15 @@ class PositionalEmbedding(Layer):
 
 def _attention_compute(q, k, v, *, causal, impl, axis_name=None,
                        ring_block_size=None, window=None,
-                       segment_ids=None, block_len=None):
+                       segment_ids=None, block_len=None, scale=None):
     """Dispatch on attention implementation. q/k/v are BSHD.
 
     ``segment_ids`` (packed sequences) flows to EVERY impl (round 4):
     flash/xla mask in-kernel; ring rotates the k-side ids with their K/V
     shards; Ulysses all-gathers the ids alongside its head-scatter. For
     the sequence-parallel impls the ids are the local [B, S_local] shard.
+    ``scale`` multiplies the scores (None: the root of the head width);
+    the sequence-parallel impls take the default only.
     """
     if impl == "auto":
         # measured on TPU v5e (bench.py --model lm): the Pallas flash
@@ -149,7 +151,10 @@ def _attention_compute(q, k, v, *, causal, impl, axis_name=None,
         from distkeras_tpu.ops.flash_attention import flash_attention
         return flash_attention(q, k, v, causal=causal, window=window,
                                segment_ids=segment_ids,
-                               block_len=block_len)
+                               block_len=block_len, scale=scale)
+    if scale is not None and impl != "xla":
+        raise ValueError(f"an attention scale is not built for "
+                         f"attn_impl={impl!r}")
     if block_len is not None and impl != "xla":
         raise ValueError(
             f"block-causal attention is not supported with "
@@ -185,7 +190,7 @@ def _attention_compute(q, k, v, *, causal, impl, axis_name=None,
     note_path("flash_attention", "xla_reference")
     return dot_product_attention(q, k, v, causal=causal, window=window,
                                  segment_ids=segment_ids,
-                                 block_len=block_len)
+                                 block_len=block_len, scale=scale)
 
 
 @register_layer
@@ -205,6 +210,10 @@ class MultiHeadAttention(Layer):
 
     ``qk_norm`` adds an RMSNorm (epsilon 1e-6, one learned scale of
     ``head_dim``) over every head's query and key before RoPE.
+    ``attn_scale`` multiplies the scores in place of ``head_dim ** -0.5``
+    (a published attention multiplier); ``use_rope=False`` with no
+    position layer in the stack is attention with no position
+    embedding at all (NoPE).
     ``rope_base`` is RoPE's theta; ``rotary_dim`` rotates only the
     first so many dimensions of each head, ``rope_yarn`` gives YaRN's
     frequencies and attention factor (``ops.attention.yarn_inv_freq``).
@@ -228,7 +237,10 @@ class MultiHeadAttention(Layer):
                  qk_norm: bool = False, rope_base: float = 10000.0,
                  block_len: Optional[int] = None,
                  rotary_dim: Optional[int] = None,
-                 rope_yarn: Optional[dict] = None):
+                 rope_yarn: Optional[dict] = None,
+                 attn_scale: Optional[float] = None):
+        #: the scores' multiplier (None: the root of the head width)
+        self.attn_scale = None if attn_scale is None else float(attn_scale)
         self.rope_scale = float(rope_scale)
         self.rope_base = float(rope_base)
         #: RoPE over the first ``rotary_dim`` dimensions of each head
@@ -278,6 +290,13 @@ class MultiHeadAttention(Layer):
     @property
     def kv_heads(self) -> int:
         return self.num_kv_heads or self.num_heads
+
+    def softmax_scale(self, head_dim: int) -> float:
+        """The scores' multiplier: ``attn_scale``, else the root of the
+        head width."""
+        if self.attn_scale is not None:
+            return self.attn_scale
+        return (self.head_dim or head_dim) ** -0.5
 
     def init(self, rng, input_shape):
         d_model = input_shape[-1]
@@ -372,7 +391,8 @@ class MultiHeadAttention(Layer):
             out = flash_attention(q, k, v, causal=self.causal,
                                   layout="bhsd", window=self.attn_window,
                                   segment_ids=segment_ids,
-                                  block_len=self.block_len)
+                                  block_len=self.block_len,
+                                  scale=self.attn_scale)
             y = jnp.einsum("bhse,hed->bsd", out, params["wo"].astype(dt))
             return y.astype(x.dtype), state
 
@@ -390,7 +410,8 @@ class MultiHeadAttention(Layer):
                                  ring_block_size=self.ring_block_size,
                                  window=self.attn_window,
                                  segment_ids=segment_ids,
-                                 block_len=self.block_len)
+                                 block_len=self.block_len,
+                                 scale=self.attn_scale)
         y = jnp.einsum("bshe,hed->bsd", out, params["wo"].astype(dt))
         return y.astype(x.dtype), state
 
@@ -406,7 +427,8 @@ class MultiHeadAttention(Layer):
                 "attn_window": self.attn_window,
                 "qk_norm": self.qk_norm, "rope_base": self.rope_base,
                 "block_len": self.block_len,
-                "rotary_dim": self.rotary_dim, "rope_yarn": self.rope_yarn}
+                "rotary_dim": self.rotary_dim, "rope_yarn": self.rope_yarn,
+                "attn_scale": self.attn_scale}
 
 
 @register_layer
@@ -630,8 +652,12 @@ class TransformerBlock(Layer):
 
     ``mlp`` may be a ``TransformerMLP`` or a ``models.moe.MoE`` (expert
     parallelism); both expose the same Layer protocol. ``attn_layer``
-    takes the attention's place likewise (a ``LatentAttention``);
-    ``norm_eps`` states the norms' epsilon.
+    takes the attention's place likewise (a ``LatentAttention``, or a
+    ``models.mamba.Mamba2Mixer``: a state-space layer);
+    ``norm_eps`` states the norms' epsilon; ``residual_scale``
+    multiplies both branches before they join the residual stream
+    (``x + s * attn(n1(x))``, then ``x + s * mlp(n2(x))``);
+    ``attn_scale`` is the attention's ``attn_scale``.
 
     A SHORTCUT-CONNECTED expert layer spans two blocks. The first
     (``shortcut_layer=``) computes it from its own post-attention norm,
@@ -667,8 +693,12 @@ class TransformerBlock(Layer):
                  attn_layer: Optional[Layer] = None,
                  shortcut_layer: Optional[Layer] = None,
                  shortcut_add: bool = False,
-                 norm_eps: Optional[float] = None):
+                 norm_eps: Optional[float] = None,
+                 residual_scale: float = 1.0,
+                 attn_scale: Optional[float] = None):
         self.num_heads = int(num_heads)
+        self.residual_scale = float(residual_scale)
+        self.attn_scale = attn_scale
         self.rotary_dim = rotary_dim
         self.rope_yarn = rope_yarn
         self.norm_eps = None if norm_eps is None else float(norm_eps)
@@ -714,7 +744,8 @@ class TransformerBlock(Layer):
             ring_block_size=ring_block_size, num_kv_heads=num_kv_heads,
             rope_scale=rope_scale, attn_window=attn_window,
             qk_norm=qk_norm, rope_base=rope_base, block_len=block_len,
-            rotary_dim=rotary_dim, rope_yarn=rope_yarn)
+            rotary_dim=rotary_dim, rope_yarn=rope_yarn,
+            attn_scale=attn_scale)
         self.mlp = mlp_layer  # resolved in init once d_model is known
 
     def init(self, rng, input_shape):
@@ -738,6 +769,13 @@ class TransformerBlock(Layer):
             p["shortcut"], s["shortcut"], _ = self.shortcut.init(
                 jax.random.fold_in(ks[3], 1), tuple(input_shape))
         return p, s, tuple(input_shape)
+
+    def residual(self, x, y):
+        """``x + y`` on the residual stream, ``y`` times
+        ``residual_scale``, in the stream's type."""
+        if self.residual_scale != 1.0:
+            y = y * self.residual_scale
+        return x + y.astype(x.dtype)
 
     def apply(self, params, state, x, *, training=False, rng=None,
               segment_ids=None):
@@ -766,7 +804,7 @@ class TransformerBlock(Layer):
         use_dropout = self.dropout_rate and training and rng is not None
         if use_dropout:
             a = drop(a, k_drop1)
-        x = x + a
+        x = self.residual(x, a)
         with jax.named_scope("mlp"):
             h, new_state["norm2"] = self.norm2.apply(
                 params["norm2"], state["norm2"], x, training=training)
@@ -783,7 +821,7 @@ class TransformerBlock(Layer):
             return (x + m, handed), new_state
         if carry is not None:
             return x + m + carry, new_state
-        return x + m, new_state
+        return self.residual(x, m), new_state
 
     def sub_layers(self):
         subs = {"norm1": self.norm1, "attn": self.attn,
@@ -810,6 +848,10 @@ class TransformerBlock(Layer):
                "rotary_dim": self.rotary_dim, "rope_yarn": self.rope_yarn}
         if self.norm_eps is not None:
             cfg["norm_eps"] = self.norm_eps
+        if self.residual_scale != 1.0:
+            cfg["residual_scale"] = self.residual_scale
+        if self.attn_scale is not None:
+            cfg["attn_scale"] = self.attn_scale
         if self.shortcut_add:
             cfg["shortcut_add"] = True
         for key, layer in (("mlp_layer", self._mlp_override),

@@ -155,6 +155,11 @@ class Sequential(Layer):
         for layer in self.layers:
             rng, sub = jax.random.split(rng)
             p, s, shape = layer.init(sub, shape)
+            tied = getattr(layer, "tied_to", None)
+            if tied is not None:
+                # a tied layer (``layers.TiedHead``) shares the parameters
+                # of the layer it names: one table, not two of one value
+                p = params[tied]
             params.append(p)
             state.append(s)
         return params, state, shape

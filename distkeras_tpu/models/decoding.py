@@ -48,6 +48,8 @@ from distkeras_tpu.models.attention import (LatentAttention,
                                             TransformerBlock)
 from distkeras_tpu.models.core import Model, Sequential, scoped
 from distkeras_tpu.models.layers import Dropout
+from distkeras_tpu.models.mamba import Mamba2Mixer
+from distkeras_tpu.ops import ssm_kernels
 from distkeras_tpu.ops.attention import NEG_INF
 
 
@@ -80,7 +82,8 @@ def block_len_of(module: Sequential) -> Optional[int]:
 
 
 def init_cache(module: Sequential, batch: int, max_len: int,
-               dtype=jnp.float32, check_len: int = None):
+               dtype=jnp.float32, check_len: int = None,
+               state_batch: Optional[int] = None):
     """Per-layer KV buffers ([B, H, max_len, Dh]) mirroring the Sequential;
     non-attention layers get ``None``. The HEAD-major layout (round 4)
     keeps each head's [L, Dh] plane contiguous, so the per-step cache
@@ -106,6 +109,11 @@ def init_cache(module: Sequential, batch: int, max_len: int,
     per byte along the position axis). The empty ``"q4"`` marker leaf
     records the 4-bit grid in the pytree STRUCTURE (jit-static, rides
     through scans/vmaps for free).
+
+    A state-space block (``models.mamba.Mamba2Mixer``) gets ``{"ssm",
+    "conv"}``: a state and a convolution window a sequence, no position
+    axis, ``state_batch`` rows of each (default ``batch``; the page pool
+    passes its slot count, its ``batch`` being pages).
     """
     int4 = isinstance(dtype, str) and dtype == "int4"
     int8 = int4 or (isinstance(dtype, str) and dtype == "int8") or \
@@ -121,7 +129,14 @@ def init_cache(module: Sequential, batch: int, max_len: int,
                 f"PositionalEmbedding(max_len={layer.max_len}) is too small "
                 f"for a {need}-position decode cache")
         block = _decode_block_of(layer)
-        if block is not None and isinstance(block.attn, LatentAttention):
+        if block is not None and isinstance(block.attn, Mamba2Mixer):
+            if int8:
+                raise ValueError("a state-space layer keeps its cache in a "
+                                 "float type: no int8 / int4 pages")
+            rows = batch if state_batch is None else state_batch
+            cache.append({k: jnp.zeros(shape, t) for k, (shape, t)
+                          in block.attn.state_shapes(rows, dtype).items()})
+        elif block is not None and isinstance(block.attn, LatentAttention):
             # latent attention keeps ONE latent and one shared rope key
             # a token: a plane with no head axis and no separate V,
             # positions LAST (see the latent section below)
@@ -303,10 +318,24 @@ def _is_latent(block: TransformerBlock) -> bool:
     return isinstance(block.attn, LatentAttention)
 
 
-def _refuse_latent(block: TransformerBlock, what: str) -> None:
-    if _is_latent(block):
+def _is_ssm(block: TransformerBlock) -> bool:
+    return isinstance(block.attn, Mamba2Mixer)
+
+
+def state_layers(module: Sequential) -> list:
+    """Indices of the stack's state-space blocks (a cache a sequence,
+    not a token: ``init_cache``'s ``"ssm"`` / ``"conv"``)."""
+    return [i for i, layer in enumerate(module.layers)
+            if (blk := _decode_block_of(layer)) is not None
+            and _is_ssm(blk)]
+
+
+def _refuse_engine_only(block: TransformerBlock, what: str) -> None:
+    if _is_latent(block) or _is_ssm(block):
+        kind = "latent attention" if _is_latent(block) \
+            else "a state-space layer"
         raise NotImplementedError(
-            f"latent attention is not decoded by {what}: it is served "
+            f"{kind} is not decoded by {what}: it is served "
             "by ServingEngine (whole and chunked prefill, the paged "
             "one-token step)")
 
@@ -325,7 +354,7 @@ def _decode_attn(attn: MultiHeadAttention, p, kv, x, t):
         q = attn.rope(q, pos)
         k = attn.rope(k, pos)
     kv = _cache_write(kv, k, v, t)
-    scale = (attn.head_dim or q.shape[-1]) ** -0.5
+    scale = attn.softmax_scale(q.shape[-1])
     b = q.shape[0]
     hkv = attn.kv_heads
     g = attn.num_heads // hkv
@@ -374,15 +403,15 @@ def _decode_attn(attn: MultiHeadAttention, p, kv, x, t):
 
 
 def _decode_block(block: TransformerBlock, p, s, kv, x, t):
-    _refuse_latent(block, "generate()'s scalar step")
+    _refuse_engine_only(block, "generate()'s scalar step")
     with jax.named_scope("attn"):
         h, _ = block.norm1.apply(p["norm1"], s["norm1"], x)
         a, kv = _decode_attn(block.attn, p["attn"], kv, h, t)
-    x = x + a
+    x = block.residual(x, a)
     with jax.named_scope("mlp"):
         h, _ = block.norm2.apply(p["norm2"], s["norm2"], x)
         m, _ = block.mlp.apply(p["mlp"], s["mlp"], h, training=False)
-    return x + m, kv
+    return block.residual(x, m), kv
 
 
 def _prefill_block(block: TransformerBlock, p, s, kv, x, positions,
@@ -399,6 +428,8 @@ def _prefill_block(block: TransformerBlock, p, s, kv, x, positions,
     if _is_latent(block):
         return _latent_prefill_block(block, p, s, kv, x, positions, 0,
                                      routing)
+    if _is_ssm(block):
+        return _ssm_prefill_block(block, p, s, kv, x, 0, routing)
     attn = block.attn
     dt = jnp.dtype(attn.dtype)
     with jax.named_scope("attn"):
@@ -413,15 +444,16 @@ def _prefill_block(block: TransformerBlock, p, s, kv, x, positions,
         impl = "flash" if backend_is_tpu() else "xla"
         out = _attention_compute(q, ke, ve, causal=True, impl=impl,
                                  window=attn.attn_window,
-                                 block_len=attn.block_len)
+                                 block_len=attn.block_len,
+                                 scale=attn.attn_scale)
         y = jnp.einsum("bshe,hed->bsd", out.astype(dt), p["attn"]["wo"]
                        .astype(dt))
-    x = x + y.astype(x.dtype)
+    x = block.residual(x, y)
     with jax.named_scope("mlp"):
         h_, _ = block.norm2.apply(p["norm2"], s["norm2"], x)
         m = _apply_mlp_decode(block.mlp, p["mlp"], s["mlp"], h_,
                               routing is not None, routing)
-    return x + m, kv
+    return block.residual(x, m), kv
 
 
 def _merge_attention(o_a, lse_a, o_b, lse_b):
@@ -543,6 +575,8 @@ def _prefill_block_chunked(block: TransformerBlock, p, s, kv, x, positions,
     if _is_latent(block):
         return _latent_prefill_block(block, p, s, kv, x, positions, t0,
                                      routing, kv_only)
+    if _is_ssm(block):
+        return _ssm_prefill_block(block, p, s, kv, x, t0, routing, kv_only)
     attn = block.attn
     dt = jnp.dtype(attn.dtype)
     with jax.named_scope("attn"):
@@ -558,7 +592,7 @@ def _prefill_block_chunked(block: TransformerBlock, p, s, kv, x, positions,
         b, q_len, nh, dh = q.shape
         hkv = attn.kv_heads
         g = nh // hkv
-        scale = (attn.head_dim or dh) ** -0.5
+        scale = attn.softmax_scale(dh)
         window = attn.attn_window
         # (b) causal within the chunk (small: kv expansion is chunk-sized);
         # sliding-window models window the diagonal pass too
@@ -614,12 +648,12 @@ def _prefill_block_chunked(block: TransformerBlock, p, s, kv, x, positions,
             out = o_diag
         y = jnp.einsum("bshe,hed->bsd", out.astype(dt),
                        p["attn"]["wo"].astype(dt))
-    x = x + y.astype(x.dtype)
+    x = block.residual(x, y)
     with jax.named_scope("mlp"):
         h_, _ = block.norm2.apply(p["norm2"], s["norm2"], x)
         m = _apply_mlp_decode(block.mlp, p["mlp"], s["mlp"], h_,
                               routing is not None, routing)
-    return x + m, kv
+    return block.residual(x, m), kv
 
 # --- latent attention (MLA) -------------------------------------------------
 #
@@ -648,7 +682,7 @@ def _block_tail(block: TransformerBlock, p, s, x, y, carry,
     MLP on the post-attention norm, and the shortcut-connected expert
     layer: computed from that same norm and handed on (``(x, m)``), or
     the one handed in (``carry``) added after the MLP."""
-    x = x + y.astype(x.dtype)
+    x = block.residual(x, y)
     with jax.named_scope("mlp"):
         u, _ = block.norm2.apply(p["norm2"], s["norm2"], x)
         m = _apply_mlp_decode(block.mlp, p["mlp"], s["mlp"], u,
@@ -658,7 +692,7 @@ def _block_tail(block: TransformerBlock, p, s, x, y, carry,
             handed = _apply_mlp_decode(
                 block.shortcut, p["shortcut"], s["shortcut"], u,
                 moe_dispatched, routing)
-    x = x + m
+    x = block.residual(x, m)
     if carry is not None:
         x = x + carry
     return x if handed is None else (x, handed)
@@ -758,6 +792,63 @@ def _latent_decode_block(block: TransformerBlock, p, s, kv, x, t, table,
         y = attn.unabsorb_v(p["attn"], o, dt)
     return _block_tail(block, p, s, x, y, carry, moe_dispatched,
                        routing), {"c": plane}
+
+
+# --- state-space layers (Mamba-2) ------------------------------------------
+#
+# A ``Mamba2Mixer`` block's cache entry is ``{"ssm": [B, H, P, N] float32,
+# "conv": [B, K-1, conv_dim]}``: the state and the last inputs of the
+# convolution of ONE sequence a row, whatever its length: no positions and
+# no pages. In the page pool the rows are the SLOTS (``PagedKVPool``'s
+# state group); in the staging cache, the one sequence being prefilled.
+# PREFILL runs the chunked scan over a chunk from the state the chunk
+# before it left (from zero at ``t0 = 0``: staging rows are reused from
+# one request to the next); DECODE updates the row of every live slot in
+# place and leaves the others bit for bit (``ops.ssm_kernels``).
+
+
+def _ssm_prefill_block(block: TransformerBlock, p, s, kv, x, t0: int,
+                       routing=None, kv_only: bool = False):
+    """One chunk ``[t0, t0 + Q)`` of one state-space block: the scan
+    from the carried state (zero at ``t0 = 0``), the gated output and
+    the block's MLP. ``kv_only``: stop once the state is written."""
+    mixer = block.attn
+    dt = jnp.dtype(mixer.dtype)
+    with jax.named_scope("attn"):
+        h_, _ = block.norm1.apply(p["norm1"], s["norm1"], x)
+        z, xbc, step = mixer.project(p["attn"], h_.astype(dt))
+        state, window = kv["ssm"], kv["conv"]
+        if t0 == 0:
+            state, window = jnp.zeros_like(state), jnp.zeros_like(window)
+        y, state, window = ssm_kernels.ssd_chunk_scan(
+            xbc, step, window, state, *mixer.scan_args(p["attn"]),
+            heads=mixer.num_heads, d_state=mixer.d_state,
+            chunk=mixer.chunk)
+        kv = {"ssm": state, "conv": window}
+        if kv_only:
+            return x, kv
+        y = mixer.out(p["attn"], y, z, dt)
+    return _block_tail(block, p, s, x, y, None, routing is not None,
+                       routing), kv
+
+
+def _ssm_decode_block(block: TransformerBlock, p, s, kv, x, t,
+                      live_len: int, moe_dispatched=True, routing=None):
+    """One token a slot through a state-space block: every slot's row
+    of the state and the window updated in place where ``t < live_len``
+    (``ops.ssm_kernels.ssm_decode_step``), the gated output, the MLP."""
+    mixer = block.attn
+    dt = jnp.dtype(mixer.dtype)
+    with jax.named_scope("attn"):
+        h_, _ = block.norm1.apply(p["norm1"], s["norm1"], x)
+        z, xbc, step = mixer.project(p["attn"], h_.astype(dt))
+        y, state, window = ssm_kernels.ssm_decode_step(
+            xbc[:, 0], step[:, 0], kv["conv"], kv["ssm"],
+            *mixer.scan_args(p["attn"]), t, heads=mixer.num_heads,
+            d_state=mixer.d_state, live_len=live_len)
+        y = mixer.out(p["attn"], y[:, None], z, dt)
+    return _block_tail(block, p, s, x, y, None, moe_dispatched,
+                       routing), {"ssm": state, "conv": window}
 
 
 def prefill_chunk_step(module: Sequential, params, state, cache, chunk,
@@ -1077,7 +1168,7 @@ def _slot_attn_readout(attn: MultiHeadAttention, p, q, kv, t, dt,
     ``kpos`` ([S, L] int, a gathered RING of a window layer's pages):
     the position each key of the view holds (negative: none), in place
     of its index; chain windows of a sliding-window layer only."""
-    scale = (attn.head_dim or q.shape[-1]) ** -0.5
+    scale = attn.softmax_scale(q.shape[-1])
     b = q.shape[0]
     w_len = q.shape[1]
     hkv = attn.kv_heads
@@ -1120,16 +1211,16 @@ def _decode_attn_slots(attn: MultiHeadAttention, p, kv, x, t):
 def _decode_block_slots(block: TransformerBlock, p, s, kv, x, t,
                         moe_dispatched=True, routing=None):
     """Contiguous-cache reference; the engine does not call this."""
-    _refuse_latent(block, "the contiguous-cache reference step")
+    _refuse_engine_only(block, "the contiguous-cache reference step")
     with jax.named_scope("attn"):
         h, _ = block.norm1.apply(p["norm1"], s["norm1"], x)
         a, kv = _decode_attn_slots(block.attn, p["attn"], kv, h, t)
-    x = x + a
+    x = block.residual(x, a)
     with jax.named_scope("mlp"):
         h, _ = block.norm2.apply(p["norm2"], s["norm2"], x)
         m = _apply_mlp_decode(block.mlp, p["mlp"], s["mlp"], h,
                               moe_dispatched, routing)
-    return x + m, kv
+    return block.residual(x, m), kv
 
 
 def decode_step_slots(module: Sequential, params, state, cache, tok, t,
@@ -1378,7 +1469,7 @@ def _paged_attn_readout(attn: MultiHeadAttention, p, q, kv, t, table,
     b, w_len, nh, dh = q.shape
     hkv = attn.kv_heads
     g = nh // hkv
-    scale = (attn.head_dim or dh) ** -0.5
+    scale = attn.softmax_scale(dh)
     qg = q.astype(jnp.float32).reshape(b, w_len, hkv, g, dh)
     sc = {}
     if "k_scale" in kv:
@@ -1425,19 +1516,19 @@ def _decode_block_slots_paged(block: TransformerBlock, p, s, kv, x, t,
         a, kv = _decode_attn_slots_paged(block.attn, p["attn"], kv, h, t,
                                          table, page_len, paged_kernel,
                                          ring)
-    x = x + a
+    x = block.residual(x, a)
     with jax.named_scope("mlp"):
         h, _ = block.norm2.apply(p["norm2"], s["norm2"], x)
         m = _apply_mlp_decode(block.mlp, p["mlp"], s["mlp"], h,
                               moe_dispatched, routing)
-    return x + m, kv
+    return block.residual(x, m), kv
 
 
 def decode_step_slots_paged(module: Sequential, params, state, cache,
                             tok, t, table, page_len: int,
                             *, moe_dispatched: bool = True,
                             moe_stats=None, paged_kernel=None,
-                            groups=None):
+                            groups=None, live_len: Optional[int] = None):
     """One token through the stack against a PAGED pooled cache: tok
     [S] int, t [S] int, table [S, P] int page tables; returns
     ([S, V] logits, cache). The paged mirror of ``decode_step_slots``
@@ -1453,14 +1544,26 @@ def decode_step_slots_paged(module: Sequential, params, state, cache,
     ``paged_kernel`` selects the readout (decode-kernel PR): None =
     the Pallas page-table kernel on TPU and the ``_gather_pages``
     reference elsewhere; True forces the kernel (interpret mode
-    off-TPU — the oracle hook); False forces the gather path."""
+    off-TPU — the oracle hook); False forces the gather path.
+
+    State-space blocks update the rows of the slots whose ``t`` is under
+    ``live_len`` (the engine's free-slot sentinel, its ``max_len``; a
+    model with such blocks must pass it) and give every other slot its
+    row back as it was: the sentinel marks a slot that is free or still
+    prefilling."""
     x = tok[:, None]                                     # [S, 1]
     new_cache = list(cache)
     routing = [] if moe_stats is not None else None
     for i, layer in enumerate(module.layers):
         p, s, kv = params[i], state[i], cache[i]
         block = _decode_block_of(layer)
-        if block is not None:
+        if block is not None and _is_ssm(block):
+            if live_len is None:
+                raise ValueError("a state-space block needs live_len: the "
+                                 "position at which a slot is not decoding")
+            x, new_cache[i] = _ssm_decode_block(
+                block, p, s, kv, x, t, live_len, moe_dispatched, routing)
+        elif block is not None:
             tbl, ring = (table, None) if groups is None \
                 else (table[groups[i][0]], groups[i][1])
             x, new_cache[i] = _decode_block_slots_paged(
@@ -1520,7 +1623,7 @@ def _decode_block_slots_window(block: TransformerBlock, p, s, kv, x, t,
     path at its contiguous final positions. ``kv_only``: the block
     stops once the window's K/V are written (the deepest block of a
     pass that needs no logits)."""
-    _refuse_latent(block, "a verify or block-diffusion window")
+    _refuse_engine_only(block, "a verify or block-diffusion window")
     attn = block.attn
     with jax.named_scope("attn"):
         h, _ = block.norm1.apply(p["norm1"], s["norm1"], x)
@@ -1557,12 +1660,12 @@ def _decode_block_slots_window(block: TransformerBlock, p, s, kv, x, t,
             y = _paged_attn_readout(attn, p["attn"], q, kv, t, table,
                                     page_len, dt, paged_kernel, tree=tree,
                                     full_window=full)
-    x = x + y.astype(x.dtype)
+    x = block.residual(x, y)
     with jax.named_scope("mlp"):
         h, _ = block.norm2.apply(p["norm2"], s["norm2"], x)
         m = _apply_mlp_decode(block.mlp, p["mlp"], s["mlp"], h,
                               moe_dispatched, routing)
-    return x + m, kv
+    return block.residual(x, m), kv
 
 
 def _verify_window(module: Sequential, params, state, cache, toks, t,
@@ -2302,7 +2405,7 @@ def generate(model: Model, prompts, max_new_tokens: int,
     for layer in module.layers:
         blk = _decode_block_of(layer)
         if blk is not None:
-            _refuse_latent(blk, "generate()")
+            _refuse_engine_only(blk, "generate()")
     if block_len_of(module) is not None:
         raise ValueError(
             "generate() decodes one token a step; a block-causal "

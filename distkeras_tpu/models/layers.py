@@ -641,3 +641,56 @@ class Embedding(Layer):
     def get_config(self):
         return {"vocab_size": self.vocab_size, "dim": self.dim,
                 "embeddings_init": self.embeddings_init}
+
+
+@register_layer
+class Scale(Layer):
+    """``x * factor`` in the input's type: a fixed multiplier with no
+    parameters (a language model's embedding multiplier)."""
+
+    def __init__(self, factor: float):
+        self.factor = float(factor)
+
+    def init(self, rng, input_shape):
+        return {}, {}, tuple(input_shape)
+
+    def apply(self, params, state, x, *, training=False, rng=None):
+        return (x * self.factor).astype(x.dtype), state
+
+    def get_config(self):
+        return {"factor": self.factor}
+
+
+@register_layer
+class TiedHead(Layer):
+    """The vocabulary head of a model whose head IS its embedding: logits
+    ``(x E^T) * scale`` over a table ``E`` [vocab, d] laid out as the
+    ``Embedding``'s. ``Sequential.init`` hands it the parameters of the
+    layer ``tied_to`` names (the stack's first), so both are one table;
+    ``scale`` is the logits' multiplier (``1 / logits_scaling``). The
+    tie holds for serving: a trainer sees two leaves of one value and
+    would update them apart."""
+
+    #: the layer of the stack whose parameters this one shares
+    tied_to = 0
+
+    def __init__(self, vocab_size: int, scale: float = 1.0,
+                 dtype: str = "float32"):
+        self.vocab_size = int(vocab_size)
+        self.scale = float(scale)
+        self.dtype = dtype
+
+    def init(self, rng, input_shape):
+        params = {"embeddings": init_weights(
+            "uniform_scaling", rng, (self.vocab_size, input_shape[-1]))}
+        return params, {}, tuple(input_shape[:-1]) + (self.vocab_size,)
+
+    def apply(self, params, state, x, *, training=False, rng=None):
+        dt = jnp.dtype(self.dtype)
+        y = jnp.einsum("...d,vd->...v", x.astype(dt),
+                       params["embeddings"].astype(dt))
+        return (y if self.scale == 1.0 else y * self.scale), state
+
+    def get_config(self):
+        return {"vocab_size": self.vocab_size, "scale": self.scale,
+                "dtype": self.dtype}

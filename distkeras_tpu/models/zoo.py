@@ -184,7 +184,11 @@ def transformer_lm(vocab_size: int, d_model: int = 512, num_heads: int = 8,
                    moe_zero_experts: int = 0,
                    moe_experts_held: Optional[Sequence[int]] = None,
                    moe_select_bias: bool = False,
-                   norm_eps: Optional[float] = None) -> Sequential:
+                   norm_eps: Optional[float] = None,
+                   embedding_multiplier: float = 1.0,
+                   residual_multiplier: float = 1.0,
+                   logits_scaling: float = 1.0,
+                   tie_embeddings: bool = False) -> Sequential:
     """Decoder-only causal transformer LM — the long-context flagship.
 
     Absent from the reference (no attention models; SURVEY §5.7); this is
@@ -257,11 +261,31 @@ def transformer_lm(vocab_size: int, d_model: int = 512, num_heads: int = 8,
     (``TransformerBlock``'s class doc); it is two blocks of the
     ``Sequential`` and two entries of a serving cache. ``norm_eps``
     states every norm's epsilon.
+
+    STATE-SPACE layers: an ``attn_kinds[kind]`` with a ``"mamba"`` entry
+    (``{"num_heads", "head_dim", "d_state", "d_conv", "n_groups",
+    "chunk"}``) makes the layers of that kind ``models.mamba.
+    Mamba2Mixer`` blocks (the mixer in attention's place, the block's
+    MLP or experts after it); ``ServingEngine`` keeps a state a slot for
+    them (docs/serving.md §State groups). An attention kind may also
+    state ``use_rope`` (False: no position embedding at all, NoPE, when
+    the stack has no position layer) and ``attn_scale`` (the scores'
+    multiplier). ``embedding_multiplier`` scales the embedding's output,
+    ``residual_multiplier`` both branches of every block before they
+    join the residual stream, ``logits_scaling`` divides the logits,
+    and ``tie_embeddings`` makes the head the embedding's own table
+    (``layers.TiedHead``; ``logits_scaling`` needs it).
     """
     from distkeras_tpu.models.attention import (
         LayerNorm, PositionalEmbedding, RMSNorm, TransformerBlock)
 
     layers = [Embedding(vocab_size, d_model)]
+    if embedding_multiplier != 1.0:
+        from distkeras_tpu.models.layers import Scale
+        layers.append(Scale(embedding_multiplier))
+    if logits_scaling != 1.0 and not tie_embeddings:
+        raise ValueError("logits_scaling is built for a tied head "
+                         "(tie_embeddings=True)")
     if not use_rope:
         if max_len is None:
             raise ValueError("max_len required when use_rope=False")
@@ -288,16 +312,21 @@ def transformer_lm(vocab_size: int, d_model: int = 512, num_heads: int = 8,
         if layer_types is not None:
             kind = dict((attn_kinds or {}).get(layer_types[i], {}))
             unknown = set(kind) - set(attn) - {"rotary_dim", "rope_yarn",
-                                               "latent"}
+                                               "latent", "mamba",
+                                               "use_rope", "attn_scale"}
             if unknown:
                 raise ValueError(
                     f"attn_kinds[{layer_types[i]!r}] has unknown keys "
                     f"{sorted(unknown)}")
             attn.update(kind)
         latent = attn.pop("latent", None)
-        if latent is not None and block_len is not None:
+        mamba = attn.pop("mamba", None)
+        layer_rope = attn.pop("use_rope", use_rope)
+        if (latent is not None or mamba is not None) \
+                and block_len is not None:
             raise ValueError("block_len (block-causal attention) is not "
-                             "built for latent attention layers")
+                             "built for latent attention or state-space "
+                             "layers")
         if mlp_layer_types is None:
             sparse = bool(moe_every and num_experts
                           and (i + 1) % moe_every == 0)
@@ -332,8 +361,15 @@ def transformer_lm(vocab_size: int, d_model: int = 512, num_heads: int = 8,
         elif mlp_layer_types is not None and dense_mlp_dim:
             layer_mlp_dim = dense_mlp_dim
         extra = {} if norm_eps is None else {"norm_eps": norm_eps}
+        if residual_multiplier != 1.0:
+            extra["residual_scale"] = residual_multiplier
 
         def attention():
+            if mamba is not None:
+                from distkeras_tpu.models.mamba import Mamba2Mixer
+                return {"attn_layer": Mamba2Mixer(
+                    norm_eps=1e-5 if norm_eps is None else norm_eps,
+                    dtype=dtype, **mamba)}
             if latent is None:
                 return {}
             from distkeras_tpu.models.attention import LatentAttention
@@ -347,7 +383,7 @@ def transformer_lm(vocab_size: int, d_model: int = 512, num_heads: int = 8,
         def block_of(**kw):
             block = TransformerBlock(
                 mlp_ratio=mlp_ratio, causal=True,
-                use_rope=use_rope, activation=mlp_activation,
+                use_rope=layer_rope, activation=mlp_activation,
                 norm=norm, dtype=dtype, attn_impl=attn_impl,
                 seq_axis_name=seq_axis_name,
                 block_len=block_len,
@@ -373,7 +409,12 @@ def transformer_lm(vocab_size: int, d_model: int = 512, num_heads: int = 8,
                       else LayerNorm(norm_eps))
     else:
         layers.append(RMSNorm() if norm == "rmsnorm" else LayerNorm())
-    layers.append(Dense(vocab_size, use_bias=False, dtype=dtype))
+    if tie_embeddings:
+        from distkeras_tpu.models.layers import TiedHead
+        layers.append(TiedHead(vocab_size, 1.0 / logits_scaling,
+                               dtype=dtype))
+    else:
+        layers.append(Dense(vocab_size, use_bias=False, dtype=dtype))
     return Sequential(layers)
 
 

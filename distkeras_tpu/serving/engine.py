@@ -109,7 +109,8 @@ from distkeras_tpu.models.decoding import (_attn_compute_dtype,
                                            decode_fused_slots,
                                            decode_step_slots_paged,
                                            prefill, prefill_chunk_step,
-                                           routing_counts, tree_walk,
+                                           routing_counts, state_layers,
+                                           tree_walk,
                                            verify_step_slots_paged)
 from distkeras_tpu.models.moe import MoE
 from distkeras_tpu.resilience import faults
@@ -363,6 +364,21 @@ class ServingEngine:
     Greedy only, no speculation, fused windows or host offload; the
     prefix cache shares at block boundaries.
 
+    State-space layers (docs/serving.md §State groups): a model whose
+    blocks include ``models.mamba.Mamba2Mixer`` layers keeps, for each,
+    a state and a convolution window a slot beside the K/V pages of its
+    attention layers (``PagedKVPool``'s state group). A slot's rows are
+    zeroed when a request takes it (``serving.ssm.reset``), written
+    whole by the last chunk of its prefill, and updated in place by
+    each decode step, which leaves the rows of slots still prefilling
+    as they were; a preempted request re-prefills prompt + generated.
+    Refused by name, there being no snapshot of a state yet: the
+    prefix cache (``prefix_cache=False`` is required), ``draft``,
+    ``fuse_steps``, ``host_kv_pages``, block diffusion, ``ep_mesh``
+    and ``weight_quant``. ``ssm_counters`` counts what the state
+    group did (slot updates by decode, positions scanned by prefill,
+    resets).
+
     A dispatched-MoE engine also feeds MoE telemetry: per-expert load
     and router-entropy gauges (``ServingMetrics.record_moe_route``), a
     ``moe_route`` tracer event on the decode cadence, and a smoothed
@@ -405,6 +421,24 @@ class ServingEngine:
         if not isinstance(module, Sequential):
             raise TypeError("ServingEngine expects a Sequential LM "
                             f"(got {type(module).__name__})")
+        #: the state-space layers (class doc: the state group)
+        self._ssm_layers = state_layers(module)
+        if self._ssm_layers:
+            refused = [name for name, on in (
+                ("prefix_cache", prefix_cache),
+                ("draft", draft is not None), ("fuse_steps", fuse_steps),
+                ("host_kv_pages", host_kv_pages),
+                ("block diffusion", block_len_of(module) is not None),
+                ("ep_mesh", ep_mesh is not None),
+                ("weight_quant", weight_quant is not None)) if on]
+            if refused:
+                raise ValueError(
+                    "a model with state-space layers is served without "
+                    f"{', '.join(refused)}: a slot's state has no snapshot "
+                    "to share, roll back or swap yet")
+        self.ssm_counters = dict.fromkeys(
+            ("ssm_state_slot_steps", "ssm_prefill_positions",
+             "ssm_state_resets"), 0)
         self.model = model
         self.module = module
         _resolve_head_dims(module, model.params)
@@ -1213,7 +1247,7 @@ class ServingEngine:
             if po > so or pr > sr:
                 m.record_offload(po - so, pr - sr, ob - sb)
                 self._off_seen = (po, pr, ob)
-            if self.pool.aux or self.pool.latent:
+            if self.pool.aux or self.pool.latent or self.pool.state_layers:
                 m.record_kv_groups(self._kv_groups())
             if self.prefix is not None:
                 now = (self.prefix.evictions,
@@ -1264,6 +1298,12 @@ class ServingEngine:
             "pages_free": pool.free_pages,
             "pages_live": int((pool.tables < pool.num_pages).sum()),
             "pages_shared": pool.shared_pages, "pages_released": 0}}
+        if pool.state_layers:
+            # the state group: rows a slot, not pages (class doc)
+            out["ssm"] = {"window": None, "layers": len(pool.state_layers),
+                          "slots": pool.num_slots,
+                          "bytes": pool.state_bytes,
+                          "resets": self.ssm_counters["ssm_state_resets"]}
         for g, grp in enumerate(pool.aux):
             out[grp.name] = {
                 "window": grp.window, "pages_total": grp.num_pages,
@@ -1395,6 +1435,8 @@ class ServingEngine:
         for slot, _ in slots:
             self._t[slot] += count
             dirty[slot] = False          # chain live until overridden
+        self.ssm_counters["ssm_state_slot_steps"] += \
+            count * len(slots) * len(self._ssm_layers)
         prefills, self._prefill_routed = self._prefill_routed, []
         return _PendingStep(nxt, last, keys, moe, slots, count, t0,
                             tuple(prefills))
@@ -1548,7 +1590,8 @@ class ServingEngine:
             page_len = self.page_len
             moe_kw = dict(
                 moe_dispatched=self._moe_dispatched,
-                moe_stats=self.max_len if self._moe_stats_on else None)
+                moe_stats=self.max_len if self._moe_stats_on else None,
+                live_len=self.max_len)
             stats_on = self._moe_stats_on
             pk = self._paged_kernel
             if self._groups is not None:
@@ -2034,6 +2077,12 @@ class ServingEngine:
                 sch.admit_one(req)
                 self._comp_ver += 1
                 self._apply_page_plan(req, plan)
+                if self._ssm_layers:
+                    # the slot's state rows start from zero (a
+                    # previous occupant's, or garbage, are there)
+                    with obs.span("serving.ssm.reset"):
+                        self.pool.reset_state(req.slot)
+                    self.ssm_counters["ssm_state_resets"] += 1
                 admitted.append(req)
                 continue
             if not self._preempt_victim(beneficiary=req,
@@ -2961,7 +3010,7 @@ class ServingEngine:
                 "free": pool.host_free_pages,
                 "offloaded": pool.pages_offloaded,
                 "restored": pool.pages_restored})}
-        if pool.aux or pool.latent:
+        if pool.aux or pool.latent or pool.state_layers:
             out["kv_groups"] = self._kv_groups()
         out["prefix_cache"] = (
             None if self.prefix is None else {
@@ -2995,7 +3044,9 @@ class ServingEngine:
                           else bool(self._moe)
                           and moe_decode == "dispatched")
             module, page_len = self.module, self.page_len
-            kw = {} if self._groups is None else {"groups": self._groups}
+            kw = {"live_len": self.max_len}
+            if self._groups is not None:
+                kw["groups"] = self._groups
 
             def f(params, state, cache, tok, t, tables):
                 return decode_step_slots_paged(
@@ -3114,6 +3165,8 @@ class ServingEngine:
         # pass's results (``_block_step``), not waited for here
         self._prefill_routed += routed
         req.prefill_pos = t0 + q_len
+        self.ssm_counters["ssm_prefill_positions"] += \
+            q_len * len(self._ssm_layers)
         self.metrics.record_prefill_chunk()
         self.tracer.on_prefill_chunk(req.rid, t0, q_len)
         if not final:

@@ -37,6 +37,17 @@ the positions last (``pool.latent``; ``models.decoding`` says why). Tables, refc
 loads are the pool's as ever; there is no host tier, no quantized page
 and no byte budget for them yet.
 
+STATE GROUP: a model with state-space layers (``models.mamba.
+Mamba2Mixer``) keeps, for each of them, a state and a convolution window
+a SLOT, not a page: ``[num_slots, H, P, N]`` float32 and ``[num_slots,
+K-1, conv_dim]`` (``"ssm"`` / ``"conv"`` beside the K/V planes of its
+attention layers). ``layer_groups`` names the group: ``("ssm", None)``
+for those layers, ``(0, None)`` for the attention layers' pages. The rows
+are written whole by the insert of a prefill (the staging cache's one
+row), zeroed by ``reset_state`` when a request takes the slot, and
+updated in place by the decode steps; no table, no refcount, no prefix,
+no host tier.
+
 PAGE GROUPS BY ATTENTION KIND: a model whose layers are not all of one
 kind (some attend to the whole context, some to a sliding window) gets
 one group of pages per kind. The layers without a window are the pool
@@ -81,15 +92,20 @@ import numpy as np
 
 from distkeras_tpu.models.attention import LatentAttention
 from distkeras_tpu.models.decoding import (_decode_block_of, init_cache,
-                                           pack_int4, unpack_int4)
+                                           pack_int4, state_layers,
+                                           unpack_int4)
 
 
 #: refcount slot for "no page": table entries >= num_pages are the
 #: unallocated sentinel (scatter drops, gather clamps into masked range)
 
 
+#: the planes of a state-space layer: rows are slots, not pages
+STATE_KEYS = ("ssm", "conv")
+
+
 @partial(jax.jit, donate_argnums=0, static_argnames=("groups",))
-def _write_pages(pool, staging, table, groups=None):
+def _write_pages(pool, staging, table, slot, groups=None):
     """Scatter staging pages into the pool: logical page ``p`` of the
     batch-1 staging cache lands on physical page ``table[p]``; sentinel
     entries (>= N) drop. One compiled program serves every insert —
@@ -101,7 +117,8 @@ def _write_pages(pool, staging, table, groups=None):
     ``pool`` is donated (the pages are written in place, the marker
     leaf passes through aliased); ``staging`` lives on. ``groups`` (a
     pool with page groups: per layer its group's index) makes ``table``
-    the tuple of one such vector per group."""
+    the tuple of one such vector per group. A state plane's one staging
+    row lands on row ``slot``."""
     def write(pl, st, packed, table, latent=False):
         if latent:
             # a latent plane [N, C, page_len] has no head axis: staging
@@ -126,6 +143,10 @@ def _write_pages(pool, staging, table, groups=None):
         if pl_kv is None:
             out.append(None)
             continue
+        if "ssm" in pl_kv:
+            out.append({key: pl.at[slot].set(st_kv[key][0].astype(pl.dtype))
+                        for key, pl in pl_kv.items()})
+            continue
         q4 = "q4" in pl_kv
         tbl = table if groups is None else table[groups[i]]
         out.append({
@@ -134,6 +155,16 @@ def _write_pages(pool, staging, table, groups=None):
                        key == "c")
             for key, pl in pl_kv.items()})
     return out
+
+
+@partial(jax.jit, donate_argnums=0)
+def _zero_state(pool, slot):
+    """Row ``slot`` of every state plane to zero; the K/V planes pass
+    through aliased. ``pool`` is donated."""
+    return [kv if kv is None or "ssm" not in kv else
+            {key: pl.at[slot].set(jnp.zeros(pl.shape[1:], pl.dtype))
+             for key, pl in kv.items()}
+            for kv in pool]
 
 
 @jax.jit
@@ -189,8 +220,8 @@ def _load_pages(staging, pool, table, valid, groups=None):
         return sel.transpose(1, 0, 2).reshape(1, h, s_max)
     out = []
     for i, (st_kv, pl_kv) in enumerate(zip(staging, pool)):
-        if st_kv is None:
-            out.append(None)
+        if st_kv is None or "ssm" in st_kv:
+            out.append(st_kv)
             continue
         q4 = "q4" in pl_kv
         tbl, ok = (table, valid) if groups is None \
@@ -414,9 +445,11 @@ class PagedKVPool(_PageBook):
         # decide. One kind: this pool alone, as ever. Several: the
         # layers without a window are this pool, each window a group
         windows, kinds = {}, set()
+        #: the state-space layers (the state group; module doc)
+        self.state_layers = state_layers(module)
         for i, layer in enumerate(module.layers):
             blk = _decode_block_of(layer)
-            if blk is not None:
+            if blk is not None and i not in self.state_layers:
                 windows.setdefault(blk.attn.attn_window, []).append(i)
                 kinds.add(isinstance(blk.attn, LatentAttention))
         #: the pool's layers are LATENT attention: the planes are
@@ -431,11 +464,24 @@ class PagedKVPool(_PageBook):
             raise ValueError(
                 "latent pages are served without host_kv_pages, "
                 "hbm_budget or int8 / int4 pages")
+        if self.state_layers and (
+                host_pages or hbm_budget is not None or self.latent
+                or len(windows) > 1 or isinstance(dtype, str)):
+            raise ValueError(
+                "state-space layers are served beside full attention "
+                "from float pages: no host_kv_pages, hbm_budget, int8 / "
+                "int4 pages, window groups or latent pages")
         self.aux: List[WindowPages] = []
         #: per layer ``(group index, ring)`` (None: no attention), or
         #: None for a pool of one group: ``ring`` is the logical pages a
-        #: slot spans where the group's table is a ring, else None
+        #: slot spans where the group's table is a ring, else None. A
+        #: state-space layer's entry is ``("ssm", None)`` (module doc)
         self.layer_groups = None
+        if self.state_layers:
+            self.layer_groups = tuple(
+                ("ssm", None) if i in self.state_layers
+                else None if _decode_block_of(layer) is None else (0, None)
+                for i, layer in enumerate(module.layers))
         aux_pages = ()
         if len(windows) > 1:
             if None not in windows:
@@ -487,7 +533,8 @@ class PagedKVPool(_PageBook):
             # position table is validated against max_len (check_len),
             # not the page length
             self.cache = init_cache(module, self.num_pages, self.page_len,
-                                    dtype, check_len=self.max_len)
+                                    dtype, check_len=self.max_len,
+                                    state_batch=self.num_slots)
         else:
             kinds = sorted(w for w in windows if w is not None)
             if len(aux_pages) > len(kinds):
@@ -533,6 +580,10 @@ class PagedKVPool(_PageBook):
                           if key in ("k", "v") else a)
                     for key, a in kv.items()}
                 for kv in self.cache]
+        #: bytes of the state group's planes (every slot's row)
+        self.state_bytes = sum(
+            a.nbytes for i in self.state_layers
+            for a in self.cache[i].values())
         self.tables = np.full((self.num_slots, self.pages_per_slot),
                               self.num_pages, np.int32)
         #: cached [pages_per_slot] logical-page index — reused by the
@@ -592,7 +643,7 @@ class PagedKVPool(_PageBook):
             if kv is None or (layers is not None and i not in layers):
                 continue
             for key, a in kv.items():
-                if key == "q4":
+                if key == "q4" or key in STATE_KEYS:
                     continue
                 n = int(np.prod(a.shape)) * jnp.dtype(a.dtype).itemsize
                 if int4 and key in ("k", "v"):
@@ -621,9 +672,10 @@ class PagedKVPool(_PageBook):
         page assignment made after its dispatch."""
         if self._tables_dev is None:
             self._tables_dev = jnp.asarray(self.tables.copy())
-        if self.aux:
+        if self.layer_groups is not None:
             # one table a group, the window groups' rings behind this
-            # pool's own (``layer_groups`` says which layer reads which)
+            # pool's own (``layer_groups`` says which layer reads which;
+            # the state group reads none)
             return (self._tables_dev,) + tuple(
                 g.device_table() for g in self.aux)
         return self._tables_dev
@@ -819,13 +871,20 @@ class PagedKVPool(_PageBook):
         n_needed = self.pages_for(n_pos)
         tv = np.full(self.pages_per_slot, self.num_pages, np.int32)
         tv[skip_pages:n_needed] = self.tables[slot, skip_pages:n_needed]
+        slot = jnp.int32(slot)
         if not self.aux:
-            self.cache = _write_pages(self.cache, staging, jnp.asarray(tv))
+            self.cache = _write_pages(self.cache, staging, jnp.asarray(tv),
+                                      slot)
             return
         tvs, _ = self._group_vectors(aux_pages)
         self.cache = _write_pages(self.cache, staging,
-                                  (jnp.asarray(tv), *tvs),
+                                  (jnp.asarray(tv), *tvs), slot,
                                   groups=self._group_of_layer)
+
+    def reset_state(self, slot: int) -> None:
+        """Zero the slot's row of every state plane (a request takes the
+        slot: it starts from no state). The pool is donated."""
+        self.cache = _zero_state(self.cache, jnp.int32(slot))
 
     def load_prefix(self, staging, page_ids: List[int], n_tokens: int,
                     aux_pages=None):

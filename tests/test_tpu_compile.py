@@ -61,10 +61,10 @@ def as_tpu(monkeypatch):
     from distkeras_tpu.models import attention, decoding
     from distkeras_tpu.ops import (decode_attention, flash_attention,
                                    moe_kernels, paged_attention,
-                                   quant_matmul, sampling)
+                                   quant_matmul, sampling, ssm_kernels)
     for mod in (compat, attention, decoding, decode_attention,
                 flash_attention, moe_kernels, paged_attention,
-                quant_matmul, sampling):
+                quant_matmul, sampling, ssm_kernels):
         monkeypatch.setattr(mod, "backend_is_tpu", lambda: True)
 
 
@@ -877,3 +877,151 @@ def test_prefill_chunk_over_latent_prefix(one_chip, as_tpu, t0, final):
     flash = (2 if t0 else 1) * (2 if final else 1)
     assert text.count('custom_call_target="tpu_custom_call"') \
         == flash + (1 if final else 0)
+
+
+# --- state-space layers (Granite-4.0-H's widths) ------------------------------
+
+#: a Mamba-2 mixer of Granite-4.0-H: 128 heads of 64, a state of 128, the
+#: convolved channels x | B | C
+SSM_H, SSM_P, SSM_N, SSM_C = 128, 64, 128, 128 * 64 + 2 * 128
+
+
+def _ssm_params(s):
+    return (s((4, SSM_C), jnp.bfloat16), s((SSM_C,), jnp.float32),
+            s((SSM_H,), jnp.float32), s((SSM_H,), jnp.float32),
+            s((SSM_H,), jnp.float32))
+
+
+def test_ssm_decode_step_granite_widths(one_chip, as_tpu):
+    """The decode step's state update over 64 slots of 4 MB float32 states:
+    one kernel, the state and the window aliased in place, nothing of the
+    state copied."""
+    import re
+    from distkeras_tpu.ops import ssm_kernels
+    s, slots = _spec(one_chip), 64
+    fn = lambda xbc, dt, win, st, *rest: ssm_kernels.ssm_decode_step(
+        xbc, dt, win, st, *rest, heads=SSM_H, d_state=SSM_N, live_len=4096,
+        kernel=True)
+    args = (s((slots, SSM_C), jnp.bfloat16), s((slots, SSM_H), jnp.bfloat16),
+            s((slots, 3, SSM_C), jnp.bfloat16),
+            s((slots, SSM_H, SSM_P, SSM_N), jnp.float32), *_ssm_params(s),
+            s((slots,), jnp.int32))
+    text = jax.jit(fn, donate_argnums=(2, 3)).lower(*args).compile() \
+        .as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 1
+    assert "ssm_decode_step" in text
+    assert text.split("\n", 1)[0].count("may-alias") == 2
+    plane = slots * SSM_H * SSM_P * SSM_N
+    moved = [line for line in text.splitlines()
+             if (dims := re.search(r"= \w+\[([\d,]+)\]\S* copy\(", line))
+             and int(np.prod(list(map(int, dims.group(1).split(","))))) == plane]
+    assert not moved
+
+
+@pytest.mark.parametrize("positions", [128, 1024])
+def test_ssd_chunk_scan_granite_widths(one_chip, as_tpu, positions):
+    """The prefill scan over a chunk of 1,024 positions (four SSD chunks of
+    256) and over a short prompt padded to one, from a carried state."""
+    from distkeras_tpu.ops import ssm_kernels
+    s = _spec(one_chip)
+    fn = lambda xbc, dt, win, st, *rest: ssm_kernels.ssd_chunk_scan(
+        xbc, dt, win, st, *rest, heads=SSM_H, d_state=SSM_N, kernel=True)
+    n, text = _compile(fn, s((1, positions, SSM_C), jnp.bfloat16),
+                       s((1, positions, SSM_H), jnp.bfloat16),
+                       s((1, 3, SSM_C), jnp.bfloat16),
+                       s((1, SSM_H, SSM_P, SSM_N), jnp.float32),
+                       *_ssm_params(s))
+    assert n == 1 and "ssd_chunk_scan" in text
+
+
+def _granite_module(kinds):
+    from distkeras_tpu.models import zoo
+    mamba = {"num_heads": SSM_H, "head_dim": SSM_P, "d_state": SSM_N,
+             "d_conv": 4, "n_groups": 1, "chunk": 256}
+    return zoo.transformer_lm(
+        50176, d_model=4096, num_heads=32, num_kv_heads=8, head_dim=128,
+        num_layers=len(kinds), max_len=4096, norm_eps=1e-5,
+        dtype="bfloat16", layer_types=kinds,
+        attn_kinds={"mamba": {"mamba": mamba},
+                    "attention": {"use_rope": False,
+                                  "attn_scale": 0.0078125}},
+        mlp_layer_types=["sparse"] * len(kinds), mlp_dim=768,
+        mlp_activation="silu", mlp_gated=True, mlp_bias=False,
+        num_experts=72, moe_top_k=10, moe_dispatch="grouped",
+        moe_shared_dim=1536, moe_experts_held=(0, 36),
+        embedding_multiplier=12.0, residual_multiplier=0.22,
+        logits_scaling=16.0, tie_embeddings=True)
+
+
+def test_decode_step_over_a_state_group(one_chip, as_tpu):
+    """One decode step of a mamba and an attention layer at Granite's
+    widths, 64 slots, over a donated pool with a state group beside the
+    attention layer's pages: the state kernel, the paged kernel and two
+    grouped expert kernels, every plane aliased and no state plane copied."""
+    import re
+    from distkeras_tpu.models.decoding import (decode_step_slots_paged,
+                                               init_cache)
+    module = _granite_module(["mamba", "attention"])
+    params, state = jax.eval_shape(
+        lambda k: module.init(k, (16,))[:2], jax.random.PRNGKey(0))
+    slots, page_len, pages, s = 64, 128, 2048, _spec(one_chip)
+    probe = jax.eval_shape(lambda: init_cache(
+        module, pages, page_len, jnp.bfloat16, check_len=4096,
+        state_batch=slots))
+    cache = [None if kv is None else
+             {k: s(a.shape, a.dtype) for k, a in kv.items()} for kv in probe]
+    groups = (None, None, ("ssm", None), (0, None), None, None)
+
+    def step(params, state, cache, tok, t, tables):
+        logits, cache, moe = decode_step_slots_paged(
+            module, params, state, cache, tok, t, tables, page_len,
+            moe_stats=4096, groups=groups, live_len=4096)
+        return jnp.argmax(logits, -1), cache, moe["routed"]
+
+    per_slot = s((slots,), jnp.int32)
+    text = jax.jit(step, donate_argnums=2).lower(
+        _on_chip(params, s, jnp.bfloat16), _on_chip(state, s), cache,
+        per_slot, per_slot, (s((slots, 32), jnp.int32),)).compile().as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 4
+    assert "ssm_decode_step" in text
+    leaves = jax.tree_util.tree_leaves(cache)
+    assert text.split("\n", 1)[0].count("may-alias") == len(leaves)
+    plane = slots * SSM_H * SSM_P * SSM_N
+    moved = [line.strip()[:160] for line in text.splitlines()
+             if (dims := re.search(r"= \w+\[([\d,]+)\]\S* copy\(", line))
+             and int(np.prod(list(map(int, dims.group(1).split(","))))) == plane]
+    assert not moved, moved[:2]
+
+
+@pytest.mark.parametrize("t0,final,kernels", [(0, False, 2), (1024, True, 5)],
+                         ids=["first_chunk", "second_chunk"])
+def test_prefill_chunk_carries_a_state(one_chip, as_tpu, t0, final,
+                                       kernels):
+    """A prefill chunk of 1,024 through a mamba and an attention layer at
+    Granite's widths against the batch-1 staging cache. The first of two
+    chunks: the scan from zero and the mamba layer's experts, the
+    attention layer (the last block) only writing its K/V. The second
+    and final: the scan from the carried state, flash on the chunk and
+    over the prefix, both expert layers."""
+    from distkeras_tpu.models.decoding import (init_cache,
+                                               prefill_chunk_step,
+                                               routing_counts)
+    module = _granite_module(["mamba", "attention"])
+    params, state = jax.eval_shape(
+        lambda k: module.init(k, (16,))[:2], jax.random.PRNGKey(0))
+    s = _spec(one_chip)
+    cache = _on_chip(jax.eval_shape(
+        lambda: init_cache(module, 1, 4096, jnp.bfloat16)), s)
+
+    def chunk(params, state, cache, toks):
+        routing = []
+        logits, cache = prefill_chunk_step(module, params, state, cache,
+                                           toks, t0, final=final,
+                                           routing=routing)
+        return logits, cache, routing_counts(routing)
+
+    text = jax.jit(chunk, donate_argnums=2).lower(
+        _on_chip(params, s, jnp.bfloat16), _on_chip(state, s), cache,
+        s((1, 1024), jnp.int32)).compile().as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == kernels
+    assert "ssd_chunk_scan" in text
